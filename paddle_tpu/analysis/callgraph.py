@@ -7,7 +7,7 @@ entries the framework actually uses:
 * ``instrumented_jit(fn, name, ...)`` (`jit/functional.py`) and plain
   ``jax.jit(fn, ...)``
 * ``parallel.shard_map(body, mesh=..., in_specs=..., out_specs=...)``
-  (the 0.4.x compat shim) and ``jax.experimental.shard_map.shard_map``
+  (the package's one door to ``jax.shard_map``) and ``jax.shard_map``
 * ``jax.lax.scan(body, ...)`` bodies
 
 The function argument is resolved through the package's real idioms:
@@ -55,7 +55,7 @@ TRACE_ENTRIES = {
 #: imported-module targets that count for the bare ``shard_map`` /
 #: ``lax.scan`` suffixes (a user-defined shard_map in some unrelated
 #: module must not create trace roots)
-_SHARD_MAP_HOMES = ("parallel", "jax.experimental.shard_map", "jax")
+_SHARD_MAP_HOMES = ("parallel", "jax")
 _SCAN_HOMES = ("jax.lax", "jax")
 
 

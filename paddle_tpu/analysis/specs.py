@@ -7,9 +7,11 @@ trailing-None pool specs, PR 10 EP-mesh ``P()`` collapse): the jit
 cache keys on *input shardings*, and two placement-IDENTICAL specs
 written differently — ``P('a')`` vs ``P('a', None)``, or
 ``P(None, None, None, 'mp')`` on a size-1 ``mp`` axis vs ``P()`` —
-are DIFFERENT cache keys (verified on this container's jax 0.4.37:
-feeding a ``device_put`` placed with one form into a jit whose
-previous call saw the other form compiles a second executable).
+are DIFFERENT cache keys. (On the installed jax 0.9.0 the second
+form is a new entry in jit's dispatch cache and a trip through the
+slow dispatch path; it no longer builds a second executable — checked
+with ``instrumented_jit``'s compile counter. Older jax compiled
+twice, which is how the lesson was learned.)
 Whenever a step's output arrays are fed back as the next call's
 inputs, the initial ``device_put`` spec and the step's out-spec must
 therefore be written in one agreed normal form, or step 2 silently

@@ -50,12 +50,34 @@ class RNGState:
         return sub
 
 
-_stack = [RNGState(make_key(0))]
+class _SeededState(RNGState):
+    """Base state that builds its key from the seed on first use.
+    Building a key initialises the jax backend, which claims the chip:
+    `import paddle_tpu` and `paddle.seed()` must not do that, or a
+    supervisor that only imports the package starves the worker it
+    spawns."""
+
+    def __init__(self, s: int):
+        self._seed = int(s)
+        self._key = None
+
+    @property
+    def key(self):
+        if self._key is None:
+            self._key = make_key(self._seed)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
+
+
+_stack = [_SeededState(0)]
 
 
 def seed(s: int):
     """paddle.seed parity."""
-    _stack[0] = RNGState(make_key(int(s)))
+    _stack[0] = _SeededState(s)
     return _stack[0]
 
 
@@ -96,7 +118,7 @@ class RNGStatesTracker:
     def add(self, name, s):
         if name in self.states_:
             raise ValueError(f"state {name} already exists")
-        self.states_[name] = RNGState(make_key(int(s)))
+        self.states_[name] = _SeededState(s)
 
     def reset(self):
         self.states_ = {}

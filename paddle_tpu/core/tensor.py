@@ -101,13 +101,10 @@ class Tensor:
 
     @property
     def place(self):
-        try:
-            dev = list(self._data.devices())[0]
-            plat = place_mod._platform_of(dev)
-        except Exception:
-            plat = "cpu"
-        cls = place_mod.TPUPlace if plat == "tpu" else place_mod.CPUPlace
-        return cls(getattr(dev, "id", 0))
+        dev = next(iter(self._data.devices()))
+        cls = (place_mod.TPUPlace if dev.platform == "tpu"
+               else place_mod.CPUPlace)
+        return cls(dev.id)
 
     @property
     def is_leaf(self):

@@ -340,7 +340,8 @@ def create_serving_engine(config: Config, model, sampling=None, seed=0,
 def create_serving_router(config: Config, model, sampling=None, seed=0):
     """Build the multi-replica serving stack: `num_replicas` engines
     (tensor-parallel when `tensor_parallel > 1`; replica r takes the
-    next `tp` local devices, wrapping around) each behind a
+    next `tp` local devices — one device at tp=1 — wrapping around)
+    each behind a
     `ServingFrontend`, fronted by a prefix-affinity
     `serving.distributed.ReplicaRouter`. `async with router:` starts
     every replica's step loop plus the health prober;
@@ -372,14 +373,13 @@ def create_serving_router(config: Config, model, sampling=None, seed=0):
             raise ValueError(f"num_replicas must be >= 1, got {n}")
     from .serving.distributed.router import ReplicaRouter
     from .serving.frontend import ServingFrontend
+    import jax
     tp = int(config._tensor_parallel or 1)
     ep = int(config._expert_parallel or 1)
+    devices = jax.devices()
     meshes = [None] * n
     if tp > 1 or ep > 1:
-        import jax
-
         from .parallel.mp_layers import tp_ep_mesh, tp_mesh
-        devices = jax.devices()
         world = tp * ep
         picks = [[devices[(r * world + i) % len(devices)]
                   for i in range(world)] for r in range(n)]
@@ -396,6 +396,14 @@ def create_serving_router(config: Config, model, sampling=None, seed=0):
         fkw["max_pending"] = int(config._max_pending)
 
     def _overrides(r):
+        ov = _role_overrides(r)
+        if meshes[r] is None:
+            # a one-chip replica lives on ITS chip: replica r takes
+            # local device r (wrapping around), like the tp picks above
+            ov["device"] = devices[r % len(devices)]
+        return ov
+
+    def _role_overrides(r):
         if roles is None:
             return {}
         if roles[r] == "prefill":
@@ -465,8 +473,9 @@ def create_fleet_controller(config: Config, model, sampling=None,
 
     `bundle` names an existing bundle directory (or passes a loaded
     `FleetBundle`); otherwise, with `export=True`, a bundle for
-    `version` is exported under `bundle_root` (default: next to the
-    persistent kernel-autotune cache) from replica 0's engine.
+    `version` is exported under `bundle_root` (required then: a bundle
+    is a deployment artifact, the caller says where it goes) from
+    replica 0's engine.
     Returns `(router, controller)` — boot the fleet with
     `async with router:`, then drive `controller.boot_replica()` /
     `rolling_upgrade()` / an attached `SLOAutoscaler`
@@ -476,6 +485,10 @@ def create_fleet_controller(config: Config, model, sampling=None,
     router = create_serving_router(config, model, sampling=sampling,
                                    seed=seed)
     if bundle is None and export:
+        if bundle_root is None:
+            raise ValueError(
+                "create_fleet_controller(export=True) needs bundle_root "
+                "(or pass bundle=, or export=False)")
         bdir = export_bundle(router.frontends[0].engine,
                              bundle_root, version=str(version),
                              seed=seed)

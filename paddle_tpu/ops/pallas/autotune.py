@@ -44,9 +44,10 @@ Env contract:
   registered search for that kernel (bounded by its time budget) and
   persists the winner: the re-tune-on-new-hardware path
   (docs/KERNELS.md).
-* ``PADDLE_TPU_KERNEL_CACHE=<path>`` — the writable cache location
-  (default ``~/.cache/paddle_tpu/kernel_autotune.json``); the seeded
-  package cache stays read-only underneath it.
+* ``PADDLE_TPU_KERNEL_CACHE=<path>`` — a writable overlay over the
+  seeded package cache, read and written only when the variable names
+  it; unset, tuned winners live in-process and the committed seed file
+  alone decides what a fresh process compiles.
 
 Alignment single source of truth: `paged_alignment_ok` below is THE
 definition of the paged kernels' shape constraints. The dispatch gate
@@ -65,8 +66,12 @@ import numpy as np
 # ---------------------------------------------------------------------
 # alignment constraints — ONE source of truth for the dispatch gate
 # (paged_attention.paged_pallas_enabled) AND every tuner candidate
-# filter. head_dim rides the 128-wide lane axis of the KV tiles,
-# block_size the 8-deep sublane axis.
+# filter. In the [BS, H, Dh] KV tile head_dim rides the 128-wide lane
+# axis and H the sublane axis; block_size is a leading axis. Mosaic
+# pads H up to the dtype's sublane count (8 fp32, 16 bf16, 32 int8/fp8):
+# H=2 at every pool dtype and H=16 in fp32/bf16 match their oracles on
+# a v5e (PR 21), so H is not gated. The block_size multiple is kept as
+# the tuner's candidate discipline, not because Mosaic needs it.
 # ---------------------------------------------------------------------
 
 LANE_ALIGN = 128
@@ -110,16 +115,12 @@ def backend_key() -> str:
     CPU interpret-mode numbers the CI cache ships). The CPU backend
     drops the count: `--xla_force_host_platform_device_count` is a
     test-harness knob, not a topology."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", dev.platform) or dev.platform
-        kind = "".join(c if c.isalnum() else "-" for c in str(kind))
-        if dev.platform == "cpu":
-            return f"cpu-{kind}"
-        return f"{dev.platform}-{kind}-d{jax.device_count()}"
-    except Exception:  # noqa: BLE001 — no backend: key must still form
-        return "none"
+    import jax
+    dev = jax.devices()[0]
+    kind = "".join(c if c.isalnum() else "-" for c in dev.device_kind)
+    if dev.platform == "cpu":
+        return f"cpu-{kind}"
+    return f"{dev.platform}-{kind}-d{jax.device_count()}"
 
 
 def _pow2_bucket(n, lo=1):
@@ -156,12 +157,12 @@ _MEMO = {}               # key -> config (the one-dict-lookup hot path)
 _REQUESTED = {}          # key -> bool hit (audit + stale detection)
 
 
-def user_cache_path() -> str:
-    p = os.environ.get("PADDLE_TPU_KERNEL_CACHE", "")
-    if p:
-        return p
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "paddle_tpu", "kernel_autotune.json")
+def user_cache_path() -> str | None:
+    """The writable overlay, if `PADDLE_TPU_KERNEL_CACHE` names one.
+    There is no default location: without the variable, what gets
+    compiled depends on the committed seed file alone — never on a
+    file outside the tree that a fresh checkout would not have."""
+    return os.environ.get("PADDLE_TPU_KERNEL_CACHE") or None
 
 
 def _read_json(path):
@@ -174,18 +175,23 @@ def _read_json(path):
 
 
 def load_cache(refresh=False) -> dict:
-    """The merged cache (seeded package entries under the user
-    overlay). Loaded once per process; `refresh=True` re-reads disk."""
+    """The merged cache (seeded package entries under the overlay,
+    when one is named). Loaded once per process; `refresh=True`
+    re-reads disk."""
     global _CACHE
     if _CACHE is None or refresh:
         _CACHE = _read_json(_SEED_CACHE_FILE)
-        _CACHE.update(_read_json(user_cache_path()))
+        overlay = user_cache_path()
+        if overlay is not None:
+            _CACHE.update(_read_json(overlay))
         _MEMO.clear()
     return _CACHE
 
 
 def _persist(key, entry):
     path = user_cache_path()
+    if path is None:
+        return False        # no overlay named: the winner lives in-process
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         user = _read_json(path)
@@ -367,14 +373,11 @@ def search(kernel, bucket, dtype, candidates, build, args, oracle,
         else:
             fn, cand_args = built, args
         cand_ref = ref if cand_args is args else oracle(*cand_args)
-        try:
-            out = fn(*cand_args)
-        except Exception:  # noqa: BLE001 — an untileable candidate is
-            # a rejection, not a search abort
-            rejected += 1
-            if pm._enabled:
-                pm.KERNEL_AUTOTUNE_REJECTED_PARITY.labels(kernel).inc()
-            continue
+        # a candidate that fails to build or compile RAISES out of the
+        # search: the spaces above only emit tiles that divide their
+        # axes, so an exception here is a kernel or compiler fault, and
+        # counting it as a parity rejection would hide it
+        out = fn(*cand_args)
         if not _parity_ok(out, cand_ref, rtol, atol):
             rejected += 1
             if pm._enabled:
@@ -461,11 +464,10 @@ def ensure(kernel, bucket, dtype, default, searcher=None,
             searcher = _default_searcher(kernel, bucket, dtype,
                                          budget_s)
         if searcher is not None:
-            try:
-                return dict(searcher().config)
-            except Exception:  # noqa: BLE001 — tuning must degrade to
-                # the hand-picked default, never take serving down
-                return default
+            # the operator asked for a search: one that cannot build,
+            # compile or pass parity raises instead of quietly serving
+            # the default
+            return dict(searcher().config)
     return default
 
 

@@ -11,9 +11,9 @@ Two tiers:
   kernel (fwd + fused dkv/dq backward, causal block-skipping), tuned
   block sizes for v5e. Trace-measured 2.1x faster fwd+bwd than XLA's
   fused attention at [32,16,1024,64] and the engine behind the GPT
-  training headline (see docs/gpt_perf_analysis.md). Falls back to
-  XLA's `jax.nn.dot_product_attention` off-TPU (the CPU test mesh) or
-  for shapes the kernel doesn't tile.
+  training headline (see docs/gpt_perf_analysis.md). Off-TPU (the CPU
+  test mesh) and for shapes `splash_supported` refuses it runs XLA's
+  `jax.nn.dot_product_attention`; on a TPU that refusal warns.
 * `flash_attention` — the hand-written educational fwd kernel kept for
   the paddle [B, S, H, D] API surface; backward recomputes in XLA.
 """
@@ -37,9 +37,6 @@ DEFAULT_BLOCK_K = 256
 
 _SPLASH_CACHE = {}
 
-# (seq_len, head_dim) combos the installed kernel refused at trace time
-_SPLASH_REFUSED = set()
-
 # Set by tests to run the splash kernel in Pallas interpret mode on the
 # CPU mesh (exercises the real mask/segment plumbing without a TPU).
 _INTERPRET = False
@@ -52,48 +49,12 @@ def _on_tpu_backend() -> bool:
     return on_tpu_backend()
 
 
-_SPLASH_DIM_QUANTUM = None
-
-
-def splash_head_dim_quantum() -> int:
-    """head_dim multiple the INSTALLED splash kernel accepts.
-
-    jax 0.4.x's kernel refuses head_dim % 128 != 0 at trace time
-    ("head_dim=64 should be a multiple of 128") where newer kernels
-    pad 64-multiples — probed ONCE by `jax.eval_shape`-tracing a
-    minimal kernel at head_dim 64 (abstract eval only: no device work,
-    no compile), so `splash_supported` can gate unsupported shapes to
-    the XLA path at the callsite instead of relying on the
-    trace-and-refuse `_SPLASH_REFUSED` machinery below (which stays as
-    the belt-and-braces net for refusals this probe can't predict)."""
-    global _SPLASH_DIM_QUANTUM
-    if _SPLASH_DIM_QUANTUM is None:
-        try:
-            from jax.experimental.pallas.ops.tpu.splash_attention import (
-                splash_attention_kernel as sk,
-                splash_attention_mask as smask)
-            mask = smask.MultiHeadMask([smask.CausalMask((128, 128))])
-            kern = jax.vmap(sk.make_splash_mha(
-                mask, head_shards=1, q_seq_shards=1, interpret=True))
-            probe = jax.ShapeDtypeStruct((1, 1, 128, 64), jnp.float32)
-            jax.eval_shape(kern, probe, probe, probe)
-            _SPLASH_DIM_QUANTUM = 64
-        except Exception:  # noqa: BLE001 — the gate must never raise:
-            # NotImplementedError is the known 0.4.x refusal, but a
-            # moved module path (ImportError) or a different refusal
-            # type must also degrade to "128-multiples only", keeping
-            # splash_supported a pure fallback decision.
-            _SPLASH_DIM_QUANTUM = 128
-    return _SPLASH_DIM_QUANTUM
-
-
 def splash_supported(seq_len: int, head_dim: int) -> bool:
-    """Static gate for the splash kernel: lane-aligned sequence and a
-    head_dim the installed kernel actually tiles (64-multiples only
-    where the kernel pads them — jax 0.4.x wants 128)."""
+    """Static gate for the splash kernel: a TPU backend (or the tests'
+    interpret mode), a lane-aligned sequence and a head_dim the kernel
+    tiles (multiples of 64; it pads them to the 128 lanes)."""
     return (_on_tpu_backend() and seq_len % 128 == 0
-            and head_dim % splash_head_dim_quantum() == 0
-            and seq_len >= 128)
+            and head_dim % 64 == 0 and seq_len >= 128)
 
 
 def _splash_kernel(n_heads: int, seq_len: int, causal: bool,
@@ -153,9 +114,16 @@ def _splash_kernel(n_heads: int, seq_len: int, causal: bool,
         m = (smask.CausalMask((seq_len, seq_len)) if causal
              else smask.FullMask((seq_len, seq_len)))
         mask = smask.MultiHeadMask([m] * n_heads)
-        kern = sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                                  block_sizes=bs, interpret=_INTERPRET,
-                                  residual_checkpoint_name=residual_ckpt)
+        # the kernel object holds its mask tables as jnp arrays. This
+        # runs inside whatever jit is tracing the caller, where jnp
+        # constants are tracers — and the object is CACHED, so the next
+        # jit to use it would meet another trace's tracers
+        # (UnexpectedTracerError). Build it with concrete arrays.
+        with jax.ensure_compile_time_eval():
+            kern = sk.make_splash_mha(
+                mask, head_shards=1, q_seq_shards=1, block_sizes=bs,
+                interpret=_INTERPRET,
+                residual_checkpoint_name=residual_ckpt)
         if segmented:
             _SPLASH_CACHE[key] = jax.vmap(
                 lambda q, k, v, seg: kern(q, k, v, segment_ids=seg))
@@ -202,27 +170,25 @@ def splash_mha(q, k, v, *, causal=True, scale=None, kv_keep=None,
             f"got q H={h}, k H={k.shape[1]}, v H={v.shape[1]}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if splash_supported(s, d) and (s, d) not in _SPLASH_REFUSED:
-        try:
-            qs = (q * scale).astype(q.dtype)
-            rc = SPLASH_RESIDUAL_NAME if save_residuals_for_remat \
-                else None
-            if kv_keep is not None:
-                from jax.experimental.pallas.ops.tpu.splash_attention \
-                    import splash_attention_kernel as sk
-                seg = kv_keep.astype(jnp.int32)
-                kern = _splash_kernel(h, s, causal, segmented=True,
-                                      residual_ckpt=rc,
-                                      dtype=str(q.dtype), head_dim=d)
-                return kern(qs, k, v, sk.SegmentIds(q=seg, kv=seg))
-            kern = _splash_kernel(h, s, causal, residual_ckpt=rc,
+    if splash_supported(s, d):
+        # a shape the gate accepts either runs the kernel or raises:
+        # a trace-time refusal is a bug in the gate, not a fallback
+        qs = (q * scale).astype(q.dtype)
+        rc = SPLASH_RESIDUAL_NAME if save_residuals_for_remat else None
+        if kv_keep is not None:
+            from jax.experimental.pallas.ops.tpu.splash_attention \
+                import splash_attention_kernel as sk
+            seg = kv_keep.astype(jnp.int32)
+            kern = _splash_kernel(h, s, causal, segmented=True,
+                                  residual_ckpt=rc,
                                   dtype=str(q.dtype), head_dim=d)
-            return kern(qs, k, v)
-        except NotImplementedError:
-            # the installed kernel refused the shape at trace time
-            # (e.g. jax 0.4.x tiles head_dim by 128 where newer
-            # kernels pad 64) — remember and take the XLA path
-            _SPLASH_REFUSED.add((s, d))
+            return kern(qs, k, v, sk.SegmentIds(q=seg, kv=seg))
+        kern = _splash_kernel(h, s, causal, residual_ckpt=rc,
+                              dtype=str(q.dtype), head_dim=d)
+        return kern(qs, k, v)
+    from . import xla_fallback
+    xla_fallback("splash_mha", f"the gate refuses S={s}, head_dim={d} "
+                 "(needs S % 128 == 0 and head_dim % 64 == 0)")
     mask = None
     if kv_keep is not None:
         mask = (kv_keep != 0)[:, None, None, :]  # [B, 1, 1(q), S]
@@ -294,7 +260,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        interpret=_INTERPRET,
+        interpret=_INTERPRET, name="flash_fwd",
     )(q, k, v)
 
 
@@ -517,8 +483,21 @@ def _check_pool_heads(name, h_q, k_pool, v_pool):
 
 
 def _paged_kernel_enabled(head_dim, block_size):
+    """True -> the block-table-native kernel runs (and a Mosaic refusal
+    raises); False -> the gather reference, said out loud when that
+    happens on a TPU for any reason but the operator's kill-switch."""
     from . import paged_attention as _pk
-    return _pk.paged_pallas_enabled(head_dim, block_size)
+    if _pk.paged_pallas_enabled(head_dim, block_size):
+        return True
+    if not _pk.pallas_killed():
+        from . import xla_fallback
+        xla_fallback("paged_attention",
+                     f"the gate refuses head_dim={head_dim}, "
+                     f"block_size={block_size} (needs head_dim % 128 "
+                     "== 0 and block_size % 8 == 0); the gather "
+                     "reference materialises every slot's whole "
+                     "context per query")
+    return False
 
 
 def _gather_dequant(pool, scale_pool, bt, q_dtype):

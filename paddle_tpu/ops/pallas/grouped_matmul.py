@@ -73,8 +73,13 @@ def grouped_matmul_enabled(d_in, d_out) -> bool:
         return False
     if _INTERPRET:
         return True
-    return (_on_tpu_backend() and d_in % autotune.LANE_ALIGN == 0
-            and d_out % autotune.LANE_ALIGN == 0)
+    if d_in % autotune.LANE_ALIGN == 0 and d_out % autotune.LANE_ALIGN == 0:
+        return _on_tpu_backend()
+    from . import xla_fallback
+    xla_fallback("grouped_expert_matmul",
+                 f"the gate refuses d_in={d_in}, d_out={d_out} (both "
+                 f"must be multiples of {autotune.LANE_ALIGN})")
+    return False
 
 
 # ---------------------------------------------------------------------
@@ -106,9 +111,12 @@ def unpack_int4(packed, axis=-2):
     round trip is exact over [-8, 7]). Pure vector ops, so the grouped
     kernel unpacks its weight tile with the same function."""
     axis = axis % packed.ndim
-    low = (packed << 4) >> 4
-    high = packed >> 4
-    out = jnp.stack([low, high], axis=axis + 1)
+    # shift in int32: Mosaic has no 8-bit vector shifts on v5e ("failed
+    # to legalize operation 'arith.shli' ... vector<..xi8>")
+    p32 = packed.astype(jnp.int32)
+    low = (p32 << 28) >> 28
+    high = p32 >> 4
+    out = jnp.stack([low, high], axis=axis + 1).astype(jnp.int8)
     shape = list(packed.shape)
     shape[axis] *= 2
     return out.reshape(shape)
@@ -160,7 +168,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd, qmax):
     """One (expert, c-tile, f-tile, d-tile) grid cell.
 
     x tile [1, bc, bd]; w tile [1, bd, bf] (int8 when quantized);
-    optional scale tile [1, bf] fp32; out tile [1, bc, bf]; fp32
+    optional scale tile [1, 1, bf] fp32; out tile [1, bc, bf]; fp32
     accumulator scratch [bc, bf] carried across the d axis."""
     d = pl.program_id(3)
 
@@ -188,7 +196,7 @@ def _gmm_kernel_quant(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nd, qmax):
     # weight-only dequant fused at the tile load: int8 tile * per-
     # out-channel scale/qmax (same formula as fused_transformer._deq)
     w = w_ref[0].astype(jnp.float32) \
-        * (s_ref[0].astype(jnp.float32) / qmax)[None, :]
+        * (s_ref[0].astype(jnp.float32) / qmax)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[0].astype(jnp.float32), w,
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -213,7 +221,7 @@ def _gmm_kernel_quant4(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nd,
 
     w4 = unpack_int4(w_ref[0], axis=0)               # [bd, bf] int4
     w = w4.astype(jnp.float32) \
-        * (s_ref[0].astype(jnp.float32) / qmax)[None, :]
+        * (s_ref[0].astype(jnp.float32) / qmax)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[0].astype(jnp.float32), w,
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -255,8 +263,16 @@ def _gmm_call(x, w, scale, qmax, bc, bf, bd, out_dtype):
     ]
     args = [x, w]
     if scale is not None:
-        in_specs.append(pl.BlockSpec((1, bf), lambda e, c, f, d: (e, f)))
-        args.append(scale)
+        # [E, F] -> [E, 1, F]: Mosaic wants a block's second-minor dim
+        # to be a multiple of 8 or the whole axis, and a one-expert row
+        # of a 2-D [E, F] array is neither
+        in_specs.append(pl.BlockSpec((1, 1, bf),
+                                     lambda e, c, f, d: (e, 0, f)))
+        # ... and fp32: the int4 path stores fp16 scales, which v5e
+        # cannot load as a vector ("Invalid vector type for load ...
+        # vector<..xf16>"); widening [E, F] here costs nothing next to
+        # the weight read
+        args.append(scale.astype(jnp.float32).reshape(E, 1, F))
         kernel = functools.partial(
             _gmm_kernel_quant4 if int4 else _gmm_kernel_quant, nd=nd,
             qmax=float(qmax))
@@ -269,7 +285,7 @@ def _gmm_call(x, w, scale, qmax, bc, bf, bd, out_dtype):
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, c, f, d: (e, c, f)),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((E, C, F), out_dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -278,7 +294,7 @@ def _gmm_call(x, w, scale, qmax, bc, bf, bd, out_dtype):
                             + w.size * w.dtype.itemsize
                             + E * C * F * jnp.dtype(out_dtype).itemsize),
             transcendentals=0),
-        interpret=_INTERPRET,
+        interpret=_INTERPRET, name="grouped_matmul",
     )(*args)
 
 

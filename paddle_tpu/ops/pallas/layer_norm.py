@@ -12,7 +12,8 @@ XLA (they fuse into a single f32[d] pass).
 API (used by parallel/hybrid_gpt.py when enabled):
     add_ln(x, r, w, b, eps)     -> (normalized, z=x+r)   (z is the new
                                    residual stream)
-Falls back to plain jnp math off-TPU or for non-tileable shapes.
+Plain jnp math off-TPU and for non-tileable shapes (on a TPU the
+latter warns).
 `_INTERPRET` runs the kernels in pallas interpret mode (CPU tests).
 """
 from __future__ import annotations
@@ -90,7 +91,7 @@ def _run_fwd(x2, r2, w, b, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=_INTERPRET, name="add_ln_fwd",
     )(x2, r2, w.reshape(1, d), b.reshape(1, d))
     return out, z, mu, rs
 
@@ -112,7 +113,7 @@ def _run_bwd_dz(z2, w, mu, rs, g2, eps):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), g2.dtype),
-        interpret=_INTERPRET,
+        interpret=_INTERPRET, name="add_ln_bwd",
     )(z2, w.reshape(1, d), mu, rs, g2)
 
 
@@ -159,18 +160,29 @@ def _add_ln_bwd(eps, res, cts):
 _add_ln.defvjp(_add_ln_fwd, _add_ln_bwd)
 
 
-def add_ln(x, r, w, b, eps=1e-5):
-    """(LN(x + r) * w + b, x + r) — fused on TPU, jnp fallback off-TPU
-    or when rows/features don't tile (rows % 256, d % 128)."""
-    import math as _math
-    n_rows = _math.prod(x.shape[:-1])
-    if (_on_tpu() or _INTERPRET) and x.shape[-1] % 128 == 0 \
-            and n_rows % _BLOCK_ROWS == 0:
-        return _add_ln(x, r, w.astype(jnp.float32),
-                       b.astype(jnp.float32), eps)
+def add_ln_reference(x, r, w, b, eps=1e-5):
+    """The plain jnp form of `add_ln`: the off-TPU path and the oracle
+    the kernel is checked against."""
     z = x + r
     zf = z.astype(jnp.float32)
     mu = jnp.mean(zf, axis=-1, keepdims=True)
     var = jnp.var(zf, axis=-1, keepdims=True)
     out = ((zf - mu) / jnp.sqrt(var + eps) * w + b).astype(x.dtype)
     return out, z
+
+
+def add_ln(x, r, w, b, eps=1e-5):
+    """(LN(x + r) * w + b, x + r) — fused on TPU; `add_ln_reference`
+    off-TPU or when rows/features don't tile (rows % 256, d % 128)."""
+    import math as _math
+    n_rows = _math.prod(x.shape[:-1])
+    if x.shape[-1] % 128 == 0 and n_rows % _BLOCK_ROWS == 0:
+        if _on_tpu() or _INTERPRET:
+            return _add_ln(x, r, w.astype(jnp.float32),
+                           b.astype(jnp.float32), eps)
+    else:
+        from . import xla_fallback
+        xla_fallback("add_ln", f"the gate refuses {n_rows} rows x "
+                     f"{x.shape[-1]} features (needs rows % "
+                     f"{_BLOCK_ROWS} == 0 and features % 128 == 0)")
+    return add_ln_reference(x, r, w, b, eps)

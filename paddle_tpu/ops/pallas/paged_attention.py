@@ -202,7 +202,7 @@ def _paged_attend_grouped(q, k_pool, v_pool, block_tables, slot_ids,
     dim_sem = tuned.get("dimension_semantics")
     compiler_params = None
     if dim_sem is not None:
-        compiler_params = pltpu.TPUCompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=tuple(dim_sem))
     qs = (q.astype(jnp.float32) * scale).astype(
         q.dtype if q.dtype != jnp.float64 else jnp.float32)
@@ -243,7 +243,7 @@ def _paged_attend_grouped(q, k_pool, v_pool, block_tables, slot_ids,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, G, H, Dh), q.dtype),
-        interpret=_INTERPRET, **extra,
+        interpret=_INTERPRET, name=kernel_name, **extra,
         cost_estimate=pl.CostEstimate(
             flops=4 * N * G * H * Dh * MB * BS,
             bytes_accessed=(2 * N * MB * BS * H * Dh

@@ -6,113 +6,13 @@ import jax as _jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """`jax.shard_map` compat shim.
-
-    jax >= 0.5 exposes `jax.shard_map(..., check_vma=...)`; on the 0.4.x
-    line the same machinery lives at
-    `jax.experimental.shard_map.shard_map(..., check_rep=...)`. Every
-    manual-collective module in this package goes through this one
-    helper so the framework runs on both. Defined before the submodule
-    imports below so `from . import shard_map` works during package
-    init."""
-    native = getattr(_jax, "shard_map", None)
-    if native is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return native(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _esm
-    if check_vma is None or check_vma:
-        if check_vma:
-            kw["check_rep"] = True
-        return _esm(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, **kw)
-    # check_vma=False path. 0.4.x's shard_map is broken when DIFFERENTIATED
-    # with the check disabled (its partial-eval/transpose machinery trips a
-    # _SpecError on scalar residuals), and check_rep=True rejects the
-    # lax.cond bodies these callers run — which is why they disable the
-    # check in the first place. Forward-only works fine, so: wrap the
-    # forward shard_map in a custom_vjp whose backward runs jax.vjp of the
-    # body INSIDE a second shard_map (recompute-style), reproducing the
-    # non-rewrite transpose semantics by hand — cotangents of outputs
-    # replicated over unmentioned mesh axes are pre-divided by the axis
-    # product, and input cotangents are psum'ed over their spec's
-    # unmentioned axes. The old primitive is never transposed.
-    import numpy as _np
-
-    import jax.numpy as _jnp
-    from jax.dtypes import float0 as _float0
-    from jax.sharding import PartitionSpec as _P
-    try:
-        from jax._src.tree_util import broadcast_prefix as _bprefix
-    except ImportError:  # same helper, re-exported
-        from jax.experimental.shard_map import broadcast_prefix as _bprefix
-
-    _is_spec = lambda s: isinstance(s, _P)
-    axis_sizes = dict(mesh.shape)
-
-    def _mentioned(spec):
-        names = set()
-        for entry in spec:
-            if entry is None:
-                continue
-            names.update(entry if isinstance(entry, tuple) else (entry,))
-        return names
-
-    def _unmentioned_prod(spec):
-        return int(_np.prod([axis_sizes[a] for a in axis_sizes
-                             if a not in _mentioned(spec)] or [1]))
-
-    def _run_fwd(*args):
-        return _esm(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False, **kw)(*args)
-
-    call = _jax.custom_vjp(_run_fwd)
-
-    def _fwd(*args):
-        return _run_fwd(*args), args
-
-    def _bwd(args, g):
-        g_flat, g_tree = _jax.tree.flatten(g)
-        g_specs = _bprefix(out_specs, g, is_leaf=_is_spec)
-        g_flat = [gl if gl.dtype == _float0
-                  else gl / _unmentioned_prod(s)
-                  for gl, s in zip(g_flat, g_specs)]
-        g = _jax.tree.unflatten(g_tree, g_flat)
-        a_flat, a_tree = _jax.tree.flatten(args)
-        a_specs = _bprefix(in_specs, args, is_leaf=_is_spec)
-        diff = [i for i, x in enumerate(a_flat)
-                if _jnp.issubdtype(_jnp.result_type(x), _jnp.inexact)]
-
-        def bwd_body(args, g):
-            flat = _jax.tree.leaves(args)
-
-            def restricted(*diff_leaves):
-                full = list(flat)
-                for i, leaf in zip(diff, diff_leaves):
-                    full[i] = leaf
-                return f(*_jax.tree.unflatten(a_tree, full))
-
-            _, vjp_fn = _jax.vjp(restricted, *[flat[i] for i in diff])
-            cts = vjp_fn(g)
-            return tuple(
-                _jax.lax.psum(ct, un) if (un := tuple(
-                    a for a in axis_sizes
-                    if a not in _mentioned(a_specs[i]))) else ct
-                for ct, i in zip(cts, diff))
-
-        bwd_sm = _esm(bwd_body, mesh=mesh,
-                      in_specs=(in_specs, out_specs),
-                      out_specs=tuple(a_specs[i] for i in diff),
-                      check_rep=False, **kw)
-        diff_cts = bwd_sm(args, g) if diff else ()
-        ct_flat = [_np.zeros(_jnp.shape(x), _float0) for x in a_flat]
-        for i, ct in zip(diff, diff_cts):
-            ct_flat[i] = ct
-        return tuple(_jax.tree.unflatten(a_tree, ct_flat))
-
-    call.defvjp(_fwd, _bwd)
-    return call
+    """`jax.shard_map`, through one door: every manual-collective module
+    in this package calls this. Defined before the submodule imports
+    below so `from . import shard_map` works during package init."""
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, **kw)
 
 
 from . import env  # noqa: F401,E402

@@ -266,6 +266,14 @@ class ElasticManager:
         a node dropped from the membership returns "scaled-in".
         `poll_timeout` bounds either loop (tests)."""
         deadline = time.time() + poll_timeout if poll_timeout else None
+        from ..core.place import holds_accelerator
+        if holds_accelerator():
+            raise RuntimeError(
+                "the elastic supervisor has initialised a jax "
+                "accelerator backend and so holds the chip: the "
+                "training process it starts would fail or hang. "
+                "Supervise from a process that imports paddle_tpu but "
+                "runs no jax computation.")
         if not elastic:
             while True:
                 self.register()

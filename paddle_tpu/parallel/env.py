@@ -65,12 +65,9 @@ def init_parallel_env():
     except Exception:
         pass
     if coord and n_nodes > 1 and not already:
-        # NOTE: importing paddle_tpu initialises the XLA backend, after
-        # which jax.distributed.initialize refuses to run — multi-process
-        # programs must call jax.distributed.initialize (with
-        # jax_cpu_collectives_implementation="gloo" on CPU) BEFORE the
-        # import; this path covers launcher-driven runs where the env is
-        # set and nothing touched jax yet.
+        # jax.distributed.initialize refuses to run once a backend is
+        # initialised. Importing paddle_tpu does not do that; the first
+        # jax computation does — so this must run before any.
         port = os.environ.get("MASTER_PORT", "8476")
         pid = int(os.environ.get("PADDLE_NODE_RANK",
                                  os.environ.get("NODE_RANK", "0")))
@@ -113,6 +110,21 @@ def device_count():
 
 def is_initialized():
     return _initialized
+
+
+def device_grid(devices, shape):
+    """`devices` as an ndarray of `shape`, ready for a `Mesh`.
+
+    A whole TPU slice goes through `jax.experimental.mesh_utils`, which
+    lays every mesh axis along physical ICI rings: on a 2x2 v5e tray the
+    ids 0,1,2,3 in order are NOT a ring (1 -> 2 is the diagonal), the
+    ring is 0,1,3,2. A part of a slice, or the CPU test mesh, has no
+    topology to honour and is reshaped as given."""
+    devices = list(devices)
+    if devices[0].platform == "tpu" and len(devices) == jax.device_count():
+        from jax.experimental import mesh_utils
+        return mesh_utils.create_device_mesh(tuple(shape), devices=devices)
+    return np.array(devices).reshape(shape)
 
 
 def global_mesh(axes=None):

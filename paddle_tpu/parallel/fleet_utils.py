@@ -203,7 +203,7 @@ def fused_allreduce_gradients(parameter_list, hcg=None,
     `_apply_collective_grads` divides the summed gradients by nranks
     (an unscaled sum would step with grads nranks(x) too large).
 
-    The win on the 0.4.x eager multi-process path is the COLLECTIVE
+    The win on the eager multi-process path is the COLLECTIVE
     COUNT (n buckets instead of n params — each eager all_reduce is a
     synchronous host round-trip through jax.device_get, so fewer
     round-trips is the whole game; true wire/compute overlap is the

@@ -18,7 +18,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import shard_map as _shard_map
@@ -82,12 +81,13 @@ def ring_attention(q, k, v, mesh=None, axis_name="cp", causal=True,
                    scale=None):
     """q/k/v: [B, S, H, D] logical arrays (or sharded); returns same.
 
-    When `mesh` is None builds a 1-D mesh over all devices. S must divide
-    by the cp size.
+    When `mesh` is None builds a 1-D ring over all devices, in the
+    slice's physical ring order. S must divide by the cp size.
     """
     if mesh is None:
-        n = jax.device_count()
-        mesh = Mesh(np.array(jax.devices()).reshape(n), (axis_name,))
+        from .env import device_grid
+        mesh = Mesh(device_grid(jax.devices(), (jax.device_count(),)),
+                    (axis_name,))
     cp = mesh.shape[axis_name]
     B, S, H, D = q.shape
     assert S % cp == 0, f"seq {S} must divide cp {cp}"

@@ -1,4 +1,4 @@
-"""ctypes loader for the native ps_core library; builds on first import.
+"""ctypes loader for the native ps_core library; builds on first use.
 
 The reference's pybind bridge role (`paddle/fluid/pybind/`) is played by a
 plain C ABI + ctypes (pybind11 is not in this image); numpy arrays pass
@@ -7,40 +7,56 @@ zero-copy via ctypes pointers.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
-import sys
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "csrc", "ps_core.cpp")
-_LIB = os.path.join(_HERE, "csrc", "libps_core.so")
 
 _lib = None
 
 
-def _build():
+def lib_path(src=_SRC):
+    """`libps_core.<hash of the source>.so`, beside the source. The
+    hash is in the NAME so that a binary built from another version of
+    `ps_core.cpp` — copied along with the tree, whatever its mtime —
+    can never be the one that loads."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(src), f"libps_core.{digest}.so")
+
+
+def _build(out):
+    tmp = f"{out}.tmp{os.getpid()}"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC,
-           "-o", _LIB, "-lpthread"]
+           "-o", tmp, "-lpthread"]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(
             f"native ps_core build failed ({' '.join(cmd)}):\n"
             f"{proc.stderr[-4000:]}")
+    os.replace(tmp, out)        # atomic: a concurrent loader sees all or nothing
+    for old in glob.glob(os.path.join(os.path.dirname(out),
+                                      "libps_core*.so")):
+        if old != out:
+            os.remove(old)
 
 
 def get_lib():
     global _lib
     if _lib is not None:
         return _lib
-    if (not os.path.exists(_LIB)) or \
-            os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-        _build()
+    path = lib_path()
+    if not os.path.exists(path):
+        _build(path)
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
     except OSError:
-        # stale/foreign binary (e.g. different arch): rebuild from source
-        _build()
-        lib = ctypes.CDLL(_LIB)
+        # foreign binary (e.g. different arch): rebuild from source
+        _build(path)
+        lib = ctypes.CDLL(path)
     u64p = ctypes.POINTER(ctypes.c_uint64)
     f32p = ctypes.POINTER(ctypes.c_float)
     i32p = ctypes.POINTER(ctypes.c_int)

@@ -162,6 +162,9 @@ class TPServingEngine(ServingEngine):
                 + [serving_tp_spec(n, moe=moe)[0] for n in names]
                 + [P(), P(), P()])
 
+    def step_devices(self):
+        return list(self.mesh.devices.flat)
+
     def _adapter_specs(self):
         """PartitionSpec per adapter slot tensor, in
         `AdapterCache.array_names` order (SERVING_LORA_TP_SPECS)."""
@@ -334,7 +337,7 @@ class TPServingEngine(ServingEngine):
         # count histogram (ISSUE 19: the [S, Vb] device-updatable form
         # of the old history window) and the rng key replicate; sampled
         # tokens come off the replicated post-psum hidden state so the
-        # token outputs replicate too (check_vma=False: 0.4.x's checker
+        # token outputs replicate too (check_vma=False: the checker
         # can't see through the scanned psum)
         n_data = 6 + (1 if self.adapters is not None else 0) \
             + (1 if batcher.needs_history(self.sampling) else 0)

@@ -82,7 +82,8 @@ class ServingEngine:
                  lora_alpha=None, moe_weight_dtype=None,
                  sparse_blocks=None, sparse_recent=2,
                  track_summaries=None, name=None,
-                 ticks_per_dispatch=1, multitick_async=None):
+                 ticks_per_dispatch=1, multitick_async=None,
+                 device=None):
         import functools
 
         import jax
@@ -292,11 +293,23 @@ class ServingEngine:
             reserve_region=self._sparse)
         dtype = cache_dtype or getattr(model, "_gen_cache_dtype",
                                        "bfloat16")
-        self.kv = PagedKVCache(
-            L, H, Dh, num_blocks=num_blocks,
-            block_size=self.block_size, max_slots=max_slots,
-            max_blocks_per_slot=mbps, dtype=dtype, kv_dtype=kv_dtype,
-            summaries=self._track_summaries)
+        # `device`: the one chip this replica lives on (None = jax's
+        # default device). Weights, KV pools and the rng are COMMITTED
+        # to it, so the mixed step — and every pool copy/import jit —
+        # runs there whatever the process default is: four one-chip
+        # replicas of a router sit on four chips, not all on chip 0.
+        # The pools are also created there, never staged through the
+        # default device's HBM.
+        self.device = device
+        commit = self._commit = (lambda a: a) if device is None else \
+            functools.partial(jax.device_put, device=device)
+        with jax.default_device(device):
+            self.kv = PagedKVCache(
+                L, H, Dh, num_blocks=num_blocks,
+                block_size=self.block_size, max_slots=max_slots,
+                max_blocks_per_slot=mbps, dtype=dtype, kv_dtype=kv_dtype,
+                summaries=self._track_summaries)
+        self.kv._set_pools([commit(p) for p in self.kv._pools()])
         # radix prefix cache: cross-request KV reuse for shared prompt
         # heads (system prompts, few-shot templates, chat history) —
         # registers itself as the kv cache's eviction backstop
@@ -314,10 +327,14 @@ class ServingEngine:
         self.adapters = None
         if int(max_adapters):
             from .adapters import AdapterCache
-            self.adapters = AdapterCache(
-                dec, max_adapters=int(max_adapters),
-                rank=int(lora_rank), alpha=lora_alpha,
-                dtype=cdt_name, clock=clock)
+            with jax.default_device(device):
+                self.adapters = AdapterCache(
+                    dec, max_adapters=int(max_adapters),
+                    rank=int(lora_rank), alpha=lora_alpha,
+                    dtype=cdt_name, clock=clock)
+            for n in self.adapters.array_names:
+                self.adapters._arrays[n] = commit(
+                    self.adapters._arrays[n])
         from .draft import ngram_propose
 
         def _windowed_draft(tokens, _k=self.draft_k,
@@ -340,13 +357,14 @@ class ServingEngine:
         self.scheduler.replica = self.name
         self.eos_token_id = eos_token_id
         self.clock = clock
-        self._rng = jax.random.PRNGKey(int(seed))
+        self._rng = commit(jax.random.PRNGKey(int(seed)))
         # cast float params to the compute dtype ONCE (same discipline
         # as generation.generate: a per-step astype re-reads the full
         # parameter set every token)
         cdt = jnp.dtype(cdt_name)
-        self._arrays = [a.astype(cdt)
-                        if a.dtype in (jnp.float32, jnp.float64) else a
+        self._arrays = [commit(a.astype(cdt)
+                               if a.dtype in (jnp.float32, jnp.float64)
+                               else a)
                         for a in (t._data for t in model._gen_tensors())]
         # the engine owns its decoder-param NAME list (a copy of the
         # model's): engine-side expert quantization below may extend
@@ -376,6 +394,7 @@ class ServingEngine:
             step_fn = self._build_multitick(step_fn)
         self._step_fn = instrumented_jit(
             step_fn, STEP_FN_NAME, donate_argnums=donate)
+        self._aot_step = False
         # multi-tick host runtime state: double-buffered plan tensors
         # (pack k+1 while k's may still be in flight), the deferred
         # observability lane (dispatch k's metrics/flight flush after
@@ -1772,13 +1791,10 @@ class ServingEngine:
         if trace_on:
             # flight-recorder note: every field is a host int/float the
             # loop already holds — no device readback, no jit input.
-            # The jit cache size probes a host dict; a growing value
-            # across records is a compile event (the watchdog fails the
-            # run outright, this just timestamps it).
-            try:
-                compiled = int(self._step_fn._jitted._cache_size())
-            except Exception:
-                compiled = -1
+            # The step's running compile count: a growing value across
+            # records is a compile event (the watchdog fails the run
+            # outright, this just timestamps it).
+            compiled = self.step_compile_count()
             self.flight.note(
                 ts=t0, dur=self.clock() - t0,
                 prefill_tokens=int(sp.prefill_tokens),
@@ -2083,10 +2099,7 @@ class ServingEngine:
                              pc.evictions - e0)
             self._prefix_seen = (pc.hit_tokens, pc.miss_tokens,
                                  pc.evictions)
-        try:
-            compiled = int(self._step_fn._jitted._cache_size())
-        except Exception:
-            compiled = -1
+        compiled = self.step_compile_count()
 
         def observe():
             if _pmetrics._enabled:
@@ -2259,17 +2272,33 @@ class ServingEngine:
         deserialized AOT executable (fleet/export.py). The replica
         then performs ZERO `serving_mixed_step` jit compiles — the
         property tools/fleet_smoke.py asserts with a budget-0
-        watchdog. The flight recorder's compile-cache probe degrades
-        to -1 (the AOT callable has no jit cache), which is the
-        truthful reading for an executable that can never compile."""
+        watchdog, and `step_compile_count()` then reads 0 for good:
+        an AOT executable can never compile."""
         self._step_fn = fn
+        self._aot_step = True
+
+    def step_devices(self):
+        """The devices the mixed step runs on, in executable order (one
+        for this engine; the mesh's for TPServingEngine) — what an AOT
+        executable has to be loaded onto."""
+        import jax
+        return [self.device if self.device is not None
+                else jax.devices()[0]]
+
+    def step_compile_count(self):
+        """How many executables jax has built for this engine's mixed
+        step (`instrumented_jit`'s count; the contract is 1 after the
+        first step and 1 for ever after)."""
+        return 0 if self._aot_step else self._step_fn.compile_count()
 
     def _prep_swap_arrays(self, arrays):
         """Host-side staging for `swap_weights`. The base engine takes
-        the canonical model-order checkpoint as-is; TPServingEngine
-        overrides this with the shard-major QKV permute + sharded
-        placement its step layout requires."""
-        return [np.asarray(a) for a in arrays]
+        the canonical model-order checkpoint as-is — committed to the
+        engine's own `device` when it has one, so the cast runs there
+        and the new weights do not land on the process default;
+        TPServingEngine overrides this with the shard-major QKV
+        permute + sharded placement its step layout requires."""
+        return [self._commit(np.asarray(a)) for a in arrays]
 
     def _swap_jit_kwargs(self):
         """Extra jit kwargs for the swap cast (TP: out_shardings)."""

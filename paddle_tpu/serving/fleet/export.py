@@ -13,24 +13,22 @@ A bundle is one directory per checkpoint version:
                             for the jitted mixed step, lowered against
                             `engine.example_step_args()`
 
-The default root sits NEXT TO the persistent kernel-autotune cache
-(`ops.pallas.autotune.user_cache_path()`): both are
-build-once-boot-many artifacts of the same deployment.
+There is no default root: a bundle is a deployment artifact, and the
+caller says where it goes.
 
 Boot path: `boot_engine_from_bundle` reconstructs the model from the
 manifest, injects the bundled weights into the model tensors BEFORE
 engine construction (so the engine's own compute-dtype cast / MoE
 quantization / TP shard layout all apply unchanged — a booted engine
 is bit-identical to the exporting one), then installs the
-deserialized executable via `engine.install_aot_step`. The replica
-performs ZERO `serving_mixed_step` jit compiles — watchdog-assertable
-with `guards.sanitize(budgets={"serving_mixed_step": 0})` — and
-serves its first token straight off the deserialized executable.
-
-On a jax without executable serialization the bundle still carries
-config + weights; boot falls back to the ordinary jit path, where the
-persistent HLO compilation cache (conftest wires one) absorbs most of
-the compile cost. `FleetBundle.has_executable` tells the two apart.
+deserialized executable via `engine.install_aot_step`, loaded onto the
+devices that engine lives on. The replica performs ZERO
+`serving_mixed_step` jit compiles — watchdog-assertable with
+`guards.sanitize(budgets={"serving_mixed_step": 0})` — and serves its
+first token straight off the deserialized executable. A bundle
+exported with `include_executable=False` (or for another `(role, tp)`)
+carries config + weights only and boots through the ordinary jit path;
+`FleetBundle.has_executable` tells the two apart.
 """
 from __future__ import annotations
 
@@ -44,27 +42,6 @@ import numpy as np
 MANIFEST = "manifest.json"
 WEIGHTS = "weights.npz"
 FORMAT = 1
-
-
-def _serialize_mod():
-    """The 0.4.x AOT (de)serialization entry points, or None when this
-    jax build lacks them (the persistent-HLO-cache fallback)."""
-    try:
-        from jax.experimental import serialize_executable
-        return serialize_executable
-    except Exception:
-        return None
-
-
-def aot_available():
-    return _serialize_mod() is not None
-
-
-def default_bundle_root():
-    """`<dir of the persistent autotune cache>/fleet_bundles`."""
-    from ...ops.pallas import autotune as _kt
-    return os.path.join(os.path.dirname(_kt.user_cache_path()),
-                        "fleet_bundles")
 
 
 def _exec_key(role, tp):
@@ -140,40 +117,20 @@ def _serialize_step(engine):
     `._jitted.lower(...)` directly — the AOT path neither populates
     the instrumented wrapper's jit cache nor ticks the compile
     watchdog, so exporting from inside a sanitized test costs no
-    budget.
+    budget. The compile must not come out of the persistent cache
+    (`core.compile_cache.compile_fresh` says why)."""
+    from jax.experimental import serialize_executable
 
-    The compile must be FRESH: on jax 0.4.x, `serialize()` of an
-    executable the persistent compilation cache handed back emits a
-    payload whose jitted symbol bodies are missing ("Symbols not
-    found" at deserialize). Flipping `jax_compilation_cache_dir` is
-    not enough on its own — `compilation_cache.is_cache_used()`
-    memoizes its verdict process-wide the first time it runs, so the
-    dir toggle must be bracketed with `reset_cache()` to force a
-    re-evaluation (and again after restoring, so normal compiles
-    re-adopt the configured cache)."""
-    import jax
-    from jax._src import compilation_cache as _cc
-    ser = _serialize_mod()
-    if ser is None:
-        return None
+    from ...core.compile_cache import compile_fresh
     lowered = engine._step_fn._jitted.lower(*engine.example_step_args())
-    cache_dir = jax.config.jax_compilation_cache_dir
-    try:
-        if cache_dir is not None:
-            jax.config.update("jax_compilation_cache_dir", None)
-            _cc.reset_cache()
-        compiled = lowered.compile()
-    finally:
-        if cache_dir is not None:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            _cc.reset_cache()
-    payload, in_tree, out_tree = ser.serialize(compiled)
+    payload, in_tree, out_tree = serialize_executable.serialize(
+        compile_fresh(lowered))
     return pickle.dumps({"payload": payload, "in_tree": in_tree,
                          "out_tree": out_tree},
                         protocol=pickle.HIGHEST_PROTOCOL)
 
 
-def export_bundle(engine, path=None, *, version="v1", seed=0,
+def export_bundle(engine, path, *, version="v1", seed=0,
                   include_executable=True):
     """Write `engine`'s boot bundle for `version`; returns the bundle
     directory. Weights are the CANONICAL model tensors (pre-cast,
@@ -181,8 +138,7 @@ def export_bundle(engine, path=None, *, version="v1", seed=0,
     the boot replays the engine constructor's own transforms, which
     keeps one weights file valid for every (role, TP) executable in
     the bundle."""
-    root = path if path is not None else default_bundle_root()
-    bdir = os.path.join(root, str(version))
+    bdir = os.path.join(path, str(version))
     os.makedirs(bdir, exist_ok=True)
     tensors = list(engine.model._gen_tensors())
     arrays = [np.asarray(t._data) for t in tensors]
@@ -202,14 +158,12 @@ def export_bundle(engine, path=None, *, version="v1", seed=0,
     }
     mpath = os.path.join(bdir, MANIFEST)
     if include_executable:
-        blob = _serialize_step(engine)
-        if blob is not None:
-            role = engine.role
-            tp = int(getattr(engine, "tensor_parallel", 1))
-            fname = _exec_file(role, tp)
-            with open(os.path.join(bdir, fname), "wb") as f:
-                f.write(blob)
-            manifest["executables"][_exec_key(role, tp)] = fname
+        role = engine.role
+        tp = int(getattr(engine, "tensor_parallel", 1))
+        fname = _exec_file(role, tp)
+        with open(os.path.join(bdir, fname), "wb") as f:
+            f.write(_serialize_step(engine))
+        manifest["executables"][_exec_key(role, tp)] = fname
     if os.path.exists(mpath):
         # re-export for another (role, TP): merge executable indices,
         # keep the shared config/weights freshly written above
@@ -255,18 +209,20 @@ class FleetBundle:
     def has_executable(self, role="mixed", tp=1):
         return _exec_key(role, tp) in self.manifest["executables"]
 
-    def executable(self, role="mixed", tp=1):
-        """Deserialize the (role, tp) step executable into a callable
-        that runs WITHOUT compiling; None when the bundle carries no
-        executable for that key (or this jax can't deserialize)."""
-        ser = _serialize_mod()
+    def executable(self, devices, role="mixed", tp=1):
+        """Deserialize the (role, tp) step executable onto `devices` —
+        the devices of the engine that will run it, in the engine's
+        order — into a callable that runs WITHOUT compiling; None when
+        the bundle carries no executable for that key."""
+        from jax.experimental import serialize_executable
         fname = self.manifest["executables"].get(_exec_key(role, tp))
-        if ser is None or fname is None:
+        if fname is None:
             return None
         with open(os.path.join(self.path, fname), "rb") as f:
             d = pickle.load(f)
-        return ser.deserialize_and_load(d["payload"], d["in_tree"],
-                                        d["out_tree"])
+        return serialize_executable.deserialize_and_load(
+            d["payload"], d["in_tree"], d["out_tree"],
+            execution_devices=list(devices))
 
     def build_model(self):
         """Reconstruct the model from the manifest and inject the
@@ -329,7 +285,7 @@ def boot_engine_from_bundle(bundle, *, aot=True, warm_prefix=None,
         engine = ServingEngine(model, **ecfg)
     engine.weights_version = bundle.version
     if aot:
-        fn = bundle.executable(role, tp)
+        fn = bundle.executable(engine.step_devices(), role, tp)
         if fn is not None:
             engine.install_aot_step(fn)
     if warm_prefix is not None and engine.prefix_cache is not None \

@@ -5,8 +5,6 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
 
-import tempfile  # noqa: E402
-
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
@@ -18,13 +16,13 @@ jax.config.update("jax_platforms", "cpu")
 # on disk (keyed by HLO hash — semantics-free by construction) lets
 # later duplicates load instead of recompile, both within one tier-1
 # run and across runs, keeping the suite inside its wall-clock budget.
-# Compile-COUNT contracts are unaffected: instrumented_jit counts
-# trace-level cache misses, and a disk hit is still one of those.
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get("PADDLE_TPU_TEST_JAX_CACHE",
-                   os.path.join(tempfile.gettempdir(),
-                                "paddle_tpu_jax_cache")))
+# Compile-COUNT contracts are unaffected: instrumented_jit counts every
+# executable jax builds, and a disk hit is still one of those.
+# Placement follows core/compile_cache.py: JAX_COMPILATION_CACHE_DIR if
+# set, else <checkout>/.jax_cache.
+from paddle_tpu.core.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.4)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 

@@ -69,24 +69,14 @@ def test_splash_gate():
     assert out.shape == (1, 2, 100, 32)
 
 
-def test_splash_head_dim_quantum_gates_at_callsite(_interpret_splash):
-    """The installed-kernel head_dim limitation (jax 0.4.x refuses
-    head_dim % 128 at trace time) must be detected by the static gate,
-    not by the trace-and-refuse net: a 64-but-not-128 head_dim is
-    either supported by the probe (newer kernels) or gated OFF, and
-    calling splash_mha on it must neither raise nor grow the refusal
-    set."""
-    from paddle_tpu.ops.pallas import flash_attention as fa
-    quantum = fa.splash_head_dim_quantum()
-    assert quantum in (64, 128)
-    assert splash_supported(256, 64) == (quantum == 64)
+def test_splash_gate_takes_head_dim_64(_interpret_splash):
+    """head_dim 64 (GPT-350M, BERT) is inside the gate and runs the
+    kernel; a refusal from the kernel would raise, not fall back."""
+    assert splash_supported(256, 64)
     assert splash_supported(256, 128)
-    fa._SPLASH_REFUSED.clear()
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 128, 64))
     out = splash_mha(q, q, q, causal=True)
     assert out.shape == (1, 2, 128, 64)
-    # the callsite gate (not a trace refusal) routed the fallback
-    assert (128, 64) not in fa._SPLASH_REFUSED or quantum == 64
 
 
 def test_functional_flash_attention_uses_dispatch():

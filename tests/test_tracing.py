@@ -363,10 +363,10 @@ class TestEngineTracing:
             return time.perf_counter() - t0
 
         off = min(run_once() for _ in range(2))
-        compiles0 = eng._step_fn._jitted._cache_size()
+        compiles0 = eng.step_compile_count()
         tracing.enable()
         on = min(run_once() for _ in range(2))
-        assert eng._step_fn._jitted._cache_size() == compiles0
+        assert eng.step_compile_count() == compiles0
         assert TRACER.traces()                       # it did record
         # host-side dict appends vs multi-ms jitted steps: generous
         # bound absorbs CI noise while catching a hot-path regression
