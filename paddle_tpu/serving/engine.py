@@ -71,6 +71,29 @@ import itertools as _itertools  # noqa: E402
 _ENGINE_SEQ = _itertools.count()
 
 
+def _group_width(tok):
+    """Tokens a decode entry of a plan feeds: one, or a verify group."""
+    return 1 if np.isscalar(tok) or getattr(
+        tok, "ndim", None) == 0 else len(tok)
+
+
+def _attention_work(plan):
+    """(kv_tokens_read, attn_pairs) of one tick fed `plan`. Per slot fed
+    `n` query tokens from position `start` (a decode group is `width`
+    tokens from `pos`, a prefill chunk `len(chunk)` from its `start`):
+    the context it must read once is `start + n` tokens, and its
+    queries attend `start + 1 .. start + n` keys, `n * start +
+    n * (n + 1) / 2` pairs in all. Token counts only: what they cost
+    in bytes and FLOPs is the benchmark's arithmetic."""
+    read = pairs = 0
+    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
+    groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
+    for start, n in groups:
+        read += start + n
+        pairs += n * start + n * (n + 1) // 2
+    return int(read), int(pairs)
+
+
 class ServingEngine:
     def __init__(self, model, *, max_slots=8, block_size=16,
                  num_blocks=None, max_seq_len=None, token_budget=None,
@@ -461,6 +484,11 @@ class ServingEngine:
         # registered so profiler chrome export / summary() merge it
         self.flight = _tracing.StepFlightRecorder(self.name, self.role)
         _tracing.register_flight_recorder(self.flight)
+        # where the host's time goes (tracing.HOST_PHASES): marked by
+        # step() and by the frontend's step loop, only while tracing
+        # is enabled; `_step_end` is when the last traced step ended
+        self.phases = _tracing.PhaseMarker(self.clock)
+        self._step_end = None
 
     def _flight_extra(self):
         """Extra per-step flight-recorder fields; TPServingEngine
@@ -1571,14 +1599,19 @@ class ServingEngine:
         # tracing state is sampled ONCE per step: recording stays
         # consistent across the step even if a monitor attaches midway
         trace_on = _tracing._enabled
-        t0 = self.clock() if trace_on else None
+        ph = self.phases
+        t0 = ph.mark("engine.plan", self.steps_run) if trace_on else None
         plan = sch.plan()
         if _pmetrics._enabled and plan.expired:
             for _ in plan.expired:
                 smetrics.SERVING_REQUESTS.labels("expired").inc()
         if plan.empty:
             self._flush_deferred()
+            if trace_on:
+                ph.close()
             return bool(plan.expired)
+        if trace_on:
+            ph.mark("engine.pack")
         if self._multitick:
             return self._step_multitick(plan, trace_on, t0)
         sp = pack_step(self.token_budget, self.kv.max_slots,
@@ -1598,7 +1631,11 @@ class ServingEngine:
         if batcher.needs_history(self.sampling):
             args.append(jnp.asarray(self._penalty_counts()))
         args.append(sub)
+        if trace_on:
+            ph.mark("engine.dispatch")
         res = self._step_fn(*args)
+        if trace_on:
+            ph.mark("engine.wait")
         moe_stats = None
         if self.num_experts:
             res, moe_stats = res[:-1], res[-1]
@@ -1612,12 +1649,15 @@ class ServingEngine:
             # group per layer), so the skip accounting is pure host
             # math — no device readback
             for slot, tok, pos in plan.decode:
-                width = 1 if np.isscalar(tok) or getattr(
-                    tok, "ndim", None) == 0 else len(tok)
-                n_blk = (pos + width - 1) // self.block_size + 1
+                n_blk = (pos + _group_width(tok) - 1) \
+                    // self.block_size + 1
                 self.sparse_candidate_blocks += n_blk
                 self.sparse_selected_blocks += min(
                     n_blk, self.sparse_table_width)
+        if trace_on:
+            # the attention work of this step, counted while the device
+            # does it: host arithmetic on the plan, no readback
+            kv_read, pairs = _attention_work(plan)
         tokres_np = acc_np = None
         if self.draft_k and self.spec_sampling:
             tok_np, tokv_np, tokres_np, acc_np = (np.asarray(t)
@@ -1626,7 +1666,7 @@ class ServingEngine:
             tok_np, tokv_np = (np.asarray(t) for t in out)
         else:
             tok_np, tokv_np = np.asarray(out), None
-        now = self.clock()
+        now = ph.mark("engine.emit") if trace_on else self.clock()
         if trace_on:
             # one prefill_chunk span per planned chunk: slot residents
             # are stable between plan() and here (admissions happen
@@ -1696,7 +1736,6 @@ class ServingEngine:
                         _tracing.TRACER.event(
                             req.trace_id, "handoff",
                             replica=self.name, ts=now)
-        spec_accept = spec_groups = 0
         if self.draft_k:
             from .draft import accept_length, accept_length_sampled
             for slot, toks, pos in sp.decode_entries:
@@ -1725,9 +1764,6 @@ class ServingEngine:
                             "proposed").inc(len(toks) - 1)
                         smetrics.SERVING_DRAFT_TOKENS.labels(
                             "accepted").inc(m)
-                if trace_on:
-                    spec_accept += m + 1
-                    spec_groups += 1
                 done = emit(req, emitted, verify=True)
                 if not done:
                     # roll back blocks whose only contents were
@@ -1741,6 +1777,8 @@ class ServingEngine:
                 req = sch.slots[slot]
                 if req is not None:
                     emit(req, [int(tok_np[slot])])
+        if trace_on:
+            ph.mark("engine.note")
         if moe_stats is not None:
             self._note_moe_stats(moe_stats)
         if _pmetrics._enabled:
@@ -1794,30 +1832,54 @@ class ServingEngine:
             # The step's running compile count: a growing value across
             # records is a compile event (the watchdog fails the run
             # outright, this just timestamps it).
-            compiled = self.step_compile_count()
-            self.flight.note(
-                ts=t0, dur=self.clock() - t0,
-                prefill_tokens=int(sp.prefill_tokens),
+            self.flight.note(**self._step_record(
+                t0, prefill_tokens=int(sp.prefill_tokens),
                 decode_tokens=int(sp.decode_tokens),
-                active_slots=int(sch.num_active),
-                queue_depth=len(sch.queue),
-                spec_accept_tokens=spec_accept,
-                spec_groups=spec_groups,
-                sparse_skip_ratio=(
-                    1.0 - self.sparse_selected_blocks
-                    / self.sparse_candidate_blocks
-                    if self._sparse and self.sparse_candidate_blocks
-                    else 0.0),
-                blocks_imported=int(self.kv.blocks_imported),
-                compile_cache_size=compiled,
-                **self._flight_extra())
+                kv_tokens_read=kv_read, attn_pairs=pairs))
         return True
 
+    def _step_record(self, t0, **fields):
+        """The flight record of the step that began at `t0`, from the
+        engine's state now: every value a host int or float. The step
+        ends here, where its last phase does, so the `ph_*` fields of
+        this step sum to `dur`; the frontend's ran in the gap before
+        it."""
+        sch = self.scheduler
+        fields.update(
+            active_slots=int(sch.num_active),
+            queue_depth=len(sch.queue),
+            sparse_skip_ratio=(
+                1.0 - self.sparse_selected_blocks
+                / self.sparse_candidate_blocks
+                if self._sparse and self.sparse_candidate_blocks
+                else 0.0),
+            blocks_imported=int(self.kv.blocks_imported),
+            compile_cache_size=self.step_compile_count(),
+            kv_blocks_in_use=int(self.kv.blocks_in_use),
+            kv_blocks_total=int(self.kv.num_blocks),
+            preemptions=int(sch.preemption_count),
+            **self._flight_extra())
+        end = self.phases.close()
+        fields.update(self.phases.take(), ts=t0, dur=end - t0)
+        if self._step_end is not None:
+            fields["gap_before"] = t0 - self._step_end
+        self._step_end = end
+        return fields
+
     # ------------------------------------- multi-tick dispatch (ISSUE 18)
-    def _flush_deferred(self):
+    def _flush_deferred(self, trace_on=False):
+        """Publish the last multi-tick dispatch's deferred notes. Inside
+        a traced step that is `engine.note` time, taken out of the
+        phase it interrupts (`engine.wait`, where it hides behind the
+        device)."""
         cb, self._deferred = self._deferred, None
         if cb is not None:
+            if trace_on:
+                resume = self.phases.name
+                self.phases.mark("engine.note")
             cb()
+            if trace_on:
+                self.phases.mark(resume)
 
     def flush_observability(self):
         """Flush the deferred observability of the LAST multi-tick
@@ -1845,6 +1907,7 @@ class ServingEngine:
         host bookkeeping a 1-tick engine runs per step."""
         import jax.numpy as jnp
         sch = self.scheduler
+        ph = self.phases
         S = self.kv.max_slots
         t_launch = self.clock()
         if self._gap_ema is not None or self._last_harvest is not None:
@@ -1911,7 +1974,11 @@ class ServingEngine:
         if K > 1:
             ring, rcnt = self._draft_ring_state()
             args += [jnp.asarray(ring), jnp.asarray(rcnt)]
+        if trace_on:
+            ph.mark("engine.dispatch")
         res = self._step_fn(*args)
+        if trace_on:
+            ph.mark("engine.wait")
         moe_stats = None
         if self.num_experts:
             res, moe_stats = res[:-1], res[-1]
@@ -1932,7 +1999,7 @@ class ServingEngine:
                     a.copy_to_host_async()
                 except Exception:
                     pass
-            self._flush_deferred()
+            self._flush_deferred(trace_on)
         hs0 = self.clock()
         counts_np = np.asarray(counts_d)
         events_np = np.asarray(events_d)
@@ -1954,11 +2021,13 @@ class ServingEngine:
         host_stall = self.clock() - hs0
         self._last_harvest = self.clock()
         self.host_stall_total += host_stall
+        if trace_on:
+            ph.mark("engine.emit")
         if not self._multitick_async:
             # sync mode (the bench's "before" arm): block on readback
             # first, do last dispatch's bookkeeping after — the legacy
             # ordering the async lane exists to beat
-            self._flush_deferred()
+            self._flush_deferred(trace_on)
         if ticks_run > 0:
             d = (self._last_harvest - t_launch) / ticks_run
             self._tick_ema = (d if self._tick_ema is None
@@ -1993,6 +2062,17 @@ class ServingEngine:
                         n_blk, self.sparse_table_width)
         now = self.clock()
         if trace_on:
+            # the first tick's attention work is the plan's; each
+            # further token a slot emitted in the device loop is one
+            # query at the next position. Exact without speculation;
+            # with device drafting the rejected draft columns are work
+            # the host never sees
+            kv_read, pairs = _attention_work(plan)
+            for slot, _tok, pos in plan.decode:
+                c = max(int(counts_np[slot]), 1)
+                later = (c - 1) * (pos + 1) + c * (c - 1) // 2
+                kv_read += later
+                pairs += later
             for slot, chunk, start, completes in plan.prefills:
                 req = sch.slots[slot]
                 if req is not None:
@@ -2087,8 +2167,7 @@ class ServingEngine:
             ev_finish=ev_finish, ev_over=ev_over,
             spec_prop=spec_prop, spec_acc=spec_acc,
             spec_hist=(None if spec_hist is None
-                       else [int(x) for x in spec_hist]),
-            dur=self.clock() - t0 if trace_on else 0.0)
+                       else [int(x) for x in spec_hist]))
         self._preempt_seen = sch.preemption_count
         self._imported_seen = self.kv.blocks_imported
         prefix_deltas = None
@@ -2099,7 +2178,17 @@ class ServingEngine:
                              pc.evictions - e0)
             self._prefix_seen = (pc.hit_tokens, pc.miss_tokens,
                                  pc.evictions)
-        compiled = self.step_compile_count()
+        record = None
+        if trace_on:
+            # the flight record is made now, while the step's span and
+            # phases are this step's, and noted with the rest
+            record = self._step_record(
+                t0, prefill_tokens=snap["prefill_tokens"],
+                decode_tokens=snap["decode_tokens"],
+                kv_tokens_read=kv_read, attn_pairs=pairs,
+                ticks=snap["ticks"], host_stall=snap["host_stall"],
+                early_exit_finish=snap["ev_finish"],
+                early_exit_overflow=snap["ev_over"])
 
         def observe():
             if _pmetrics._enabled:
@@ -2162,29 +2251,8 @@ class ServingEngine:
                         smetrics.SERVING_PREFIX_MISS_TOKENS.inc(dm)
                     if de:
                         smetrics.SERVING_PREFIX_EVICTIONS.inc(de)
-            if trace_on:
-                self.flight.note(
-                    ts=t0, dur=snap["dur"],
-                    prefill_tokens=snap["prefill_tokens"],
-                    decode_tokens=snap["decode_tokens"],
-                    active_slots=snap["active_slots"],
-                    queue_depth=snap["queue_depth"],
-                    spec_accept_tokens=(
-                        snap["spec_acc"] + sum(snap["spec_hist"])
-                        if snap["spec_hist"] else 0),
-                    spec_groups=(sum(snap["spec_hist"])
-                                 if snap["spec_hist"] else 0),
-                    sparse_skip_ratio=(
-                        1.0 - snap["sparse_sel"] / snap["sparse_cand"]
-                        if self._sparse and snap["sparse_cand"]
-                        else 0.0),
-                    blocks_imported=snap["blocks_imported"],
-                    compile_cache_size=compiled,
-                    ticks=snap["ticks"],
-                    host_stall=snap["host_stall"],
-                    early_exit_finish=snap["ev_finish"],
-                    early_exit_overflow=snap["ev_over"],
-                    **self._flight_extra())
+            if record is not None:
+                self.flight.note(**record)
 
         if sch.has_work:
             self._deferred = observe

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import asyncio
 
+from . import tracing as _tracing
 from .batcher import FairQueue
 
 _DONE = object()
@@ -448,17 +449,43 @@ class ServingFrontend:
             for handle in list(self._live):
                 self._finish_handle(handle, e)
             self._live.clear()
+        finally:
+            # stopped mid-cycle: no host phase stays open
+            self.engine.phases.close()
+
+    def _traced_step(self):
+        """`engine.step` on the executor thread, the hop back to the
+        loop marked from the instant it returns."""
+        did = self.engine.step()
+        self.engine.phases.mark("frontend.hop_out")
+        return did
 
     async def _step_loop_inner(self):
         loop = asyncio.get_running_loop()
+        ph = self.engine.phases
         backoff = 0.0
         while not self._closed:
+            # host phases of the cycle (tracing.HOST_PHASES): the
+            # engine marks its own inside step(); sampled once a cycle
+            trace_on = _tracing._enabled
+            if trace_on:
+                ph.mark("frontend.admit", self.engine.steps_run)
+            elif ph.name is not None:
+                # tracing went off mid-cycle: drop the open phase
+                ph.close()
+                ph.take()
             self._apply_cancellations()
             self._apply_extractions()
             self._admit_pending()
             if self.engine.scheduler.has_work:
                 self.step_calls += 1
-                did = await loop.run_in_executor(None, self.engine.step)
+                if trace_on:
+                    ph.mark("frontend.hop_in")
+                did = await loop.run_in_executor(
+                    None,
+                    self._traced_step if trace_on else self.engine.step)
+                if trace_on:
+                    ph.mark("frontend.publish")
                 self._stream_blocks()
                 self._publish()
                 if did:
@@ -494,6 +521,8 @@ class ServingFrontend:
             flush = getattr(self.engine, "flush_observability", None)
             if flush is not None:
                 flush()
+            if trace_on:
+                ph.close()           # waiting for work is no phase
             self._wake.clear()
             soonest = self._next_pending_deadline()
             try:

@@ -16,9 +16,16 @@ Two host-side event stores, both bounded, both branch-gated like
   `_REOPEN_EVENTS`) — the surviving replica's terminal outcome wins.
 * **Step flight recorders** (`StepFlightRecorder`, one per engine) —
   a bounded ring of per-step records (role, tokens prefilled/decoded,
-  active slots, spec accept length, sparse skip ratio, blocks
-  imported, jit cache size, step wall time) exportable as chrome
-  "X" slices on an `engine:<name>` track.
+  active slots, sparse skip ratio, blocks imported, jit cache size,
+  step wall time, the step's host phases, the KV tokens it read and
+  the pool's occupancy) exportable as chrome "X" slices on an
+  `engine:<name>` track, the phases nested under their step.
+* **Host phases** (`PhaseMarker`, one per engine, `HOST_PHASES`) —
+  where a serving cycle's host time goes, on two clocks at once: the
+  engine's monotonic clock (durations land in the step's flight
+  record) and the profiler's own, as `jax.profiler.TraceAnnotation`s
+  that sit on `/host:CPU` beside the device planes of a
+  `jax.profiler` trace, which is the only clock device ops share.
 
 Both stores register with the profiler's provider hooks
 (`profiler.register_chrome_source` / `register_summary_section`), so
@@ -52,8 +59,9 @@ from . import metrics as _smetrics
 
 __all__ = [
     "TRACER", "RequestTracer", "Trace", "TraceEvent",
-    "StepFlightRecorder", "enable", "disable", "enabled",
-    "register_flight_recorder", "flight_recorders",
+    "StepFlightRecorder", "PhaseMarker", "HOST_PHASES", "enable",
+    "disable", "enabled", "register_flight_recorder",
+    "flight_recorders",
 ]
 
 _enabled = os.environ.get(
@@ -485,6 +493,83 @@ def on_terminal(req, outcome, replica=None, ts=None):
                    ts if ts is not None else TRACER.clock())
 
 
+# ------------------------------------------------------- host phases
+#: the host phases of one serving cycle, in the order they run; they
+#: tile it end to end. The `frontend.*` four are marked by
+#: `ServingFrontend`'s step loop, the `engine.*` six by
+#: `ServingEngine.step`. docs/OBSERVABILITY.md says what bounds each.
+HOST_PHASES = (
+    "frontend.admit", "frontend.hop_in", "engine.plan", "engine.pack",
+    "engine.dispatch", "engine.wait", "engine.emit", "engine.note",
+    "frontend.hop_out", "frontend.publish",
+)
+
+
+def _phase_field(name):
+    """`engine.plan` -> `ph_plan`: the flat flight-record field."""
+    return "ph_" + name.split(".", 1)[1]
+
+
+class PhaseMarker:
+    """Marks where one engine's host time goes (one per engine).
+
+    `mark(name)` ends the open phase and starts the next at one reading
+    of the engine's clock, so consecutive phases share their boundary
+    and tile the cycle. Each phase is also an open
+    `jax.profiler.TraceAnnotation` named by the phase and carrying
+    `step=<index of the cycle's engine step>`: under a `jax.profiler`
+    session it lands on `/host:CPU`, on the clock of the device ops
+    (which the engine's clock cannot be lined up with; the profiler's
+    own alignment is good to a millisecond or two a session, which
+    tools/host_gaps.py bounds); without a session it costs half a
+    microsecond. Phases may start on one thread and end on another
+    (`frontend.hop_in` does): the annotation then shows on the thread
+    that ended it.
+
+    Seconds per phase accumulate until `take()`, which the engine calls
+    once per step for the flight record. Call sites guard with
+    ``if trace_on:`` like every other tracing hook, so with tracing off
+    no annotation is ever built."""
+
+    __slots__ = ("clock", "step", "name", "_seconds", "_t0", "_note")
+
+    def __init__(self, clock=time.monotonic):
+        self.clock = clock
+        self.step = 0
+        self.name = None            # the open phase
+        self._seconds = {}
+        self._t0 = self._note = None
+
+    def mark(self, name, step=None):
+        """End the open phase, start `name`; returns the clock reading
+        both share. `step` names the cycle from here on."""
+        import jax
+        now = self.close()
+        if step is not None:
+            self.step = step
+        self.name, self._t0 = name, now
+        self._note = jax.profiler.TraceAnnotation(name, step=self.step)
+        self._note.__enter__()
+        return now
+
+    def close(self):
+        """End the open phase, if any; returns the clock reading."""
+        now = self.clock()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._seconds[self.name] = self._seconds.get(
+                self.name, 0.0) + now - self._t0
+            self.name = self._t0 = self._note = None
+        return now
+
+    def take(self):
+        """{flight field: seconds} of the phases ended since the last
+        `take()`."""
+        out = {_phase_field(k): v for k, v in self._seconds.items()}
+        self._seconds = {}
+        return out
+
+
 # ------------------------------------------------- step flight recorder
 _FLIGHT = weakref.WeakSet()
 
@@ -522,16 +607,43 @@ class StepFlightRecorder:
         self.steps += 1
 
     def chrome_events(self):
+        """One "X" slice per step, its engine phases nested under it in
+        the order they run (a multi-tick step flushes the previous
+        dispatch's notes before its wait, not after its emit: its
+        `engine.note` is drawn late), and the frontend's phases of the
+        gap before it beside it: hop-out and publish from the end of
+        the previous step, admit and hop-in up to this one."""
         pid = os.getpid()
         tid = f"engine:{self.engine_name}"
         out = []
+        before, in_step, after = (HOST_PHASES[:2], HOST_PHASES[2:8],
+                                  HOST_PHASES[8:])
+
+        def lay(names, r, at, back=False):
+            spans = [(n, r[_phase_field(n)]) for n in names
+                     if _phase_field(n) in r]
+            if back:
+                at -= sum(d for _, d in spans)
+            for name, dur in spans:
+                out.append({"name": name, "ph": "X", "ts": at * 1e6,
+                            "dur": dur * 1e6, "pid": pid, "tid": tid,
+                            "args": {}})
+                at += dur
+
         for r in self.records:
+            ts = r.get("ts", 0.0)
             args = {k: v for k, v in r.items()
                     if k not in ("ts", "dur")}
             out.append({"name": f"step[{self.role}]", "ph": "X",
-                        "ts": r.get("ts", 0.0) * 1e6,
+                        "ts": ts * 1e6,
                         "dur": r.get("dur", 0.0) * 1e6,
                         "pid": pid, "tid": tid, "args": args})
+            lay(in_step, r, ts)
+            if "gap_before" in r:
+                lay(after, r, ts - r["gap_before"])
+                lay(before, r, ts, back=True)
+            else:
+                lay(after + before, r, ts, back=True)
         return out
 
     def summary(self):

@@ -373,6 +373,378 @@ class TestEngineTracing:
         assert on <= off * 2.0 + 0.05, (on, off)
 
 
+# ------------------------------------------ host phases and work counters
+
+ENGINE_FIELDS = ("ph_plan", "ph_pack", "ph_dispatch", "ph_wait",
+                 "ph_emit", "ph_note")
+FRONTEND_FIELDS = ("ph_hop_out", "ph_publish", "ph_admit", "ph_hop_in")
+
+
+def _serve(eng, prompts, max_new_tokens=6):
+    """The prompts through a `ServingFrontend`, all at once."""
+    async def run():
+        async with ServingFrontend(eng, max_pending=16) as fe:
+            return await asyncio.gather(*[
+                fe.submit(p, max_new_tokens=max_new_tokens)
+                for p in prompts])
+    return asyncio.run(run())
+
+
+def _brute_work(plan):
+    """(kv_tokens_read, attn_pairs) counted key by key."""
+    read = pairs = 0
+    groups = [(pos, np.atleast_1d(tok).size)
+              for _, tok, pos in plan.decode]
+    groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
+    for start, n in groups:
+        read += len({k for q in range(start, start + n)
+                     for k in range(q + 1)})
+        pairs += sum(1 for q in range(start, start + n)
+                     for k in range(q + 1))
+    return read, pairs
+
+
+class TestHostPhases:
+    @pytest.mark.parametrize("ticks", (1, 4))
+    @pytest.mark.parametrize("behind", ("frontend", "solo"))
+    def test_phases_tile_step_and_gap(self, ticks, behind):
+        eng = _engine(_model(), ticks_per_dispatch=ticks)
+        prompts = [_prompt(n, seed=n) for n in (5, 9, 13)]
+        eng.generate_batch([[7, 7]], max_new_tokens=1)    # warm compile
+        tracing.enable()
+        if behind == "frontend":
+            _serve(eng, prompts, max_new_tokens=8)
+        else:
+            eng.generate_batch(prompts, max_new_tokens=8)
+        eng.flush_observability()
+        recs = list(eng.flight.records)
+        assert len(recs) == eng.flight.steps >= 4
+        for r in recs:
+            # one shape of record on both paths: the engine's phases
+            # tile the step
+            have = [f for f in ENGINE_FIELDS if f in r]
+            assert set(ENGINE_FIELDS[:5]) <= set(have), r
+            assert sum(r[f] for f in have) == pytest.approx(
+                r["dur"], abs=2e-4)
+            assert all(r[f] >= 0 for f in have)
+        assert "gap_before" not in recs[0]
+        for a, b in zip(recs, recs[1:]):
+            assert b["gap_before"] == pytest.approx(
+                b["ts"] - (a["ts"] + a["dur"]), abs=1e-9)
+            assert b["gap_before"] >= 0
+            if behind == "frontend":
+                # the four frontend phases of the gap before the step
+                # ride on its record, and fit inside that gap
+                assert set(FRONTEND_FIELDS) <= set(b), b
+                assert sum(b[f] for f in FRONTEND_FIELDS) <= \
+                    b["gap_before"] + 2e-4
+            else:
+                assert not set(FRONTEND_FIELDS) & set(b)
+        if ticks > 1:
+            assert any(r["ticks"] > 1 for r in recs)
+        # nothing is left open or untaken once the engine is idle
+        assert eng.phases.name is None or behind == "solo"
+
+    @pytest.mark.parametrize("ticks", (1, 4))
+    def test_work_counters_match_brute_force(self, ticks):
+        """Chunked prefill riding with decode: a short request decodes
+        while a long prompt is fed in chunks of the token budget."""
+        eng = _engine(_model(), token_budget=8, max_slots=3,
+                      ticks_per_dispatch=ticks)
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        plans = []
+        real_plan = eng.scheduler.plan
+
+        def plan():
+            p = real_plan()
+            if not p.empty:
+                plans.append(p)
+            return p
+        eng.scheduler.plan = plan
+        tracing.enable()
+        eng.submit(_prompt(5, seed=1), max_new_tokens=12)
+        eng.step()
+        eng.submit(_prompt(27, seed=2), max_new_tokens=3)
+        eng.run()
+        eng.flush_observability()
+        recs = list(eng.flight.records)
+        assert len(recs) == len(plans) >= 5
+        assert any(p.decode and p.prefills for p in plans)
+        assert any(start > 0 for p in plans
+                   for _, _, start, _ in p.prefills)
+        for r, p in zip(recs, plans):
+            read, pairs = _brute_work(p)
+            if r.get("ticks", 1) == 1:
+                assert (r["kv_tokens_read"], r["attn_pairs"]) == \
+                    (read, pairs), (r, p)
+            else:
+                # later ticks of the device loop: one query a token
+                assert not p.prefills
+                extra = r["decode_tokens"] - len(p.decode)
+                assert extra > 0
+                assert r["kv_tokens_read"] - read == \
+                    r["attn_pairs"] - pairs >= extra
+        assert all(r["kv_tokens_read"] > 0 for r in recs)
+        if ticks > 1:
+            assert any(r["ticks"] > 1 for r in recs)
+            # a slot that decodes c tokens from pos reads pos+1..pos+c
+            r, p = next((r, p) for r, p in zip(recs, plans)
+                        if r["ticks"] > 1 and len(p.decode) == 1)
+            pos, c = p.decode[0][2], r["decode_tokens"]
+            assert r["attn_pairs"] == sum(pos + j + 1 for j in range(c))
+
+    def test_pool_counters_read_at_note_time(self):
+        eng = _engine(_model(), token_budget=8)
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        seen = []
+        note = eng.flight.note
+
+        def spy(**fields):
+            seen.append((eng.kv.blocks_in_use,
+                         eng.scheduler.preemption_count))
+            note(**fields)
+        eng.flight.note = spy
+        tracing.enable()
+        eng.generate_batch([_prompt(n, seed=n) for n in (6, 11, 19)],
+                           max_new_tokens=7)
+        recs = list(eng.flight.records)
+        assert len(recs) == len(seen) > 3
+        assert [(r["kv_blocks_in_use"], r["preemptions"])
+                for r in recs] == seen
+        assert {r["kv_blocks_total"] for r in recs} == {eng.kv.num_blocks}
+        assert max(r["kv_blocks_in_use"] for r in recs) > 0
+        assert len({r["kv_blocks_in_use"] for r in recs}) > 1
+
+    def test_off_builds_no_annotation_and_notes_nothing(self, monkeypatch):
+        import jax
+        built = []
+        real = jax.profiler.TraceAnnotation
+
+        class Counting(real):
+            def __init__(self, *a, **kw):
+                built.append(a[0])
+                super().__init__(*a, **kw)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        eng = _engine(_model())
+        prompts = [_prompt(n, seed=n) for n in (5, 8)]
+        want = _serve(eng, prompts)
+        compiles = eng.step_compile_count()
+        assert built == [] and eng.flight.steps == 0
+        assert eng.phases.take() == {} and eng.phases.name is None
+        assert TRACER.traces() == []
+        # on: the same tokens, the same one executable, and every
+        # phase of the cycle gets its annotation
+        tracing.enable()
+        assert _serve(eng, prompts) == want
+        assert eng.step_compile_count() == compiles == 1
+        assert set(built) == set(tracing.HOST_PHASES)
+        assert eng.flight.steps > 0 and eng.phases.name is None
+
+    def test_annotations_land_on_the_profilers_clock(self, tmp_path):
+        """Under a `jax.profiler` session every phase is an event of
+        `/host:CPU`, the plane device ops share a clock with, and
+        carries the cycle's step index."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        eng = _engine(_model())
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        tracing.enable()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            first = eng.steps_run
+            _serve(eng, [_prompt(n, seed=n) for n in (5, 9)])
+        finally:
+            jax.profiler.stop_trace()
+        data = ProfileData.from_file(glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))[0])
+        steps = {}
+        for plane in data.planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in tracing.HOST_PHASES:
+                        stats = dict(ev.stats)
+                        assert "step" in stats, ev.name
+                        steps.setdefault(ev.name, []).append(
+                            int(stats["step"]))
+        assert set(steps) == set(tracing.HOST_PHASES)
+        ran = set(range(first, eng.steps_run))
+        for name in tracing.HOST_PHASES[2:]:
+            # every step's own phases carry its index
+            assert ran <= set(steps[name]), (name, steps[name])
+        assert set(steps["frontend.hop_in"]) >= ran
+
+    def test_chrome_events_nest_phases_under_their_step(self):
+        eng = _engine(_model(), name="nest_t")
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        tracing.enable()
+        _serve(eng, [_prompt(7, seed=3)])
+        evs = [e for e in eng.flight.chrome_events()
+               if e["tid"] == "engine:nest_t"]
+        steps = [e for e in evs if e["name"].startswith("step[")]
+        inner = [e for e in evs if e["name"].startswith("engine.")]
+        outer = [e for e in evs if e["name"].startswith("frontend.")]
+        assert len(steps) == eng.flight.steps
+        assert len(inner) == 6 * len(steps)
+        assert {e["name"] for e in inner + outer} == \
+            set(tracing.HOST_PHASES)
+        for e in inner:
+            assert any(s["ts"] - 1 <= e["ts"] and e["ts"] + e["dur"]
+                       <= s["ts"] + s["dur"] + 200 for s in steps), e
+        for e in outer:
+            # between steps, never inside one
+            assert not any(s["ts"] + 1 < e["ts"] + e["dur"] / 2
+                           < s["ts"] + s["dur"] - 1 for s in steps), e
+
+    def test_stop_or_disable_mid_cycle_leaves_no_phase_open(self):
+        eng = _engine(_model())
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        tracing.enable()
+
+        async def run():
+            fe = ServingFrontend(eng, max_pending=4)
+            await fe.start()
+            task = asyncio.ensure_future(
+                fe.submit(_prompt(9), max_new_tokens=40))
+            while eng.flight.steps < 3:
+                await asyncio.sleep(0.001)
+            assert eng.phases.name is not None      # mid-cycle
+            await fe.stop()                         # while busy
+            with pytest.raises(Exception):
+                await task
+            assert eng.phases.name is None
+            # tracing switched off between two cycles: the phase the
+            # frontend had open is dropped, not charged to a later step
+            fe = ServingFrontend(eng, max_pending=4)
+            await fe.start()
+            task = asyncio.ensure_future(
+                fe.submit(_prompt(9), max_new_tokens=30))
+            while eng.flight.steps < 6:
+                await asyncio.sleep(0.001)
+            tracing.disable()
+            await task
+            await fe.stop()
+        asyncio.run(run())
+        assert eng.phases.name is None and eng.phases.take() == {}
+
+    def test_host_gaps_tool_names_idle_time_by_phase(self, tmp_path,
+                                                     capsys):
+        """tools/host_gaps.py on a trace made here (CPU: host events
+        with an `hlo_op` stat stand in for device ops), and its split of
+        a gap among the phases on a hand-made list."""
+        import importlib.util
+        import os
+
+        import jax
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "host_gaps.py")
+        spec = importlib.util.spec_from_file_location("host_gaps", path)
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        ms = 1_000_000
+        host = [("engine.wait", 0, 10 * ms), ("engine.emit", 10 * ms, ms),
+                ("frontend.hop_out", 11 * ms, 3 * ms),
+                ("client_idle", 0, 100 * ms),
+                ("engine.pack", 16 * ms, 2 * ms)]
+        # a gap of 9..17 ms: 1 wait, 1 emit, 3 hop_out, 2 nobody's,
+        # 1 pack; and one of 30..31 ms that no phase covers
+        got = tool.split_by_phase(
+            [(0.009, 0.008, "x"), (0.030, 0.001, "x")], host)
+        assert got == pytest.approx({
+            "engine.wait": 0.001, "engine.emit": 0.001,
+            "frontend.hop_out": 0.003, "engine.pack": 0.001,
+            "host_between_dispatches": 0.003})
+        # the device planes run ahead of the host's by at least the
+        # most a step program leads the dispatch that launches it
+        device = {"/device:TPU:0": {
+            "ops": [("fusion.1", 4 * ms, ms)],
+            "modules": [("jit_serving_mixed_step(1)", 4 * ms, 60 * ms),
+                        ("jit__threefry_split(2)", 1 * ms, 1000),
+                        ("jit_serving_mixed_step(1)", 71 * ms, 60 * ms)]}}
+        host = [("engine.dispatch", 6 * ms, ms),
+                ("engine.dispatch", 72 * ms, ms)]
+        assert tool.device_lead(device, host) == 2 * ms
+        assert tool.device_lead(device, host[1:]) == 1 * ms
+        assert tool.device_lead(device, [("engine.dispatch", ms, ms)]) == 0
+        assert tool.later(device, 2 * ms)["/device:TPU:0"]["ops"] == [
+            ("fusion.1", 6 * ms, ms)]
+
+        eng = _engine(_model())
+        eng.generate_batch([[7, 7]], max_new_tokens=1)
+        tracing.enable()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _serve(eng, [_prompt(n, seed=n) for n in (5, 9)])
+        finally:
+            jax.profiler.stop_trace()
+        assert tool.main([str(tmp_path), "--rehearse"]) == 0
+        out = capsys.readouterr().out
+        assert "CPU REHEARSAL" in out
+        for name in tracing.HOST_PHASES:
+            assert name in out
+        assert "under a phase name" in out
+        assert tool.main([]) == 2
+
+
+class TestXplaneTables:
+    def test_device_op_table_counts_calls_without_tensorflow(
+            self, tmp_path, monkeypatch):
+        import sys
+        import types
+
+        from paddle_tpu.profiler import xplane
+        monkeypatch.setitem(sys.modules, "tensorflow", None)
+        ev = lambda name, ns: types.SimpleNamespace(  # noqa: E731
+            name=name, duration_ns=ns)
+        data = types.SimpleNamespace(planes=[
+            types.SimpleNamespace(name="/device:TPU:0", lines=[
+                types.SimpleNamespace(name="XLA Ops", events=[
+                    ev("%fusion.1 = f32[] fusion()", 3000),
+                    ev("paged_ragged.3", 2000), ev("paged_ragged.3", 2500)]),
+                types.SimpleNamespace(name="XLA Modules", events=[
+                    ev("jit_step(1)", 9000)])]),
+            types.SimpleNamespace(name="/host:CPU", lines=[
+                types.SimpleNamespace(name="XLA Ops", events=[
+                    ev("host", 7)])])])
+        assert xplane.device_op_stats(data) == {
+            "%fusion.1 = f32[] fusion()": [3000, 1],
+            "paged_ragged.3": [4500, 2]}
+        assert xplane.device_op_times(data).most_common(1) == [
+            ("paged_ragged.3", 4500)]
+        monkeypatch.setattr(xplane, "load_xplane", lambda d: data)
+        assert xplane.device_op_table("x", n_steps=2) == [
+            ("paged_ragged.3", 0.00225, 2),
+            ("%fusion.1 = f32[] fusion()", 0.0015, 1)]
+
+    def test_reads_a_real_trace_with_jax_alone(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu import profiler
+        from paddle_tpu.profiler import xplane
+        with pytest.raises(FileNotFoundError):
+            xplane.load_xplane(str(tmp_path))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            jnp.ones((8, 8)).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        data = xplane.load_xplane(str(tmp_path))
+        assert any(p.name == "/host:CPU" for p in data.planes)
+        # a CPU trace has no TPU plane: an empty table, not an error
+        assert xplane.device_op_table(str(tmp_path)) == []
+        assert "Device (TPU) Op Summary" in profiler.summary(
+            trace_dir=str(tmp_path))
+
+
 # ------------------------------------------------- stitching edge cases
 
 

@@ -6,9 +6,7 @@ Finds the newest .xplane.pb under <trace_dir>, sums duration by HLO op
 name on the TPU device plane's "XLA Ops" line, and prints a per-step
 table (total / n_steps).  The device trace is the ground truth for
 per-kernel time; a host clock around one async dispatch is not.
-
-Requires PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python (the in-image
-C++ protobuf lacks the xplane descriptor); set automatically below.
+The trace is read with `jax.profiler.ProfileData`: nothing but jax.
 """
 import collections
 import os
