@@ -11,10 +11,10 @@ numbers self-maintaining:
 
 * **Search spaces** parameterize the tunable axes of each kernel
   entry — block/tile sizes (flash ``block_q``/``block_k``, splash's
-  six block numbers, grouped-matmul ``block_c/f/d``), grid layout /
-  pipeline behaviour (``dimension_semantics`` per grid axis for the
-  paged family), and the engine-level KV ``block_size`` whose choice
-  reshapes every paged tile.
+  six block numbers, grouped-matmul ``block_c/f/d``), the KV blocks
+  per compute step of the paged family (``kv_blocks``), and the
+  engine-level KV ``block_size`` whose choice reshapes every paged
+  tile.
 * **Candidates are measured, not modeled**: `search()` times each
   admitted candidate with the PR 1 timer statistics (min over a
   window of repeats — the same `profiler.timer._Stat` the throughput
@@ -67,11 +67,13 @@ import numpy as np
 # alignment constraints — ONE source of truth for the dispatch gate
 # (paged_attention.paged_pallas_enabled) AND every tuner candidate
 # filter. In the [BS, H, Dh] KV tile head_dim rides the 128-wide lane
-# axis and H the sublane axis; block_size is a leading axis. Mosaic
-# pads H up to the dtype's sublane count (8 fp32, 16 bf16, 32 int8/fp8):
-# H=2 at every pool dtype and H=16 in fp32/bf16 match their oracles on
-# a v5e (PR 21), so H is not gated. The block_size multiple is kept as
-# the tuner's candidate discipline, not because Mosaic needs it.
+# axis and H the sublane axis; block_size is a leading axis. The
+# run-major kernel (PR 26) folds a tile to [BS * H, Dh] rows and was
+# compiled for a described v5e at H = 2, 8, 16, 32 (fp32 / bf16 pools)
+# and, for int8 / fp8 pools, wherever BS * H fills whole lane tiles —
+# the one head-count condition, which `paged_pallas_enabled` adds for
+# quantized pools. The block_size multiple is kept as the tuner's
+# candidate discipline, not because Mosaic needs it.
 # ---------------------------------------------------------------------
 
 LANE_ALIGN = 128
@@ -517,20 +519,15 @@ def splash_candidates(seq_len):
     return cands
 
 
-#: grid-layout / pipeline variants for the paged family: how Mosaic
-#: may schedule the (group, kv-block) grid. The kv-block axis carries
-#: the online-softmax carry, so it is always "arbitrary" (sequential);
-#: the group axis can be declared parallel, letting the pipeline
-#: overlap groups, or left arbitrary (the conservative default).
-PAGED_DIMENSION_SEMANTICS = (
-    ("arbitrary", "arbitrary"),
-    ("parallel", "arbitrary"),
-)
+#: KV blocks per compute step of the run-major paged kernel: how many
+#: pool blocks one double-buffered fetch brings and one MXU product
+#: attends. Absent a cached winner the kernel picks from its shapes
+#: (`paged_attention.run_tiles`: 8 at H = 16, BS = 16 — 128 keys).
+PAGED_KV_BLOCKS = (4, 8, 16)
 
 
 def paged_candidates():
-    return [{"dimension_semantics": list(ds)}
-            for ds in PAGED_DIMENSION_SEMANTICS]
+    return [{"kv_blocks": g} for g in PAGED_KV_BLOCKS]
 
 
 def paged_block_size_candidates(head_dim, max_seq_len=None):

@@ -482,21 +482,23 @@ def _check_pool_heads(name, h_q, k_pool, v_pool):
             "pools together on the 'mp' axis)")
 
 
-def _paged_kernel_enabled(head_dim, block_size):
+def _paged_kernel_enabled(head_dim, block_size, heads, quantized):
     """True -> the block-table-native kernel runs (and a Mosaic refusal
     raises); False -> the gather reference, said out loud when that
     happens on a TPU for any reason but the operator's kill-switch."""
     from . import paged_attention as _pk
-    if _pk.paged_pallas_enabled(head_dim, block_size):
+    if _pk.paged_pallas_enabled(head_dim, block_size, heads, quantized):
         return True
     if not _pk.pallas_killed():
         from . import xla_fallback
         xla_fallback("paged_attention",
                      f"the gate refuses head_dim={head_dim}, "
-                     f"block_size={block_size} (needs head_dim % 128 "
-                     "== 0 and block_size % 8 == 0); the gather "
-                     "reference materialises every slot's whole "
-                     "context per query")
+                     f"block_size={block_size}, heads={heads}"
+                     f"{' (quantized pools)' if quantized else ''} "
+                     "(needs head_dim % 128 == 0, block_size % 8 == 0 "
+                     "and, for quantized pools, block_size * heads % "
+                     "128 == 0); the gather reference materialises "
+                     "every slot's whole context per query")
     return False
 
 
@@ -512,7 +514,8 @@ def _gather_dequant(pool, scale_pool, bt, q_dtype):
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
-                           scale=None, kernel_name="paged_ragged"):
+                           scale=None, kernel_name="paged_ragged",
+                           runs=None):
     """Flat-token attention over a block-paged KV cache — the kernel of
     the continuous-batching mixed step (`paddle_tpu.serving.engine`),
     following the Ragged-Paged-Attention shape discipline: ONE fixed
@@ -544,7 +547,11 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     (`ops.pallas.paged_attention.ragged_attend`) — no gathered
     contiguous KV copy is ever materialized; `PADDLE_TPU_PAGED_PALLAS=0`
     or a CPU backend keeps the pure-XLA gather path below, which runs
-    under JAX_PLATFORMS=cpu and is the parity oracle.
+    under JAX_PLATFORMS=cpu and is the parity oracle. The kernel walks
+    each QUERY RUN's slot once (`paged_attention.paged_runs`: a decode
+    token is a run of 1, a prefill chunk one run); `runs` hands it the
+    runs of (slot_ids, positions) a caller already derived — the
+    serving step does, once, outside its layer scan.
 
     Tensor parallelism: the TP serving engine
     (`serving.distributed.tp_engine`) calls this INSIDE shard_map with
@@ -556,11 +563,11 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     BS = k_pool.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    if _paged_kernel_enabled(Dh, BS):
+    if _paged_kernel_enabled(Dh, BS, H, k_scale is not None):
         from .paged_attention import ragged_attend
         return ragged_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
-                             kernel_name=kernel_name)
+                             kernel_name=kernel_name, runs=runs)
     return ragged_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
                                    v_scale, scale=scale)
@@ -626,7 +633,7 @@ def verify_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     BS = k_pool.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    if _paged_kernel_enabled(Dh, BS):
+    if _paged_kernel_enabled(Dh, BS, H, k_scale is not None):
         from .paged_attention import verify_attend
         return verify_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
@@ -683,7 +690,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     BS = k_pool.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    if _paged_kernel_enabled(Dh, BS):
+    if _paged_kernel_enabled(Dh, BS, H, k_scale is not None):
         from .paged_attention import decode_attend
         return decode_attend(q, k_pool, v_pool, block_tables,
                              context_lens, k_scale, v_scale,

@@ -1,36 +1,42 @@
-"""Block-table-native Pallas TPU kernels for paged attention.
+"""Block-table-native Pallas TPU kernel for paged attention.
 
-The serving engine's three attention shapes — ragged chunked prefill
-(one query per flat token), K-wide speculative verify (K consecutive
-queries per slot) and K=1 decode — all reduce to ONE grouped pattern:
-`G` queries that share a slot attend that slot's paged K/V at key
-positions `<= their own`. The pure-XLA paths in
+The serving engine's three attention shapes — ragged chunked prefill,
+K-wide speculative verify and K=1 decode — are ONE pattern: a **query
+run**, consecutive flat tokens that share a slot and carry consecutive
+positions (a decode token is a run of 1, a prefill chunk of n tokens
+one run of n, a verify group a run of K), attends its slot's paged K/V
+at key positions `<= each query's own`. The pure-XLA paths in
 `ops.pallas.flash_attention` gather the slot's whole block list into a
-contiguous `[S_max, H, Dh]` copy before attending; these kernels never
-materialize that copy. Instead the grid iterates the
-`[max_slots, max_blocks]` block tables directly:
+contiguous `[S_max, H, Dh]` copy per query before attending; this
+kernel never materializes that copy, and walks each run's slot ONCE:
 
-* the block tables, owning-slot ids and per-query positions ride in
-  **scalar memory** (`pltpu.PrefetchScalarGridSpec`), so each grid
-  step's KV tile address is computed from the table BEFORE the body
-  runs and Pallas double-buffers the `[block_size, H, Dh]` tile fetch
-  against compute;
-* the body runs **online softmax** (running max / denominator /
-  weighted accumulator in VMEM scratch) over one KV block per grid
-  step — peak live KV is one tile per buffer, not one sequence;
-* **per-slot context-length masking** zeroes keys past the query's
-  position, which also guarantees the NULL block's garbage and the
-  unwritten tail of the newest block are never read through;
-* KV tiles past the query group's last needed block are skipped with
-  `pl.when` (the grid is rectangular over `max_blocks`, real work is
-  ragged).
+* one invocation a layer loops over the step's runs (`paged_runs`,
+  derived from `slot_ids` / `positions` with fixed shapes); run
+  metadata and the `[max_slots, max_blocks]` block tables ride in
+  **scalar memory** (`pltpu.PrefetchScalarGridSpec`), the pools stay
+  in HBM;
+* per run, `cdiv(last_pos // BS + 1, G)` **double-buffered fetches**
+  of G KV blocks (`make_async_copy` from `block_tables[slot, col]`),
+  the next fetch — of this run or the next — flying while the current
+  group is attended: the work follows the contexts, nothing is sized
+  by `max_blocks`;
+* every q tile of the run attends each fetched group while it sits in
+  VMEM. Rows are (token, head) pairs and columns (key, head) pairs, so
+  one `[rows, Dh] x [Dh, cols]` MXU product in the pools' precision
+  (fp32 accumulation) gives every head's logits with NO relayout of
+  the `[BS, H, Dh]` tiles; one int32 compare applies the
+  head-diagonal and the causal mask together;
+* **online softmax** (running max / denominator / weighted accumulator
+  in fp32 VMEM scratch) per run; **context-length masking** hides the
+  unwritten tail of the newest block, blocks past the run's last
+  position are never fetched, padding rows leave as zeros.
 
 Quantized pools: with `k_scale`/`v_scale` (`[NB, BS, H]` fp32,
 per-pool-entry-per-head — see `serving.kv_cache.PagedKVCache`), the
-K/V tiles arrive int8 and are dequantized INSIDE the kernel right
-after the tile load; the scale tiles ride the same block-table index
-maps as the pools, so quantization adds two small scalar-indexed
-fetches and two VPU multiplies per tile and nothing else changes.
+K/V tiles arrive int8 / fp8 and each block's `BS * H` scales arrive
+as one lane row beside them: K's scales multiply the logits' (key,
+head) columns, V's the probabilities' — the tile's dequantisation,
+applied where that axis is the lane axis.
 
 The XLA gather paths stay the CPU parity oracles and the
 `PADDLE_TPU_PAGED_PALLAS=0` fallback; `tests/test_paged_kernels.py`
@@ -73,7 +79,8 @@ def pallas_killed() -> bool:
     return os.environ.get("PADDLE_TPU_PAGED_PALLAS", "1") == "0"
 
 
-def paged_pallas_enabled(head_dim, block_size) -> bool:
+def paged_pallas_enabled(head_dim, block_size, heads=None,
+                         quantized=False) -> bool:
     """Dispatch gate for the block-table-native kernels.
 
     Env kill-switch first (`PADDLE_TPU_PAGED_PALLAS=0` restores the
@@ -83,175 +90,381 @@ def paged_pallas_enabled(head_dim, block_size) -> bool:
     `_INTERPRET` (tests) any shape runs. The alignment predicate is
     `autotune.paged_alignment_ok` — the SAME source of truth the
     kernel tuner's candidate filters use, so a tuned candidate the
-    serve-time gate would refuse cannot exist (ISSUE 11)."""
+    serve-time gate would refuse cannot exist (ISSUE 11). Quantized
+    pools add one condition: a block's `block_size * heads` scales
+    ride the lane axis beside the logits' (key, head) columns, so
+    they must fill whole 128-lane tiles."""
     if pallas_killed():
         return False
     if _INTERPRET:
         return True
+    if quantized and heads is not None \
+            and (int(block_size) * int(heads)) % autotune.LANE_ALIGN:
+        return False
     return (_on_tpu_backend()
             and autotune.paged_alignment_ok(head_dim, block_size))
 
 
-def _group_positions(pos_ref, g, G):
-    """The group's G query positions as a [G] vector. G is static and
-    tiny (1, or draft_k+1), so per-element SMEM reads unroll."""
-    return jnp.stack([pos_ref[g, j] for j in range(G)])
+def paged_runs(slot_ids, positions):
+    """The query runs of one flat-token step: maximal runs of
+    consecutive flat tokens that share a slot and carry consecutive
+    positions — a decode token is a run of 1, a prefill chunk of n
+    tokens (or a verify group of K) one run of n. Padding tokens
+    (`slot_ids == -1`) belong to no run.
+
+    slot_ids, positions [T] int32 -> (n_runs [1], start [T], length
+    [T], slot [T], first_pos [T]) int32; entries past `n_runs` hold
+    length 0. Fixed shapes, a handful of [T]-sized ops: the serving
+    step derives them once, outside its layer scan."""
+    T = slot_ids.shape[0]
+    slot = slot_ids.astype(jnp.int32)
+    pos = positions.astype(jnp.int32)
+    valid = slot >= 0
+    off = jnp.full((1,), -2, jnp.int32)
+    cont = (valid & (slot == jnp.concatenate([off, slot[:-1]]))
+            & (pos == jnp.concatenate([off, pos[:-1]]) + 1))
+    is_start = valid & ~cont
+    # a run ends where the next token starts one, pads, or the axis ends
+    is_end = valid & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
+    idx = jnp.arange(T, dtype=jnp.int32)
+    start = jnp.sort(jnp.where(is_start, idx, T))
+    end = jnp.sort(jnp.where(is_end, idx, T))
+    length = jnp.where(start < T, end - start + 1, 0)
+    first = jnp.minimum(start, T - 1)
+    n_runs = jnp.sum(is_start, dtype=jnp.int32).reshape(1)
+    return n_runs, start, length, slot[first], pos[first]
 
 
-def _paged_attend_kernel(slot_ref, bt_ref, pos_ref, q_ref, k_ref, v_ref,
-                         *rest, block_size, G, quantized):
-    """One (group, kv-block) grid cell.
+def blocks_walked(runs, block_size):
+    """KV blocks the kernel fetches for `runs`, (first position, tokens)
+    pairs: each run walks its slot's table once, up to its last
+    position, whatever q tiles its tokens are split into."""
+    return sum((pos + n - 1) // block_size + 1 for pos, n in runs)
 
-    Refs: scalar-prefetch (slots [N], block tables [S, MB], positions
-    [N, G]); q tile [1, G, H, Dh]; k/v tiles [1, BS, H, Dh] (int8 when
-    quantized, + [1, BS, H] fp32 scale tiles); out tile [1, G, H, Dh];
-    scratch m/l [H, G] and acc [H, G, Dh] carried across the kv-block
-    grid axis."""
+
+def run_tiles(H, BS, MB):
+    """(G, TQ) from the shapes alone: G KV blocks per compute step so
+    that a step's `G * BS * H` (key, head) rows fill ~2048 MXU columns
+    (128 keys at H = 16), TQ query tokens per q tile so that its
+    `TQ * H` (token, head) rows fill ~128 MXU rows."""
+    G = max(1, min(2048 // (BS * H), 8, MB))
+    TQ = max(1, min(128 // H, 16))
+    return G, TQ
+
+
+def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
+                bt_ref, q_ref, dmat_ref, rowtok_ref, k_hbm, v_hbm, *rest,
+                BS, H, G, TQ, quantized, mxu_dtype):
+    """The whole step in one invocation: for every run, walk the run's
+    slot once — `cdiv(last_pos // BS + 1, G)` double-buffered fetches
+    of G KV blocks — and let every q tile of the run attend each
+    fetched group while it sits in VMEM.
+
+    Rows are (token, head) pairs and columns (key, head) pairs, so one
+    `[R, Dh] x [Dh, C]` MXU product gives the logits of every head at
+    once with no relayout of the `[BS, H, Dh]` pool tiles; `dmat`
+    (column key index where the heads agree, a huge value elsewhere)
+    folds the head-diagonal and the causal mask into one compare.
+
+    Refs: scalar prefetch (run count, start, length, slot, first
+    position; block tables [S, MB]); q [T*H + pad, Dh] fp32, pre-scaled;
+    dmat [TQ*H, C] int32; rowtok [TQ*H, 1] int32 (row -> token of its
+    tile); pools in HBM as [NB, BS*H, Dh] (+ scales [NB, 1, BS*H]);
+    out [T*H + pad, Dh]; scratch: two KV buffers, DMA semaphores and
+    the runs' online-softmax state."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
+         m_ref, l_ref, acc_ref) = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    g = pl.program_id(0)
-    b = pl.program_id(1)
-    nb = pl.num_programs(1)
+        o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
+        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    BH = BS * H
+    C = G * BH
+    n_runs = nruns_ref[0]
+    last_run = rstart_ref.shape[0] - 1
 
-    @pl.when(b == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = _group_positions(pos_ref, g, G)            # [G] int32
-    max_pos = pos[G - 1] if G > 1 else pos[0]
-    # positions within a verify group ascend, but take the true max so
-    # the skip never depends on that packing detail
-    for j in range(G - 1):
-        max_pos = jnp.maximum(max_pos, pos[j])
-
-    @pl.when(b * block_size <= max_pos)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)             # [G, H, Dh]
-        k = k_ref[0].astype(jnp.float32)             # [BS, H, Dh]
-        v = v_ref[0].astype(jnp.float32)
+    def copies(buf, slot, col, j):
+        blk = bt_ref[slot, col]
+        out = [pltpu.make_async_copy(
+                   k_hbm.at[blk], kbuf.at[buf, pl.ds(j * BH, BH)],
+                   sem.at[buf, 0]),
+               pltpu.make_async_copy(
+                   v_hbm.at[blk], vbuf.at[buf, pl.ds(j * BH, BH)],
+                   sem.at[buf, 1])]
         if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)[..., None]
-            v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        # [H, G, BS] logits: one MXU contraction per head over Dh
-        s = jax.lax.dot_general(
-            jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        key_pos = b * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (G, block_size), 1)           # [G, BS]
-        keep = key_pos <= pos[:, None]               # [G, BS]
-        s = jnp.where(keep[None], s, MASK_VALUE)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=-1)                  # [H, G]
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zeroing: on an all-masked tile s == m_new == MASK
-        # and exp(0) would otherwise count the mask as probability 1
-        p = jnp.exp(s - m_new[..., None]) * keep[None].astype(jnp.float32)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[..., None]
-                        + jax.lax.dot_general(
-                            p, jnp.swapaxes(v, 0, 1),
-                            (((2,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32))
+            out += [pltpu.make_async_copy(
+                        ks_hbm.at[blk],
+                        ksbuf.at[buf, :, pl.ds(j * BH, BH)],
+                        sem.at[buf, 2]),
+                    pltpu.make_async_copy(
+                        vs_hbm.at[blk],
+                        vsbuf.at[buf, :, pl.ds(j * BH, BH)],
+                        sem.at[buf, 3])]
+        return out
 
-    @pl.when(b == nb - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)[..., None]    # [H, G, 1]
-        out = acc_ref[...] / l                           # [H, G, Dh]
-        o_ref[0] = jnp.swapaxes(out, 0, 1).astype(o_ref.dtype)
+    def fetch(buf, slot, g, nblk, wait):
+        """Start (or wait for) the copies of group g of `slot`: only
+        the blocks the run needs; the rest of the buffer keeps an
+        older group's (finite) contents, which the mask hides."""
+        for j in range(G):
+            col = g * G + j
+
+            @pl.when(col < nblk)
+            def _(col=col, j=j):
+                for c in copies(buf, slot, col, j):
+                    c.wait() if wait else c.start()
+
+    # stale buffer contents are masked, never trusted: make them finite
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    if quantized:
+        ksbuf[...] = jnp.zeros_like(ksbuf)
+        vsbuf[...] = jnp.zeros_like(vsbuf)
+    # padding rows are never attended: they leave as zeros
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def run_blocks(r):
+        # at least one, so every run is an item of the fetch chain
+        return jnp.maximum(rpos_ref[r] + rlen_ref[r] - 1, 0) // BS + 1
+
+    @pl.when(n_runs > 0)
+    def _prime():
+        fetch(0, rslot_ref[0], 0, run_blocks(0), wait=False)
+
+    def attend(tq, buf, g, first, last, start, n, pos0):
+        """Every q tile (tq tokens, R = tq * H rows) of the run against
+        the group in buffer `buf`."""
+        R = tq * H
+        base = g * (G * BS)               # first key position of group
+
+        def tile(j, carry):
+            off = j * tq
+            rs = off * H                  # state rows of this tile
+            rq = (start + off) * H        # its rows in q / out
+
+            @pl.when(first)
+            def _init():
+                m_ref[pl.ds(rs, R)] = jnp.full((R, 1), MASK_VALUE,
+                                               jnp.float32)
+                l_ref[pl.ds(rs, R)] = jnp.zeros((R, 1), jnp.float32)
+                acc_ref[pl.ds(rs, R)] = jnp.zeros(
+                    (R, acc_ref.shape[1]), jnp.float32)
+
+            # causal skip: the group lies past the tile's last query
+            @pl.when(base <= pos0 + off + tq - 1)
+            def _accumulate():
+                q = q_ref[pl.ds(rq, R)].astype(mxu_dtype)     # [R, Dh]
+                k = kbuf[buf]                                 # [C, Dh]
+                v = vbuf[buf]
+                if quantized:
+                    k = k.astype(jnp.float32)
+                    v = v.astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k.astype(mxu_dtype), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # [R, C]
+                if quantized:
+                    s = s * ksbuf[buf]                        # [1, C]
+                # key index (heads agreeing) <= query position - base
+                tok = rowtok_ref[0:R] + off                   # [R, 1]
+                thr = jnp.where(tok < n, tok + (pos0 - base), -1)
+                keep = dmat_ref[0:R] <= thr
+                s = jnp.where(keep, s, MASK_VALUE)
+                m_prev = m_ref[pl.ds(rs, R)]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # rows past the run's end in its last tile keep no key
+                # and would count the mask as probability 1: zero them
+                p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+                m_ref[pl.ds(rs, R)] = m_new
+                l_ref[pl.ds(rs, R)] = (
+                    l_ref[pl.ds(rs, R)] * alpha
+                    + jnp.sum(p, axis=-1, keepdims=True))
+                if quantized:
+                    p = p * vsbuf[buf]
+                acc_ref[pl.ds(rs, R)] = (
+                    acc_ref[pl.ds(rs, R)] * alpha
+                    + jax.lax.dot_general(
+                        p.astype(mxu_dtype), v.astype(mxu_dtype),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+
+            @pl.when(last)
+            def _finalize():
+                # ascending runs: rows this tile writes past the run's
+                # end (zeros, l == 0) are the next runs' rows, rewritten
+                # when their turn comes
+                l = jnp.maximum(l_ref[pl.ds(rs, R)], 1e-30)
+                o_ref[pl.ds(rq, R)] = (
+                    acc_ref[pl.ds(rs, R)] / l).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, (n + tq - 1) // tq, tile, 0)
+
+    def run_body(r, it):
+        start, n = rstart_ref[r], rlen_ref[r]
+        slot, pos0 = rslot_ref[r], rpos_ref[r]
+        nblk = run_blocks(r)
+        ngroups = (nblk + G - 1) // G
+        nxt = jnp.minimum(r + 1, last_run)
+        more_runs = r + 1 < n_runs
+
+        def group_body(g, it):
+            buf = it % 2
+            fetch(buf, slot, g, nblk, wait=True)
+            last = g == ngroups - 1
+
+            # the next item's fetch flies while this group is attended
+            @pl.when(jnp.logical_not(last))
+            def _next_group():
+                fetch(1 - buf, slot, g + 1, nblk, wait=False)
+
+            @pl.when(last & more_runs)
+            def _next_run():
+                fetch(1 - buf, rslot_ref[nxt], 0, run_blocks(nxt),
+                      wait=False)
+
+            @pl.when(n == 1)
+            def _single():
+                attend(1, buf, g, g == 0, last, start, n, pos0)
+
+            if TQ > 1:
+                @pl.when(n > 1)
+                def _multi():
+                    attend(TQ, buf, g, g == 0, last, start, n, pos0)
+            return it + 1
+
+        return jax.lax.fori_loop(0, ngroups, group_body, it)
+
+    jax.lax.fori_loop(0, n_runs, run_body, 0)
 
 
-def _paged_attend_grouped(q, k_pool, v_pool, block_tables, slot_ids,
-                          positions, k_scale=None, v_scale=None, *,
-                          scale=None, kernel_name="paged_ragged",
-                          tuning=None):
-    """Grouped block-table-native attention.
+def _mask_tables(H, BS, G, TQ):
+    """dmat [TQ*H, C]: the column's key index within its group where
+    row and column heads agree, a value no threshold reaches elsewhere;
+    rowtok [TQ*H, 1]: the row's token within its q tile."""
+    import numpy as np
+    rows = np.arange(TQ * H)
+    cols = np.arange(G * BS * H)
+    same = (rows[:, None] % H) == (cols[None, :] % H)
+    dmat = np.where(same, cols[None, :] // H, np.int32(2 ** 30))
+    return (jnp.asarray(dmat, jnp.int32),
+            jnp.asarray(rows[:, None] // H, jnp.int32))
 
-    q [N, G, H, Dh]; k_pool/v_pool [NB, BS, H, Dh]; block_tables
-    [S, MB] int32; slot_ids [N] int32 (-1 = padding group); positions
-    [N, G] int32. Optional k_scale/v_scale [NB, BS, H] fp32 dequantize
-    int8 pools inside the kernel. Returns [N, G, H, Dh] in q.dtype.
 
-    `kernel_name` keys the autotuner lookup: the tuned grid-layout
-    config (`dimension_semantics` — whether Mosaic may treat the
-    group axis as parallel) is resolved HERE, at trace time, so a
-    cached winner costs one dict probe inside the one compile and
-    nothing per step. The block-sparse decode entry ("paged_sparse",
-    ISSUE 15) is this same kernel fed a SHORTENED per-slot block table
-    — the table width IS the sparsity budget, so its cache bucket
-    carries MB where the dense entries' buckets do not."""
-    N, G, H, Dh = q.shape
+def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
+                       positions, k_scale=None, v_scale=None, *,
+                       scale=None, kernel_name="paged_ragged",
+                       tuning=None, runs=None, groups=None):
+    """Run-major block-table-native attention — ONE walk per (slot,
+    step).
+
+    q [T, H, Dh]; k_pool/v_pool [NB, BS, H, Dh]; block_tables [S, MB]
+    int32; slot_ids [T] int32 (-1 = padding); positions [T] int32.
+    Optional k_scale/v_scale [NB, BS, H] fp32 dequantize int8 / fp8
+    pools inside the tile. Returns [T, H, Dh] in q.dtype (padding rows
+    zero). `runs` takes a precomputed `paged_runs(slot_ids,
+    positions)` so a layer scan derives it once.
+
+    `kernel_name` names the Mosaic call (what a device trace and the
+    benchmark's kernel check read) and keys the autotuner lookup: the
+    one tunable is `kv_blocks`, the KV blocks per compute step,
+    resolved HERE at trace time — a cached winner costs one dict probe
+    inside the one compile and nothing per step; absent, `run_tiles`
+    picks it from the shapes. The block-sparse decode entry
+    ("paged_sparse", ISSUE 15) is this same kernel fed a SHORTENED
+    per-slot block table — the table width IS the sparsity budget, so
+    its cache bucket carries MB where the dense entries' buckets do
+    not."""
+    T, H, Dh = q.shape
     NB, BS = k_pool.shape[0], k_pool.shape[1]
     S, MB = block_tables.shape
     quantized = k_scale is not None
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
+    N, K = groups or (T, 1)               # the tuner's bucket: groups
     if kernel_name == "paged_sparse":
-        bucket = autotune.shape_bucket(N, G, H, Dh, BS, MB)
+        bucket = autotune.shape_bucket(N, K, H, Dh, BS, MB)
     else:
-        bucket = autotune.shape_bucket(N, G, H, Dh, BS)
+        bucket = autotune.shape_bucket(N, K, H, Dh, BS)
     tuned = tuning if tuning is not None else autotune.kernel_config(
         kernel_name, bucket, k_pool.dtype, default=None) or {}
-    dim_sem = tuned.get("dimension_semantics")
-    compiler_params = None
-    if dim_sem is not None:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=tuple(dim_sem))
-    qs = (q.astype(jnp.float32) * scale).astype(
-        q.dtype if q.dtype != jnp.float64 else jnp.float32)
-
-    def pool_map(g, b, slots, bt, pos):
-        # padding groups (slot -1) clamp to slot 0; their table entries
-        # may be NULL — the position mask hides whatever is fetched
-        return (bt[jnp.maximum(slots[g], 0), b], 0, 0, 0)
-
-    def scale_map(g, b, slots, bt, pos):
-        return (bt[jnp.maximum(slots[g], 0), b], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, G, H, Dh), lambda g, b, *_: (g, 0, 0, 0)),
-        pl.BlockSpec((1, BS, H, Dh), pool_map),
-        pl.BlockSpec((1, BS, H, Dh), pool_map),
-    ]
-    args = [qs, k_pool, v_pool]
+    G, TQ = run_tiles(H, BS, MB)
+    G = max(1, min(int(tuned.get("kv_blocks", G)), MB))
+    BH, C = BS * H, G * BS * H
+    # MXU operands in the pools' / queries' own precision (bf16 x bf16
+    # on a bf16 deployment), fp32 accumulation and softmax state
+    kv_float = q.dtype if quantized else k_pool.dtype
+    mxu_dtype = (jnp.bfloat16 if q.dtype == kv_float == jnp.bfloat16
+                 else jnp.float32)
+    out_dtype = q.dtype if q.dtype != jnp.float64 else jnp.float32
+    # a token's H rows start anywhere a multiple of H: a packed dtype
+    # leaves the kernel as such only where that is a whole tile of it
+    o_dtype = (out_dtype if H % (32 // jnp.dtype(out_dtype).itemsize) == 0
+               else jnp.float32)
+    if runs is None:
+        runs = paged_runs(slot_ids, positions)
+    rows = -(-T // TQ) * TQ * H           # state rows: a run of T tokens
+    pad = TQ * H                          # a tile may overhang the axis
+    # pre-scaled in fp32; the kernel rounds each tile to the MXU dtype
+    q2 = jnp.pad((q.astype(jnp.float32) * scale).reshape(T * H, Dh),
+                 ((0, pad), (0, 0)))
+    dmat, rowtok = _mask_tables(H, BS, G, TQ)
+    args = [q2, dmat, rowtok,
+            k_pool.reshape(NB, BH, Dh), v_pool.reshape(NB, BH, Dh)]
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [vmem, vmem, vmem, hbm, hbm]
+    scratch = [pltpu.VMEM((2, C, Dh), k_pool.dtype),
+               pltpu.VMEM((2, C, Dh), v_pool.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, BS, H), scale_map),
-                     pl.BlockSpec((1, BS, H), scale_map)]
-        args += [k_scale, v_scale]
+        args += [k_scale.astype(jnp.float32).reshape(NB, 1, BH),
+                 v_scale.astype(jnp.float32).reshape(NB, 1, BH)]
+        in_specs += [hbm, hbm]
+        scratch += [pltpu.VMEM((2, 1, C), jnp.float32),
+                    pltpu.VMEM((2, 1, C), jnp.float32)]
+    scratch += [pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, Dh), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(N, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, H, Dh),
-                               lambda g, b, *_: (g, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((H, G), jnp.float32),
-                        pltpu.VMEM((H, G), jnp.float32),
-                        pltpu.VMEM((H, G, Dh), jnp.float32)],
-    )
+        num_scalar_prefetch=6, grid=(), in_specs=in_specs,
+        out_specs=vmem, scratch_shapes=scratch)
     kernel = functools.partial(
-        _paged_attend_kernel, block_size=BS, G=G, quantized=quantized)
-    extra = {}
-    if compiler_params is not None:
-        extra["compiler_params"] = compiler_params
-    return pl.pallas_call(
+        _run_kernel, BS=BS, H=H, G=G, TQ=TQ, quantized=quantized,
+        mxu_dtype=mxu_dtype)
+    # a full pool read once, every query against a mean slot's share
+    # of it: the work follows the contexts, not the table's width
+    kv_tokens = min(NB, S * MB) * BS
+    ctx = min(kv_tokens // max(S, 1) + 1, MB * BS)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, G, H, Dh), q.dtype),
-        interpret=_INTERPRET, name=kernel_name, **extra,
+        out_shape=jax.ShapeDtypeStruct((T * H + pad, Dh), o_dtype),
+        interpret=_INTERPRET, name=kernel_name,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(T * H + pad, rows, TQ * H, C,
+                                         Dh, k_pool.dtype.itemsize)),
         cost_estimate=pl.CostEstimate(
-            flops=4 * N * G * H * Dh * MB * BS,
-            bytes_accessed=(2 * N * MB * BS * H * Dh
+            flops=4 * T * H * Dh * ctx,
+            bytes_accessed=(2 * kv_tokens * H * Dh
                             * k_pool.dtype.itemsize
-                            + 2 * N * G * H * Dh * q.dtype.itemsize),
-            transcendentals=N * G * H * MB * BS),
-    )(slot_ids.astype(jnp.int32), block_tables.astype(jnp.int32),
-      positions.astype(jnp.int32), *args)
+                            + 2 * T * H * Dh * q.dtype.itemsize),
+            transcendentals=T * H * ctx),
+    )(*runs, block_tables.astype(jnp.int32), *args)
+    return out[:T * H].astype(out_dtype).reshape(T, H, Dh)
+
+
+def _vmem_limit(q_rows, state_rows, tile_rows, C, Dh, kv_itemsize):
+    """Scoped-VMEM ask of the run kernel: what it keeps resident
+    (queries and output, the mask table, two K and V buffers, the
+    softmax state) plus the logits and probabilities of one tile, with
+    headroom for Mosaic's own temporaries."""
+    lanes = max(Dh, 128)
+    resident = (2 * q_rows * lanes * 4 + tile_rows * C * 4
+                + 4 * C * lanes * kv_itemsize
+                + state_rows * (2 * 128 + lanes) * 4)
+    ask = 2 * (resident + 4 * tile_rows * C * 4)
+    return int(min(100 * 2 ** 20, max(32 * 2 ** 20, ask)))
 
 
 # --------------------------------------------------------------- entries
@@ -259,41 +472,43 @@ def _paged_attend_grouped(q, k_pool, v_pool, block_tables, slot_ids,
 
 def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
-                  kernel_name="paged_ragged"):
+                  kernel_name="paged_ragged", runs=None):
     """Flat-token ragged paged attention (chunked prefill + plain
-    decode): q [T, H, Dh], one G=1 group per flat token. Signature
-    mirrors `flash_attention.ragged_paged_attention`. The sparse
-    decode region passes `kernel_name="paged_sparse"` with its
-    shortened tables so tuned configs resolve under the sparse key."""
-    T = q.shape[0]
-    out = _paged_attend_grouped(
-        q[:, None], k_pool, v_pool, block_tables, slot_ids,
-        positions.reshape(T, 1), k_scale, v_scale, scale=scale,
-        kernel_name=kernel_name)
-    return out[:, 0]
+    decode): q [T, H, Dh]. Signature mirrors
+    `flash_attention.ragged_paged_attention`. The sparse decode region
+    passes `kernel_name="paged_sparse"` with its shortened tables so
+    tuned configs resolve under the sparse key."""
+    return _paged_attend_runs(
+        q, k_pool, v_pool, block_tables, slot_ids, positions,
+        k_scale, v_scale, scale=scale, kernel_name=kernel_name,
+        runs=runs)
 
 
 def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
-                  kernel_name="paged_verify"):
+                  kernel_name="paged_verify", tuning=None):
     """K-wide speculative verify: q [B, K, H, Dh], positions [B, K] —
-    one G=K group per slot, ONE block-table walk per group."""
-    return _paged_attend_grouped(
-        q, k_pool, v_pool, block_tables, slot_ids, positions,
-        k_scale, v_scale, scale=scale, kernel_name=kernel_name)
+    the groups laid flat, so a group of consecutive positions is one
+    run of K and ONE block-table walk."""
+    B, K, H, Dh = q.shape
+    out = _paged_attend_runs(
+        q.reshape(B * K, H, Dh), k_pool, v_pool, block_tables,
+        jnp.repeat(slot_ids.astype(jnp.int32), K),
+        positions.reshape(B * K), k_scale, v_scale, scale=scale,
+        kernel_name=kernel_name, tuning=tuning, groups=(B, K))
+    return out.reshape(B, K, H, Dh)
 
 
 def decode_attend(q, k_pool, v_pool, block_tables, context_lens,
                   k_scale=None, v_scale=None, *, scale=None):
     """K=1 decode: q [B, H, Dh], one query per slot attending its first
-    `context_lens[b]` cached tokens."""
+    `context_lens[b]` cached tokens — B runs of 1."""
     B = q.shape[0]
-    positions = (context_lens.astype(jnp.int32) - 1).reshape(B, 1)
-    out = _paged_attend_grouped(
-        q[:, None], k_pool, v_pool, block_tables,
-        jnp.arange(B, dtype=jnp.int32), positions,
-        k_scale, v_scale, scale=scale, kernel_name="paged_decode")
-    return out[:, 0]
+    return _paged_attend_runs(
+        q, k_pool, v_pool, block_tables,
+        jnp.arange(B, dtype=jnp.int32),
+        context_lens.astype(jnp.int32) - 1, k_scale, v_scale,
+        scale=scale, kernel_name="paged_decode")
 
 
 # ----------------------------------------------------------- autotuning
@@ -348,7 +563,7 @@ def _synth_paged_inputs(N, G, H, Dh, BS, context_len, dtype, seed):
 def tune_paged_kernel(kernel_name, N, G, H, Dh, BS, *,
                       context_len=None, dtype="float32", seed=0,
                       budget_s=None, timer=None, persist=True):
-    """Search the grid-layout space of one paged-attention bucket.
+    """Search the `kv_blocks` axis of one paged-attention bucket.
 
     Candidates run the REAL block-table kernel (interpret mode off-TPU
     — the same plumbing tier-1 parity uses) against the XLA gather
@@ -373,10 +588,8 @@ def tune_paged_kernel(kernel_name, N, G, H, Dh, BS, *,
 
     def build(cfg):
         def run(q, kp, vp, bt, slots, pos, ks, vs):
-            out = _paged_attend_grouped(q, kp, vp, bt, slots, pos,
-                                        ks, vs,
-                                        kernel_name=kernel_name,
-                                        tuning=cfg)
+            out = verify_attend(q, kp, vp, bt, slots, pos, ks, vs,
+                                kernel_name=kernel_name, tuning=cfg)
             return out[:, 0] if G == 1 else out
         return run
 
@@ -396,7 +609,7 @@ def tune_paged_kernel(kernel_name, N, G, H, Dh, BS, *,
 
 def tune_paged_sparse(N, G, H, Dh, BS, B, *, dtype="float32", seed=0,
                       budget_s=None, timer=None, persist=True):
-    """Search the grid-layout space of the BLOCK-SPARSE decode bucket
+    """Search the `kv_blocks` axis of the BLOCK-SPARSE decode bucket
     (ISSUE 15): the same grouped kernel fed a shortened `[N, B]` block
     table — the table width IS the sparsity budget, so the bucket key
     carries B (`shape_bucket(N, G, H, Dh, BS, B)`) and a tuned dense
@@ -421,10 +634,8 @@ def tune_paged_sparse(N, G, H, Dh, BS, B, *, dtype="float32", seed=0,
 
     def build(cfg):
         def run(q, kp, vp, bt, slots, pos, ks, vs):
-            out = _paged_attend_grouped(q, kp, vp, bt, slots, pos,
-                                        ks, vs,
-                                        kernel_name="paged_sparse",
-                                        tuning=cfg)
+            out = verify_attend(q, kp, vp, bt, slots, pos, ks, vs,
+                                kernel_name="paged_sparse", tuning=cfg)
             return out[:, 0] if G == 1 else out
         return run
 
@@ -470,10 +681,9 @@ def tune_block_size(max_slots, H, Dh, *, context_len=64,
 
         def run(q, kp, vp, bt, slots, pos, ks, vs):
             if paged_pallas_enabled(Dh, bs):
-                out = _paged_attend_grouped(q, kp, vp, bt, slots, pos,
-                                            ks, vs,
-                                            kernel_name="paged_decode")
-                return out[:, 0]
+                return _paged_attend_runs(
+                    q[:, 0], kp, vp, bt, slots, pos[:, 0], ks, vs,
+                    kernel_name="paged_decode")
             return fa.ragged_gather_reference(q[:, 0], kp, vp, bt,
                                               slots, pos[:, 0], ks, vs)
         return run, cand_args

@@ -77,21 +77,31 @@ def _group_width(tok):
         tok, "ndim", None) == 0 else len(tok)
 
 
-def _attention_work(plan):
-    """(kv_tokens_read, attn_pairs) of one tick fed `plan`. Per slot fed
-    `n` query tokens from position `start` (a decode group is `width`
-    tokens from `pos`, a prefill chunk `len(chunk)` from its `start`):
-    the context it must read once is `start + n` tokens, and its
-    queries attend `start + 1 .. start + n` keys, `n * start +
-    n * (n + 1) / 2` pairs in all. Token counts only: what they cost
-    in bytes and FLOPs is the benchmark's arithmetic."""
-    read = pairs = 0
+def _attention_work(plan, block_size):
+    """The attention work of one tick fed `plan`, as the flight
+    record's fields. Per slot fed `n` query tokens from position
+    `start` (a decode group is `width` tokens from `pos`, a prefill
+    chunk `len(chunk)` from its `start`): the context it must read once
+    is `start + n` tokens (`kv_tokens_read`), `ceil((start + n) /
+    block_size)` blocks (`kv_blocks_needed`), and its queries attend
+    `start + 1 .. start + n` keys, `n * start + n * (n + 1) / 2`
+    `attn_pairs` in all. `kv_blocks_walked` is what the paged kernel
+    fetches for the same groups, by its own rule
+    (`paged_attention.blocks_walked`): the packer lays each group as
+    one run. Token and block counts only: what they cost in bytes and
+    FLOPs is the benchmark's arithmetic."""
+    from ..ops.pallas.paged_attention import blocks_walked
+    read = pairs = needed = 0
     groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
     groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
     for start, n in groups:
         read += start + n
         pairs += n * start + n * (n + 1) // 2
-    return int(read), int(pairs)
+        needed += -(-(start + n) // block_size)
+    walked = blocks_walked(groups, block_size)
+    return dict(kv_tokens_read=int(read), attn_pairs=int(pairs),
+                kv_blocks_needed=int(needed),
+                kv_blocks_walked=int(walked))
 
 
 class ServingEngine:
@@ -612,6 +622,7 @@ class ServingEngine:
             _mm, _qkv)
         from ..ops.pallas.flash_attention import (
             ragged_paged_attention, verify_paged_attention)
+        from ..ops.pallas.paged_attention import paged_runs
 
         from .kv_cache import FP8_MAX, SUMMARY_INIT, kv_jnp_dtype
 
@@ -789,6 +800,11 @@ class ServingEngine:
             # padding tokens write into the reserved NULL block
             wb = jnp.where(valid, block_tables[safe_slot, pos // BS], 0)
             wo = pos % BS
+            # the query runs the ragged kernel walks (a decode token a
+            # run of 1, a prefill chunk one run): the same for every
+            # layer, so derived here, once a step
+            r0 = R if region_on else 0
+            runs = paged_runs(slot_ids[r0:], pos[r0:])
 
             def layer(carry, xs):
                 at = 3
@@ -860,14 +876,14 @@ class ServingEngine:
                             R, cfg.num_heads, cfg.head_dim)
                     ap = ragged_paged_attention(
                         q[R:], kp[li], vp[li], block_tables,
-                        slot_ids[R:], pos[R:], ks_l, vs_l)
+                        slot_ids[R:], pos[R:], ks_l, vs_l, runs=runs)
                     attn = jnp.concatenate(
                         [ar.reshape(R, cfg.num_heads, cfg.head_dim),
                          ap], axis=0)
                 elif K == 1:
                     attn = ragged_paged_attention(
                         q, kp[li], vp[li], block_tables, slot_ids, pos,
-                        ks_l, vs_l)
+                        ks_l, vs_l, runs=runs)
                 else:
                     # the fixed verify region (slot s owns flat tokens
                     # [s*K, (s+1)*K)) runs through the verify-shaped
@@ -881,7 +897,7 @@ class ServingEngine:
                         pos[:R].reshape(S, K), ks_l, vs_l)
                     ap = ragged_paged_attention(
                         q[R:], kp[li], vp[li], block_tables,
-                        slot_ids[R:], pos[R:], ks_l, vs_l)
+                        slot_ids[R:], pos[R:], ks_l, vs_l, runs=runs)
                     attn = jnp.concatenate(
                         [av.reshape(R, cfg.num_heads, cfg.head_dim),
                          ap], axis=0)
@@ -1657,7 +1673,7 @@ class ServingEngine:
         if trace_on:
             # the attention work of this step, counted while the device
             # does it: host arithmetic on the plan, no readback
-            kv_read, pairs = _attention_work(plan)
+            work = _attention_work(plan, self.block_size)
         tokres_np = acc_np = None
         if self.draft_k and self.spec_sampling:
             tok_np, tokv_np, tokres_np, acc_np = (np.asarray(t)
@@ -1834,8 +1850,7 @@ class ServingEngine:
             # outright, this just timestamps it).
             self.flight.note(**self._step_record(
                 t0, prefill_tokens=int(sp.prefill_tokens),
-                decode_tokens=int(sp.decode_tokens),
-                kv_tokens_read=kv_read, attn_pairs=pairs))
+                decode_tokens=int(sp.decode_tokens), **work))
         return True
 
     def _step_record(self, t0, **fields):
@@ -2067,12 +2082,16 @@ class ServingEngine:
             # query at the next position. Exact without speculation;
             # with device drafting the rejected draft columns are work
             # the host never sees
-            kv_read, pairs = _attention_work(plan)
+            work = _attention_work(plan, self.block_size)
             for slot, _tok, pos in plan.decode:
                 c = max(int(counts_np[slot]), 1)
                 later = (c - 1) * (pos + 1) + c * (c - 1) // 2
-                kv_read += later
-                pairs += later
+                work["kv_tokens_read"] += later
+                work["attn_pairs"] += later
+                blocks = sum((pos + j) // self.block_size + 1
+                             for j in range(1, c))
+                work["kv_blocks_needed"] += blocks
+                work["kv_blocks_walked"] += blocks
             for slot, chunk, start, completes in plan.prefills:
                 req = sch.slots[slot]
                 if req is not None:
@@ -2184,8 +2203,7 @@ class ServingEngine:
             # phases are this step's, and noted with the rest
             record = self._step_record(
                 t0, prefill_tokens=snap["prefill_tokens"],
-                decode_tokens=snap["decode_tokens"],
-                kv_tokens_read=kv_read, attn_pairs=pairs,
+                decode_tokens=snap["decode_tokens"], **work,
                 ticks=snap["ticks"], host_stall=snap["host_stall"],
                 early_exit_finish=snap["ev_finish"],
                 early_exit_overflow=snap["ev_over"])

@@ -328,7 +328,7 @@ def test_sparse_and_fp8_buckets_registered(model):
 def test_tune_paged_sparse_search():
     res = pa.tune_paged_sparse(4, 1, 2, 16, 4, 3, persist=False,
                                budget_s=5)
-    assert res.config["dimension_semantics"] is not None
+    assert res.config["kv_blocks"] in (4, 8, 16)
     assert res.tried >= 1
 
 

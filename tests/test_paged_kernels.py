@@ -138,6 +138,146 @@ class TestKernelOracleParity:
         assert not pa.paged_pallas_enabled(128, 16)
 
 
+# ------------------------------------------------- the run-major kernel
+
+
+class TestRunKernel:
+    """One walk per (slot, step): layouts the packer makes — decode
+    runs of 1, prefill chunks, padding — against the gather oracle, and
+    the work the walks do against what the contexts hold."""
+    NB, BS, H, Dh, S, MB, T = 41, 4, 2, 16, 6, 8, 24
+
+    #: name -> [(slot, first position, tokens)] laid flat in order
+    LAYOUTS = {
+        "decodes_then_two_chunks": [(0, 17, 1), (1, 30, 1), (2, 9, 1),
+                                    (3, 6, 9), (4, 2, 5)],
+        "chunk_mid_block_across_blocks": [(1, 3, 14)],
+        "chunk_from_zero": [(5, 0, 11), (0, 5, 1)],
+        "padding_at_tail": [(2, 20, 1), (4, 0, 3)],
+        "all_padding": [],
+        "full_table": [(3, 8 * 4 - 6, 6), (0, 8 * 4 - 1, 1)],
+        "same_slot_twice": [(1, 4, 3), (1, 12, 2), (2, 0, 1)],
+        "whole_axis_one_run": [(0, 5, 24)],
+    }
+
+    def _layout(self, runs):
+        slots = np.full(self.T, -1, np.int32)
+        pos = np.zeros(self.T, np.int32)
+        t = 0
+        for slot, first, n in runs:
+            slots[t:t + n] = slot
+            pos[t:t + n] = np.arange(first, first + n)
+            t += n
+        return slots, pos
+
+    def _pools(self, rng, dtype):
+        import jax.numpy as jnp
+        shape = (self.NB, self.BS, self.H, self.Dh)
+        if dtype in ("int8", "fp8"):
+            if dtype == "int8":
+                kp, vp, ks, vs = _rand_pools(rng, *shape, True)
+                kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+            else:
+                kp, vp = (jnp.asarray(np.clip(
+                    rng.randn(*shape) * 100, -440, 440).astype(
+                        np.float32)).astype(jnp.float8_e4m3fn)
+                    for _ in range(2))
+                ks, vs = ((np.abs(rng.randn(*shape[:3])) * 0.02
+                           + 0.005).astype(np.float32) for _ in range(2))
+            return kp, vp, jnp.asarray(ks), jnp.asarray(vs), jnp.float32
+        dt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+        kp, vp, _, _ = _rand_pools(rng, *shape, False)
+        return (jnp.asarray(kp).astype(dt), jnp.asarray(vp).astype(dt),
+                None, None, dt)
+
+    def _check(self, runs, dtype, kernel_name="paged_ragged", width=None,
+               monkeypatch=None):
+        import jax
+        import jax.numpy as jnp
+        rng = np.random.RandomState(3)
+        kp, vp, ks, vs, qdt = self._pools(rng, dtype)
+        bt = rng.randint(1, self.NB, (self.S, self.MB)).astype(np.int32)
+        bt = jnp.asarray(bt[:, :width or self.MB])
+        slots, pos = self._layout(runs)
+        q = jnp.asarray(rng.randn(self.T, self.H, self.Dh).astype(
+            np.float32)).astype(qdt)
+        got = jax.jit(fa.ragged_paged_attention,
+                      static_argnames=("kernel_name",))(
+            q, kp, vp, bt, jnp.asarray(slots), jnp.asarray(pos), ks, vs,
+            kernel_name=kernel_name)
+        ref = fa.ragged_gather_reference(
+            q, kp, vp, bt, jnp.asarray(slots), jnp.asarray(pos), ks, vs)
+        got, ref = (np.asarray(x.astype(jnp.float32)) for x in (got, ref))
+        valid = slots >= 0
+        tol = {"bf16": 3e-2, "fp8": 1e-3}.get(dtype, 2e-5)
+        np.testing.assert_allclose(got[valid], ref[valid], rtol=tol,
+                                   atol=tol)
+        # padding rows are never attended: finite, and zero
+        assert not got[~valid].any()
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_layouts_match_oracle(self, layout, _interpret_paged):
+        self._check(self.LAYOUTS[layout], "fp32")
+
+    @pytest.mark.parametrize("dtype", ["bf16", "int8", "fp8"])
+    def test_pool_dtypes_match_oracle(self, dtype, _interpret_paged):
+        self._check(self.LAYOUTS["decodes_then_two_chunks"], dtype)
+
+    def test_sparse_name_with_shortened_table(self, _interpret_paged):
+        # the sparse region's shape: runs of 1 over a table of 3 blocks,
+        # positions compacted into its coordinates
+        self._check([(0, 11, 1), (1, 4, 1), (2, 0, 1), (3, 9, 1)],
+                    "fp32", kernel_name="paged_sparse", width=3)
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_jitted_runs_are_the_layout(self, layout):
+        import jax
+        import jax.numpy as jnp
+        runs = self.LAYOUTS[layout]
+        slots, pos = self._layout(runs)
+        n, start, length, slot, first = (np.asarray(x) for x in jax.jit(
+            pa.paged_runs)(jnp.asarray(slots), jnp.asarray(pos)))
+        starts = np.cumsum([0] + [c for _, _, c in runs])[:-1]
+        want = [(t, c, s, p) for t, (s, p, c) in zip(starts, runs)]
+        assert int(n[0]) == len(runs)
+        assert list(zip(start, length, slot, first))[:len(runs)] == want
+        assert not length[len(runs):].any()
+
+    def test_work_follows_the_contexts(self, _interpret_paged):
+        """`kv_blocks_walked == kv_blocks_needed` for a plan of unsplit
+        runs (q tiles of a run share its walk), and nothing static in
+        the kernel call grows with the table's width."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.serving.engine import _attention_work
+        from paddle_tpu.serving.scheduler import Plan
+        plan = Plan(
+            decode=[(0, 7, 17), (1, 7, 30)],
+            prefills=[(3, list(range(20)), 6, False),   # 3 q tiles
+                      (4, list(range(5)), 0, True)],
+            expired=[])
+        work = _attention_work(plan, self.BS)
+        assert work["kv_blocks_needed"] == 5 + 8 + 7 + 2
+        assert work["kv_blocks_walked"] == work["kv_blocks_needed"]
+        assert work["kv_tokens_read"] == 18 + 31 + 26 + 5
+
+        def call(mb):
+            rng = np.random.RandomState(0)
+            kp = jnp.zeros((self.NB, self.BS, self.H, self.Dh))
+            bt = jnp.asarray(rng.randint(1, self.NB, (self.S, mb)),
+                             jnp.int32)
+            q = jnp.zeros((self.T, self.H, self.Dh))
+            z = jnp.zeros((self.T,), jnp.int32)
+            jaxpr = jax.make_jaxpr(pa.ragged_attend)(q, kp, kp, bt, z, z)
+            (eqn,) = [e for e in jaxpr.eqns
+                      if e.primitive.name == "pallas_call"]
+            gm = eqn.params["grid_mapping"]
+            scratch = [str(v.aval) for v in
+                       eqn.params["jaxpr"].invars[-gm.num_scratch_operands:]]
+            return gm.grid, scratch, eqn.params["cost_estimate"]
+        assert call(16) == call(256)
+
+
 # --------------------------------------------------------- engine matrix
 
 

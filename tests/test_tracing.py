@@ -485,6 +485,16 @@ class TestHostPhases:
                 assert r["kv_tokens_read"] - read == \
                     r["attn_pairs"] - pairs >= extra
         assert all(r["kv_tokens_read"] > 0 for r in recs)
+        # the same contexts in blocks, each walked once by the kernel
+        bs = eng.block_size
+        for r, p in zip(recs, plans):
+            assert r["kv_blocks_walked"] == r["kv_blocks_needed"] > 0
+            if r.get("ticks", 1) == 1:
+                ends = [pos + np.atleast_1d(tok).size
+                        for _, tok, pos in p.decode]
+                ends += [start + len(c) for _, c, start, _ in p.prefills]
+                assert r["kv_blocks_needed"] == sum(
+                    -(-e // bs) for e in ends)
         if ticks > 1:
             assert any(r["ticks"] > 1 for r in recs)
             # a slot that decodes c tokens from pos reads pos+1..pos+c

@@ -79,7 +79,7 @@ def _exact(fn):
     return run
 
 
-def validate_paged(*, H=2, Dh=128, BS=16, max_blocks=4, ragged_n=4,
+def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
                    slots=4, verify_width=3, sparse_blocks=3,
                    dtypes=("float32", "bfloat16", "int8",
                            "float8_e4m3fn")):
@@ -88,7 +88,9 @@ def validate_paged(*, H=2, Dh=128, BS=16, max_blocks=4, ragged_n=4,
     and decode entries, all over `[slots|ragged_n, max_blocks]` block
     tables with RAGGED per-group context lengths, so the kernel's
     block-skipping and position mask are both exercised.
-    `sparse_blocks=0` leaves out the short-table entry."""
+    `sparse_blocks=0` leaves out the short-table entry. H = 8 is the
+    fewest heads whose int8 / fp8 scales fill a lane tile at BS = 16
+    (`paged_pallas_enabled`)."""
     import numpy as np
 
     import jax.numpy as jnp
