@@ -467,12 +467,16 @@ def tune_splash(seq_len, *, n_heads=2, batch=1, head_dim=128,
 # ---------------------------------------------------------------------------
 
 
-def _check_pool_heads(name, h_q, k_pool, v_pool):
-    """Queries and pools must carry the SAME head count. Under tensor
+def _check_pool_heads(name, h_q, k_pool, v_pool, grouped=False):
+    """Queries and pools must carry the SAME head count (`grouped`: the
+    query heads a whole multiple of the pools'). Under tensor
     parallelism both are the per-shard slice (`H // tp`); a mismatch
     means a caller handed a sharded pool to unsharded queries (or vice
     versa), which the einsums would otherwise mis-broadcast into
     garbage attention instead of failing."""
+    h_kv = k_pool.shape[-2]
+    if grouped and v_pool.shape[-2] == h_kv and h_q % h_kv == 0:
+        return
     if k_pool.shape[-2] != h_q or v_pool.shape[-2] != h_q:
         raise ValueError(
             f"{name}: q has {h_q} heads but k_pool/v_pool have "
@@ -515,7 +519,7 @@ def _gather_dequant(pool, scale_pool, bt, q_dtype):
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
                            scale=None, kernel_name="paged_ragged",
-                           runs=None):
+                           runs=None, window=None, max_run=None):
     """Flat-token attention over a block-paged KV cache — the kernel of
     the continuous-batching mixed step (`paddle_tpu.serving.engine`),
     following the Ragged-Paged-Attention shape discipline: ONE fixed
@@ -557,42 +561,62 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     (`serving.distributed.tp_engine`) calls this INSIDE shard_map with
     the head axis partitioned on `mp` — q and the pools both arrive as
     the per-shard head slice, and per-head attention needs no
-    cross-shard communication. The head counts must agree."""
+    cross-shard communication. The head counts must agree, or the
+    query heads be a multiple of the pools' (grouped queries: query
+    head i reads KV head `i // (Hq // H)`).
+
+    `window` (None = full attention) keeps keys `p - window < j <= p`
+    for a query at p; table columns wholly behind a query's window are
+    never read. `max_run` cuts the kernel's query runs (see
+    `paged_attention.paged_runs`); the math does not depend on it."""
     T, H, Dh = q.shape
-    _check_pool_heads("ragged_paged_attention", H, k_pool, v_pool)
+    _check_pool_heads("ragged_paged_attention", H, k_pool, v_pool,
+                      grouped=True)
     BS = k_pool.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    if _paged_kernel_enabled(Dh, BS, H, k_scale is not None):
+    if _paged_kernel_enabled(Dh, BS, k_pool.shape[2],
+                             k_scale is not None):
         from .paged_attention import ragged_attend
         return ragged_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
-                             kernel_name=kernel_name, runs=runs)
+                             kernel_name=kernel_name, runs=runs,
+                             window=window, max_run=max_run)
     return ragged_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
-                                   v_scale, scale=scale)
+                                   v_scale, scale=scale, window=window)
 
 
 def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
                             positions, k_scale=None, v_scale=None, *,
-                            scale=None):
+                            scale=None, window=None):
     """The pure-XLA gather implementation of `ragged_paged_attention`
     — the CPU path, the kernel-parity oracle, and the admission gate
     the autotuner holds every paged candidate against."""
     T, H, Dh = q.shape
-    BS = k_pool.shape[1]
+    BS, Hkv = k_pool.shape[1], k_pool.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
     safe_slot = jnp.clip(slot_ids, 0, block_tables.shape[0] - 1)
     bt = block_tables[safe_slot]                      # [T, MB]
     S = bt.shape[1] * BS
     k = _gather_dequant(k_pool, k_scale, bt, q.dtype).reshape(
-        T, S, H, Dh)
+        T, S, Hkv, Dh)
     v = _gather_dequant(v_pool, v_scale, bt, q.dtype).reshape(
-        T, S, H, Dh)
+        T, S, Hkv, Dh)
+    keep = jnp.arange(S)[None, :] <= positions[:, None]   # [T, S]
+    if window is not None:
+        keep &= jnp.arange(S)[None, :] > positions[:, None] - window
+    if Hkv != H:
+        # grouped queries: Gq query heads read each KV head
+        qg = q.reshape(T, Hkv, H // Hkv, Dh)
+        logits = jnp.einsum("thgd,tshd->thgs", qg, k).astype(
+            jnp.float32) * scale
+        logits = jnp.where(keep[:, None, None, :], logits, -1e9)
+        p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("thgs,tshd->thgd", p, v).reshape(T, H, Dh)
     logits = jnp.einsum("thd,tshd->ths", q, k).astype(jnp.float32)
     logits = logits * scale
-    keep = jnp.arange(S)[None, :] <= positions[:, None]   # [T, S]
     logits = jnp.where(keep[:, None, :], logits, -1e9)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("ths,tshd->thd", p, v)

@@ -393,6 +393,161 @@ def grouped_matmul_oracle(x, w, scale=None, *, qmax=None,
     return jnp.einsum("ecd,edf->ecf", x.astype(cd), wf).astype(cd)
 
 
+# ---------------------------------------------------------------------
+# ragged groups: the dropless expert layer (models/afmoe.py)
+# ---------------------------------------------------------------------
+
+#: rows of one m-tile of the ragged kernel. Every tile belongs to ONE
+#: expert (each group is padded up to whole tiles), so an expert with at
+#: most this many pairs reads its weights once; the padding is bounded
+#: by one tile an expert, whatever the routing.
+RAGGED_BLOCK_M = 64
+
+
+def ragged_num_tiles(num_rows, num_groups, block_m=RAGGED_BLOCK_M):
+    """m-tiles that hold `num_rows` rows in `num_groups` groups however
+    they are divided: the rows' own tiles plus one part-filled tile a
+    group. Static, from shapes alone."""
+    return -(-int(num_rows) // block_m) + int(num_groups)
+
+
+def ragged_layout(group_sizes, num_tiles, block_m=RAGGED_BLOCK_M):
+    """Where each group's rows live in the tile-padded row axis.
+
+    group_sizes [E] int32 -> (row_start [E], tile_expert [num_tiles],
+    n_used [1]): group e's rows start at `row_start[e]` (a multiple of
+    `block_m`), tile i belongs to expert `tile_expert[i]`, and tiles
+    from `n_used` on hold no row (they repeat the last used expert, so
+    that the kernel fetches nothing new for them)."""
+    sizes = group_sizes.astype(jnp.int32)
+    tiles = (sizes + block_m - 1) // block_m
+    tile_end = jnp.cumsum(tiles)
+    n_used = tile_end[-1]
+    ids = jnp.arange(num_tiles, dtype=jnp.int32)
+    ids = jnp.minimum(ids, jnp.maximum(n_used - 1, 0))
+    tile_expert = jnp.searchsorted(tile_end, ids, side="right")
+    tile_expert = jnp.minimum(tile_expert, sizes.shape[0] - 1)
+    return ((tile_end - tiles) * block_m, tile_expert.astype(jnp.int32),
+            n_used.reshape(1).astype(jnp.int32))
+
+
+def _rgmm_kernel(te_ref, nu_ref, x_ref, w_ref, *rest, nd, swiglu):
+    """One (m-tile, d-tile) grid cell of the ragged grouped matmul:
+    x tile [bm, bd] times its expert's weight tile [1, bd, F] (whole
+    rows of the weight: one contiguous read), accumulated in fp32
+    across the d axis. With `swiglu` a second weight rides the same
+    tiles and the last d step writes `silu(x Wg) * (x Wu)`. Tiles past
+    `n_used` compute nothing and fetch nothing."""
+    if swiglu:
+        w2_ref, o_ref, acc_ref, acc2_ref = rest
+    else:
+        o_ref, acc_ref = rest
+        w2_ref = acc2_ref = None
+    i, d = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < nu_ref[0])
+    def _live():
+        @pl.when(d == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            if swiglu:
+                acc2_ref[...] = jnp.zeros_like(acc2_ref)
+
+        x = x_ref[...]
+        dims = (((1,), (0,)), ((), ()))
+        acc_ref[...] += jax.lax.dot_general(
+            x, w_ref[0], dims, preferred_element_type=jnp.float32)
+        if swiglu:
+            acc2_ref[...] += jax.lax.dot_general(
+                x, w2_ref[0], dims, preferred_element_type=jnp.float32)
+
+        @pl.when(d == nd - 1)
+        def _finalize():
+            y = acc_ref[...]
+            if swiglu:
+                y = y * jax.nn.sigmoid(y) * acc2_ref[...]
+            o_ref[...] = y.astype(o_ref.dtype)
+
+
+def ragged_expert_matmul(x, w, tile_expert, n_used, w_up=None, *,
+                         block_m=RAGGED_BLOCK_M, block_d=512,
+                         out_dtype=None, name="moe_experts"):
+    """Rows grouped by expert times each group's own weight:
+    `x [M, D] @ w[tile_expert[i]] [D, F]` for every m-tile i of
+    `block_m` rows, `M = len(tile_expert) * block_m` (`ragged_layout`
+    places the rows). With `w_up` the result is the SwiGLU hidden
+    `silu(x w) * (x w_up)`. Operands keep their dtype (bf16 on a bf16
+    deployment), accumulation is fp32. Each expert's weights are read
+    once a tile it owns; tiles from `n_used` on cost nothing, and their
+    output rows are left unwritten: read only rows `ragged_layout` gave
+    to a group.
+
+    On a TPU backend (or in kernel-test interpret mode) this is the
+    Pallas kernel, named `name` in the device trace; elsewhere
+    `jax.lax.ragged_dot` over the tile-padded groups."""
+    M, D = x.shape
+    E, _, F = w.shape
+    NT = tile_expert.shape[0]
+    if M != NT * block_m:
+        raise ValueError(f"{M} rows are not {NT} tiles of {block_m}")
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    swiglu = w_up is not None
+    if not grouped_matmul_enabled(D, F):
+        live = jnp.arange(NT) < n_used[0]
+        sizes = jnp.zeros((E,), jnp.int32).at[tile_expert].add(
+            jnp.where(live, block_m, 0).astype(jnp.int32))
+        y = jax.lax.ragged_dot(x, w, sizes,
+                               preferred_element_type=jnp.float32)
+        if swiglu:
+            y = jax.nn.silu(y) * jax.lax.ragged_dot(
+                x, w_up, sizes, preferred_element_type=jnp.float32)
+        return y.astype(out_dtype)
+    bd = _pick_block(D, block_d)
+    nd = D // bd
+
+    def live_tile(i, nu):
+        return jnp.where(i < nu[0], i, jnp.maximum(nu[0] - 1, 0))
+
+    def live_d(i, d, nu):
+        return jnp.where(i < nu[0], d, nd - 1)
+
+    x_spec = pl.BlockSpec(
+        (block_m, bd), lambda i, d, te, nu: (live_tile(i, nu),
+                                             live_d(i, d, nu)))
+    w_spec = pl.BlockSpec(
+        (1, bd, F), lambda i, d, te, nu: (te[i], live_d(i, d, nu), 0))
+    o_spec = pl.BlockSpec(
+        (block_m, F), lambda i, d, te, nu: (live_tile(i, nu), 0))
+    scratch = [pltpu.VMEM((block_m, F), jnp.float32)]
+    args = [x, w]
+    in_specs = [x_spec, w_spec]
+    if swiglu:
+        args.append(w_up)
+        in_specs.append(w_spec)
+        scratch.append(pltpu.VMEM((block_m, F), jnp.float32))
+    n_w = 2 if swiglu else 1
+    vmem = (2 * n_w * bd * F * w.dtype.itemsize
+            + 2 * block_m * (bd + F) * 4 + n_w * block_m * F * 4)
+    return pl.pallas_call(
+        functools.partial(_rgmm_kernel, nd=nd, swiglu=swiglu),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(NT, nd), in_specs=in_specs,
+            out_specs=o_spec, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((M, F), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(100 * 2 ** 20,
+                                     max(32 * 2 ** 20, 2 * vmem)))),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * n_w * M * D * F,
+            bytes_accessed=(n_w * min(E, NT) * D * F * w.dtype.itemsize
+                            + M * D * x.dtype.itemsize
+                            + M * F * out_dtype.itemsize),
+            transcendentals=M * F if swiglu else 0),
+        interpret=_INTERPRET, name=name,
+    )(tile_expert, n_used, *args)
+
+
 def tune_grouped_matmul(E, C, D, F, *, dtype="float32",
                         quantized=False, seed=0, budget_s=None,
                         timer=None, persist=True):

@@ -105,12 +105,14 @@ def paged_pallas_enabled(head_dim, block_size, heads=None,
             and autotune.paged_alignment_ok(head_dim, block_size))
 
 
-def paged_runs(slot_ids, positions):
+def paged_runs(slot_ids, positions, max_run=None):
     """The query runs of one flat-token step: maximal runs of
     consecutive flat tokens that share a slot and carry consecutive
     positions — a decode token is a run of 1, a prefill chunk of n
     tokens (or a verify group of K) one run of n. Padding tokens
-    (`slot_ids == -1`) belong to no run.
+    (`slot_ids == -1`) belong to no run. With `max_run`, a longer run
+    is cut into runs of at most that many tokens (each walks its slot
+    again): the kernel's softmax state is sized by the longest run.
 
     slot_ids, positions [T] int32 -> (n_runs [1], start [T], length
     [T], slot [T], first_pos [T]) int32; entries past `n_runs` hold
@@ -127,6 +129,11 @@ def paged_runs(slot_ids, positions):
     # a run ends where the next token starts one, pads, or the axis ends
     is_end = valid & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
     idx = jnp.arange(T, dtype=jnp.int32)
+    if max_run is not None and max_run < T:
+        # tokens since the run's start; a new run every `max_run`
+        off = idx - jax.lax.cummax(jnp.where(is_start, idx, 0))
+        is_start = is_start | (valid & (off % max_run == 0))
+        is_end = is_end | (valid & ((off + 1) % max_run == 0))
     start = jnp.sort(jnp.where(is_start, idx, T))
     end = jnp.sort(jnp.where(is_end, idx, T))
     length = jnp.where(start < T, end - start + 1, 0)
@@ -142,19 +149,23 @@ def blocks_walked(runs, block_size):
     return sum((pos + n - 1) // block_size + 1 for pos, n in runs)
 
 
-def run_tiles(H, BS, MB):
+def run_tiles(H, BS, MB, Gq=1):
     """(G, TQ) from the shapes alone: G KV blocks per compute step so
     that a step's `G * BS * H` (key, head) rows fill ~2048 MXU columns
     (128 keys at H = 16), TQ query tokens per q tile so that its
-    `TQ * H` (token, head) rows fill ~128 MXU rows."""
+    `TQ * H` (token, head) rows fill ~128 MXU rows. With `Gq` query
+    heads to each of the H KV heads a tile has `TQ * H * Gq` rows, and
+    TQ is halved until they are at most 512."""
     G = max(1, min(2048 // (BS * H), 8, MB))
     TQ = max(1, min(128 // H, 16))
+    while TQ > 1 and TQ * H * Gq > 512:
+        TQ //= 2
     return G, TQ
 
 
 def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 bt_ref, q_ref, dmat_ref, rowtok_ref, k_hbm, v_hbm, *rest,
-                BS, H, G, TQ, quantized, mxu_dtype):
+                BS, H, G, TQ, quantized, mxu_dtype, Gq=1, window=None):
     """The whole step in one invocation: for every run, walk the run's
     slot once — `cdiv(last_pos // BS + 1, G)` double-buffered fetches
     of G KV blocks — and let every q tile of the run attend each
@@ -165,6 +176,12 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     once with no relayout of the `[BS, H, Dh]` pool tiles; `dmat`
     (column key index where the heads agree, a huge value elsewhere)
     folds the head-diagonal and the causal mask into one compare.
+    With `Gq` query heads to a KV head the rows are (token, query
+    head), `H * Gq` a token, and the `Gq` heads of a group share the
+    fetched tile's columns of their KV head. With a `window`, a query
+    at p attends keys `p - window < j <= p`: a run's walk starts at the
+    first block its first query reaches, and a second compare masks
+    inside it.
 
     Refs: scalar prefetch (run count, start, length, slot, first
     position; block tables [S, MB]); q [T*H + pad, Dh] fp32, pre-scaled;
@@ -179,6 +196,7 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
         ks_hbm = vs_hbm = ksbuf = vsbuf = None
     BH = BS * H
+    HQ = H * Gq                           # rows a token
     C = G * BH
     n_runs = nruns_ref[0]
     last_run = rstart_ref.shape[0] - 1
@@ -202,14 +220,17 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                         sem.at[buf, 3])]
         return out
 
-    def fetch(buf, slot, g, nblk, wait):
+    def fetch(buf, slot, g, nblk, wait, lo=None):
         """Start (or wait for) the copies of group g of `slot`: only
-        the blocks the run needs; the rest of the buffer keeps an
-        older group's (finite) contents, which the mask hides."""
+        the blocks the run needs (from block `lo` on, under a window);
+        the rest of the buffer keeps an older group's (finite)
+        contents, which the mask hides."""
         for j in range(G):
             col = g * G + j
+            need = col < nblk if lo is None \
+                else (col < nblk) & (col >= lo)
 
-            @pl.when(col < nblk)
+            @pl.when(need)
             def _(col=col, j=j):
                 for c in copies(buf, slot, col, j):
                     c.wait() if wait else c.start()
@@ -227,20 +248,34 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         # at least one, so every run is an item of the fetch chain
         return jnp.maximum(rpos_ref[r] + rlen_ref[r] - 1, 0) // BS + 1
 
+    def run_first(r):
+        """(first block, first group) of run r's walk: block 0 with no
+        window, else the block of the first key its first query sees."""
+        if window is None:
+            return None, 0
+        lo = jnp.maximum(rpos_ref[r] - (window - 1), 0) // BS
+        return lo, lo // G
+
     @pl.when(n_runs > 0)
     def _prime():
-        fetch(0, rslot_ref[0], 0, run_blocks(0), wait=False)
+        lo, g0 = run_first(0)
+        fetch(0, rslot_ref[0], g0, run_blocks(0), wait=False, lo=lo)
 
     def attend(tq, buf, g, first, last, start, n, pos0):
-        """Every q tile (tq tokens, R = tq * H rows) of the run against
-        the group in buffer `buf`."""
-        R = tq * H
+        """Every q tile (tq tokens, R = tq * HQ rows) of the run
+        against the group in buffer `buf`."""
+        R = tq * HQ
         base = g * (G * BS)               # first key position of group
 
         def tile(j, carry):
             off = j * tq
-            rs = off * H                  # state rows of this tile
-            rq = (start + off) * H        # its rows in q / out
+            rs = off * HQ                 # state rows of this tile
+            rq = (start + off) * HQ       # its rows in q / out
+            # causal skip: the group lies past the tile's last query;
+            # window skip: it lies behind the window of its first
+            live = base <= pos0 + off + tq - 1
+            if window is not None:
+                live &= base + G * BS - 1 > pos0 + off - window
 
             @pl.when(first)
             def _init():
@@ -250,8 +285,7 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 acc_ref[pl.ds(rs, R)] = jnp.zeros(
                     (R, acc_ref.shape[1]), jnp.float32)
 
-            # causal skip: the group lies past the tile's last query
-            @pl.when(base <= pos0 + off + tq - 1)
+            @pl.when(live)
             def _accumulate():
                 q = q_ref[pl.ds(rq, R)].astype(mxu_dtype)     # [R, Dh]
                 k = kbuf[buf]                                 # [C, Dh]
@@ -268,6 +302,8 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 tok = rowtok_ref[0:R] + off                   # [R, 1]
                 thr = jnp.where(tok < n, tok + (pos0 - base), -1)
                 keep = dmat_ref[0:R] <= thr
+                if window is not None:
+                    keep &= dmat_ref[0:R] > thr - window
                 s = jnp.where(keep, s, MASK_VALUE)
                 m_prev = m_ref[pl.ds(rs, R)]
                 m_new = jnp.maximum(m_prev,
@@ -306,65 +342,76 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         slot, pos0 = rslot_ref[r], rpos_ref[r]
         nblk = run_blocks(r)
         ngroups = (nblk + G - 1) // G
+        lo, g0 = run_first(r)
         nxt = jnp.minimum(r + 1, last_run)
         more_runs = r + 1 < n_runs
 
         def group_body(g, it):
             buf = it % 2
-            fetch(buf, slot, g, nblk, wait=True)
+            fetch(buf, slot, g, nblk, wait=True, lo=lo)
             last = g == ngroups - 1
 
             # the next item's fetch flies while this group is attended
             @pl.when(jnp.logical_not(last))
             def _next_group():
-                fetch(1 - buf, slot, g + 1, nblk, wait=False)
+                fetch(1 - buf, slot, g + 1, nblk, wait=False, lo=lo)
 
             @pl.when(last & more_runs)
             def _next_run():
-                fetch(1 - buf, rslot_ref[nxt], 0, run_blocks(nxt),
-                      wait=False)
+                lo_n, g0_n = run_first(nxt)
+                fetch(1 - buf, rslot_ref[nxt], g0_n, run_blocks(nxt),
+                      wait=False, lo=lo_n)
 
             @pl.when(n == 1)
             def _single():
-                attend(1, buf, g, g == 0, last, start, n, pos0)
+                attend(1, buf, g, g == g0, last, start, n, pos0)
 
             if TQ > 1:
                 @pl.when(n > 1)
                 def _multi():
-                    attend(TQ, buf, g, g == 0, last, start, n, pos0)
+                    attend(TQ, buf, g, g == g0, last, start, n, pos0)
             return it + 1
 
-        return jax.lax.fori_loop(0, ngroups, group_body, it)
+        return jax.lax.fori_loop(g0, ngroups, group_body, it)
 
     jax.lax.fori_loop(0, n_runs, run_body, 0)
 
 
-def _mask_tables(H, BS, G, TQ):
-    """dmat [TQ*H, C]: the column's key index within its group where
-    row and column heads agree, a value no threshold reaches elsewhere;
-    rowtok [TQ*H, 1]: the row's token within its q tile."""
+def _mask_tables(H, BS, G, TQ, Gq=1):
+    """dmat [TQ*H*Gq, C]: the column's key index within its group where
+    the row's query head belongs to the column's KV head, a value no
+    threshold reaches elsewhere; rowtok [TQ*H*Gq, 1]: the row's token
+    within its q tile."""
     import numpy as np
-    rows = np.arange(TQ * H)
+    HQ = H * Gq
+    rows = np.arange(TQ * HQ)
     cols = np.arange(G * BS * H)
-    same = (rows[:, None] % H) == (cols[None, :] % H)
+    same = ((rows[:, None] % HQ) // Gq) == (cols[None, :] % H)
     dmat = np.where(same, cols[None, :] // H, np.int32(2 ** 30))
     return (jnp.asarray(dmat, jnp.int32),
-            jnp.asarray(rows[:, None] // H, jnp.int32))
+            jnp.asarray(rows[:, None] // HQ, jnp.int32))
 
 
 def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                        positions, k_scale=None, v_scale=None, *,
                        scale=None, kernel_name="paged_ragged",
-                       tuning=None, runs=None, groups=None):
+                       tuning=None, runs=None, groups=None, window=None,
+                       max_run=None):
     """Run-major block-table-native attention — ONE walk per (slot,
     step).
 
-    q [T, H, Dh]; k_pool/v_pool [NB, BS, H, Dh]; block_tables [S, MB]
-    int32; slot_ids [T] int32 (-1 = padding); positions [T] int32.
-    Optional k_scale/v_scale [NB, BS, H] fp32 dequantize int8 / fp8
-    pools inside the tile. Returns [T, H, Dh] in q.dtype (padding rows
-    zero). `runs` takes a precomputed `paged_runs(slot_ids,
-    positions)` so a layer scan derives it once.
+    q [T, Hq, Dh]; k_pool/v_pool [NB, BS, H, Dh] with Hq a multiple of
+    H (query head i reads KV head `i // (Hq // H)`); block_tables
+    [S, MB] int32; slot_ids [T] int32 (-1 = padding); positions [T]
+    int32. Optional k_scale/v_scale [NB, BS, H] fp32 dequantize int8 /
+    fp8 pools inside the tile. Returns [T, Hq, Dh] in q.dtype (padding
+    rows zero). `runs` takes a precomputed `paged_runs(slot_ids,
+    positions, max_run)` so a layer scan derives it once. `window`
+    (None = full attention): a query at p attends keys
+    `p - window < j <= p`, and table columns behind a run's window are
+    never fetched (the cache manager may have released them).
+    `max_run` bounds the tokens of one run, and with them the softmax
+    state the kernel keeps in VMEM (None = the whole token axis).
 
     `kernel_name` names the Mosaic call (what a device trace and the
     benchmark's kernel check read) and keys the autotuner lookup: the
@@ -376,8 +423,12 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     per-slot block table — the table width IS the sparsity budget, so
     its cache bucket carries MB where the dense entries' buckets do
     not."""
-    T, H, Dh = q.shape
-    NB, BS = k_pool.shape[0], k_pool.shape[1]
+    T, HQ, Dh = q.shape
+    NB, BS, H = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    if HQ % H:
+        raise ValueError(f"{HQ} query heads do not divide into groups "
+                         f"over {H} KV heads")
+    Gq = HQ // H
     S, MB = block_tables.shape
     quantized = k_scale is not None
     if scale is None:
@@ -389,7 +440,7 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         bucket = autotune.shape_bucket(N, K, H, Dh, BS)
     tuned = tuning if tuning is not None else autotune.kernel_config(
         kernel_name, bucket, k_pool.dtype, default=None) or {}
-    G, TQ = run_tiles(H, BS, MB)
+    G, TQ = run_tiles(H, BS, MB, Gq)
     G = max(1, min(int(tuned.get("kv_blocks", G)), MB))
     BH, C = BS * H, G * BS * H
     # MXU operands in the pools' / queries' own precision (bf16 x bf16
@@ -400,16 +451,18 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     out_dtype = q.dtype if q.dtype != jnp.float64 else jnp.float32
     # a token's H rows start anywhere a multiple of H: a packed dtype
     # leaves the kernel as such only where that is a whole tile of it
-    o_dtype = (out_dtype if H % (32 // jnp.dtype(out_dtype).itemsize) == 0
+    o_dtype = (out_dtype
+               if HQ % (32 // jnp.dtype(out_dtype).itemsize) == 0
                else jnp.float32)
+    longest = T if max_run is None else min(int(max_run), T)
     if runs is None:
-        runs = paged_runs(slot_ids, positions)
-    rows = -(-T // TQ) * TQ * H           # state rows: a run of T tokens
-    pad = TQ * H                          # a tile may overhang the axis
+        runs = paged_runs(slot_ids, positions, max_run)
+    rows = -(-longest // TQ) * TQ * HQ    # state rows: the longest run
+    pad = TQ * HQ                         # a tile may overhang the axis
     # pre-scaled in fp32; the kernel rounds each tile to the MXU dtype
-    q2 = jnp.pad((q.astype(jnp.float32) * scale).reshape(T * H, Dh),
+    q2 = jnp.pad((q.astype(jnp.float32) * scale).reshape(T * HQ, Dh),
                  ((0, pad), (0, 0)))
-    dmat, rowtok = _mask_tables(H, BS, G, TQ)
+    dmat, rowtok = _mask_tables(H, BS, G, TQ, Gq)
     args = [q2, dmat, rowtok,
             k_pool.reshape(NB, BH, Dh), v_pool.reshape(NB, BH, Dh)]
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
@@ -432,26 +485,29 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         out_specs=vmem, scratch_shapes=scratch)
     kernel = functools.partial(
         _run_kernel, BS=BS, H=H, G=G, TQ=TQ, quantized=quantized,
-        mxu_dtype=mxu_dtype)
+        mxu_dtype=mxu_dtype, Gq=Gq,
+        window=None if window is None else int(window))
     # a full pool read once, every query against a mean slot's share
     # of it: the work follows the contexts, not the table's width
     kv_tokens = min(NB, S * MB) * BS
     ctx = min(kv_tokens // max(S, 1) + 1, MB * BS)
+    if window is not None:
+        ctx = min(ctx, int(window))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * H + pad, Dh), o_dtype),
+        out_shape=jax.ShapeDtypeStruct((T * HQ + pad, Dh), o_dtype),
         interpret=_INTERPRET, name=kernel_name,
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(T * H + pad, rows, TQ * H, C,
+            vmem_limit_bytes=_vmem_limit(T * HQ + pad, rows, TQ * HQ, C,
                                          Dh, k_pool.dtype.itemsize)),
         cost_estimate=pl.CostEstimate(
-            flops=4 * T * H * Dh * ctx,
+            flops=4 * T * HQ * Dh * ctx,
             bytes_accessed=(2 * kv_tokens * H * Dh
                             * k_pool.dtype.itemsize
-                            + 2 * T * H * Dh * q.dtype.itemsize),
-            transcendentals=T * H * ctx),
+                            + 2 * T * HQ * Dh * q.dtype.itemsize),
+            transcendentals=T * HQ * ctx),
     )(*runs, block_tables.astype(jnp.int32), *args)
-    return out[:T * H].astype(out_dtype).reshape(T, H, Dh)
+    return out[:T * HQ].astype(out_dtype).reshape(T, HQ, Dh)
 
 
 def _vmem_limit(q_rows, state_rows, tile_rows, C, Dh, kv_itemsize):
@@ -472,7 +528,8 @@ def _vmem_limit(q_rows, state_rows, tile_rows, C, Dh, kv_itemsize):
 
 def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
-                  kernel_name="paged_ragged", runs=None):
+                  kernel_name="paged_ragged", runs=None, window=None,
+                  max_run=None):
     """Flat-token ragged paged attention (chunked prefill + plain
     decode): q [T, H, Dh]. Signature mirrors
     `flash_attention.ragged_paged_attention`. The sparse decode region
@@ -481,7 +538,7 @@ def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
     return _paged_attend_runs(
         q, k_pool, v_pool, block_tables, slot_ids, positions,
         k_scale, v_scale, scale=scale, kernel_name=kernel_name,
-        runs=runs)
+        runs=runs, window=window, max_run=max_run)
 
 
 def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,

@@ -411,3 +411,89 @@ def global_gather(x, local_count, global_count, group=None,
     if got.shape[0] != int(lc.sum()):
         raise ValueError("global_gather: local_count mismatch")
     return Tensor(got)
+
+
+# ---------------------------------------------------------------------
+# sigmoid top-k routing and the share-aware DROPLESS expert layer
+# (models/afmoe.py: a chip of an expert-parallel deployment holds
+# `experts_held` of the routed experts, routes over all of them, and
+# computes its own experts' part; no capacity slot, no dropped pair)
+# ---------------------------------------------------------------------
+
+
+def route_sigmoid_topk(x, w_router, select_bias, top_k, *,
+                       route_norm=True, route_scale=1.0):
+    """Sigmoid router with a selection-only bias.
+
+    x [T, D]; w_router [D, E]; select_bias [E]. Scores
+    `s = sigmoid(x W)` in float32; the experts are CHOSEN by
+    `top_k(s + select_bias)` and WEIGHTED by `s` alone, normalised over
+    the chosen (`route_norm`) and scaled. Returns (idx [T, k] int32,
+    weights [T, k] float32)."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + select_bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * route_scale
+
+
+def dropless_expert_ffn(x, idx, weights, valid, w_gate, w_up, w_down,
+                        expert_rank=0):
+    """The held experts' part of a routed SwiGLU layer, nothing dropped.
+
+    x [T, D] tokens; idx, weights [T, k] from the router over ALL the
+    experts; valid [T] bool (padding tokens route nowhere); w_gate,
+    w_up [Eh, D, F] and w_down [Eh, F, D] are the `Eh` experts held
+    here, global experts `[Eh * expert_rank, Eh * (expert_rank + 1))`.
+    Every (token, choice) pair that lands on a held expert is computed:
+    the pairs are sorted by expert into tile-padded ragged groups
+    (`grouped_matmul.ragged_layout`; static shapes from T and k alone)
+    and the three matmuls run as ragged grouped matmuls. Pairs routed
+    to experts held elsewhere cost nothing here and add nothing: that
+    part of the sum is the other chips'.
+
+    Returns (out [T, D] = sum over the held chosen experts of
+    weight * expert(x), stats): stats holds int32 scalars
+    `pairs_total`, `pairs_local`, `experts_hit`, `max_expert_pairs`."""
+    import jax.numpy as jnp
+
+    from ..ops.pallas import grouped_matmul as gm
+    T, D = x.shape
+    k = idx.shape[1]
+    Eh = w_gate.shape[0]
+    le = idx - expert_rank * Eh
+    local = (le >= 0) & (le < Eh) & valid[:, None]            # [T, k]
+    key = jnp.where(local, le, Eh).reshape(-1)                # [T*k]
+    counts = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)
+    order = jnp.argsort(key, stable=True)                     # pair ids
+    skey = key[order]
+    first = jnp.cumsum(counts) - counts          # group's first sorted
+    rank = jnp.arange(T * k, dtype=jnp.int32) - first[skey]
+    NT = gm.ragged_num_tiles(T * k, Eh)
+    bm = gm.RAGGED_BLOCK_M
+    row_start, tile_expert, n_used = gm.ragged_layout(counts[:Eh], NT)
+    rows = NT * bm
+    # sorted pair -> its row; pairs of absent experts go out of range
+    dest_sorted = jnp.where(
+        skey < Eh, jnp.append(row_start, 0)[skey] + rank, rows)
+    src = jnp.full((rows,), T, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    xs = jnp.where((src < T)[:, None], x[jnp.minimum(src, T - 1)], 0)
+    hidden = gm.ragged_expert_matmul(xs, w_gate, tile_expert, n_used,
+                                     w_up)
+    ys = gm.ragged_expert_matmul(hidden, w_down, tile_expert, n_used)
+    dest = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.minimum(dest_sorted, rows - 1).astype(jnp.int32))
+    picked = ys[dest].reshape(T, k, D).astype(jnp.float32)
+    out = jnp.sum(jnp.where(local[..., None],
+                            picked * weights[..., None], 0.0), axis=1)
+    stats = {"pairs_total": jnp.sum(valid, dtype=jnp.int32) * k,
+             "pairs_local": jnp.sum(local, dtype=jnp.int32),
+             "experts_hit": jnp.sum(counts[:Eh] > 0, dtype=jnp.int32),
+             "max_expert_pairs": jnp.max(counts[:Eh])}
+    return out.astype(x.dtype), stats

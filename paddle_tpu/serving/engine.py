@@ -104,6 +104,36 @@ def _attention_work(plan, block_size):
                 kv_blocks_walked=int(walked))
 
 
+def _attention_work_by_kind(plan, window):
+    """`_attention_work` for a model with window and full layers: the
+    work of ONE layer of each kind (the benchmark multiplies by the
+    layers of the kind). A window layer's query at p reads and attends
+    keys `p - window < j <= p`: a group of `n` tokens from `start`
+    reads `start + n - max(start - window + 1, 0)` tokens once."""
+    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
+    groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
+    out = {}
+    for kind, w in (("window", window), ("full", None)):
+        read = pairs = 0
+        for start, n in groups:
+            read += start + n - (max(start - w + 1, 0) if w else 0)
+            # a query at p attends min(p + 1, w) keys: p + 1 runs over
+            # start + 1 .. start + n, capped at w
+            lo, hi = start + 1, start + n
+            cap = hi if w is None else min(hi, max(w, lo - 1))
+            pairs += (lo + cap) * (cap - lo + 1) // 2 \
+                + (0 if w is None else w * (hi - cap))
+        out[f"kv_tokens_read_{kind}"] = int(read)
+        out[f"attn_pairs_{kind}"] = int(pairs)
+    return out
+
+
+#: the longest query run the paged kernel takes at once in the step of a
+#: model-provided block: its softmax state is `max_run x query heads`
+#: rows of VMEM, and a longer prefill chunk walks its slot once a cut
+_BLOCK_MAX_RUN = 128
+
+
 class ServingEngine:
     def __init__(self, model, *, max_slots=8, block_size=16,
                  num_blocks=None, max_seq_len=None, token_budget=None,
@@ -123,13 +153,46 @@ class ServingEngine:
         import jax.numpy as jnp
         model.eval()
         self.model = model
-        dec = model.decoder
-        self.num_experts = int(getattr(dec, "_num_experts", 0))
-        if self.num_experts and getattr(dec, "_ep_size", 1) > 1:
-            raise ValueError(
-                "serve a FULL MoE stack (ep_size=1): the engine shards "
-                "experts itself (TPServingEngine expert_parallel=)")
-        L, H, Dh = dec.num_layers, dec.num_heads, dec.head_dim
+        # the seam: a model that brings its own block (embed, a layer
+        # body given `attend(q, k, v, layer)`, final norm + head; see
+        # `models.afmoe.ServingBlock`) is stepped through it, layer by
+        # layer, each layer on its own K/V pool. The GPT decoder has no
+        # such block: its step is the scan over stacked layers below.
+        self._block = (model.serving_block()
+                       if hasattr(model, "serving_block") else None)
+        if self._block is not None:
+            arch = self._block.arch
+            # options built into the GPT scan body and not (yet) into
+            # the step of a model-provided block
+            asked = dict(draft_k=draft_k, kv_dtype=kv_dtype,
+                         max_adapters=max_adapters,
+                         moe_weight_dtype=moe_weight_dtype,
+                         sparse_blocks=sparse_blocks,
+                         track_summaries=track_summaries,
+                         ticks_per_dispatch=ticks_per_dispatch != 1,
+                         role=role != "mixed")
+            bad = [k for k, v in asked.items() if v]
+            if prefix_caching:
+                raise ValueError(
+                    "prefix_caching with window layers is not built: a "
+                    "window table lets go of the blocks a cached prefix "
+                    "would share")
+            if bad or batcher.needs_history(sampling or SamplingConfig()):
+                raise ValueError(
+                    f"{type(model).__name__} is served through its own "
+                    f"block; {bad or ['penalized sampling']} is built "
+                    "into the GPT step only")
+            self.num_experts = 0    # the capacity router's statistics
+            L, H, Dh = len(arch.layers), arch.num_heads, arch.head_dim
+        else:
+            dec = model.decoder
+            self.num_experts = int(getattr(dec, "_num_experts", 0))
+            if self.num_experts and getattr(dec, "_ep_size", 1) > 1:
+                raise ValueError(
+                    "serve a FULL MoE stack (ep_size=1): the engine "
+                    "shards experts itself (TPServingEngine "
+                    "expert_parallel=)")
+            L, H, Dh = dec.num_layers, dec.num_heads, dec.head_dim
         maxpos = model.max_position_embeddings
         max_seq_len = min(max_seq_len or maxpos, maxpos)
         if block_size == "auto":
@@ -336,12 +399,22 @@ class ServingEngine:
         self.device = device
         commit = self._commit = (lambda a: a) if device is None else \
             functools.partial(jax.device_put, device=device)
+        kinds = {}
+        if self._block is not None:
+            # a window pool never runs dry: every slot a whole window
+            # and a step's tokens
+            kinds = dict(
+                num_kv_heads=arch.num_kv_heads,
+                layer_kinds=arch.layer_kinds, window=arch.window,
+                num_window_blocks=max_slots * min(mbps, -(-(
+                    arch.window + self.token_budget) // self.block_size)
+                    + 1) + 1)
         with jax.default_device(device):
             self.kv = PagedKVCache(
                 L, H, Dh, num_blocks=num_blocks,
                 block_size=self.block_size, max_slots=max_slots,
                 max_blocks_per_slot=mbps, dtype=dtype, kv_dtype=kv_dtype,
-                summaries=self._track_summaries)
+                summaries=self._track_summaries, **kinds)
         self.kv._set_pools([commit(p) for p in self.kv._pools()])
         # radix prefix cache: cross-request KV reuse for shared prompt
         # heads (system prompts, few-shot templates, chat history) —
@@ -395,14 +468,20 @@ class ServingEngine:
         # as generation.generate: a per-step astype re-reads the full
         # parameter set every token)
         cdt = jnp.dtype(cdt_name)
-        self._arrays = [commit(a.astype(cdt)
-                               if a.dtype in (jnp.float32, jnp.float64)
-                               else a)
-                        for a in (t._data for t in model._gen_tensors())]
-        # the engine owns its decoder-param NAME list (a copy of the
-        # model's): engine-side expert quantization below may extend
-        # it with scale entries the float model never had
-        self._names = list(model._dec_names)
+        if self._block is not None:
+            # the block's weights are made in the compute dtype and
+            # held ONCE: the step takes the model's own tree
+            self._arrays = jax.tree.map(commit, self._block.weights)
+            self._names = []
+        else:
+            self._arrays = [
+                commit(a.astype(cdt)
+                       if a.dtype in (jnp.float32, jnp.float64) else a)
+                for a in (t._data for t in model._gen_tensors())]
+            # the engine owns its decoder-param NAME list (a copy of
+            # the model's): engine-side expert quantization below may
+            # extend it with scale entries the float model never had
+            self._names = list(model._dec_names)
         # engine-side weight-only expert quantization (ISSUE 14):
         # serve a float/bf16 MoE stack with int8 or packed-int4
         # experts without rebuilding the model — the expert arrays in
@@ -473,6 +552,10 @@ class ServingEngine:
         # dict probes — the step itself is untouched.
         self._kernel_buckets = self._note_kernel_buckets()
         self._preempt_seen = 0
+        self._released_seen = 0          # window blocks, since a record
+        #: the last step's float32 logits at the sample rows,
+        #: [max_slots, V] on the device (a model-provided block only)
+        self.sample_logits = None
         self._prefix_seen = (0, 0, 0)    # hit / miss / evicted deltas
         self._imported_seen = 0          # kv.blocks_imported delta
         self.steps_run = 0
@@ -556,6 +639,8 @@ class ServingEngine:
         the backend/device-count component `autotune.backend_key`
         already carries."""
         from ..ops.pallas import autotune as _kt
+        if self._block is not None:
+            return []       # the block's kernels take their shape defaults
         cfg = self._step_cfg()
         H, Dh, BS = cfg.num_heads, cfg.head_dim, self.block_size
         # key by the POOL dtype (int8 pools are int8, fp8 pools
@@ -611,7 +696,80 @@ class ServingEngine:
         return cfg
 
     def _build_step(self):
+        if self._block is not None:
+            return self._block_step_body()
         return self._step_body(self._step_cfg())
+
+    def _block_step_body(self):
+        """The mixed step of a model that brings its own block: embed,
+        then the layers UNROLLED — each of its own kind (window or
+        full attention, dense or expert FFN), each updating and reading
+        its own K/V pool in place — then final norm, head and sampling
+        at the slots' sample rows. The engine owns what the block must
+        not know: the paged pools, the two kinds of block table, the
+        query runs, padding. One compile: every shape is the token
+        budget's or the pool's.
+
+        step(weights, k0, v0, k1, v1, ..., token_ids, slot_ids,
+        positions, tables_full, tables_window, sample_index, rng)
+        -> (tokens [max_slots], k0, v0, ..., the block's counters
+        (`block.stat_names`, folded over the layers by
+        `block.fold_stats`: the engine does not know what they count),
+        float32 logits of the sample rows [max_slots, V])."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.pallas.flash_attention import ragged_paged_attention
+        from ..ops.pallas.paged_attention import paged_runs
+        block = self._block
+        arch = block.arch
+        kinds = arch.layer_kinds
+        L, BS, T = len(kinds), self.block_size, self.token_budget
+        sc = self.sampling
+        max_run = min(T, _BLOCK_MAX_RUN)
+
+        def step(weights, *rest):
+            pools = list(rest[:2 * L])
+            (token_ids, slot_ids, positions, bt_full, bt_window,
+             sample_index, rng) = rest[2 * L:]
+            valid = slot_ids >= 0
+            pos = jnp.where(valid, positions, 0)
+            safe_slot = jnp.where(valid, slot_ids, 0)
+            tables = {"full": bt_full, "sliding": bt_window}
+            # padding tokens write into the reserved NULL block
+            wb = {k: jnp.where(valid, t[safe_slot, pos // BS], 0)
+                  for k, t in tables.items()}
+            wo = pos % BS
+            runs = paged_runs(slot_ids, pos, max_run)
+
+            def attend(q, k, v, li):
+                kind = kinds[li]
+                kp, vp = pools[2 * li], pools[2 * li + 1]
+                kp = kp.at[wb[kind], wo].set(k.astype(kp.dtype))
+                vp = vp.at[wb[kind], wo].set(v.astype(vp.dtype))
+                pools[2 * li], pools[2 * li + 1] = kp, vp
+                sliding = kind == "sliding"
+                with jax.named_scope(
+                        "attn_window" if sliding else "attn_full"):
+                    return ragged_paged_attention(
+                        q, kp, vp, tables[kind], slot_ids, pos,
+                        runs=runs, max_run=max_run,
+                        window=arch.window if sliding else None)
+
+            h = block.embed(arch, weights,
+                            jnp.where(valid, token_ids, 0))
+            # one array: one readback
+            stats = jnp.zeros((len(block.stat_names),), jnp.int32)
+            for li, lw in enumerate(weights["layers"]):
+                h, st = block.layer(arch, li, lw, h, pos, valid, attend)
+                if st is not None:
+                    stats = block.fold_stats(stats, st)
+            rows = h[jnp.clip(sample_index, 0, T - 1)]
+            logits = block.head(arch, weights, rows)
+            tok = select_token(logits, rng, sc)
+            return (tok, *pools, stats, logits.astype(jnp.float32))
+
+        return step
 
     def _step_body(self, cfg):
         import jax
@@ -1640,7 +1798,7 @@ class ServingEngine:
             args += self.adapters.device_arrays()
         args += [jnp.asarray(sp.token_ids), jnp.asarray(sp.slot_ids),
                  jnp.asarray(sp.positions),
-                 jnp.asarray(self.kv.block_tables),
+                 *(jnp.asarray(t) for t in self.kv.tables()),
                  jnp.asarray(sp.sample_index)]
         if self.adapters is not None:
             args.append(jnp.asarray(self._adapter_token_ids(sp)))
@@ -1652,9 +1810,14 @@ class ServingEngine:
         res = self._step_fn(*args)
         if trace_on:
             ph.mark("engine.wait")
-        moe_stats = None
+        moe_stats = block_stats = None
         if self.num_experts:
             res, moe_stats = res[:-1], res[-1]
+        elif self._block is not None:
+            # the sample rows' logits stay on the device, a row a slot:
+            # nothing reads them back but a caller who asks
+            res, block_stats, self.sample_logits = \
+                res[:-2], res[-2], res[-1]
         out = res[0]
         self.kv._set_pools(res[1:])
         sch.note_fed(plan)
@@ -1670,10 +1833,12 @@ class ServingEngine:
                 self.sparse_candidate_blocks += n_blk
                 self.sparse_selected_blocks += min(
                     n_blk, self.sparse_table_width)
-        if trace_on:
+        if trace_on and self._block is None:
             # the attention work of this step, counted while the device
             # does it: host arithmetic on the plan, no readback
             work = _attention_work(plan, self.block_size)
+        elif trace_on:
+            work = _attention_work_by_kind(plan, self.kv.window)
         tokres_np = acc_np = None
         if self.draft_k and self.spec_sampling:
             tok_np, tokv_np, tokres_np, acc_np = (np.asarray(t)
@@ -1848,6 +2013,25 @@ class ServingEngine:
             # The step's running compile count: a growing value across
             # records is a compile event (the watchdog fails the run
             # outright, this just timestamps it).
+            if block_stats is not None:
+                # the expert layers' counters, read back with the
+                # tokens; the two kinds of block, from the allocators
+                work.update(
+                    {n: int(v) for n, v in zip(
+                        self._block.stat_names,
+                        np.asarray(block_stats))},
+                    kv_blocks_in_use_full=int(
+                        self.kv.allocator.num_used),
+                    kv_blocks_in_use_window=int(
+                        self.kv.window_allocator.num_used),
+                    kv_blocks_released_behind_window=int(
+                        self.kv.blocks_released_behind_window
+                        - self._released_seen))
+                self._released_seen = \
+                    self.kv.blocks_released_behind_window
+                held, ctx = self.kv.window_held_tokens()
+                work.update(kv_tokens_held_window=held,
+                            kv_tokens_context=ctx)
             self.flight.note(**self._step_record(
                 t0, prefill_tokens=int(sp.prefill_tokens),
                 decode_tokens=int(sp.decode_tokens), **work))
@@ -1871,7 +2055,7 @@ class ServingEngine:
             blocks_imported=int(self.kv.blocks_imported),
             compile_cache_size=self.step_compile_count(),
             kv_blocks_in_use=int(self.kv.blocks_in_use),
-            kv_blocks_total=int(self.kv.num_blocks),
+            kv_blocks_total=int(self.kv.blocks_total),
             preemptions=int(sch.preemption_count),
             **self._flight_extra())
         end = self.phases.close()
@@ -2325,7 +2509,7 @@ class ServingEngine:
             args += self.adapters.device_arrays()
         args += [jnp.asarray(sp.token_ids), jnp.asarray(sp.slot_ids),
                  jnp.asarray(sp.positions),
-                 jnp.asarray(self.kv.block_tables),
+                 *(jnp.asarray(t) for t in self.kv.tables()),
                  jnp.asarray(sp.sample_index)]
         if self.adapters is not None:
             args.append(jnp.asarray(self._adapter_token_ids(sp)))

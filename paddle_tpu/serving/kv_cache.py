@@ -173,11 +173,31 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_heads, head_dim, *, num_blocks,
                  block_size, max_slots, max_blocks_per_slot,
-                 dtype="float32", kv_dtype=None, summaries=False):
+                 dtype="float32", kv_dtype=None, summaries=False,
+                 num_kv_heads=None, layer_kinds=None, window=None,
+                 num_window_blocks=None):
         import jax.numpy as jnp
         self.num_layers = num_layers
+        # the heads the pools hold: the KV heads of a grouped-query
+        # model, else the model's heads
+        num_heads = int(num_kv_heads or num_heads)
         self.num_heads = num_heads
         self.head_dim = head_dim
+        # window layers (`layer_kinds`: "sliding" / "full" a layer): a
+        # pool of its own a layer, one block table a slot for the full
+        # layers and one for the window layers, which lets go of the
+        # blocks behind the window (see `_init_layer_kinds`)
+        self.layer_kinds = tuple(layer_kinds) if layer_kinds else None
+        self.window = int(window) if window else None
+        if self.layer_kinds is not None:
+            if len(self.layer_kinds) != num_layers or not self.window:
+                raise ValueError(
+                    "layer_kinds names every layer and needs a window")
+            if summaries or KV_DTYPES.get(
+                    str(kv_dtype or dtype), (0, False))[1]:
+                raise ValueError(
+                    "quantized pools and block summaries are not built "
+                    "for a cache with window layers")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.max_slots = int(max_slots)
@@ -190,10 +210,15 @@ class PagedKVCache:
                 f"of {sorted(KV_DTYPES)} ('int8'/'fp8_e4m3' store "
                 "per-entry-per-head scaled quantized pools)")
         self.summaries = bool(summaries)
-        shape = (num_layers, self.num_blocks, self.block_size,
-                 num_heads, head_dim)
-        self.k_pool = jnp.zeros(shape, kv_jnp_dtype(self.kv_dtype))
-        self.v_pool = jnp.zeros(shape, kv_jnp_dtype(self.kv_dtype))
+        self.window_allocator = None
+        self.blocks_released_behind_window = 0
+        if self.layer_kinds is not None:
+            self._init_layer_kinds(num_window_blocks)
+        else:
+            shape = (num_layers, self.num_blocks, self.block_size,
+                     num_heads, head_dim)
+            self.k_pool = jnp.zeros(shape, kv_jnp_dtype(self.kv_dtype))
+            self.v_pool = jnp.zeros(shape, kv_jnp_dtype(self.kv_dtype))
         self.k_scale = self.v_scale = None
         if self.quantized:
             sshape = shape[:-1]                      # [L, NB, BS, H]
@@ -229,6 +254,108 @@ class PagedKVCache:
         self.place_pools = None
         self.blocks_exported = 0
         self.blocks_imported = 0
+
+    # ------------------------------------------------------ window layers
+    def _init_layer_kinds(self, num_window_blocks):
+        """A cache whose layers are of two kinds. Each layer has its own
+        K and V pool `[NB_kind, BS, H, Dh]` (`k_pools[li]`): the step
+        updates and reads a layer's pool in place, no slice of a stacked
+        array. A slot has TWO block tables, both `[max_blocks_per_slot]`
+        wide with column c holding positions `[c * BS, (c + 1) * BS)`:
+        `block_tables` for the full layers, drawn from `allocator`
+        (`num_blocks` blocks a full layer), and `window_tables` for the
+        window layers, drawn from `window_allocator`
+        (`num_window_blocks` a window layer; all window layers of a
+        slot share the table). A window table gives a block back once
+        every token in it is `window` or more behind the slot's next
+        position (`release_behind_windows`, at the head of the
+        scheduler's next `plan()`): its column reads NULL again and no
+        query reaches it."""
+        import jax.numpy as jnp
+        full = sum(k == "full" for k in self.layer_kinds)
+        if full + sum(k == "sliding" for k in self.layer_kinds) \
+                != self.num_layers:
+            raise ValueError(f"layer kinds {self.layer_kinds}: each is "
+                             "'sliding' or 'full'")
+        if not num_window_blocks:
+            raise ValueError("a cache with window layers is told its "
+                             "window pools' size (num_window_blocks)")
+        self.num_window_blocks = int(num_window_blocks)
+        dt = kv_jnp_dtype(self.kv_dtype)
+        tail = (self.block_size, self.num_heads, self.head_dim)
+        nb = {"full": self.num_blocks, "sliding": self.num_window_blocks}
+        self.k_pools = [jnp.zeros((nb[k],) + tail, dt)
+                        for k in self.layer_kinds]
+        self.v_pools = [jnp.zeros((nb[k],) + tail, dt)
+                        for k in self.layer_kinds]
+        self.k_pool = self.v_pool = None
+        self.window_allocator = BlockAllocator(self.num_window_blocks)
+        self.window_tables = np.zeros(
+            (self.max_slots, self.max_blocks_per_slot), np.int32)
+        # the slot's held window blocks, columns [first, first + len)
+        self._slot_wblocks = [[] for _ in range(self.max_slots)]
+        self._slot_wfirst = [0] * self.max_slots
+
+    def _window_first_col(self, next_pos):
+        """The first table column a query at `next_pos` (or later) can
+        reach in a window layer."""
+        return max(int(next_pos) - self.window + 1, 0) // self.block_size
+
+    def release_behind_window(self, slot):
+        """Give back the window blocks of `slot` that lie wholly behind
+        the window of its next position. Returns how many."""
+        keep_from = self._window_first_col(self.slot_lens[slot])
+        row, first = self._slot_wblocks[slot], self._slot_wfirst[slot]
+        n = min(max(keep_from - first, 0), len(row))
+        if n:
+            self.window_allocator.free(row[:n])
+            self.window_tables[slot, first:first + n] = NULL_BLOCK
+            self._slot_wblocks[slot] = row[n:]
+            self._slot_wfirst[slot] = first + n
+            self.blocks_released_behind_window += n
+        return n
+
+    def release_behind_windows(self):
+        """`release_behind_window` for every slot that holds tokens
+        (nothing to do in a cache without window layers). The scheduler
+        calls it at the head of `plan()`: the tables are a dispatched
+        step's inputs and change only between steps."""
+        if self.layer_kinds is not None:
+            for slot in np.flatnonzero(self.slot_lens):
+                self.release_behind_window(int(slot))
+
+    def tables(self):
+        """The block tables the step takes, as `[max_slots, MB]` int32
+        arrays: one, or (full, window) with window layers."""
+        if self.layer_kinds is None:
+            return [self.block_tables]
+        return [self.block_tables, self.window_tables]
+
+    def fit_tokens(self, slot):
+        """The longest length `ensure_capacity(slot, ...)` could cover
+        from the slot's blocks and the FREE ones (no eviction)."""
+        fit = (len(self._slot_blocks[slot]) + self.allocator.num_free) \
+            * self.block_size
+        if self.layer_kinds is not None:
+            held_to = self._slot_wfirst[slot] + len(self._slot_wblocks[slot])
+            fit = min(fit, (held_to + self.window_allocator.num_free)
+                      * self.block_size)
+        return fit
+
+    def window_held_tokens(self):
+        """(tokens of contexts held in window-layer blocks, tokens the
+        same slots' contexts hold): what the window allocator keeps
+        against what a table with no window would."""
+        if self.layer_kinds is None:
+            return 0, 0
+        held = ctx = 0
+        for slot in range(self.max_slots):
+            n = int(self.slot_lens[slot])
+            if n:
+                ctx += n
+                held += n - min(n, self._slot_wfirst[slot]
+                                * self.block_size)
+        return held, ctx
 
     # ------------------------------------------------------------ sizing
     @property
@@ -302,15 +429,32 @@ class PagedKVCache:
                 f"{self.max_blocks_per_slot} x block_size="
                 f"{self.block_size} caps it at {self.max_slot_tokens}")
         need = self.blocks_missing(slot, new_len)
-        if need == 0:
-            return True
-        got = self._alloc(need)
-        if got is None:
-            return False
-        row = self._slot_blocks[slot]
-        for b in got:
-            self.block_tables[slot, len(row)] = b
-            row.append(b)
+        wneed = 0
+        if self.layer_kinds is not None:
+            # columns from the first the slot's next query can reach
+            wrow = self._slot_wblocks[slot]
+            if not wrow:
+                self._slot_wfirst[slot] = max(
+                    self._slot_wfirst[slot],
+                    self._window_first_col(self.slot_lens[slot]))
+            wneed = max(0, self.blocks_for(new_len)
+                        - self._slot_wfirst[slot] - len(wrow))
+            if wneed > self.window_allocator.num_free:
+                return False
+        if need:
+            got = self._alloc(need)
+            if got is None:
+                return False
+            row = self._slot_blocks[slot]
+            for b in got:
+                self.block_tables[slot, len(row)] = b
+                row.append(b)
+        if wneed:
+            col = self._slot_wfirst[slot] + len(wrow)
+            for b in self.window_allocator.alloc(wneed):
+                self.window_tables[slot, col] = b
+                wrow.append(b)
+                col += 1
         return True
 
     # ---------------------------------------------------- prefix sharing
@@ -430,6 +574,10 @@ class PagedKVCache:
         return fn
 
     def _pools(self):
+        if self.layer_kinds is not None:
+            # k0, v0, k1, v1, ...: a pool a layer
+            return [p for kv in zip(self.k_pools, self.v_pools)
+                    for p in kv]
         out = [self.k_pool, self.v_pool]
         if self.quantized:
             out += [self.k_scale, self.v_scale]
@@ -441,6 +589,9 @@ class PagedKVCache:
         """Inverse of `_pools()`: rebind the pool attributes from a
         jitted executable's output tuple (same fixed order)."""
         arrays = list(arrays)
+        if self.layer_kinds is not None:
+            self.k_pools, self.v_pools = arrays[0::2], arrays[1::2]
+            return
         self.k_pool, self.v_pool = arrays[:2]
         arrays = arrays[2:]
         if self.quantized:
@@ -591,12 +742,31 @@ class PagedKVCache:
         self._slot_blocks[slot] = []
         self.block_tables[slot, :] = NULL_BLOCK
         self.slot_lens[slot] = 0
+        if self.layer_kinds is not None:
+            if self._slot_wblocks[slot]:
+                self.window_allocator.free(self._slot_wblocks[slot])
+            self._slot_wblocks[slot] = []
+            self._slot_wfirst[slot] = 0
+            self.window_tables[slot, :] = NULL_BLOCK
 
     # ----------------------------------------------------------- metrics
     @property
     def blocks_in_use(self):
-        return self.allocator.num_used
+        """Blocks that hold a request's tokens, of both kinds."""
+        used = self.allocator.num_used
+        if self.window_allocator is not None:
+            used += self.window_allocator.num_used
+        return used
+
+    @property
+    def blocks_total(self):
+        """Blocks the tables can draw on, of both kinds, NULL included."""
+        return self.num_blocks + (self.num_window_blocks
+                                  if self.window_allocator else 0)
 
     @property
     def utilization(self):
-        return self.allocator.num_used / max(1, self.allocator.capacity)
+        cap = self.allocator.capacity
+        if self.window_allocator is not None:
+            cap += self.window_allocator.capacity
+        return self.blocks_in_use / max(1, cap)

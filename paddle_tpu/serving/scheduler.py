@@ -375,9 +375,7 @@ class Scheduler:
             # free-block extension only: shrink k to the free coverage
             while k > 0 and not self.kv.ensure_capacity(
                     req.slot, pos + 1 + k):
-                fit = (self.kv.slot_num_blocks(req.slot)
-                       + self.kv.allocator.num_free) \
-                    * self.kv.block_size - (pos + 1)
+                fit = self.kv.fit_tokens(req.slot) - (pos + 1)
                 k = min(k - 1, fit) if fit > 0 else 0
         if k <= 0:
             return [req.output[-1]]
@@ -399,9 +397,7 @@ class Scheduler:
         at every dispatch boundary matches a 1-tick engine's."""
         k = min(int(n_ticks) - 1, self.kv.max_slot_tokens - (pos + 1))
         while k > 0 and not self.kv.ensure_capacity(slot, pos + 1 + k):
-            fit = (self.kv.slot_num_blocks(slot)
-                   + self.kv.allocator.num_free) \
-                * self.kv.block_size - (pos + 1)
+            fit = self.kv.fit_tokens(slot) - (pos + 1)
             k = min(k - 1, fit) if fit > 0 else 0
         return pos + 1 + max(k, 0)
 
@@ -411,6 +407,11 @@ class Scheduler:
         (admissions, block allocation, preemptions, expiries)."""
         now = self.clock()
         expired = self._expire(now)
+        # window layers: what lies behind every slot's window goes back
+        # first. HERE, before anything is allotted and while no step is
+        # in flight: the tables are a step's inputs (on the CPU backend
+        # jax reads the numpy buffer in place)
+        self.kv.release_behind_windows()
         self._admit()
 
         decode = []
@@ -466,9 +467,8 @@ class Scheduler:
             # prefill only uses FREE blocks — shrink to what fits
             while chunk > 0 and not self.kv.ensure_capacity(
                     req.slot, req.fed + chunk):
-                fit = (self.kv.slot_num_blocks(req.slot)
-                       + self.kv.allocator.num_free) \
-                    * self.kv.block_size - req.fed
+                # blocks of BOTH kinds, where the cache has two
+                fit = self.kv.fit_tokens(req.slot) - req.fed
                 chunk = min(chunk - 1, fit) if fit > 0 else 0
             if chunk <= 0:
                 continue

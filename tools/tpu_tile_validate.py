@@ -151,6 +151,99 @@ def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
     return cells
 
 
+def validate_paged_gqa_window(*, H=8, Gq=6, Dh=128, BS=16, max_blocks=24,
+                              window=128, chunk=40,
+                              dtypes=("float32", "bfloat16")):
+    """The run kernel with `Gq` query heads to each of `H` KV heads
+    (Trinity's 48 on 8), with and without a window, runs cut at 16
+    tokens: a chunk of `chunk` tokens deep in one slot's context, a
+    decode token in each of two others, padding after. Under a window
+    the table columns behind each slot's window read NULL, as a window
+    table's do."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    ragged_ref = _exact(fa.ragged_gather_reference)
+    cells = []
+    T, S = chunk + 8, 3
+    ctx = max_blocks * BS
+    firsts = (ctx - chunk, ctx - 1, BS + 3)
+    slot = [0] * chunk + [1, 2] + [-1] * (T - chunk - 2)
+    pos = list(range(firsts[0], ctx)) + [firsts[1], firsts[2]] \
+        + [0] * (T - chunk - 2)
+    slot, pos = jnp.asarray(slot, jnp.int32), jnp.asarray(pos, jnp.int32)
+    for dtype in dtypes:
+        tol = 5e-2 if dtype == "bfloat16" else 2e-2
+        rng = np.random.RandomState(7)
+        NB = S * max_blocks + 1
+        kp, vp = (jnp.asarray(rng.randn(NB, BS, H, Dh), dtype)
+                  for _ in range(2))
+        q = jnp.asarray(rng.randn(T, H * Gq, Dh), dtype)
+        bt = (1 + np.arange(S * max_blocks, dtype=np.int32)).reshape(
+            S, max_blocks)
+        for w in (None, window):
+            table = bt.copy()
+            if w is not None:
+                for s_, first in enumerate(firsts):
+                    table[s_, :max(first - w + 1, 0) // BS] = 0
+            got = pa.ragged_attend(q, kp, vp, jnp.asarray(table), slot,
+                                   pos, window=w, max_run=16)
+            want = ragged_ref(q, kp, vp, jnp.asarray(bt), slot, pos,
+                              window=w)
+            want = jnp.where((slot >= 0)[:, None, None], want, 0)
+            cells.append(_cell(
+                f"paged_ragged gqa {dtype} Hq={H * Gq} H={H} Dh={Dh} "
+                f"BS={BS} MB={max_blocks} window={w}", got, want, tol,
+                tol))
+    return cells
+
+
+def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
+                                  dtypes=("float32", "bfloat16")):
+    """The dropless expert layer's ragged grouped matmul, plain and
+    SwiGLU, against per-group `x @ w`: groups of uneven sizes (one
+    empty, one over a tile) in tile-padded rows."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    cells = []
+    E = len(sizes)
+    NT = gmm.ragged_num_tiles(sum(sizes) + 60, E)
+    bm = gmm.RAGGED_BLOCK_M
+    row_start, te, nu = gmm.ragged_layout(jnp.asarray(sizes, jnp.int32),
+                                          NT)
+    rows = np.concatenate([int(row_start[e]) + np.arange(n)
+                           for e, n in enumerate(sizes)])
+    group = np.concatenate([np.full(n, e) for e, n in enumerate(sizes)])
+    for dtype in dtypes:
+        rng = np.random.RandomState(11)
+        x = np.zeros((NT * bm, D), np.float32)
+        x[rows] = rng.randn(len(rows), D)
+        x = jnp.asarray(x, dtype)
+        w, w2 = (jnp.asarray(rng.randn(E, D, F) / 16, dtype)
+                 for _ in range(2))
+        with jax.default_matmul_precision("highest"):
+            xf = x[rows].astype(jnp.float32)
+            a = jnp.einsum("rd,rdf->rf", xf, w.astype(jnp.float32)[group])
+            b = jnp.einsum("rd,rdf->rf", xf, w2.astype(jnp.float32)[group])
+        tol = 5e-2 if dtype == "bfloat16" else 2e-2
+        cells.append(_cell(
+            f"moe_experts ragged {dtype} sizes={list(sizes)} D={D} F={F}",
+            gmm.ragged_expert_matmul(x, w, te, nu)[rows], a, tol, tol))
+        cells.append(_cell(
+            f"moe_experts ragged swiglu {dtype} sizes={list(sizes)} "
+            f"D={D} F={F}",
+            gmm.ragged_expert_matmul(x, w, te, nu, w2)[rows],
+            jax.nn.silu(a) * b, tol, tol))
+    return cells
+
+
 def validate_add_ln(*, rows=512, d=256, dtype="bfloat16"):
     """Fused residual-add + LayerNorm against its jnp form: the forward
     pair (normalized, new residual) and the input/scale/shift grads of
@@ -290,8 +383,10 @@ def validate_grouped_matmul(*, E=4, C=128, D=128, F=256):
 
 def run_matrix():
     """The default matrix: every family at small aligned shapes."""
-    return (validate_paged() + validate_add_ln() + validate_splash()
-            + validate_flash() + validate_grouped_matmul())
+    return (validate_paged() + validate_paged_gqa_window()
+            + validate_ragged_expert_matmul() + validate_add_ln()
+            + validate_splash() + validate_flash()
+            + validate_grouped_matmul())
 
 
 def main(argv=None):
