@@ -519,7 +519,8 @@ def _gather_dequant(pool, scale_pool, bt, q_dtype):
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
                            scale=None, kernel_name="paged_ragged",
-                           runs=None, window=None, max_run=None):
+                           runs=None, window=None, max_run=None,
+                           layer=None):
     """Flat-token attention over a block-paged KV cache — the kernel of
     the continuous-batching mixed step (`paddle_tpu.serving.engine`),
     following the Ragged-Paged-Attention shape discipline: ONE fixed
@@ -568,32 +569,43 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     `window` (None = full attention) keeps keys `p - window < j <= p`
     for a query at p; table columns wholly behind a query's window are
     never read. `max_run` cuts the kernel's query runs (see
-    `paged_attention.paged_runs`); the math does not depend on it."""
+    `paged_attention.paged_runs`); the math does not depend on it.
+
+    `layer` (None = the pools are one layer's, as above): the pools
+    and scales are STACKED over layers, `[L, NB, BS, H, Dh]` and
+    `[L, NB, BS, H]`, and layer `layer`'s blocks are read where they
+    lie (`paged_attention.layer_blocks`: the flat view and the table
+    offset, the same on the kernel and the gather path) — a layer scan
+    passes its carried pools and its index, and no slice of a layer's
+    pool is taken."""
     T, H, Dh = q.shape
     _check_pool_heads("ragged_paged_attention", H, k_pool, v_pool,
                       grouped=True)
-    BS = k_pool.shape[1]
+    BS, Hkv = k_pool.shape[-3], k_pool.shape[-2]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    if _paged_kernel_enabled(Dh, BS, k_pool.shape[2],
-                             k_scale is not None):
+    if _paged_kernel_enabled(Dh, BS, Hkv, k_scale is not None):
         from .paged_attention import ragged_attend
         return ragged_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
                              kernel_name=kernel_name, runs=runs,
-                             window=window, max_run=max_run)
+                             window=window, max_run=max_run, layer=layer)
     return ragged_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
-                                   v_scale, scale=scale, window=window)
+                                   v_scale, scale=scale, window=window,
+                                   layer=layer)
 
 
 def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
                             positions, k_scale=None, v_scale=None, *,
-                            scale=None, window=None):
+                            scale=None, window=None, layer=None):
     """The pure-XLA gather implementation of `ragged_paged_attention`
     — the CPU path, the kernel-parity oracle, and the admission gate
     the autotuner holds every paged candidate against."""
+    from .paged_attention import layer_blocks
     T, H, Dh = q.shape
+    block_tables, (k_pool, v_pool, k_scale, v_scale) = layer_blocks(
+        block_tables, layer, k_pool, v_pool, k_scale, v_scale)
     BS, Hkv = k_pool.shape[1], k_pool.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
@@ -624,7 +636,8 @@ def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
 
 def verify_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
-                           scale=None, kernel_name="paged_verify"):
+                           scale=None, kernel_name="paged_verify",
+                           layer=None):
     """Verify-shaped paged attention: q `[B, K, H, Dh]` — K queries per
     slot (the speculative draft window: the last accepted token plus
     the proposed draft tokens), each attending its own slot's paged
@@ -651,28 +664,32 @@ def verify_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     pure-XLA gather path below is the CPU-safe parity oracle. With
     `k_scale`/`v_scale` the int8 pools dequantize per entry per head.
     Under tensor parallelism q and the pools are the per-shard head
-    slice, like `ragged_paged_attention`."""
+    slice, and with `layer` the pools are stacked and read in place,
+    like `ragged_paged_attention`."""
     B, K, H, Dh = q.shape
     _check_pool_heads("verify_paged_attention", H, k_pool, v_pool)
-    BS = k_pool.shape[1]
+    BS = k_pool.shape[-3]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
     if _paged_kernel_enabled(Dh, BS, H, k_scale is not None):
         from .paged_attention import verify_attend
         return verify_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
-                             kernel_name=kernel_name)
+                             kernel_name=kernel_name, layer=layer)
     return verify_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
-                                   v_scale, scale=scale)
+                                   v_scale, scale=scale, layer=layer)
 
 
 def verify_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
                             positions, k_scale=None, v_scale=None, *,
-                            scale=None):
+                            scale=None, layer=None):
     """The pure-XLA gather implementation of `verify_paged_attention`
     (CPU path / parity oracle / tuner admission gate)."""
+    from .paged_attention import layer_blocks
     B, K, H, Dh = q.shape
+    block_tables, (k_pool, v_pool, k_scale, v_scale) = layer_blocks(
+        block_tables, layer, k_pool, v_pool, k_scale, v_scale)
     BS = k_pool.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)

@@ -38,6 +38,11 @@ as one lane row beside them: K's scales multiply the logits' (key,
 head) columns, V's the probabilities' — the tile's dequantisation,
 applied where that axis is the lane axis.
 
+Stacked pools: a layer scan carries `[L, NB, BS, H, Dh]` pools and
+passes them whole with `layer=li`; `layer_blocks` views them flat and
+offsets the block table, so the same kernel body fetches layer `li`'s
+blocks where they lie and no op takes the layer's pool out first.
+
 The XLA gather paths stay the CPU parity oracles and the
 `PADDLE_TPU_PAGED_PALLAS=0` fallback; `tests/test_paged_kernels.py`
 runs every (shape x dtype) cell of this module against them in
@@ -392,11 +397,31 @@ def _mask_tables(H, BS, G, TQ, Gq=1):
             jnp.asarray(rows[:, None] // HQ, jnp.int32))
 
 
+def layer_blocks(block_tables, layer, *pools):
+    """The ONE rule by which a layer's blocks are addressed in STACKED
+    pools `[L, NB, ...]`: the pools viewed flat, `[L * NB, ...]`
+    (merging the two leading axes moves nothing), and layer `layer`'s
+    block b is flat block `layer * NB + b` — so the offset goes onto the
+    table, and a reader fetches the layer's blocks where they lie, with
+    no slice of the layer's pool taken out first. Table entry 0 lands
+    on flat block `layer * NB`, the layer's own NULL block. `layer`
+    None: the pools are one layer's already, and everything passes
+    through. `pools` may hold None (no scales).
+
+    -> (block_tables, pools)"""
+    if layer is None:
+        return block_tables, pools
+    nb = next(p for p in pools if p is not None).shape[1]
+    flat = tuple(None if p is None else p.reshape((-1,) + p.shape[2:])
+                 for p in pools)
+    return block_tables.astype(jnp.int32) + layer * nb, flat
+
+
 def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                        positions, k_scale=None, v_scale=None, *,
                        scale=None, kernel_name="paged_ragged",
                        tuning=None, runs=None, groups=None, window=None,
-                       max_run=None):
+                       max_run=None, layer=None):
     """Run-major block-table-native attention — ONE walk per (slot,
     step).
 
@@ -412,6 +437,10 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     never fetched (the cache manager may have released them).
     `max_run` bounds the tokens of one run, and with them the softmax
     state the kernel keeps in VMEM (None = the whole token axis).
+    `layer` (None = the pools are one layer's): the pools and scales
+    are STACKED, `[L, NB, ...]`, and the kernel reads layer `layer`'s
+    blocks in place (`layer_blocks`); it may be a traced scalar, a
+    scan's layer index.
 
     `kernel_name` names the Mosaic call (what a device trace and the
     benchmark's kernel check read) and keys the autotuner lookup: the
@@ -424,6 +453,10 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     its cache bucket carries MB where the dense entries' buckets do
     not."""
     T, HQ, Dh = q.shape
+    # one layer's blocks: what the cost estimate counts, stacked or not
+    NB1 = k_pool.shape[0 if layer is None else 1]
+    block_tables, (k_pool, v_pool, k_scale, v_scale) = layer_blocks(
+        block_tables, layer, k_pool, v_pool, k_scale, v_scale)
     NB, BS, H = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     if HQ % H:
         raise ValueError(f"{HQ} query heads do not divide into groups "
@@ -489,7 +522,7 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         window=None if window is None else int(window))
     # a full pool read once, every query against a mean slot's share
     # of it: the work follows the contexts, not the table's width
-    kv_tokens = min(NB, S * MB) * BS
+    kv_tokens = min(NB1, S * MB) * BS
     ctx = min(kv_tokens // max(S, 1) + 1, MB * BS)
     if window is not None:
         ctx = min(ctx, int(window))
@@ -529,7 +562,7 @@ def _vmem_limit(q_rows, state_rows, tile_rows, C, Dh, kv_itemsize):
 def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
                   kernel_name="paged_ragged", runs=None, window=None,
-                  max_run=None):
+                  max_run=None, layer=None):
     """Flat-token ragged paged attention (chunked prefill + plain
     decode): q [T, H, Dh]. Signature mirrors
     `flash_attention.ragged_paged_attention`. The sparse decode region
@@ -538,12 +571,12 @@ def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
     return _paged_attend_runs(
         q, k_pool, v_pool, block_tables, slot_ids, positions,
         k_scale, v_scale, scale=scale, kernel_name=kernel_name,
-        runs=runs, window=window, max_run=max_run)
+        runs=runs, window=window, max_run=max_run, layer=layer)
 
 
 def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
-                  kernel_name="paged_verify", tuning=None):
+                  kernel_name="paged_verify", tuning=None, layer=None):
     """K-wide speculative verify: q [B, K, H, Dh], positions [B, K] —
     the groups laid flat, so a group of consecutive positions is one
     run of K and ONE block-table walk."""
@@ -552,7 +585,8 @@ def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
         q.reshape(B * K, H, Dh), k_pool, v_pool, block_tables,
         jnp.repeat(slot_ids.astype(jnp.int32), K),
         positions.reshape(B * K), k_scale, v_scale, scale=scale,
-        kernel_name=kernel_name, tuning=tuning, groups=(B, K))
+        kernel_name=kernel_name, tuning=tuning, groups=(B, K),
+        layer=layer)
     return out.reshape(B, K, H, Dh)
 
 

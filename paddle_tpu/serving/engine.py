@@ -780,7 +780,7 @@ class ServingEngine:
             _mm, _qkv)
         from ..ops.pallas.flash_attention import (
             ragged_paged_attention, verify_paged_attention)
-        from ..ops.pallas.paged_attention import paged_runs
+        from ..ops.pallas.paged_attention import layer_blocks, paged_runs
 
         from .kv_cache import FP8_MAX, SUMMARY_INIT, kv_jnp_dtype
 
@@ -832,7 +832,7 @@ class ServingEngine:
             q8 = jnp.round(xf / jnp.maximum(s, 1e-20)[..., None])
             return jnp.clip(q8, -127, 127).astype(jnp.int8), s
 
-        def select_blocks(q_r, pos_r, block_tables, smin_l, smax_l):
+        def select_blocks(q_r, pos_r, block_tables, smin, smax, li):
             """Top-B block selection for the decode/verify region
             (ISSUE 15, Quest-style): score every candidate block of
             each slot by the channel-wise upper bound of q . k over
@@ -859,15 +859,16 @@ class ServingEngine:
             the dense engine.
 
             q_r [S, K, H, Dh] raw queries; pos_r [S, K] true
-            positions; smin_l/smax_l [NB, H, Dh] this layer's
-            summaries."""
+            positions; smin/smax [L, NB, H, Dh] the stacked summaries,
+            layer `li`'s rows gathered in place."""
             from ..incubate.nn.fused_transformer import _maybe_psum
             qf = q_r.astype(jnp.float32)
             qpos = jnp.maximum(qf, 0.0)
             qneg = jnp.minimum(qf, 0.0)
             bt_r = block_tables[:S]                     # [S, MB]
-            sming = smin_l[bt_r]                        # [S, MB, H, Dh]
-            smaxg = smax_l[bt_r]
+            bt_l, (smin_f, smax_f) = layer_blocks(bt_r, li, smin, smax)
+            sming = smin_f[bt_l]                        # [S, MB, H, Dh]
+            smaxg = smax_f[bt_l]
             # ub(q, block) = sum_d max(q_d*min_d, q_d*max_d)
             #             = sum_d (max(q_d,0)*max_d + min(q_d,0)*min_d)
             # summed over heads: under TP each shard holds its head
@@ -988,11 +989,9 @@ class ServingEngine:
                     vp = vp.at[li, wb, wo].set(vq)
                     ksc = ksc.at[li, wb, wo].set(ks_new)
                     vsc = vsc.at[li, wb, wo].set(vs_new)
-                    ks_l, vs_l = ksc[li], vsc[li]
                 else:
                     kp = kp.at[li, wb, wo].set(k.astype(kp.dtype))
                     vp = vp.at[li, wb, wo].set(v.astype(vp.dtype))
-                    ks_l = vs_l = None
                 if track:
                     # summary update on append: the offset-0 write of
                     # a block RESETS its row first (non-first tokens
@@ -1019,29 +1018,30 @@ class ServingEngine:
                                         cfg.head_dim)
                     pos_r = pos[:R].reshape(S, K)
                     short_bt, pos_c = select_blocks(
-                        q_r, pos_r, block_tables, smin[li], smax[li])
+                        q_r, pos_r, block_tables, smin, smax, li)
                     if K == 1:
                         ar = ragged_paged_attention(
-                            q[:R], kp[li], vp[li], short_bt,
-                            slot_ids[:R], pos_c[:, 0], ks_l, vs_l,
-                            kernel_name="paged_sparse")
+                            q[:R], kp, vp, short_bt,
+                            slot_ids[:R], pos_c[:, 0], ksc, vsc,
+                            kernel_name="paged_sparse", layer=li)
                     else:
                         ar = verify_paged_attention(
-                            q_r, kp[li], vp[li], short_bt,
+                            q_r, kp, vp, short_bt,
                             jnp.arange(S, dtype=jnp.int32), pos_c,
-                            ks_l, vs_l,
-                            kernel_name="paged_sparse").reshape(
+                            ksc, vsc, kernel_name="paged_sparse",
+                            layer=li).reshape(
                             R, cfg.num_heads, cfg.head_dim)
                     ap = ragged_paged_attention(
-                        q[R:], kp[li], vp[li], block_tables,
-                        slot_ids[R:], pos[R:], ks_l, vs_l, runs=runs)
+                        q[R:], kp, vp, block_tables,
+                        slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
+                        layer=li)
                     attn = jnp.concatenate(
                         [ar.reshape(R, cfg.num_heads, cfg.head_dim),
                          ap], axis=0)
                 elif K == 1:
                     attn = ragged_paged_attention(
-                        q, kp[li], vp[li], block_tables, slot_ids, pos,
-                        ks_l, vs_l, runs=runs)
+                        q, kp, vp, block_tables, slot_ids, pos,
+                        ksc, vsc, runs=runs, layer=li)
                 else:
                     # the fixed verify region (slot s owns flat tokens
                     # [s*K, (s+1)*K)) runs through the verify-shaped
@@ -1050,12 +1050,13 @@ class ServingEngine:
                     # flat-token ragged path
                     qv = q[:R].reshape(S, K, cfg.num_heads, cfg.head_dim)
                     av = verify_paged_attention(
-                        qv, kp[li], vp[li], block_tables,
+                        qv, kp, vp, block_tables,
                         jnp.arange(S, dtype=jnp.int32),
-                        pos[:R].reshape(S, K), ks_l, vs_l)
+                        pos[:R].reshape(S, K), ksc, vsc, layer=li)
                     ap = ragged_paged_attention(
-                        q[R:], kp[li], vp[li], block_tables,
-                        slot_ids[R:], pos[R:], ks_l, vs_l, runs=runs)
+                        q[R:], kp, vp, block_tables,
+                        slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
+                        layer=li)
                     attn = jnp.concatenate(
                         [av.reshape(R, cfg.num_heads, cfg.head_dim),
                          ap], axis=0)

@@ -141,10 +141,9 @@ class TestKernelOracleParity:
 # ------------------------------------------------- the run-major kernel
 
 
-class TestRunKernel:
-    """One walk per (slot, step): layouts the packer makes — decode
-    runs of 1, prefill chunks, padding — against the gather oracle, and
-    the work the walks do against what the contexts hold."""
+class _RunShapes:
+    """Flat-token layouts and pools of every dtype for the run kernel's
+    tests (no tests of its own)."""
     NB, BS, H, Dh, S, MB, T = 41, 4, 2, 16, 6, 8, 24
 
     #: name -> [(slot, first position, tokens)] laid flat in order
@@ -190,6 +189,12 @@ class TestRunKernel:
         return (jnp.asarray(kp).astype(dt), jnp.asarray(vp).astype(dt),
                 None, None, dt)
 
+
+class TestRunKernel(_RunShapes):
+    """One walk per (slot, step): layouts the packer makes — decode
+    runs of 1, prefill chunks, padding — against the gather oracle, and
+    the work the walks do against what the contexts hold."""
+
     def _check(self, runs, dtype, kernel_name="paged_ragged", width=None,
                monkeypatch=None):
         import jax
@@ -215,7 +220,7 @@ class TestRunKernel:
         # padding rows are never attended: finite, and zero
         assert not got[~valid].any()
 
-    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    @pytest.mark.parametrize("layout", sorted(_RunShapes.LAYOUTS))
     def test_layouts_match_oracle(self, layout, _interpret_paged):
         self._check(self.LAYOUTS[layout], "fp32")
 
@@ -229,7 +234,7 @@ class TestRunKernel:
         self._check([(0, 11, 1), (1, 4, 1), (2, 0, 1), (3, 9, 1)],
                     "fp32", kernel_name="paged_sparse", width=3)
 
-    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    @pytest.mark.parametrize("layout", sorted(_RunShapes.LAYOUTS))
     def test_jitted_runs_are_the_layout(self, layout):
         import jax
         import jax.numpy as jnp
@@ -276,6 +281,169 @@ class TestRunKernel:
                        eqn.params["jaxpr"].invars[-gm.num_scratch_operands:]]
             return gm.grid, scratch, eqn.params["cost_estimate"]
         assert call(16) == call(256)
+
+
+# ------------------------------------------------ stacked pools, in place
+
+
+class TestStackedPools(_RunShapes):
+    """`layer=l` on pools stacked over layers reads layer l's blocks
+    where they lie (`pa.layer_blocks`: the flat view, the table offset)
+    and must equal the call on the slice `pool[l]` — on the gather path
+    and in the interpret-mode kernel, with the layer a traced scalar as
+    a layer scan hands it."""
+    L = 3
+    RUNS = _RunShapes.LAYOUTS["decodes_then_two_chunks"]
+
+    def _stacked(self, dtype, seed=5):
+        """L layers of DIFFERENT contents, stacked (scales too)."""
+        import jax.numpy as jnp
+        rng = np.random.RandomState(seed)
+        layers = [self._pools(rng, dtype) for _ in range(self.L)]
+        kp, vp, ks, vs = (
+            None if layers[0][i] is None
+            else jnp.stack([lay[i] for lay in layers]) for i in range(4))
+        return rng, kp, vp, ks, vs, layers[0][4]
+
+    @staticmethod
+    def _path(kernel, monkeypatch):
+        monkeypatch.setattr(pa, "_INTERPRET", kernel)
+
+    @staticmethod
+    def _at(pool, l):
+        return None if pool is None else pool[l]
+
+    def _ragged(self, kernel_name="paged_ragged"):
+        import functools
+
+        import jax
+        return jax.jit(functools.partial(fa.ragged_paged_attention,
+                                         kernel_name=kernel_name))
+
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8", "fp8"])
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["gather", "kernel"])
+    def test_ragged_layer_equals_slice(self, kernel, dtype, monkeypatch):
+        import jax.numpy as jnp
+        self._path(kernel, monkeypatch)
+        rng, kp, vp, ks, vs, qdt = self._stacked(dtype)
+        bt = jnp.asarray(rng.randint(1, self.NB, (self.S, self.MB)),
+                         jnp.int32)
+        slots, pos = (jnp.asarray(x) for x in self._layout(self.RUNS))
+        q = jnp.asarray(rng.randn(self.T, self.H, self.Dh).astype(
+            np.float32)).astype(qdt)
+        f = self._ragged()
+        outs = []
+        for l in range(self.L):
+            got = f(q, kp, vp, bt, slots, pos, ks, vs,
+                    layer=jnp.int32(l))
+            ref = f(q, kp[l], vp[l], bt, slots, pos, self._at(ks, l),
+                    self._at(vs, l))
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(ref.astype(jnp.float32)))
+            outs.append(np.asarray(got.astype(jnp.float32)))
+        # the layers do differ: a wrong offset could not pass
+        assert not np.allclose(outs[0], outs[1])
+        assert not np.allclose(outs[1], outs[2])
+
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["gather", "kernel"])
+    def test_sparse_name_shortened_table(self, kernel, monkeypatch):
+        import jax.numpy as jnp
+        self._path(kernel, monkeypatch)
+        rng, kp, vp, ks, vs, qdt = self._stacked("fp32")
+        bt = jnp.asarray(rng.randint(1, self.NB, (self.S, 3)), jnp.int32)
+        slots, pos = (jnp.asarray(x) for x in self._layout(
+            [(0, 11, 1), (1, 4, 1), (2, 0, 1), (3, 9, 1)]))
+        q = jnp.asarray(rng.randn(self.T, self.H, self.Dh).astype(
+            np.float32))
+        f = self._ragged("paged_sparse")
+        for l in range(self.L):
+            np.testing.assert_array_equal(
+                np.asarray(f(q, kp, vp, bt, slots, pos,
+                             layer=jnp.int32(l))),
+                np.asarray(f(q, kp[l], vp[l], bt, slots, pos)))
+
+    @pytest.mark.parametrize("dtype", ["fp32", "int8"])
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["gather", "kernel"])
+    def test_verify_layer_equals_slice(self, kernel, dtype, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+        self._path(kernel, monkeypatch)
+        rng, kp, vp, ks, vs, qdt = self._stacked(dtype, seed=6)
+        K = 3
+        bt = jnp.asarray(rng.randint(1, self.NB, (self.S, self.MB)),
+                         jnp.int32)
+        q = jnp.asarray(rng.randn(self.S, K, self.H, self.Dh).astype(
+            np.float32))
+        first = rng.randint(0, self.MB * self.BS - K, self.S)
+        pos = jnp.asarray(first[:, None] + np.arange(K)[None],
+                          jnp.int32)
+        slots = jnp.arange(self.S, dtype=jnp.int32)
+        f = jax.jit(fa.verify_paged_attention)
+        for l in range(self.L):
+            np.testing.assert_array_equal(
+                np.asarray(f(q, kp, vp, bt, slots, pos, ks, vs,
+                             layer=jnp.int32(l))),
+                np.asarray(f(q, kp[l], vp[l], bt, slots, pos,
+                             self._at(ks, l), self._at(vs, l))))
+
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["gather", "kernel"])
+    def test_null_block_is_the_layers_own(self, kernel, monkeypatch):
+        """Table entry 0 of layer 1 is flat block NB, layer 1's own
+        NULL block: garbage in layer 0's block 0 (flat block 0, where
+        an offset left out would read) never reaches layer 1 — neither
+        through the padding columns nor through a column that names
+        block 0 inside the attended context."""
+        import jax.numpy as jnp
+        self._path(kernel, monkeypatch)
+        rng, kp, vp, ks, vs, qdt = self._stacked("fp32", seed=7)
+        kp = kp.at[0, 0].set(jnp.nan)
+        vp = vp.at[0, 0].set(jnp.nan)
+        bt = rng.randint(1, self.NB, (self.S, self.MB)).astype(np.int32)
+        bt[:, 5:] = 0                   # NULL padding past the contexts
+        bt[1, 2] = 0                    # and block 0 inside slot 1's
+        slots, pos = (jnp.asarray(x) for x in self._layout(
+            [(0, 17, 1), (1, 15, 1), (2, 9, 1), (3, 6, 9)]))
+        q = jnp.asarray(rng.randn(self.T, self.H, self.Dh).astype(
+            np.float32))
+        f = self._ragged()
+        got = np.asarray(f(q, kp, vp, jnp.asarray(bt), slots, pos,
+                           layer=jnp.int32(1)))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(
+            got, np.asarray(f(q, kp[1], vp[1], jnp.asarray(bt), slots,
+                              pos)))
+        # layer 0 does read its own block 0
+        assert np.isnan(np.asarray(f(
+            q, kp, vp, jnp.asarray(bt), slots, pos,
+            layer=jnp.int32(0)))[1]).any()
+
+    def test_kernel_call_is_one_layers(self):
+        """The Mosaic call of a stacked pool is the call of one layer's
+        pool: same grid, scratch and cost estimate (its `kv_tokens`
+        count ONE layer's blocks, not L of them)."""
+        import jax
+        import jax.numpy as jnp
+        kp = jnp.zeros((self.L, self.NB, self.BS, self.H, self.Dh))
+        bt = jnp.ones((self.S, self.MB), jnp.int32)
+        q = jnp.zeros((self.T, self.H, self.Dh))
+        z = jnp.zeros((self.T,), jnp.int32)
+
+        def call(pool, **kw):
+            jaxpr = jax.make_jaxpr(
+                lambda *a: pa.ragged_attend(*a, **kw))(q, pool, pool, bt,
+                                                       z, z)
+            (eqn,) = [e for e in jaxpr.eqns
+                      if e.primitive.name == "pallas_call"]
+            gm = eqn.params["grid_mapping"]
+            scratch = [str(v.aval) for v in
+                       eqn.params["jaxpr"].invars[-gm.num_scratch_operands:]]
+            return gm.grid, scratch, eqn.params["cost_estimate"]
+        assert call(kp, layer=2) == call(kp[2])
 
 
 # --------------------------------------------------------- engine matrix
@@ -341,6 +509,33 @@ class TestEnginePallasPath:
                        max_seq_len=32, draft_k=2).generate_batch(
             prompts, max_new_tokens=5)
         assert spec == base
+
+    @pytest.mark.parametrize("options", [
+        {}, {"kv_dtype": "int8"}, {"draft_k": 2},
+        {"sparse_blocks": 3, "kv_dtype": "fp8_e4m3"}],
+        ids=["plain", "int8", "speculative", "sparse_fp8"])
+    @pytest.mark.parametrize("kernel", [False, True],
+                             ids=["gather", "kernel"])
+    def test_step_slices_no_layer_pool(self, kernel, options,
+                                       monkeypatch):
+        """The scanned step reads each layer's K/V blocks IN PLACE: no
+        `dynamic_slice` / `slice` of the lowered step yields one
+        layer's pool, scale pool or summary pool out of the stacked
+        array (on a chip that slice is a copy of the whole layer's
+        pool, every layer, every step)."""
+        import re
+        monkeypatch.setattr(pa, "_INTERPRET", kernel)
+        eng = _engine(ServingEngine, _model(vocab=97), max_slots=2,
+                      block_size=4, max_seq_len=32, **options)
+        text = eng._step_fn._jitted.lower(
+            *eng.example_step_args()).as_text()
+        NB = eng.kv.num_blocks
+        assert "stablehlo.while" in text and f"x{NB}x" in text
+        sliced = [
+            line.strip() for line in text.splitlines()
+            if re.search(r"stablehlo\.(dynamic_)?slice", line)
+            and re.search(rf"-> tensor<(1x)?{NB}x", line)]
+        assert not sliced, sliced
 
 
 class TestEngineInt8:

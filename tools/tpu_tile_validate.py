@@ -5,7 +5,8 @@ Tier-1 proves every Pallas kernel in INTERPRET mode on the CPU mesh —
 the real scalar-prefetch/block-table plumbing, but not the real Mosaic
 tiling. This tool is the device run: it replays the paged-attention
 family (ragged / verify / decode / sparse short-table; fp32, bf16, int8
-and fp8 pools), fused add+LayerNorm and splash attention (forward and
+and fp8 pools; the same entries on pools stacked over layers, read in
+place), fused add+LayerNorm and splash attention (forward and
 backward), the hand flash-forward kernel and the grouped-expert matmul
 (fp32 / int8 / int4 weights) against their pure-XLA oracles with
 interpret mode OFF. A
@@ -79,6 +80,20 @@ def _exact(fn):
     return run
 
 
+def _ragged_positions(n, g, width, BS, seed):
+    """[n, g] int32 positions: group i's first query sits anywhere in a
+    `width`-block table, the rest of its window follows, and group 0
+    fills the table."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    first = np.random.RandomState(seed).randint(
+        0, width * BS - g + 1, size=n)
+    first[0] = width * BS - g
+    return jnp.asarray((first[:, None] + np.arange(g)[None, :])
+                       .astype(np.int32))
+
+
 def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
                    slots=4, verify_width=3, sparse_blocks=3,
                    dtypes=("float32", "bfloat16", "int8",
@@ -93,7 +108,6 @@ def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
     (`paged_pallas_enabled`)."""
     import numpy as np
 
-    import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas import paged_attention as pa
 
@@ -104,13 +118,7 @@ def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
     def inputs(dtype, n, g, width, seed):
         q, kp, vp, bt, slot_ids, _, ks, vs = pa._synth_paged_inputs(
             n, g, H, Dh, BS, width * BS, np.dtype(dtype), seed=seed)
-        # ragged contexts: group i's first query sits anywhere in the
-        # table, the rest of its window follows
-        first = np.random.RandomState(seed).randint(
-            0, width * BS - g + 1, size=n)
-        first[0] = width * BS - g          # one group fills the table
-        pos = jnp.asarray((first[:, None] + np.arange(g)[None, :])
-                          .astype(np.int32))
+        pos = _ragged_positions(n, g, width, BS, seed)
         return q, kp, vp, bt, slot_ids, pos, ks, vs
 
     for dtype in dtypes:
@@ -148,6 +156,66 @@ def validate_paged(*, H=8, Dh=128, BS=16, max_blocks=4, ragged_n=4,
                                  vs, kernel_name="paged_sparse"),
                 ragged_ref(q[:, 0], kp, vp, bt, sl, pos[:, 0], ks, vs),
                 tol, tol))
+    return cells
+
+
+def validate_paged_stacked(*, L=3, layer=2, H=16, Dh=128, BS=16,
+                           max_blocks=128, slots=8, verify_width=3,
+                           sparse_blocks=3,
+                           dtypes=("bfloat16", "int8", "float8_e4m3fn")):
+    """The paged entries on pools STACKED over `L` layers (scales too),
+    reading layer `layer`'s blocks in place (`pa.layer_blocks`) — the
+    form the GPT mixed step's layer scan calls — against the oracle on
+    the slice `pool[layer]`, at the GPT serve cells' tiles (16 heads x
+    128, block 16, 128-block tables). Every layer holds different
+    contents, so a block fetched from another layer fails."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    ragged_ref = _exact(fa.ragged_gather_reference)
+    verify_ref = _exact(fa.verify_gather_reference)
+    cells = []
+
+    def inputs(dtype, g, width, seed):
+        """q, the STACKED (kp, vp, ks, vs), table, slots, positions."""
+        layers = [pa._synth_paged_inputs(slots, g, H, Dh, BS, width * BS,
+                                         np.dtype(dtype), seed=seed + i)
+                  for i in range(L)]
+        q, _, _, bt, sl, _, _, _ = layers[layer]
+        stacked = [None if layers[0][i] is None
+                   else jnp.stack([lay[i] for lay in layers])
+                   for i in (1, 2, 6, 7)]
+        return q, stacked, bt, sl, _ragged_positions(slots, g, width, BS,
+                                                     seed)
+
+    shape = f"L={L} layer={layer} H={H} Dh={Dh} BS={BS}"
+    entries = (("paged_ragged", 1, max_blocks, f"MB={max_blocks}"),
+               ("paged_verify", verify_width, max_blocks,
+                f"G={verify_width} MB={max_blocks}"),
+               ("paged_sparse", 1, sparse_blocks, f"B={sparse_blocks}"))
+    for dtype in dtypes:
+        tol = 5e-2 if dtype == "bfloat16" else 2e-2
+        for seed, (name, g, width, what) in enumerate(entries, 13):
+            q, (kp, vp, ks, vs), bt, sl, pos = inputs(dtype, g, width,
+                                                      seed)
+            kp1, vp1, ks1, vs1 = (None if p is None else p[layer]
+                                  for p in (kp, vp, ks, vs))
+            if name == "paged_verify":
+                got = pa.verify_attend(q, kp, vp, bt, sl, pos, ks, vs,
+                                       layer=layer)
+                want = verify_ref(q, kp1, vp1, bt, sl, pos, ks1, vs1)
+            else:
+                got = pa.ragged_attend(q[:, 0], kp, vp, bt, sl, pos[:, 0],
+                                       ks, vs, kernel_name=name,
+                                       layer=layer)
+                want = ragged_ref(q[:, 0], kp1, vp1, bt, sl, pos[:, 0],
+                                  ks1, vs1)
+            cells.append(_cell(
+                f"{name} stacked {dtype} N={slots} {shape} {what}", got,
+                want, tol, tol))
     return cells
 
 
@@ -381,9 +449,15 @@ def validate_grouped_matmul(*, E=4, C=128, D=128, F=256):
     return cells
 
 
-def run_matrix():
-    """The default matrix: every family at small aligned shapes."""
-    return (validate_paged() + validate_paged_gqa_window()
+def run_matrix(rehearse=False):
+    """The default matrix: every family at small aligned shapes, the
+    stacked-pool entries at the GPT serve cells' (a rehearsal takes
+    them small and in two dtypes: the interpreter pays seconds a cell,
+    and tier-1 runs it)."""
+    small = dict(max_blocks=8, slots=4,
+                 dtypes=("bfloat16", "int8")) if rehearse else {}
+    return (validate_paged() + validate_paged_stacked(**small)
+            + validate_paged_gqa_window()
             + validate_ragged_expert_matmul() + validate_add_ln()
             + validate_splash() + validate_flash()
             + validate_grouped_matmul())
@@ -412,7 +486,7 @@ def main(argv=None):
     else:
         mode = contextlib.nullcontext()
     with mode:
-        cells = run_matrix()
+        cells = run_matrix(args.rehearse)
     for c in cells:
         print(f"tile {c}")
     bad = [c for c in cells if not c.ok]
