@@ -4,7 +4,7 @@ One process drives the two flagship entry points once, at the full
 width of a model the repo supports, and checks what comes out:
 
 * **train** — `parallel.hybrid_gpt.HybridGPT`, GPT-2 350M widths (the
-  `bench.py` configuration), one compile and five steps on one seeded
+  train cell's, `benchmarks/configs/gpt2_medium_train.json`), one compile and five steps on one seeded
   batch, every step ended by `block_until_ready`;
 * **serve** — `inference.create_serving_frontend` ->
   `serving.ServingEngine` over `GPTForGeneration` at the `gpt3_1p3b`
@@ -111,7 +111,7 @@ def train_phase(args, devices):
                      n_layers=2)
         batch = 4 * args.chips    # add_ln tiles 256 local rows
     else:
-        # bench.py's GPT-2 350M (head_dim 64), full depth
+        # the train cell's GPT-2 350M (head_dim 64), full depth
         width = dict(vocab_size=50304, seq_len=1024, d_model=1024,
                      n_heads=16, n_layers=24)
         batch = 32

@@ -34,8 +34,10 @@ def use_compile_cache() -> str:
     return path
 
 
-def compile_fresh(lowered):
-    """`lowered.compile()` that never comes out of the persistent cache.
+def compile_fresh(fn, *args):
+    """`fn` (an `instrumented_jit`) lowered and compiled for `args` into
+    an executable that is built HERE: not out of the persistent cache,
+    and not the one `fn` already holds.
 
     For executables that will be SERIALIZED (`serving/fleet/export.py`):
     on the installed jax (0.9.0, checked on the CPU backend) an
@@ -43,8 +45,13 @@ def compile_fresh(lowered):
     loads and then fails at run time with "NOT_FOUND: ... Function
     <fusion> not found", while a freshly compiled one round-trips.
     jax decides once per process whether the cache is in use, so the
-    switch-off has to be bracketed with `reset_cache()` both ways."""
+    switch-off has to be bracketed with `reset_cache()` both ways.
+    And a jit that already ran shares its lowering with `lower()`:
+    `compile()` of that lowering hands back the executable the run
+    loaded (out of the cache, if the run hit it) without compiling
+    anything, so the lowering comes from a jit of its own (`rejit()`)."""
     from jax.experimental.compilation_cache import compilation_cache
+    lowered = fn.rejit().lower(*args)
     cache_dir = jax.config.jax_compilation_cache_dir
     if cache_dir is None:
         return lowered.compile()

@@ -144,7 +144,19 @@ def instrumented_jit(fn, name, **jit_kwargs):
             _guards.notify_compile(name, instance, compiled)
         return out
 
+    def rejit():
+        """A NEW `jax.jit` of the same function under the same name and
+        options. jax keys its in-process caches by the function object,
+        so what is lowered and compiled through it is built anew even
+        when `jitted` already holds an executable (what
+        `core.compile_cache.compile_fresh` needs)."""
+        @functools.wraps(named)
+        def again(*args, **kwargs):
+            return fn(*args, **kwargs)
+        return jax.jit(again, **jit_kwargs)
+
     call._jitted = jitted
+    call.rejit = rejit
     call._watchdog_instance = instance
     call.compile_count = lambda: total
     return call

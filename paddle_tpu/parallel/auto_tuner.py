@@ -34,7 +34,7 @@ a short profiled run's numbers (PR 1 metrics registry: step seconds or
 measured MFU, eager collective bytes/seconds) and it calibrates the
 cluster's `mxu_efficiency` / `ici_bw` terms before searching, then
 reports the chosen config WITH its predicted MFU so the prediction can
-be checked against the next measurement (bench.py records both).
+be checked against the next measurement.
 """
 from __future__ import annotations
 
@@ -140,7 +140,8 @@ class ModelSpec:
     def useful_flops(self) -> float:
         """Model FLOPs for one global batch WITHOUT recompute or
         capacity-padding overhead — the MFU numerator (same
-        6N_active + 6*L*S*d per-token convention as bench.py)."""
+        6N_active + 6*L*S*d per-token convention as the train cell's
+        MFU line, `benchmarks/harness/stats.py`)."""
         toks = self.global_batch * self.seq_len
         return (6.0 * self.active_params
                 + 6.0 * self.n_layers * self.seq_len * self.d_model) \
@@ -318,7 +319,7 @@ class CostModel:
 
     def predicted_mfu(self, m: ModelSpec, s: Strategy) -> float:
         """Useful-FLOPs MFU per chip at the predicted step time (same
-        numerator convention as bench.py's measured MFU)."""
+        numerator convention as the train cell's measured MFU)."""
         t = self.step_time(m, s)
         return m.useful_flops() / (t * s.degree() * self.cluster.peak_flops)
 
@@ -452,8 +453,7 @@ class StrategyTuner:
 @dataclasses.dataclass
 class TunedResult:
     """`tune()` output: the chosen strategy plus the prediction that a
-    later measured run is checked against (bench.py records
-    predicted_mfu next to the measured MFU)."""
+    later measured run is checked against."""
     strategy: Strategy
     step_time: float
     predicted_mfu: float

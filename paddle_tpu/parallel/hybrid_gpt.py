@@ -93,13 +93,6 @@ class GPTConfig:
     # lax.scan: trades compile time for removing the scan-backward's
     # stacked-gradient dynamic-update-slice traffic
     unroll_layers: bool = False
-    # fused Pallas qkv projection (head-pair N=128 MXU tiles): measured
-    # NEUTRAL in isolation (1.82 vs 1.85 ms/application) and slightly
-    # negative in-model (848 vs 837 ms/step) — the einsum path's trace
-    # attribution overstated its cost; kept opt-in for other shapes
-    # (carry_2d / ffn_barrier experiment knobs from the same pass were
-    # measured no-change and removed: docs/gpt_perf_analysis.md)
-    qkv_kernel: bool = False
     # AMP-O2-style step: cast params to compute_dtype once up front and
     # differentiate wrt the bf16 copies — gradients (and the scan-bwd
     # stacked-grad DUS traffic) stay bf16; Adam still updates the f32
@@ -262,28 +255,24 @@ def _attention(x, w_qkv, b_qkv, w_o, b_o, cfg: GPTConfig):
     fused attention, which never materializes the [S,S] probs either.
     """
     from ..ops.pallas.flash_attention import splash_mha
-    from ..ops.pallas.qkv_proj import qkv_proj, qkv_proj_supported
-    B, S, d = x.shape
+    d = x.shape[-1]
     h_loc = cfg.n_heads // cfg.mp
     hd = cfg.d_model // cfg.n_heads
     cd = cfg.compute_dtype
     xc = x.astype(cd)
-    if cfg.qkv_kernel and qkv_proj_supported(h_loc, S, h_loc * hd, d):
-        # fused Pallas projection: head-PAIR (N=128) MXU tiles — the
-        # direct-BHSD einsums below run at ~94 TF/s (half lanes) because
-        # each head's output N-tile is 64 wide (r5 trace)
-        q, k_, v = qkv_proj(xc, w_qkv.astype(cd), b_qkv.astype(cd), h_loc)
-    else:
-        # [B, H, S, Dh] straight out of three per-tensor projections
-        # ("bsd,dhe->bhse"): r5 traces show the old plain-matmul +
-        # transpose pattern no longer fuses (6x ~8-10ms relayout copies)
-        wq, wk, wv = jnp.split(w_qkv.astype(cd), 3, axis=-1)
-        bq, bk, bv = jnp.split(b_qkv.astype(cd), 3, axis=-1)
+    # [B, H, S, Dh] straight out of three per-tensor projections
+    # ("bsd,dhe->bhse"): r5 traces show the old plain-matmul +
+    # transpose pattern no longer fuses (6x ~8-10ms relayout copies).
+    # Each head's output N-tile is 64 wide, half the MXU's lanes: a
+    # fused head-pair Pallas projection measured no better in the model
+    # (docs/gpt_perf_analysis.md)
+    wq, wk, wv = jnp.split(w_qkv.astype(cd), 3, axis=-1)
+    bq, bk, bv = jnp.split(b_qkv.astype(cd), 3, axis=-1)
 
-        def proj(w, b):
-            out = jnp.einsum("bsd,dhe->bhse", xc, w.reshape(d, h_loc, hd))
-            return out + b.reshape(h_loc, 1, hd)
-        q, k_, v = proj(wq, bq), proj(wk, bk), proj(wv, bv)
+    def proj(w, b):
+        out = jnp.einsum("bsd,dhe->bhse", xc, w.reshape(d, h_loc, hd))
+        return out + b.reshape(h_loc, 1, hd)
+    q, k_, v = proj(wq, bq), proj(wk, bk), proj(wv, bv)
     ctx = splash_mha(q, k_, v, causal=True, scale=1.0 / math.sqrt(hd),
                      save_residuals_for_remat=(
                          cfg.remat_policy == "save_splash_residuals"))

@@ -511,7 +511,7 @@ SAMPLES_PER_SEC = REGISTRY.gauge(
     "Rolling training samples/sec (hapi fit loop)")
 TOKENS_PER_SEC = REGISTRY.gauge(
     "paddle_tpu_train_tokens_per_sec",
-    "Training tokens/sec (set by bench.py / LM training loops)")
+    "Training tokens/sec (set by LM training loops)")
 HAPI_BATCHES = REGISTRY.counter(
     "paddle_tpu_hapi_batches_total",
     "Batches seen by the hapi callback loop", ("mode",))
@@ -575,8 +575,8 @@ MOE_AUX_LOSS = REGISTRY.gauge(
 def moe_utilization_entropy(counts):
     """Normalized entropy of a per-expert token-count vector in
     [0, 1] — the `paddle_tpu_moe_expert_utilization` gauge value (one
-    definition shared by the trainer, the serving engine, bench.py and
-    the moe_smoke contract)."""
+    definition shared by the trainer, the serving engine and the
+    moe_smoke contract)."""
     import numpy as _np
     c = _np.asarray(counts, _np.float64)
     total = c.sum()

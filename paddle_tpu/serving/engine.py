@@ -60,7 +60,7 @@ from . import metrics as smetrics
 from . import tracing as _tracing
 from .batcher import SamplingConfig, pack_step, select_token
 from .kv_cache import PagedKVCache
-from .scheduler import Scheduler
+from .scheduler import Plan, Scheduler
 
 STEP_FN_NAME = "serving_mixed_step"
 SWAP_FN_NAME = "serving_weight_swap"
@@ -145,8 +145,7 @@ class ServingEngine:
                  lora_alpha=None, moe_weight_dtype=None,
                  sparse_blocks=None, sparse_recent=2,
                  track_summaries=None, name=None,
-                 ticks_per_dispatch=1, multitick_async=None,
-                 device=None):
+                 ticks_per_dispatch=1, device=None):
         import functools
 
         import jax
@@ -201,8 +200,8 @@ class ServingEngine:
             # back to the hand-picked 16. Candidates are admitted
             # through the SAME alignment predicate as the serve-time
             # Pallas dispatch gate, so "auto" can never pick a block
-            # size the kernels would refuse (bench.py's
-            # kernel_autotune extra is what populates the cache).
+            # size the kernels would refuse (nothing populates the
+            # cache on the chip yet: ROADMAP queue 3).
             from ..ops.pallas import autotune as _kt
 
             from .kv_cache import KV_DTYPES, kv_jnp_dtype
@@ -291,10 +290,6 @@ class ServingEngine:
         # and the greedy path keeps its exact token-identity verify.
         self.spec_sampling = (self.draft_k > 0
                               and self.sampling.strategy != "greedy")
-        # retired fallback flag (pre-ISSUE 19 engines zeroed draft_k
-        # under penalized sampling); kept as a constant for operators'
-        # dashboards — `speculation_mode` below is the live signal
-        self.speculation_disabled = False
         # device-resident multi-tick decode (docs/SERVING.md "Device-
         # resident decode"): with ticks_per_dispatch=N>1, pure-decode
         # dispatches run N ticks inside ONE lax.while_loop around the
@@ -322,11 +317,6 @@ class ServingEngine:
         self.speculation_mode = (
             "off" if self.draft_k == 0
             else "device" if self._multitick else "host")
-        if multitick_async is None:
-            import os
-            multitick_async = os.environ.get(
-                "PADDLE_TPU_MULTITICK_ASYNC", "1") != "0"
-        self._multitick_async = bool(multitick_async)
         # block-sparse paged decode attention (ISSUE 15, docs/
         # SERVING.md "Long-context serving"): with `sparse_blocks=B`,
         # every decode/verify query scores the slot's candidate blocks
@@ -551,13 +541,11 @@ class ServingEngine:
         # serving traffic hits that hold no tuned entry. Pure host
         # dict probes — the step itself is untouched.
         self._kernel_buckets = self._note_kernel_buckets()
-        self._preempt_seen = 0
+        self._counts_seen = {}    # counter: its count, last snapshot
         self._released_seen = 0          # window blocks, since a record
         #: the last step's float32 logits at the sample rows,
         #: [max_slots, V] on the device (a model-provided block only)
         self.sample_logits = None
-        self._prefix_seen = (0, 0, 0)    # hit / miss / evicted deltas
-        self._imported_seen = 0          # kv.blocks_imported delta
         self.steps_run = 0
         # block-sparse decode accounting (host mirrors of the fixed
         # selection arithmetic — the per-step selected count is
@@ -565,7 +553,6 @@ class ServingEngine:
         # metrics need no extra device readback)
         self.sparse_candidate_blocks = 0
         self.sparse_selected_blocks = 0
-        self._sparse_skip_seen = 0       # metrics-counter delta base
         # cumulative MoE routing state (host mirrors of the per-step
         # device stats; the smoke contracts read these directly)
         self.moe_expert_counts = np.zeros(max(self.num_experts, 1),
@@ -1675,6 +1662,15 @@ class ServingEngine:
             smetrics.SERVING_QUEUE_DEPTH.set(len(self.scheduler.queue))
         return req
 
+    def _slot_adapters(self):
+        """Each slot's pinned adapter SLOT id (0: none, or the base
+        model)."""
+        slot_ad = np.zeros(self.kv.max_slots, np.int32)
+        for s, req in enumerate(self.scheduler.slots):
+            if req is not None:
+                slot_ad[s] = req.adapter_slot
+        return slot_ad
+
     def _adapter_token_ids(self, sp):
         """Per-token adapter SLOT ids for one packed step, riding the
         flat token axis exactly like the sampling params do: each
@@ -1682,10 +1678,7 @@ class ServingEngine:
         (and base-model) tokens carry the null slot 0. Rebuilt
         host-side per step, so compiled shapes never depend on which
         adapters are resident."""
-        slot_ad = np.zeros(self.kv.max_slots, np.int32)
-        for s, req in enumerate(self.scheduler.slots):
-            if req is not None:
-                slot_ad[s] = req.adapter_slot
+        slot_ad = self._slot_adapters()
         return np.where(sp.slot_ids >= 0,
                         slot_ad[np.clip(sp.slot_ids, 0, None)],
                         0).astype(np.int32)
@@ -1765,35 +1758,19 @@ class ServingEngine:
                 utilization=self.moe_utilization_entropy())
 
     # -------------------------------------------------------------- run
-    def step(self):
-        """One engine iteration. Returns True when any work (tokens or
-        expiries) happened, False when the engine is idle/starved."""
-        import jax
+    def _pack(self, decode, prefills, buffers=None):
+        return pack_step(self.token_budget, self.kv.max_slots, decode,
+                         prefills, verify_width=self.draft_k + 1,
+                         reserve_region=self._sparse, buffers=buffers)
+
+    def _step_args(self, sp, rng, tail=()):
+        """The compiled mixed step's arguments, assembled HERE and
+        nowhere else: weights, pools, adapter arrays, the packed plan
+        with the block tables (one, or full and window), per-token
+        adapter ids, penalty counts, the key, the device loop's `tail`.
+        Live steps and `example_step_args()` (what the kernel check
+        traces and the fleet bundle compiles) both come through."""
         import jax.numpy as jnp
-        sch = self.scheduler
-        # tracing state is sampled ONCE per step: recording stays
-        # consistent across the step even if a monitor attaches midway
-        trace_on = _tracing._enabled
-        ph = self.phases
-        t0 = ph.mark("engine.plan", self.steps_run) if trace_on else None
-        plan = sch.plan()
-        if _pmetrics._enabled and plan.expired:
-            for _ in plan.expired:
-                smetrics.SERVING_REQUESTS.labels("expired").inc()
-        if plan.empty:
-            self._flush_deferred()
-            if trace_on:
-                ph.close()
-            return bool(plan.expired)
-        if trace_on:
-            ph.mark("engine.pack")
-        if self._multitick:
-            return self._step_multitick(plan, trace_on, t0)
-        sp = pack_step(self.token_budget, self.kv.max_slots,
-                       plan.decode, plan.prefills,
-                       verify_width=self.draft_k + 1,
-                       reserve_region=self._sparse)
-        self._rng, sub = jax.random.split(self._rng)
         args = [self._arrays] + self.kv._pools()
         if self.adapters is not None:
             args += self.adapters.device_arrays()
@@ -1805,49 +1782,85 @@ class ServingEngine:
             args.append(jnp.asarray(self._adapter_token_ids(sp)))
         if batcher.needs_history(self.sampling):
             args.append(jnp.asarray(self._penalty_counts()))
-        args.append(sub)
+        args.append(rng)
+        args += [jnp.asarray(a) for a in tail]
+        return args
+
+    def _multitick_tail(self, decode, n):
+        """The device loop's control tail for `n` ticks over a plan's
+        `decode` entries, as host arrays: n / eos / remain / cap
+        [/ per-slot adapter ids] [/ draft ring, ring counts]. It
+        PREALLOCATES the ticks' blocks: build it before `_step_args`
+        snapshots the block tables."""
+        sch = self.scheduler
+        S, K = self.kv.max_slots, self.draft_k + 1
+        eos = np.full(S, -1, np.int32)
+        remain = np.zeros(S, np.int32)
+        cap = np.zeros(S, np.int32)
+        for slot, _tok, pos in decode:
+            req = sch.slots[slot]
+            if req is None:
+                continue
+            if req.eos_token_id is not None:
+                eos[slot] = int(req.eos_token_id)
+            remain[slot] = req.max_new_tokens - len(req.output)
+            # FREE-block tick preallocation (scheduler.extend_for_ticks):
+            # in-device appends of later ticks land in already-mapped
+            # blocks. With speculation each tick may write up to K
+            # tokens, so the preallocation horizon is n * K; the in-loop
+            # draft clamp (k_eff <= cap - pos - 1) keeps accepted tokens
+            # inside it, and anything past it lands in the reserved
+            # null block and is never read back (attention stops at
+            # cap, harvest truncates to the emitted count).
+            cap[slot] = (sch.extend_for_ticks(slot, pos, n * K)
+                         if n * K > 1 else pos + 1)
+        tail = [np.int32(n), eos, remain, cap]
+        if self.adapters is not None:
+            tail.append(self._slot_adapters())
+        if K > 1:
+            tail += self._draft_ring_state()
+        return tail
+
+    def example_step_args(self):
+        """Zero-filled arguments matching the compiled mixed step's
+        call signature exactly: the packer on an EMPTY plan gives the
+        same fixed shapes every real step uses, so `fleet/export.py`
+        can lower + AOT-compile the step against these without the
+        engine ever serving a request (and without advancing
+        `self._rng` — boot stays deterministic)."""
+        import jax
+        sp = self._pack([], [])
+        if self._multitick:
+            return self._step_args(sp, np.asarray(self._rng),
+                                   self._multitick_tail([], 1))
+        _, sub = jax.random.split(self._rng)
+        return self._step_args(sp, sub)
+
+    def step(self):
+        """One engine iteration. Returns True when any work (tokens or
+        expiries) happened, False when the engine is idle/starved.
+        Pack, dispatch and readback are the device program's own
+        (`_run_tick`, `_run_multitick`); what they hand back is one
+        host form, and from `engine.emit` on there is one loop."""
+        sch = self.scheduler
+        # tracing state is sampled ONCE per step: recording stays
+        # consistent across the step even if a monitor attaches midway
+        trace_on = _tracing._enabled
+        ph = self.phases
+        t0 = ph.mark("engine.plan", self.steps_run) if trace_on else None
+        plan = sch.plan()
+        if _pmetrics._enabled and plan.expired:
+            smetrics.SERVING_REQUESTS.labels("expired").inc(
+                len(plan.expired))
+        if plan.empty:
+            self._flush_deferred()
+            if trace_on:
+                ph.close()
+            return bool(plan.expired)
         if trace_on:
-            ph.mark("engine.dispatch")
-        res = self._step_fn(*args)
-        if trace_on:
-            ph.mark("engine.wait")
-        moe_stats = block_stats = None
-        if self.num_experts:
-            res, moe_stats = res[:-1], res[-1]
-        elif self._block is not None:
-            # the sample rows' logits stay on the device, a row a slot:
-            # nothing reads them back but a caller who asks
-            res, block_stats, self.sample_logits = \
-                res[:-2], res[-2], res[-1]
-        out = res[0]
-        self.kv._set_pools(res[1:])
-        sch.note_fed(plan)
-        self.steps_run += 1
-        if self._sparse and plan.decode:
-            # selection arithmetic is deterministic on fixed geometry
-            # (min(allocated, table width) blocks attended per decode
-            # group per layer), so the skip accounting is pure host
-            # math — no device readback
-            for slot, tok, pos in plan.decode:
-                n_blk = (pos + _group_width(tok) - 1) \
-                    // self.block_size + 1
-                self.sparse_candidate_blocks += n_blk
-                self.sparse_selected_blocks += min(
-                    n_blk, self.sparse_table_width)
-        if trace_on and self._block is None:
-            # the attention work of this step, counted while the device
-            # does it: host arithmetic on the plan, no readback
-            work = _attention_work(plan, self.block_size)
-        elif trace_on:
-            work = _attention_work_by_kind(plan, self.kv.window)
-        tokres_np = acc_np = None
-        if self.draft_k and self.spec_sampling:
-            tok_np, tokv_np, tokres_np, acc_np = (np.asarray(t)
-                                                  for t in out)
-        elif self.draft_k:
-            tok_np, tokv_np = (np.asarray(t) for t in out)
-        else:
-            tok_np, tokv_np = np.asarray(out), None
+            ph.mark("engine.pack")
+        run = self._run_multitick if self._multitick else self._run_tick
+        sp, got = run(plan, trace_on)
         now = ph.mark("engine.emit") if trace_on else self.clock()
         if trace_on:
             # one prefill_chunk span per planned chunk: slot residents
@@ -1861,182 +1874,389 @@ class ServingEngine:
                         req.trace_id, "prefill_chunk",
                         replica=self.name, ts=now, start=int(start),
                         tokens=len(chunk), completes=bool(completes))
-
-        def emit(req, tokens, verify=False):
-            """Append generated tokens; returns True when the request
-            reached a terminal state (EOS / horizon)."""
-            if req.state == "prefill":
-                req.state = "decode"
-            first = req.first_token_time is None
-            gap = None
-            if first:
-                req.first_token_time = now
-                if _pmetrics._enabled:
-                    smetrics.SERVING_TTFT_SECONDS.observe(
-                        now - req.submit_time)
-            elif req._last_token_time is not None:
-                gap = now - req._last_token_time
-                if _pmetrics._enabled:
-                    smetrics.SERVING_INTER_TOKEN_SECONDS.observe(gap)
-            req._last_token_time = now
-            if trace_on:
-                # the span twins of the two histograms above: the
-                # first_token event's ts minus the enqueued event's ts
-                # IS `now - req.submit_time`, and decode/verify events
-                # carry the same `gap` — tools/trace_smoke.py asserts
-                # the sums match
-                if first:
-                    _tracing.on_first_token(req, self.name, ts=now)
-                else:
-                    _tracing.on_tokens(req, self.name, ts=now,
-                                       n=len(tokens), gap=gap,
-                                       verify=verify)
-            for t in tokens:
-                req.output.append(t)
-                if len(req.output) >= req.max_new_tokens or \
-                        (req.eos_token_id is not None
-                         and t == req.eos_token_id):
-                    sch.finish(req, now)
-                    if _pmetrics._enabled:
-                        smetrics.SERVING_REQUESTS.labels(
-                            "finished").inc()
-                    return True
-            return False
-
         for slot in sp.prefill_done:
             req = sch.slots[slot]
-            if req is not None:
-                done = emit(req, [int(tok_np[slot])])
-                if not done and self.role == "prefill":
-                    # prefill-role handoff point: the first token is
-                    # sampled, every prompt token's K/V is written —
-                    # the request parks until the frontend extracts it
-                    # toward a decode replica (a request that finished
-                    # AT its first token never migrates)
-                    req.state = "handoff"
-                    if trace_on:
-                        _tracing.TRACER.event(
-                            req.trace_id, "handoff",
-                            replica=self.name, ts=now)
-        if self.draft_k:
-            from .draft import accept_length, accept_length_sampled
-            for slot, toks, pos in sp.decode_entries:
-                req = sch.slots[slot]
-                if req is None:
-                    continue
-                g = tokv_np[slot]
-                if self.spec_sampling:
-                    # rejection-sampling acceptance: accepted drafts
-                    # re-emit the fed tokens, then the device's
-                    # residual resample (rejection at m) or its bonus
-                    # sample (every draft accepted)
-                    m = accept_length_sampled(toks, acc_np[slot])
-                    emitted = [int(t) for t in toks[1:m + 1]]
-                    emitted.append(int(g[m]) if m == len(toks) - 1
-                                   else int(tokres_np[slot][m]))
-                else:
-                    m = accept_length(toks, g)
-                    emitted = [int(t) for t in g[:m + 1]]
-                self.spec_proposed_total += len(toks) - 1
-                self.spec_accepted_total += m
-                if _pmetrics._enabled:
-                    smetrics.SERVING_ACCEPT_LENGTH.observe(m + 1)
-                    if len(toks) > 1:
-                        smetrics.SERVING_DRAFT_TOKENS.labels(
-                            "proposed").inc(len(toks) - 1)
-                        smetrics.SERVING_DRAFT_TOKENS.labels(
-                            "accepted").inc(m)
-                done = emit(req, emitted, verify=True)
-                if not done:
-                    # roll back blocks whose only contents were
-                    # rejected-draft K/V columns
-                    freed = sch.note_accept(slot, pos + m + 1)
-                    if freed and _pmetrics._enabled:
-                        smetrics.SERVING_SPEC_ROLLBACKS.inc()
-                        smetrics.SERVING_SPEC_ROLLBACK_BLOCKS.inc(freed)
-        else:
-            for slot in sp.decode_slots:
-                req = sch.slots[slot]
-                if req is not None:
-                    emit(req, [int(tok_np[slot])])
+            if req is not None and not self.emit(
+                    req, [int(got["first"][slot])], now, trace_on) \
+                    and self.role == "prefill":
+                # prefill-role handoff point: the first token is
+                # sampled, every prompt token's K/V is written — the
+                # request parks until the frontend extracts it toward a
+                # decode replica (a request that finished AT its first
+                # token never migrates)
+                req.state = "handoff"
+                if trace_on:
+                    _tracing.TRACER.event(req.trace_id, "handoff",
+                                          replica=self.name, ts=now)
+        for slot, tokens, accepted in got["groups"]:
+            req = sch.slots[slot]
+            if req is not None and not self.emit(
+                    req, tokens, now, trace_on, got["verify"]) \
+                    and accepted is not None:
+                self._note_accept(slot, accepted)
         if trace_on:
             ph.mark("engine.note")
-        if moe_stats is not None:
-            self._note_moe_stats(moe_stats)
+        if got["moe_stats"] is not None:
+            self._note_moe_stats(got["moe_stats"])
+        self.spec_proposed_total += got["spec"][0]
+        self.spec_accepted_total += got["spec"][1]
+        snap = record = None
         if _pmetrics._enabled:
-            smetrics.SERVING_STEPS.inc()
-            smetrics.SERVING_TOKENS.labels("prefill").inc(
-                sp.prefill_tokens)
-            smetrics.SERVING_TOKENS.labels("decode").inc(
-                sp.decode_tokens)
-            smetrics.SERVING_QUEUE_DEPTH.set(len(sch.queue))
-            smetrics.SERVING_ACTIVE_SLOTS.set(sch.num_active)
-            smetrics.SERVING_KV_BLOCKS_IN_USE.set(self.kv.blocks_in_use)
-            smetrics.SERVING_KV_BLOCK_UTILIZATION.set(
-                self.kv.utilization)
-            smetrics.SERVING_KV_BYTES_PER_TOKEN.set(
-                self.kv.kv_bytes_per_token)
-            if self._sparse and self.sparse_candidate_blocks:
-                skipped = (self.sparse_candidate_blocks
-                           - self.sparse_selected_blocks)
-                if skipped > self._sparse_skip_seen:
-                    smetrics.SERVING_KV_BLOCKS_SKIPPED.inc(
-                        skipped - self._sparse_skip_seen)
-                    self._sparse_skip_seen = skipped
-                smetrics.SERVING_SPARSE_ATTENTION_RATIO.set(
-                    self.sparse_selected_blocks
-                    / self.sparse_candidate_blocks)
-            new_p = sch.preemption_count - self._preempt_seen
-            if new_p:
-                smetrics.SERVING_PREEMPTIONS.inc(new_p)
-                self._preempt_seen = sch.preemption_count
-            new_imp = self.kv.blocks_imported - self._imported_seen
-            if new_imp:
-                smetrics.SERVING_KV_BLOCKS_MIGRATED.inc(new_imp)
-                self._imported_seen = self.kv.blocks_imported
-            if self.prefix_cache is not None:
-                pc = self.prefix_cache
-                h0, m0, e0 = self._prefix_seen
-                if pc.hit_tokens > h0:
-                    smetrics.SERVING_PREFIX_HIT_TOKENS.inc(
-                        pc.hit_tokens - h0)
-                if pc.miss_tokens > m0:
-                    smetrics.SERVING_PREFIX_MISS_TOKENS.inc(
-                        pc.miss_tokens - m0)
-                if pc.evictions > e0:
-                    smetrics.SERVING_PREFIX_EVICTIONS.inc(
-                        pc.evictions - e0)
-                self._prefix_seen = (pc.hit_tokens, pc.miss_tokens,
-                                     pc.evictions)
+            snap = self._snapshot(sp.prefill_tokens, got)
         if trace_on:
             # flight-recorder note: every field is a host int/float the
-            # loop already holds — no device readback, no jit input.
-            # The step's running compile count: a growing value across
-            # records is a compile event (the watchdog fails the run
-            # outright, this just timestamps it).
-            if block_stats is not None:
-                # the expert layers' counters, read back with the
-                # tokens; the two kinds of block, from the allocators
-                work.update(
-                    {n: int(v) for n, v in zip(
-                        self._block.stat_names,
-                        np.asarray(block_stats))},
-                    kv_blocks_in_use_full=int(
-                        self.kv.allocator.num_used),
-                    kv_blocks_in_use_window=int(
-                        self.kv.window_allocator.num_used),
-                    kv_blocks_released_behind_window=int(
-                        self.kv.blocks_released_behind_window
-                        - self._released_seen))
-                self._released_seen = \
-                    self.kv.blocks_released_behind_window
-                held, ctx = self.kv.window_held_tokens()
-                work.update(kv_tokens_held_window=held,
-                            kv_tokens_context=ctx)
-            self.flight.note(**self._step_record(
+            # loop already holds — no jit input, and no readback but
+            # the block model's counters, which came with the tokens
+            work = got["work"]
+            if got["block_stats"] is not None:
+                work.update(self._block_work(got["block_stats"]))
+            record = self._step_record(
                 t0, prefill_tokens=int(sp.prefill_tokens),
-                decode_tokens=int(sp.decode_tokens), **work))
+                decode_tokens=int(got["decode_tokens"]), **work,
+                **got["dispatch"])
+        if self._multitick and sch.has_work:
+            # deferred observability: every value was captured NOW; it
+            # publishes after the next dispatch launches (or at the
+            # idle / flush points)
+            self._deferred = (snap, record)
+        else:
+            self._observe(snap, record)
         return True
+
+    def _dispatch(self, plan, sp, rng, tail, trace_on):
+        """Run the compiled step on the packed `plan`, rebind the pools
+        it returns and note the plan fed. -> (its head output, still on
+        the device; the host form with what every program leaves alike)."""
+        args = self._step_args(sp, rng, tail)
+        if trace_on:
+            self.phases.mark("engine.dispatch")
+        res = self._step_fn(*args)
+        if trace_on:
+            self.phases.mark("engine.wait")
+        # spec: drafts proposed, drafts accepted, groups by accept length
+        got = dict(verify=False, moe_stats=None, block_stats=None,
+                   spec=(0, 0, ()), work=None, dispatch={})
+        if self.num_experts:
+            res, got["moe_stats"] = res[:-1], res[-1]
+        elif self._block is not None:
+            # the sample rows' logits stay on the device, a row a slot:
+            # nothing reads them back but a caller who asks
+            res, got["block_stats"], self.sample_logits = \
+                res[:-2], res[-2], res[-1]
+        self.kv._set_pools(res[1:])
+        self.scheduler.note_fed(plan)
+        self.steps_run += 1
+        return res[0], got
+
+    def _run_tick(self, plan, trace_on):
+        """Pack, dispatch and read back one tick of the mixed step.
+        -> (packed plan, host form): `first` the sampled token a slot
+        (what a completed prefill emits), `groups` a decode slot's
+        (slot, tokens to emit, length to roll the slot back to or
+        None), `verify` whether they were verify groups, and the
+        counters only this program has."""
+        import jax
+        sp = self._pack(plan.decode, plan.prefills)
+        self._rng, sub = jax.random.split(self._rng)
+        out, got = self._dispatch(plan, sp, sub, (), trace_on)
+        got.update(verify=bool(self.draft_k),
+                   decode_tokens=sp.decode_tokens)
+        if self._sparse:
+            self._note_sparse(pos + _group_width(tok) - 1
+                              for _, tok, pos in plan.decode)
+        if trace_on:
+            # the attention work of this step, counted while the device
+            # does it: host arithmetic on the plan, no readback
+            got["work"] = self._plan_work(plan)
+        if not self.draft_k:
+            tok_np = np.asarray(out)
+            got.update(first=tok_np, groups=[
+                (slot, [int(tok_np[slot])], None)
+                for slot in sp.decode_slots])
+            return sp, got
+        from .draft import accept_length, accept_length_sampled
+        tok_np, tokv_np, *sampled = (np.asarray(t) for t in out)
+        groups, prop, acc = [], 0, 0
+        hist = [0] * (self.draft_k + 1)
+        for slot, toks, pos in sp.decode_entries:
+            if self.scheduler.slots[slot] is None:
+                continue
+            g = tokv_np[slot]
+            if self.spec_sampling:
+                # rejection-sampling acceptance: accepted drafts
+                # re-emit the fed tokens, then the device's residual
+                # resample (rejection at m) or its bonus sample (every
+                # draft accepted)
+                tokres_np, acc_np = sampled
+                m = accept_length_sampled(toks, acc_np[slot])
+                emitted = [int(t) for t in toks[1:m + 1]]
+                emitted.append(int(g[m]) if m == len(toks) - 1
+                               else int(tokres_np[slot][m]))
+            else:
+                m = accept_length(toks, g)
+                emitted = [int(t) for t in g[:m + 1]]
+            prop += len(toks) - 1
+            acc += m
+            hist[m] += 1
+            # unless it finishes, roll back the blocks whose only
+            # contents were rejected-draft K/V columns
+            groups.append((slot, emitted, pos + m + 1))
+        got.update(first=tok_np, groups=groups, spec=(prop, acc, hist))
+        return sp, got
+
+    def _run_multitick(self, plan, trace_on):
+        """`_run_tick` for the device loop: preallocate tick capacity,
+        launch the while_loop dispatch, harvest the staging buffer into
+        the same host form, so the emitted tokens replay through the
+        host bookkeeping a 1-tick engine runs per step."""
+        K = self.draft_k + 1
+        t_launch = self.clock()
+        if self._gap_ema is not None or self._last_harvest is not None:
+            gap = max(t_launch - (self._last_harvest or t_launch), 0.0)
+            self._gap_ema = (gap if self._gap_ema is None
+                             else 0.7 * self._gap_ema + 0.3 * gap)
+        buf = self._plan_buffers[self._plan_flip]
+        self._plan_flip ^= 1
+        sp = self._pack(plan.decode, plan.prefills, buf)
+        # multi-tick only on pure-decode dispatches: a prefill chunk
+        # needs the host packer next step anyway, and a prefill-role
+        # engine's completions park in "handoff" — both pin n to 1
+        n = self.ticks_per_dispatch if not plan.prefills else 1
+        if n > 1 and self._ticks_auto:
+            n = self._auto_ticks(self.ticks_per_dispatch)
+        tail = self._multitick_tail(plan.decode, n)
+        # CHAIN key, always as a HOST array: the loop splits per tick
+        # and returns the advanced chain, which harvest materializes
+        # back to host — a device-resident key would flip the arg's
+        # sharding between dispatch 1 and 2 and recompile the step
+        # (under the TP mesh a sharded key would, too)
+        ctrl, got = self._dispatch(plan, sp, np.asarray(self._rng), tail,
+                                   trace_on)
+        # async device_get: start the control-output copies and flush
+        # the PREVIOUS dispatch's deferred observability while this
+        # dispatch still runs on device
+        for a in ctrl:
+            try:
+                a.copy_to_host_async()
+            except Exception:
+                pass
+        self._flush_deferred(trace_on)
+        hs0 = self.clock()
+        staged_np, counts_np, events_np = (np.asarray(a)
+                                           for a in ctrl[:3])
+        ticks_run = int(ctrl[3])
+        self._rng = np.asarray(ctrl[4])
+        if K > 1:
+            got["spec"] = (int(ctrl[5]), int(ctrl[6]),
+                           [int(x) for x in np.asarray(ctrl[7])])
+        host_stall = self.clock() - hs0
+        self._last_harvest = self.clock()
+        self.host_stall_total += host_stall
+        if ticks_run > 0:
+            d = (self._last_harvest - t_launch) / ticks_run
+            self._tick_ema = (d if self._tick_ema is None
+                              else 0.7 * self._tick_ema + 0.3 * d)
+            if self._gap_ema is None:
+                self._gap_ema = 0.0    # arm the gap EMA from now on
+        self.dispatches_run += 1
+        self.device_ticks_run += ticks_run
+        # what the device emitted a slot: (slot, position fed, count)
+        fed = [(slot, pos, max(int(counts_np[slot]), 1))
+               for slot, _tok, pos in plan.decode]
+        if n > 1 or K > 1:
+            # advance each decode slot to what the device actually
+            # emitted and release the preallocated tail — dispatch-
+            # boundary block state matches a 1-tick engine's exactly.
+            # With speculation the freed tail includes blocks whose
+            # only contents were rejected-draft K/V: those count as
+            # spec rollbacks, same taxonomy as the 1-tick host path.
+            for slot, pos, c in fed:
+                self._note_accept(slot, pos + c)
+        if self._sparse or trace_on:
+            # the first tick's attention work is the plan's; each
+            # further token a slot emitted in the device loop is one
+            # query at the next position. Exact without speculation;
+            # with device drafting the rejected draft columns are work
+            # the host never sees
+            ticked = plan.decode + [(slot, 0, pos + j) for slot, pos, c
+                                    in fed for j in range(1, c)]
+            if self._sparse:
+                self._note_sparse(pos for _, _, pos in ticked)
+            if trace_on:
+                got["work"] = self._plan_work(
+                    Plan(ticked, plan.prefills, ()))
+        ev_finish, ev_over = (int(np.sum((events_np & bit) > 0))
+                              if n > 1 else 0 for bit in (1, 2))
+        self.early_exit_counts["finish"] += ev_finish
+        self.early_exit_counts["overflow"] += ev_over
+        if got["moe_stats"] is not None:
+            # counts/dropped are per-tick sums; aux reports the mean
+            # balance loss over the executed ticks
+            got["moe_stats"] = dict(
+                got["moe_stats"],
+                aux=got["moe_stats"]["aux"] / max(ticks_run, 1))
+        groups = [(slot, [int(t) for t in staged_np[slot, :c]], None)
+                  for slot, _, c in fed
+                  if self.scheduler.slots[slot] is not None]
+        got.update(
+            first=staged_np[:, 0], groups=groups,
+            decode_tokens=sum(len(g[1]) for g in groups),
+            dispatch=dict(ticks=ticks_run, host_stall=float(host_stall),
+                          early_exit_finish=ev_finish,
+                          early_exit_overflow=ev_over))
+        return sp, got
+
+    def emit(self, req, tokens, now, trace_on, verify=False):
+        """Append generated tokens; returns True when the request
+        reached a terminal state (EOS / horizon: replaying a device
+        loop's tokens lands on the token its finish event flagged)."""
+        if req.state == "prefill":
+            req.state = "decode"
+        first = req.first_token_time is None
+        gap = None
+        if first:
+            req.first_token_time = now
+            if _pmetrics._enabled:
+                smetrics.SERVING_TTFT_SECONDS.observe(
+                    now - req.submit_time)
+        elif req._last_token_time is not None:
+            gap = now - req._last_token_time
+            if _pmetrics._enabled:
+                smetrics.SERVING_INTER_TOKEN_SECONDS.observe(gap)
+        req._last_token_time = now
+        if trace_on:
+            # the span twins of the two histograms above: the
+            # first_token event's ts minus the enqueued event's ts IS
+            # `now - req.submit_time`, and decode/verify events carry
+            # the same `gap` — tools/trace_smoke.py asserts the sums
+            # match
+            if first:
+                _tracing.on_first_token(req, self.name, ts=now)
+            else:
+                _tracing.on_tokens(req, self.name, ts=now,
+                                   n=len(tokens), gap=gap, verify=verify)
+        for t in tokens:
+            req.output.append(t)
+            if len(req.output) >= req.max_new_tokens or \
+                    (req.eos_token_id is not None
+                     and t == req.eos_token_id):
+                self.scheduler.finish(req, now)
+                if _pmetrics._enabled:
+                    smetrics.SERVING_REQUESTS.labels("finished").inc()
+                return True
+        return False
+
+    def _note_accept(self, slot, new_len):
+        """`new_len` tokens of the slot are cached and valid: blocks
+        past them (rejected drafts, unused tick preallocation) go back."""
+        freed = self.scheduler.note_accept(slot, new_len)
+        if freed and self.draft_k and _pmetrics._enabled:
+            smetrics.SERVING_SPEC_ROLLBACKS.inc()
+            smetrics.SERVING_SPEC_ROLLBACK_BLOCKS.inc(freed)
+
+    def _note_sparse(self, last_positions):
+        """Block-sparse skip accounting, a decode query (a verify
+        group's last) an entry. Selection is deterministic on fixed
+        geometry (min(allocated, table width) blocks attended a
+        layer): pure host math, no device readback."""
+        for p in last_positions:
+            n_blk = p // self.block_size + 1
+            self.sparse_candidate_blocks += n_blk
+            self.sparse_selected_blocks += min(
+                n_blk, self.sparse_table_width)
+
+    def _plan_work(self, plan):
+        return (_attention_work(plan, self.block_size)
+                if self._block is None
+                else _attention_work_by_kind(plan, self.kv.window))
+
+    def _block_work(self, block_stats):
+        """A block model's flight fields: the expert layers' counters,
+        read back with the tokens; the two kinds of block, from the
+        allocators."""
+        kv = self.kv
+        released = kv.blocks_released_behind_window
+        held, ctx = kv.window_held_tokens()
+        fields = dict(
+            zip(self._block.stat_names,
+                (int(v) for v in np.asarray(block_stats))),
+            kv_blocks_in_use_full=int(kv.allocator.num_used),
+            kv_blocks_in_use_window=int(kv.window_allocator.num_used),
+            kv_blocks_released_behind_window=int(
+                released - self._released_seen),
+            kv_tokens_held_window=held, kv_tokens_context=ctx)
+        self._released_seen = released
+        return fields
+
+    def _snapshot(self, prefill_tokens, got):
+        """The step's counters as host values, read NOW: `_observe`
+        publishes them at once, or (device loop) after the next
+        dispatch has launched. `grown` is what the engine's cumulative
+        counts grew by since the snapshot before."""
+        sch, kv, pc = self.scheduler, self.kv, self.prefix_cache
+        sel, cand = self.sparse_selected_blocks, \
+            self.sparse_candidate_blocks
+        gauges = [
+            (smetrics.SERVING_QUEUE_DEPTH, len(sch.queue)),
+            (smetrics.SERVING_ACTIVE_SLOTS, int(sch.num_active)),
+            (smetrics.SERVING_KV_BLOCKS_IN_USE, int(kv.blocks_in_use)),
+            (smetrics.SERVING_KV_BLOCK_UTILIZATION, float(kv.utilization)),
+            (smetrics.SERVING_KV_BYTES_PER_TOKEN,
+             float(kv.kv_bytes_per_token))]
+        counts = {smetrics.SERVING_PREEMPTIONS: sch.preemption_count,
+                  smetrics.SERVING_KV_BLOCKS_MIGRATED: kv.blocks_imported}
+        if cand:
+            gauges.append(
+                (smetrics.SERVING_SPARSE_ATTENTION_RATIO, sel / cand))
+            counts[smetrics.SERVING_KV_BLOCKS_SKIPPED] = cand - sel
+        if pc is not None:
+            counts[smetrics.SERVING_PREFIX_HIT_TOKENS] = pc.hit_tokens
+            counts[smetrics.SERVING_PREFIX_MISS_TOKENS] = pc.miss_tokens
+            counts[smetrics.SERVING_PREFIX_EVICTIONS] = pc.evictions
+        snap = dict(
+            got["dispatch"], spec=got["spec"], gauges=gauges,
+            prefill_tokens=int(prefill_tokens),
+            decode_tokens=int(got["decode_tokens"]),
+            grown=[(c, n - self._counts_seen.get(c, 0))
+                   for c, n in counts.items()])
+        self._counts_seen = counts
+        return snap
+
+    def _observe(self, snap, record):
+        """Publish one step: its snapshot to the serving metrics, its
+        flight record to the recorder (either may be None)."""
+        if snap is not None and _pmetrics._enabled:
+            smetrics.SERVING_STEPS.inc()
+            smetrics.SERVING_TOKENS.labels("prefill").inc(
+                snap["prefill_tokens"])
+            smetrics.SERVING_TOKENS.labels("decode").inc(
+                snap["decode_tokens"])
+            for gauge, value in snap["gauges"]:
+                gauge.set(value)
+            for counter, grown in snap["grown"]:
+                if grown > 0:
+                    counter.inc(grown)
+            if "ticks" in snap:
+                smetrics.SERVING_TICKS_PER_DISPATCH.observe(snap["ticks"])
+                smetrics.SERVING_HOST_STALL_SECONDS.inc(
+                    snap["host_stall"])
+                for kind in ("finish", "overflow"):
+                    if snap["early_exit_" + kind]:
+                        smetrics.SERVING_EARLY_EXITS.labels(kind).inc(
+                            snap["early_exit_" + kind])
+            proposed, accepted, hist = snap["spec"]
+            if proposed:
+                smetrics.SERVING_DRAFT_TOKENS.labels("proposed").inc(
+                    proposed)
+                smetrics.SERVING_DRAFT_TOKENS.labels("accepted").inc(
+                    accepted)
+            # bin b holds the number of verify groups that accepted
+            # exactly b drafts: an accept length of b + 1 each
+            for b, groups in enumerate(hist):
+                for _ in range(groups):
+                    smetrics.SERVING_ACCEPT_LENGTH.observe(b + 1)
+        if record is not None:
+            self.flight.note(**record)
 
     def _step_record(self, t0, **fields):
         """The flight record of the step that began at `t0`, from the
@@ -2072,12 +2292,12 @@ class ServingEngine:
         a traced step that is `engine.note` time, taken out of the
         phase it interrupts (`engine.wait`, where it hides behind the
         device)."""
-        cb, self._deferred = self._deferred, None
-        if cb is not None:
+        parked, self._deferred = self._deferred, None
+        if parked is not None:
             if trace_on:
                 resume = self.phases.name
                 self.phases.mark("engine.note")
-            cb()
+            self._observe(*parked)
             if trace_on:
                 self.phases.mark(resume)
 
@@ -2099,370 +2319,6 @@ class ServingEngine:
             return n_max
         import math
         return max(1, min(n_max, math.ceil(h / max(0.1 * d, 1e-9))))
-
-    def _step_multitick(self, plan, trace_on, t0):
-        """The multi-tick twin of `step()`'s post-plan body: preallocate
-        tick capacity, launch the while_loop dispatch, harvest the
-        staging buffer, and replay the emitted tokens through the same
-        host bookkeeping a 1-tick engine runs per step."""
-        import jax.numpy as jnp
-        sch = self.scheduler
-        ph = self.phases
-        S = self.kv.max_slots
-        t_launch = self.clock()
-        if self._gap_ema is not None or self._last_harvest is not None:
-            gap = max(t_launch - (self._last_harvest or t_launch), 0.0)
-            self._gap_ema = (gap if self._gap_ema is None
-                             else 0.7 * self._gap_ema + 0.3 * gap)
-        buf = self._plan_buffers[self._plan_flip]
-        self._plan_flip ^= 1
-        K = self.draft_k + 1
-        sp = pack_step(self.token_budget, S, plan.decode,
-                       plan.prefills, verify_width=K,
-                       reserve_region=self._sparse, buffers=buf)
-        # multi-tick only on pure-decode dispatches: a prefill chunk
-        # needs the host packer next step anyway, and a prefill-role
-        # engine's completions park in "handoff" — both pin n to 1
-        n = self.ticks_per_dispatch if not plan.prefills else 1
-        if n > 1 and self._ticks_auto:
-            n = self._auto_ticks(self.ticks_per_dispatch)
-        eos = np.full(S, -1, np.int32)
-        remain = np.zeros(S, np.int32)
-        cap = np.zeros(S, np.int32)
-        for slot, _tok, pos in plan.decode:
-            req = sch.slots[slot]
-            if req is None:
-                continue
-            if req.eos_token_id is not None:
-                eos[slot] = int(req.eos_token_id)
-            remain[slot] = req.max_new_tokens - len(req.output)
-            # FREE-block tick preallocation (scheduler.extend_for_ticks)
-            # — block_tables below is snapshotted AFTER, so in-device
-            # appends of later ticks land in already-mapped blocks.
-            # With speculation each tick may write up to K tokens, so
-            # the preallocation horizon is n * K; the in-loop draft
-            # clamp (k_eff <= cap - pos - 1) keeps accepted tokens
-            # inside it, and anything past it lands in the reserved
-            # null block and is never read back (attention stops at
-            # cap, harvest truncates to the emitted count).
-            cap[slot] = (sch.extend_for_ticks(slot, pos, n * K)
-                         if n * K > 1 else pos + 1)
-        args = [self._arrays] + self.kv._pools()
-        if self.adapters is not None:
-            args += self.adapters.device_arrays()
-        args += [jnp.asarray(sp.token_ids), jnp.asarray(sp.slot_ids),
-                 jnp.asarray(sp.positions),
-                 jnp.asarray(self.kv.block_tables),
-                 jnp.asarray(sp.sample_index)]
-        if self.adapters is not None:
-            args.append(jnp.asarray(self._adapter_token_ids(sp)))
-        if batcher.needs_history(self.sampling):
-            args.append(jnp.asarray(self._penalty_counts()))
-        # CHAIN key, always as a HOST array: the loop splits per tick
-        # and returns the advanced chain, which harvest materializes
-        # back to host — a device-resident key would flip the arg's
-        # sharding between dispatch 1 and 2 and recompile the step
-        args.append(np.asarray(self._rng))
-        args += [jnp.asarray(np.int32(n)), jnp.asarray(eos),
-                 jnp.asarray(remain), jnp.asarray(cap)]
-        if self.adapters is not None:
-            slot_ad = np.zeros(S, np.int32)
-            for s, req in enumerate(sch.slots):
-                if req is not None:
-                    slot_ad[s] = req.adapter_slot
-            args.append(jnp.asarray(slot_ad))
-        if K > 1:
-            ring, rcnt = self._draft_ring_state()
-            args += [jnp.asarray(ring), jnp.asarray(rcnt)]
-        if trace_on:
-            ph.mark("engine.dispatch")
-        res = self._step_fn(*args)
-        if trace_on:
-            ph.mark("engine.wait")
-        moe_stats = None
-        if self.num_experts:
-            res, moe_stats = res[:-1], res[-1]
-        ctrl = res[0]
-        sp_prop_d = sp_acc_d = sp_hist_d = None
-        if K > 1:
-            (staged_d, counts_d, events_d, ticks_d, new_rng,
-             sp_prop_d, sp_acc_d, sp_hist_d) = ctrl
-        else:
-            staged_d, counts_d, events_d, ticks_d, new_rng = ctrl
-        self.kv._set_pools(res[1:])
-        if self._multitick_async:
-            # async device_get: start the control-output copies and
-            # flush the PREVIOUS dispatch's deferred observability
-            # while this dispatch still runs on device
-            for a in ctrl:
-                try:
-                    a.copy_to_host_async()
-                except Exception:
-                    pass
-            self._flush_deferred(trace_on)
-        hs0 = self.clock()
-        counts_np = np.asarray(counts_d)
-        events_np = np.asarray(events_d)
-        staged_np = np.asarray(staged_d)
-        ticks_run = int(ticks_d)
-        spec_prop = spec_acc = 0
-        spec_hist = None
-        if K > 1:
-            spec_prop = int(sp_prop_d)
-            spec_acc = int(sp_acc_d)
-            spec_hist = np.asarray(sp_hist_d)
-            self.spec_proposed_total += spec_prop
-            self.spec_accepted_total += spec_acc
-        # the advanced CHAIN key comes back to host: next dispatch then
-        # passes the same uncommitted-host-key signature as the first
-        # (under the TP mesh a device-resident sharded key would change
-        # the arg sharding and force a second compile)
-        self._rng = np.asarray(new_rng)
-        host_stall = self.clock() - hs0
-        self._last_harvest = self.clock()
-        self.host_stall_total += host_stall
-        if trace_on:
-            ph.mark("engine.emit")
-        if not self._multitick_async:
-            # sync mode (the bench's "before" arm): block on readback
-            # first, do last dispatch's bookkeeping after — the legacy
-            # ordering the async lane exists to beat
-            self._flush_deferred(trace_on)
-        if ticks_run > 0:
-            d = (self._last_harvest - t_launch) / ticks_run
-            self._tick_ema = (d if self._tick_ema is None
-                              else 0.7 * self._tick_ema + 0.3 * d)
-            if self._gap_ema is None:
-                self._gap_ema = 0.0    # arm the gap EMA from now on
-        sch.note_fed(plan)
-        self.steps_run += 1
-        self.dispatches_run += 1
-        self.device_ticks_run += ticks_run
-        decode_emitted = 0
-        if n > 1 or K > 1:
-            # advance each decode slot to what the device actually
-            # emitted and release the preallocated tail — dispatch-
-            # boundary block state matches a 1-tick engine's exactly.
-            # With speculation the freed tail includes blocks whose
-            # only contents were rejected-draft K/V: those count as
-            # spec rollbacks, same taxonomy as the 1-tick host path.
-            for slot, _tok, pos in plan.decode:
-                c = max(int(counts_np[slot]), 1)
-                freed = sch.note_accept(slot, pos + c)
-                if freed and K > 1 and _pmetrics._enabled:
-                    smetrics.SERVING_SPEC_ROLLBACKS.inc()
-                    smetrics.SERVING_SPEC_ROLLBACK_BLOCKS.inc(freed)
-        if self._sparse and plan.decode:
-            for slot, _tok, pos in plan.decode:
-                c = max(int(counts_np[slot]), 1)
-                for j in range(c):
-                    n_blk = (pos + j) // self.block_size + 1
-                    self.sparse_candidate_blocks += n_blk
-                    self.sparse_selected_blocks += min(
-                        n_blk, self.sparse_table_width)
-        now = self.clock()
-        if trace_on:
-            # the first tick's attention work is the plan's; each
-            # further token a slot emitted in the device loop is one
-            # query at the next position. Exact without speculation;
-            # with device drafting the rejected draft columns are work
-            # the host never sees
-            work = _attention_work(plan, self.block_size)
-            for slot, _tok, pos in plan.decode:
-                c = max(int(counts_np[slot]), 1)
-                later = (c - 1) * (pos + 1) + c * (c - 1) // 2
-                work["kv_tokens_read"] += later
-                work["attn_pairs"] += later
-                blocks = sum((pos + j) // self.block_size + 1
-                             for j in range(1, c))
-                work["kv_blocks_needed"] += blocks
-                work["kv_blocks_walked"] += blocks
-            for slot, chunk, start, completes in plan.prefills:
-                req = sch.slots[slot]
-                if req is not None:
-                    _tracing.TRACER.event(
-                        req.trace_id, "prefill_chunk",
-                        replica=self.name, ts=now, start=int(start),
-                        tokens=len(chunk), completes=bool(completes))
-
-        def emit(req, tokens):
-            """Same terminal bookkeeping as the 1-tick `emit`: TTFT /
-            inter-token metrics, EOS + horizon replay (which lands on
-            exactly the token the device's finish event flagged)."""
-            if req.state == "prefill":
-                req.state = "decode"
-            first = req.first_token_time is None
-            gap = None
-            if first:
-                req.first_token_time = now
-                if _pmetrics._enabled:
-                    smetrics.SERVING_TTFT_SECONDS.observe(
-                        now - req.submit_time)
-            elif req._last_token_time is not None:
-                gap = now - req._last_token_time
-                if _pmetrics._enabled:
-                    smetrics.SERVING_INTER_TOKEN_SECONDS.observe(gap)
-            req._last_token_time = now
-            if trace_on:
-                if first:
-                    _tracing.on_first_token(req, self.name, ts=now)
-                else:
-                    _tracing.on_tokens(req, self.name, ts=now,
-                                       n=len(tokens), gap=gap,
-                                       verify=False)
-            for t in tokens:
-                req.output.append(t)
-                if len(req.output) >= req.max_new_tokens or \
-                        (req.eos_token_id is not None
-                         and t == req.eos_token_id):
-                    sch.finish(req, now)
-                    if _pmetrics._enabled:
-                        smetrics.SERVING_REQUESTS.labels(
-                            "finished").inc()
-                    return True
-            return False
-
-        for slot in sp.prefill_done:
-            req = sch.slots[slot]
-            if req is not None:
-                done = emit(req, [int(staged_np[slot, 0])])
-                if not done and self.role == "prefill":
-                    req.state = "handoff"
-                    if trace_on:
-                        _tracing.TRACER.event(
-                            req.trace_id, "handoff",
-                            replica=self.name, ts=now)
-        for slot in sp.decode_slots:
-            req = sch.slots[slot]
-            if req is not None:
-                c = max(int(counts_np[slot]), 1)
-                decode_emitted += c
-                emit(req, [int(t) for t in staged_np[slot, :c]])
-        ev_finish = ev_over = 0
-        if n > 1:
-            ev_finish = int(np.sum((events_np & 1) > 0))
-            ev_over = int(np.sum((events_np & 2) > 0))
-            self.early_exit_counts["finish"] += ev_finish
-            self.early_exit_counts["overflow"] += ev_over
-        if moe_stats is not None:
-            # counts/dropped are per-tick sums; aux reports the mean
-            # balance loss over the executed ticks
-            moe_stats = dict(
-                moe_stats,
-                aux=moe_stats["aux"] / max(ticks_run, 1))
-            self._note_moe_stats(moe_stats)
-        # deferred observability: capture every value NOW, publish
-        # after the next dispatch launches (or at idle/flush points)
-        snap = dict(
-            prefill_tokens=int(sp.prefill_tokens),
-            decode_tokens=int(decode_emitted),
-            queue_depth=len(sch.queue),
-            active_slots=int(sch.num_active),
-            blocks_in_use=int(self.kv.blocks_in_use),
-            utilization=float(self.kv.utilization),
-            bytes_per_token=float(self.kv.kv_bytes_per_token),
-            new_preempt=sch.preemption_count - self._preempt_seen,
-            new_imported=(self.kv.blocks_imported
-                          - self._imported_seen),
-            sparse_sel=self.sparse_selected_blocks,
-            sparse_cand=self.sparse_candidate_blocks,
-            blocks_imported=int(self.kv.blocks_imported),
-            ticks=ticks_run, host_stall=float(host_stall),
-            ev_finish=ev_finish, ev_over=ev_over,
-            spec_prop=spec_prop, spec_acc=spec_acc,
-            spec_hist=(None if spec_hist is None
-                       else [int(x) for x in spec_hist]))
-        self._preempt_seen = sch.preemption_count
-        self._imported_seen = self.kv.blocks_imported
-        prefix_deltas = None
-        if self.prefix_cache is not None:
-            pc = self.prefix_cache
-            h0, m0, e0 = self._prefix_seen
-            prefix_deltas = (pc.hit_tokens - h0, pc.miss_tokens - m0,
-                             pc.evictions - e0)
-            self._prefix_seen = (pc.hit_tokens, pc.miss_tokens,
-                                 pc.evictions)
-        record = None
-        if trace_on:
-            # the flight record is made now, while the step's span and
-            # phases are this step's, and noted with the rest
-            record = self._step_record(
-                t0, prefill_tokens=snap["prefill_tokens"],
-                decode_tokens=snap["decode_tokens"], **work,
-                ticks=snap["ticks"], host_stall=snap["host_stall"],
-                early_exit_finish=snap["ev_finish"],
-                early_exit_overflow=snap["ev_over"])
-
-        def observe():
-            if _pmetrics._enabled:
-                smetrics.SERVING_STEPS.inc()
-                smetrics.SERVING_TOKENS.labels("prefill").inc(
-                    snap["prefill_tokens"])
-                smetrics.SERVING_TOKENS.labels("decode").inc(
-                    snap["decode_tokens"])
-                smetrics.SERVING_QUEUE_DEPTH.set(snap["queue_depth"])
-                smetrics.SERVING_ACTIVE_SLOTS.set(snap["active_slots"])
-                smetrics.SERVING_KV_BLOCKS_IN_USE.set(
-                    snap["blocks_in_use"])
-                smetrics.SERVING_KV_BLOCK_UTILIZATION.set(
-                    snap["utilization"])
-                smetrics.SERVING_KV_BYTES_PER_TOKEN.set(
-                    snap["bytes_per_token"])
-                smetrics.SERVING_TICKS_PER_DISPATCH.observe(
-                    snap["ticks"])
-                smetrics.SERVING_HOST_STALL_SECONDS.inc(
-                    snap["host_stall"])
-                if snap["ev_finish"]:
-                    smetrics.SERVING_EARLY_EXITS.labels("finish").inc(
-                        snap["ev_finish"])
-                if snap["ev_over"]:
-                    smetrics.SERVING_EARLY_EXITS.labels(
-                        "overflow").inc(snap["ev_over"])
-                if snap["spec_prop"]:
-                    smetrics.SERVING_DRAFT_TOKENS.labels(
-                        "proposed").inc(snap["spec_prop"])
-                    smetrics.SERVING_DRAFT_TOKENS.labels(
-                        "accepted").inc(snap["spec_acc"])
-                if snap["spec_hist"]:
-                    # accept-length histogram bin b holds the number
-                    # of verify groups that accepted exactly b drafts
-                    # (device one_hot sum) — replay as m + 1 observes,
-                    # the 1-tick host path's exact semantics
-                    for b, cnt in enumerate(snap["spec_hist"]):
-                        for _ in range(cnt):
-                            smetrics.SERVING_ACCEPT_LENGTH.observe(
-                                b + 1)
-                if self._sparse and snap["sparse_cand"]:
-                    skipped = snap["sparse_cand"] - snap["sparse_sel"]
-                    if skipped > self._sparse_skip_seen:
-                        smetrics.SERVING_KV_BLOCKS_SKIPPED.inc(
-                            skipped - self._sparse_skip_seen)
-                        self._sparse_skip_seen = skipped
-                    smetrics.SERVING_SPARSE_ATTENTION_RATIO.set(
-                        snap["sparse_sel"] / snap["sparse_cand"])
-                if snap["new_preempt"]:
-                    smetrics.SERVING_PREEMPTIONS.inc(
-                        snap["new_preempt"])
-                if snap["new_imported"]:
-                    smetrics.SERVING_KV_BLOCKS_MIGRATED.inc(
-                        snap["new_imported"])
-                if prefix_deltas is not None:
-                    dh, dm, de = prefix_deltas
-                    if dh:
-                        smetrics.SERVING_PREFIX_HIT_TOKENS.inc(dh)
-                    if dm:
-                        smetrics.SERVING_PREFIX_MISS_TOKENS.inc(dm)
-                    if de:
-                        smetrics.SERVING_PREFIX_EVICTIONS.inc(de)
-            if record is not None:
-                self.flight.note(**record)
-
-        if sch.has_work:
-            self._deferred = observe
-        else:
-            # drain point: nothing will launch next, publish now
-            observe()
-        return True
 
     def run(self, max_steps=None):
         """Drive until every submitted request reaches a terminal
@@ -2492,52 +2348,6 @@ class ServingEngine:
         return [list(r.output) for r in reqs]
 
     # ------------------------------------------- fleet control plane
-    def example_step_args(self):
-        """Zero-filled arguments matching the compiled mixed step's
-        call signature exactly: an EMPTY StepPlan packs to the same
-        fixed shapes every real step uses, so `fleet/export.py` can
-        lower + AOT-compile the step against these without the engine
-        ever serving a request (and without advancing `self._rng` —
-        boot stays deterministic)."""
-        import jax
-        import jax.numpy as jnp
-        sp = pack_step(self.token_budget, self.kv.max_slots, [], [],
-                       verify_width=self.draft_k + 1,
-                       reserve_region=self._sparse)
-        _, sub = jax.random.split(self._rng)
-        args = [self._arrays] + self.kv._pools()
-        if self.adapters is not None:
-            args += self.adapters.device_arrays()
-        args += [jnp.asarray(sp.token_ids), jnp.asarray(sp.slot_ids),
-                 jnp.asarray(sp.positions),
-                 *(jnp.asarray(t) for t in self.kv.tables()),
-                 jnp.asarray(sp.sample_index)]
-        if self.adapters is not None:
-            args.append(jnp.asarray(self._adapter_token_ids(sp)))
-        if batcher.needs_history(self.sampling):
-            args.append(jnp.asarray(self._penalty_counts()))
-        args.append(sub)
-        if self._multitick:
-            # the while_loop wrapper's control tail (n_ticks / eos /
-            # remain / cap [/ per-slot adapter ids] [/ draft ring +
-            # ring counts]) — same fixed shapes every live dispatch
-            # passes
-            S = self.kv.max_slots
-            # the loop takes the CHAIN key (as a host array, like every
-            # live dispatch), not the split sub
-            args[-1] = np.asarray(self._rng)
-            args += [jnp.asarray(np.int32(1)),
-                     jnp.asarray(np.full(S, -1, np.int32)),
-                     jnp.asarray(np.zeros(S, np.int32)),
-                     jnp.asarray(np.zeros(S, np.int32))]
-            if self.adapters is not None:
-                args.append(jnp.asarray(np.zeros(S, np.int32)))
-            if self.draft_k:
-                args += [jnp.asarray(
-                    np.zeros((S, self.draft_ring), np.int32)),
-                    jnp.asarray(np.zeros(S, np.int32))]
-        return args
-
     def install_aot_step(self, fn):
         """Replace the instrumented mixed-step wrapper with a
         deserialized AOT executable (fleet/export.py). The replica

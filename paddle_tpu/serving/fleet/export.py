@@ -113,18 +113,17 @@ def engine_config(engine):
 
 def _serialize_step(engine):
     """Lower + AOT-compile the engine's jitted mixed step against its
-    own example arguments and serialize the executable. Goes through
-    `._jitted.lower(...)` directly — the AOT path neither populates
-    the instrumented wrapper's jit cache nor ticks the compile
-    watchdog, so exporting from inside a sanitized test costs no
-    budget. The compile must not come out of the persistent cache
-    (`core.compile_cache.compile_fresh` says why)."""
+    own example arguments and serialize the executable. The AOT path
+    neither populates the instrumented wrapper's jit cache nor ticks
+    the compile watchdog, so exporting from inside a sanitized test
+    costs no budget. The executable must come neither out of the
+    persistent cache nor from the engine's own jit, which may have
+    loaded it from there (`core.compile_cache.compile_fresh` says why)."""
     from jax.experimental import serialize_executable
 
     from ...core.compile_cache import compile_fresh
-    lowered = engine._step_fn._jitted.lower(*engine.example_step_args())
     payload, in_tree, out_tree = serialize_executable.serialize(
-        compile_fresh(lowered))
+        compile_fresh(engine._step_fn, *engine.example_step_args()))
     return pickle.dumps({"payload": payload, "in_tree": in_tree,
                          "out_tree": out_tree},
                         protocol=pickle.HIGHEST_PROTOCOL)

@@ -242,7 +242,7 @@ FLEET_COLD_START = REGISTRY.histogram(
     "paddle_tpu_serving_fleet_cold_start_seconds",
     "Boot-to-ready latency of controller-booted replicas (through "
     "first probe token when the boot carries a probe prompt): the "
-    "AOT-vs-jit A/B bench.py's serving_fleet_ops lane measures",
+    "AOT-vs-jit A/B of docs/DEPLOYMENT.md",
     buckets=exponential_buckets(1e-3, 4.0, 10))
 
 # ---- device-resident multi-tick decode (ISSUE 18) ----------------------

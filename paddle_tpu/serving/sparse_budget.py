@@ -109,9 +109,10 @@ def tune_sparse_budget(model=None, *, candidates=(4, 6, 8, 12, 16),
     ref = dense.generate_batch([list(p) for p in prompts],
                                max_new_tokens=max_new_tokens)
     total = sum(len(o) for o in ref)
-    H = int(model.hidden_size)
-    Dh = H // int(model.decoder.num_heads)
-    bucket = _kt.shape_bucket(H, Dh)
+    # the key `ServingEngine(sparse_blocks="auto")` looks up: head
+    # count and head width
+    dec = model.decoder
+    bucket = _kt.shape_bucket(dec.num_heads, dec.head_dim)
     sweep, best = [], None
     for B in sorted(int(b) for b in candidates):
         eng = engine(sparse_blocks=B, sparse_recent=int(sparse_recent))

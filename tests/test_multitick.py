@@ -409,6 +409,104 @@ class TestConfigPlumbing:
         assert engines2[1].ticks_per_dispatch == 2
 
 
+# ------------------------------------------------- one packer, one observe
+
+
+def _afmoe_engine():
+    from paddle_tpu.models import afmoe
+    from tests.test_afmoe_serving import small
+    return ServingEngine(afmoe.AfmoeForGeneration(small(), seed=3),
+                         max_slots=3, block_size=4, num_blocks=80,
+                         max_seq_len=128, token_budget=16,
+                         cache_dtype="float32")
+
+
+PACKER_CASES = {
+    "gpt": dict(),
+    "draft": dict(draft_k=2),
+    "adapters": dict(max_adapters=2, lora_rank=4),
+    "penalized": dict(sampling=SamplingConfig(
+        strategy="sampling", repetition_penalty=1.3)),
+    "sparse": dict(sparse_blocks=4),
+    "ticks4": dict(ticks_per_dispatch=4),
+    "ticks4_draft": dict(ticks_per_dispatch=4, draft_k=2),
+    "afmoe_block": None,
+}
+
+
+def _arg_specs(args):
+    """Tree structure, and per leaf (host or device, shape, dtype)."""
+    import jax
+    leaves, tree = jax.tree.flatten(list(args))
+    return tree, [("host" if isinstance(a, np.ndarray) else "device",
+                   tuple(a.shape), str(a.dtype)) for a in leaves]
+
+
+class TestOnePacker:
+    @pytest.mark.parametrize("case", list(PACKER_CASES))
+    def test_example_args_are_a_live_steps(self, model, case):
+        """`example_step_args()` is what the kernel check traces and
+        what the fleet bundle compiles: argument by argument it must be
+        what a live step hands the compiled step."""
+        kw = PACKER_CASES[case]
+        eng = _afmoe_engine() if kw is None else _engine(model, **kw)
+        example = _arg_specs(eng.example_step_args())
+        live, step_fn = [], eng._step_fn
+
+        def spy(*args):
+            live.append(_arg_specs(args))
+            return step_fn(*args)
+        eng._step_fn = spy
+        eng.generate_batch(_prompts(vocab=90), max_new_tokens=6)
+        # a prefill step, a decode step, and (multi-tick) a dispatch of
+        # several ticks: every one the same signature
+        assert len(live) >= 3
+        for tree, leaves in live:
+            assert tree == example[0]
+            assert leaves == example[1]
+        assert _arg_specs(eng.example_step_args()) == example
+
+    def test_one_tick_and_two_ticks_publish_the_same_counters(self, model):
+        """One `observe`: the same traffic leaves the same token,
+        request, preemption and prefix counters whichever device
+        program ran it (block pressure and the prefix cache apart: a
+        tick burst's preallocation may evict a cached block sooner)."""
+        from paddle_tpu.serving import metrics as sm
+        rng = np.random.RandomState(3)
+        head = rng.randint(1, 193, 8).tolist()
+        prompts = [head + rng.randint(1, 193, n).tolist()
+                   for n in (3, 7, 5, 9, 4, 6)]
+
+        def counters(ticks, **kw):
+            pm.enable()
+            pm.REGISTRY.reset()
+            try:
+                eng = _engine(model, ticks_per_dispatch=ticks, **kw)
+                out = eng.generate_batch(prompts, max_new_tokens=8)
+                return out, dict(
+                    prefill=sm.SERVING_TOKENS.labels("prefill").value,
+                    decode=sm.SERVING_TOKENS.labels("decode").value,
+                    finished=sm.SERVING_REQUESTS.labels(
+                        "finished").value,
+                    preemptions=sm.SERVING_PREEMPTIONS.value,
+                    prefix_hit=sm.SERVING_PREFIX_HIT_TOKENS.value,
+                    prefix_miss=sm.SERVING_PREFIX_MISS_TOKENS.value,
+                    prefix_evicted=sm.SERVING_PREFIX_EVICTIONS.value)
+            finally:
+                pm.REGISTRY.reset()
+                pm.disable()
+
+        for kw, moved in ((dict(num_blocks=14), "preemptions"),
+                          (dict(prefix_caching=True), "prefix_hit")):
+            out1, c1 = counters(1, **kw)
+            out2, c2 = counters(2, **kw)
+            assert out2 == out1
+            assert c2 == c1, kw
+            assert c1["finished"] == len(prompts)
+            assert c1["prefill"] > 0 and c1["decode"] > 0
+            assert c1[moved] > 0, c1
+
+
 # ------------------------------------------------- smoke-tool wiring
 
 

@@ -99,20 +99,3 @@ def test_add_ln_non_tileable_falls_back():
     np.testing.assert_allclose(np.asarray(out),
                                (zf - mu) / np.sqrt(var + 1e-5),
                                rtol=2e-4, atol=2e-4)
-
-
-def test_conv_wgrad_split_k_correct():
-    """The (measured-negative, see module docstring) split-K wgrad
-    kernel stays numerically correct."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.conv_wgrad import wgrad_1x1
-    rng = np.random.RandomState(0)
-    N, Ci, Co = 512, 128, 128
-    x = jnp.asarray(rng.randn(N, Ci), jnp.float32)
-    dy = jnp.asarray(rng.randn(N, Co), jnp.float32)
-    got = wgrad_1x1(x, dy, chunk=128, interpret=True)
-    ref = jax.lax.dot_general(x, dy, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)

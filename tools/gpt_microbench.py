@@ -1,6 +1,6 @@
 """GPT single-chip step micro-bench for perf iteration.
 
-Runs the bench.py flagship config (GPT2-350M-ish, B=32, S=1024) with
+Runs the train cell's config (GPT-2 350M, B=32, S=1024) with
 config overrides from the command line, prints ms/step and tok/s.
 
 Usage:

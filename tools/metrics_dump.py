@@ -27,7 +27,6 @@ EXPECTED_METRICS = (
     "paddle_tpu_hapi_batches_total",
     # Pallas kernel autotuner (ISSUE 11): registered by importing
     # profiler.metrics; activity is exercised by the autotune tests
-    # and bench.py's kernel_autotune extra
     "paddle_tpu_kernel_autotune_cache_hits_total",
     "paddle_tpu_kernel_autotune_cache_misses_total",
     "paddle_tpu_kernel_autotune_search_seconds_total",
