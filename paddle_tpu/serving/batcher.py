@@ -23,8 +23,10 @@ discipline — one compiled program serves a churning request mix):
                               slot's next token; -1 = no sample this
                               step (mid-prefill)
 
-Every array has the same shape every step; `block_tables` (from the
-paged KV cache) rides next to them.
+Every array has the same shape every step. In the engine they are
+views of ONE flat int32 buffer (`PlanLayout`, `PlanBuffers`) that also
+holds a copy of the block tables (and the per-token adapter ids): the
+whole plan is one upload a step.
 """
 from __future__ import annotations
 
@@ -329,23 +331,72 @@ class StepPlan:
     #                         [(slot, [tokens], position)] as planned —
     #                         the engine replays these against the
     #                         verify logits to compute accept lengths
+    buffers: "PlanBuffers" = None   # what the arrays are views of
+
+
+class PlanLayout:
+    """Where each field of a step's plan lies in ONE flat int32 buffer:
+    `fields[name] = (offset, shape)`, in the order
+
+        token_ids [T], slot_ids [T], positions [T], sample_index [S],
+        each block table [S, MB] (one, or full then window),
+        adapter_ids [T] (only with adapters registered)
+
+    Built once an engine, from what it can see of itself. The packer
+    (`PlanBuffers`) and the compiled step (`unpack` on the traced
+    buffer) read the same object, so the two cannot drift: everything
+    the host decides about a step reaches the device as one array."""
+
+    def __init__(self, token_budget, max_slots, tables, adapters=False):
+        T, S = int(token_budget), int(max_slots)
+        shapes = [("token_ids", (T,)), ("slot_ids", (T,)),
+                  ("positions", (T,)), ("sample_index", (S,))]
+        shapes += [(name, tuple(int(d) for d in shape))
+                   for name, shape in tables]
+        if adapters:
+            shapes.append(("adapter_ids", (T,)))
+        #: the block tables' field names, in `kv.tables()` order
+        self.tables = tuple(name for name, _ in tables)
+        self.fields = {}
+        self._spans = {}        # name -> the field's slice of the buffer
+        self.size = 0
+        for name, shape in shapes:
+            self.fields[name] = (self.size, shape)
+            self._spans[name] = slice(self.size,
+                                      self.size + int(np.prod(shape)))
+            self.size = self._spans[name].stop
+
+    def unpack(self, flat):
+        """name -> that field of `flat`, a static slice reshaped: numpy
+        VIEWS of a host buffer, or slices of the traced one."""
+        return {name: flat[self._spans[name]].reshape(shape)
+                for name, (_, shape) in self.fields.items()}
+
+    def replace(self, flat, **fields):
+        """The traced buffer with the named fields swapped (the device
+        loop rebuilds a tick's flat tokens and leaves the tables)."""
+        for name, value in fields.items():
+            flat = flat.at[self._spans[name]].set(value.reshape(-1))
+        return flat
 
 
 class PlanBuffers:
-    """Reusable numpy backing for `pack_step`'s fixed-shape tensors.
+    """One step's plan as the host packs it: ONE flat int32 buffer
+    (`flat`, what the engine uploads) laid out by a `PlanLayout`, with a
+    numpy VIEW of it under every field's name: `pack_step` writes
+    `token_ids`, `slot_ids`, `positions` and `sample_index` as it always
+    did, the engine copies the block tables (and the per-token adapter
+    ids) in beside them at pack time.
 
-    The multi-tick engine (docs/SERVING.md, "Device-resident decode")
-    keeps TWO of these and ping-pongs between dispatches: dispatch k's
-    arrays may still be feeding an async host→device transfer while
-    the host packs dispatch k+1 into the other buffer, so packing
-    never scribbles over an in-flight plan (the PR 6 double-buffer
-    prefetch discipline applied to the engine's plan tensors)."""
+    The engine keeps TWO of these and alternates: the buffer a
+    dispatched step may still be reading (an async host-to-device
+    transfer; on the CPU backend the device array IS the numpy memory)
+    is never the one the host packs next."""
 
-    def __init__(self, token_budget, max_slots):
-        self.token_ids = np.zeros(token_budget, np.int32)
-        self.slot_ids = np.full(token_budget, -1, np.int32)
-        self.positions = np.zeros(token_budget, np.int32)
-        self.sample_index = np.full(max_slots, -1, np.int32)
+    def __init__(self, layout):
+        self.flat = np.zeros(layout.size, np.int32)
+        vars(self).update(layout.unpack(self.flat))
+        self.reset()
 
     def reset(self):
         self.token_ids[:] = 0
@@ -380,8 +431,9 @@ def pack_step(token_budget, max_slots, decode, prefills,
     decode token of slot s sits at flat index s, and its hidden state
     still samples through `sample_index` like the dense layout).
 
-    `buffers` (a `PlanBuffers`) reuses preallocated arrays instead of
-    allocating fresh ones — same layout, same contents."""
+    `buffers` (a `PlanBuffers`) packs into its views of the one flat
+    buffer instead of allocating fresh arrays — same layout, same
+    contents; the plan then names it (`StepPlan.buffers`)."""
     vw = int(verify_width)
     region_on = vw > 1 or reserve_region
     region = max_slots * vw if region_on else 0
@@ -444,4 +496,4 @@ def pack_step(token_budget, max_slots, decode, prefills,
                     prefill_done=prefill_done,
                     prefill_tokens=n_prefill,
                     decode_tokens=n_decode, verify_width=vw,
-                    decode_entries=decode_entries)
+                    decode_entries=decode_entries, buffers=buffers)

@@ -176,8 +176,11 @@ class TPServingEngine(ServingEngine):
         device_put params + KV pools + adapter slot tensors to their
         mesh shardings, so the first step call compiles against the
         final layouts and never pays a resharding copy."""
+        import functools
+
         import jax
         from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
 
         from ...analysis.specs import canonicalize_spec
 
@@ -196,6 +199,15 @@ class TPServingEngine(ServingEngine):
             out.append(jax.device_put(
                 arr, NamedSharding(self.mesh, spec)))
         self._arrays = out
+        # what the host makes a step (the packed plan; counts, a device
+        # loop's tail) goes up replicated, and the key sits there from
+        # the start, as the step hands it back: the jit cache keys on
+        # input shardings, the key's too
+        replicated = NamedSharding(self.mesh, canonicalize_spec(
+            P(), self.mesh))
+        self._upload = functools.partial(jax.device_put,
+                                         device=replicated)
+        self._rng = jax.device_put(self._rng, replicated)
         psh = NamedSharding(self.mesh, self._pool_spec())
         ssh = NamedSharding(self.mesh, self._summary_spec())
 
@@ -327,20 +339,20 @@ class TPServingEngine(ServingEngine):
             pools += (self._summary_spec(),) * 2
         # adapter slot tensors follow the pools (engine._step_body's
         # rest-parse order), each under its SERVING_LORA_TP_SPECS
-        # sharding; the per-token adapter-id vector replicates with
-        # the other flat-token inputs
+        # sharding; the per-token adapter ids ride the packed plan
         lora_in = tuple(
             canonicalize_spec(s, self.mesh)
             for s in self._adapter_specs()) \
             if self.adapters is not None else ()
-        # flat-token inputs, block tables, the optional logit-processor
-        # count histogram (ISSUE 19: the [S, Vb] device-updatable form
-        # of the old history window) and the rng key replicate; sampled
-        # tokens come off the replicated post-psum hidden state so the
-        # token outputs replicate too (check_vma=False: the checker
-        # can't see through the scanned psum)
-        n_data = 6 + (1 if self.adapters is not None else 0) \
-            + (1 if batcher.needs_history(self.sampling) else 0)
+        # the packed plan (flat tokens, sample index, block table,
+        # adapter ids: one buffer), the optional logit-processor count
+        # histogram (ISSUE 19: the [S, Vb] device-updatable form of the
+        # old history window) and the key replicate; sampled tokens
+        # come off the replicated post-psum hidden state so the token
+        # outputs replicate too, and so does the advanced key, the last
+        # output (check_vma=False: the checker can't see through the
+        # scanned psum)
+        n_data = 2 + (1 if batcher.needs_history(self.sampling) else 0)
         data_in = (rep,) * n_data
         # spec-sampling adds the residual-resample + accept matrices
         # to the verify outputs (engine._step_body) — all replicated,
@@ -356,4 +368,5 @@ class TPServingEngine(ServingEngine):
         return _shard_map(
             body, mesh=self.mesh,
             in_specs=(self._array_specs(),) + pools + lora_in + data_in,
-            out_specs=(tok_out,) + pools + stats_out, check_vma=False)
+            out_specs=(tok_out,) + pools + stats_out + (rep,),
+            check_vma=False)

@@ -389,6 +389,8 @@ class ServingEngine:
         self.device = device
         commit = self._commit = (lambda a: a) if device is None else \
             functools.partial(jax.device_put, device=device)
+        # where a step's host arrays go, ALL in one call (`_step_args`)
+        self._upload = functools.partial(jax.device_put, device=device)
         kinds = {}
         if self._block is not None:
             # a window pool never runs dry: every slot a whole window
@@ -453,6 +455,9 @@ class ServingEngine:
         self.scheduler.replica = self.name
         self.eos_token_id = eos_token_id
         self.clock = clock
+        # the sampling key LIVES on the device: the step splits it and
+        # returns the advanced chain, `_dispatch` rebinds it like the
+        # pools and nothing reads it back
         self._rng = commit(jax.random.PRNGKey(int(seed)))
         # cast float params to the compute dtype ONCE (same discipline
         # as generation.generate: a per-step astype re-reads the full
@@ -481,6 +486,19 @@ class ServingEngine:
         self._moe_weight_bits = 0
         if moe_weight_dtype is not None:
             self._quantize_moe_experts(str(moe_weight_dtype))
+        # the step's plan: ONE flat int32 buffer a step, laid out once
+        # from what the engine sees of itself (budget, slots, the kinds
+        # of table, adapters). The packer and the compiled step share
+        # the layout; two buffers alternate, so the one a dispatched
+        # step may still be reading is never the one being packed
+        self.plan_layout = batcher.PlanLayout(
+            self.token_budget, max_slots,
+            [(n, t.shape) for n, t in zip(
+                ("block_tables", "window_tables"), self.kv.tables())],
+            adapters=self.adapters is not None)
+        self._plan_buffers = (batcher.PlanBuffers(self.plan_layout),
+                              batcher.PlanBuffers(self.plan_layout))
+        self._plan_flip = 0
         # quantized pools donate their scale arrays and summary-
         # tracking pools their min/max rows alongside the K/V pools,
         # so every in-step pool write aliases in place
@@ -497,17 +515,10 @@ class ServingEngine:
         self._step_fn = instrumented_jit(
             step_fn, STEP_FN_NAME, donate_argnums=donate)
         self._aot_step = False
-        # multi-tick host runtime state: double-buffered plan tensors
-        # (pack k+1 while k's may still be in flight), the deferred
-        # observability lane (dispatch k's metrics/flight flush after
-        # dispatch k+1 launches), and the measured-time EMAs the
-        # "auto" tick heuristic sizes dispatches from
-        self._plan_buffers = None
-        if self._multitick:
-            self._plan_buffers = (
-                batcher.PlanBuffers(self.token_budget, max_slots),
-                batcher.PlanBuffers(self.token_budget, max_slots))
-        self._plan_flip = 0
+        # multi-tick host runtime state: the deferred observability
+        # lane (dispatch k's metrics/flight flush after dispatch k+1
+        # launches), and the measured-time EMAs the "auto" tick
+        # heuristic sizes dispatches from
         self._deferred = None
         self._tick_ema = None        # seconds per device tick
         self._gap_ema = None         # host seconds between dispatches
@@ -697,12 +708,14 @@ class ServingEngine:
         query runs, padding. One compile: every shape is the token
         budget's or the pool's.
 
-        step(weights, k0, v0, k1, v1, ..., token_ids, slot_ids,
-        positions, tables_full, tables_window, sample_index, rng)
+        step(weights, k0, v0, k1, v1, ..., plan, key)
         -> (tokens [max_slots], k0, v0, ..., the block's counters
         (`block.stat_names`, folded over the layers by
         `block.fold_stats`: the engine does not know what they count),
-        float32 logits of the sample rows [max_slots, V])."""
+        float32 logits of the sample rows [max_slots, V], the key
+        advanced). `plan` is the packed buffer (`plan_layout`: flat
+        tokens, sample index, full and window tables), sliced once,
+        before the layers."""
         import jax
         import jax.numpy as jnp
 
@@ -714,15 +727,21 @@ class ServingEngine:
         L, BS, T = len(kinds), self.block_size, self.token_budget
         sc = self.sampling
         max_run = min(T, _BLOCK_MAX_RUN)
+        layout = self.plan_layout
 
         def step(weights, *rest):
             pools = list(rest[:2 * L])
-            (token_ids, slot_ids, positions, bt_full, bt_window,
-             sample_index, rng) = rest[2 * L:]
+            plan, key = rest[2 * L:]
+            f = layout.unpack(plan)
+            token_ids, slot_ids, positions, sample_index = (
+                f["token_ids"], f["slot_ids"], f["positions"],
+                f["sample_index"])
+            key, rng = jax.random.split(key)
             valid = slot_ids >= 0
             pos = jnp.where(valid, positions, 0)
             safe_slot = jnp.where(valid, slot_ids, 0)
-            tables = {"full": bt_full, "sliding": bt_window}
+            tables = {"full": f["block_tables"],
+                      "sliding": f["window_tables"]}
             # padding tokens write into the reserved NULL block
             wb = {k: jnp.where(valid, t[safe_slot, pos // BS], 0)
                   for k, t in tables.items()}
@@ -754,7 +773,7 @@ class ServingEngine:
             rows = h[jnp.clip(sample_index, 0, T - 1)]
             logits = block.head(arch, weights, rows)
             tok = select_token(logits, rng, sc)
-            return (tok, *pools, stats, logits.astype(jnp.float32))
+            return (tok, *pools, stats, logits.astype(jnp.float32), key)
 
         return step
 
@@ -798,6 +817,7 @@ class ServingEngine:
         lora = self.adapters is not None
         ad_names = tuple(self.adapters.array_names) if lora else ()
         K_ad = self.adapters.max_adapters if lora else 0
+        layout = self.plan_layout
 
         def quantize(x):
             """[T, H, Dh] fp -> (quantized values, [T, H] fp32
@@ -900,11 +920,14 @@ class ServingEngine:
             # quantized pools add (k_scale, v_scale) after the pools
             # and summary-tracking pools (k_sum_min, k_sum_max) after
             # those — the kv_cache._pools() order; adapter slot
-            # tensors follow them, with the per-token adapter ids
-            # after sample_index; active logit processors add the
-            # [S, Vb] token-count histogram before the rng (ISSUE 19:
-            # the count form replaces the [S, W] history tensor so
-            # the multi-tick loop can advance it per accepted token)
+            # tensors follow them; then the packed plan (`plan_layout`:
+            # flat tokens, sample index, block table, per-token adapter
+            # ids), sliced ONCE here, before the layer scan; active
+            # logit processors add the [S, Vb] token-count histogram
+            # before the key (ISSUE 19: the count form replaces the
+            # [S, W] history tensor so the multi-tick loop can advance
+            # it per accepted token). The key is the carried CHAIN: the
+            # step splits it and returns the advanced chain last
             rest = list(rest)
             k_scale = v_scale = counts = None
             k_sum_min = k_sum_max = None
@@ -918,13 +941,16 @@ class ServingEngine:
             if lora:
                 ad_arrays = rest[:len(ad_names)]
                 rest = rest[len(ad_names):]
-            (token_ids, slot_ids, positions, block_tables,
-             sample_index) = rest[:5]
-            rest = rest[5:]
-            adapter_ids = rest.pop(0) if lora else None
+            f = layout.unpack(rest.pop(0))
+            token_ids, slot_ids, positions, sample_index = (
+                f["token_ids"], f["slot_ids"], f["positions"],
+                f["sample_index"])
+            block_tables = f["block_tables"]
+            adapter_ids = f["adapter_ids"] if lora else None
             if use_hist:
                 counts = rest.pop(0)
-            (rng,) = rest
+            (key,) = rest
+            key, rng = jax.random.split(key)
             n_dec = len(names)
             we, pe = arrays[0], arrays[1]
             dec_arrays = arrays[2:2 + n_dec]
@@ -1113,7 +1139,7 @@ class ServingEngine:
                     rng, 4)
             tok = select_token(logits, rng, sc, counts=counts)
             if K == 1:
-                return (tok,) + pools
+                return (tok,) + pools + (key,)
             hv = xf[:R].reshape(S, K, -1)
             logits_v = jnp.matmul(hv, head.astype(hv.dtype))
             lv = logits_v.astype(jnp.float32)
@@ -1138,7 +1164,7 @@ class ServingEngine:
                 # j-th fed token — the host accepts the longest draft
                 # prefix matching it
                 tok_v = jnp.argmax(lv, axis=-1).astype(jnp.int32)
-                return ((tok, tok_v),) + pools
+                return ((tok, tok_v),) + pools + (key,)
             # REJECTION-SAMPLING verify (ISSUE 11 satellite): the
             # n-gram proposer is deterministic (a point-mass draft
             # distribution q), so the standard rule reduces to:
@@ -1169,7 +1195,7 @@ class ServingEngine:
                 axis=-1).astype(jnp.int32)
             tok_v = jax.random.categorical(
                 rng_bonus, fl, axis=-1).astype(jnp.int32)
-            return ((tok, tok_v, tok_res, acc),) + pools
+            return ((tok, tok_v, tok_res, acc),) + pools + (key,)
 
         return step
 
@@ -1178,23 +1204,26 @@ class ServingEngine:
         up to `n_ticks` decode ticks per host dispatch (docs/SERVING.md
         "Device-resident decode").
 
-        Call signature = the legacy step's, with the control tail
-        appended AFTER the rng (params stay arg 0, donated pools stay
+        Call signature = the one-tick step's, with the control tail
+        appended AFTER the key (params stay arg 0, donated pools stay
         1..n, so donation and the AOT export path are untouched):
 
-            ..., rng, n_ticks, eos [S], remain [S], cap [S][, slot_ad]
+            ..., plan, [counts,] key, n_ticks, eos [S], remain [S],
+            cap [S][, slot_ad]
 
-        `rng` is now the CHAIN key — the loop performs the exact
-        `rng, sub = split(rng)` the legacy host loop does before each
-        step, once per executed tick, and returns the advanced chain,
-        so an N-tick dispatch consumes the identical subkey sequence N
-        legacy steps would (seeded-sampling token identity).
+        `key` is the CHAIN key, as in the one-tick program: the base
+        step splits it once per executed tick and hands the advanced
+        chain back, the loop carries it, so an N-tick dispatch consumes
+        the identical subkey sequence N one-tick steps would
+        (seeded-sampling token identity).
 
-        Tick 0 consumes the host-packed plan arrays verbatim (bit-
-        identity with the single-tick dispatch); ticks >= 1 rebuild
-        the pure-decode inputs by scattering each live slot's previous
-        token at its pack-time anchor (`sample_index` — the dense
-        layout's packed index, the sparse region's own slot index),
+        Tick 0 consumes the host-packed plan verbatim (bit-identity
+        with the single-tick dispatch); ticks >= 1 rebuild the
+        pure-decode inputs in the packed buffer (`plan_layout.replace`:
+        the flat tokens change, the tables stay) by scattering each
+        live slot's previous token at its pack-time anchor
+        (`sample_index` — the dense layout's packed index, the sparse
+        region's own slot index),
         which reproduces exactly what the host packer would have built
         for the next step. The loop exits at the FIRST per-slot event
         so scheduling decisions (admission, preemption, expiry) happen
@@ -1213,12 +1242,13 @@ class ServingEngine:
         through the carry the same way.
 
         Outputs replace the token head with the control block
-        `(staged [S, N*K], counts [S], events [S], ticks, rng[,
+        `(staged [S, N*K], counts [S], events [S], ticks[,
         spec_proposed, spec_accepted, accept_hist [K]])`:
         `staged` is the -1-padded token staging buffer, `events` the
         per-slot bitmask (1 = finish: EOS or horizon; 2 = overflow:
         next tick would exceed the preallocated block capacity `cap`).
-        Pools (and summed MoE stats) follow as before."""
+        Pools (and summed MoE stats) follow as before, and the
+        advanced key comes last, where the one-tick program has it."""
         import jax
         import jax.numpy as jnp
 
@@ -1238,6 +1268,7 @@ class ServingEngine:
         n_pools = len(self.kv._pools())
         n_ad = len(self.adapters.array_names) if lora else 0
         E = self.num_experts
+        layout = self.plan_layout
 
         def multitick(arrays, *rest):
             rest = list(rest)
@@ -1245,10 +1276,12 @@ class ServingEngine:
             rest = rest[n_pools:]
             ad_arrays = tuple(rest[:n_ad])
             rest = rest[n_ad:]
-            (token_ids, slot_ids, positions, block_tables,
-             sample_index) = rest[:5]
-            rest = rest[5:]
-            adapter_ids = rest.pop(0) if lora else None
+            plan0 = rest.pop(0)
+            f = layout.unpack(plan0)
+            token_ids, slot_ids, positions, sample_index = (
+                f["token_ids"], f["slot_ids"], f["positions"],
+                f["sample_index"])
+            adapter_ids = f["adapter_ids"] if lora else None
             cnt0 = rest.pop(0) if use_hist else None
             rng0 = rest.pop(0)
             n_ticks = rest.pop(0)
@@ -1367,15 +1400,16 @@ class ServingEngine:
                         jnp.minimum(jnp.minimum(K - 1,
                                                 remain - counts - 1),
                                     cap - cur_pos - 1), 0, K - 1)
-                rng, sub = jax.random.split(rng)
-                call = [arrays] + list(pools_c) + list(ad_arrays)
-                call += [tid, sid, pid, block_tables, si]
+                fields = dict(token_ids=tid, slot_ids=sid, positions=pid,
+                              sample_index=si)
                 if lora:
-                    call.append(aid)
+                    fields["adapter_ids"] = aid
+                call = [arrays] + list(pools_c) + list(ad_arrays)
+                call.append(layout.replace(plan0, **fields))
                 if use_hist:
                     call.append(cnt)
-                call.append(sub)
-                res = base_step(*call)
+                call.append(rng)
+                *res, rng = base_step(*call)
                 out0 = res[0]
                 new_pools = res[1:]
                 if moe:
@@ -1505,13 +1539,13 @@ class ServingEngine:
             (t, rng, pools_f, staged, counts, events, _live, _tok,
              _pos, mstats, _cnt, _ring, _rcnt, spec_prop, spec_acc,
              spec_hist) = state
-            ctrl = (staged, counts, events, t, rng)
+            ctrl = (staged, counts, events, t)
             if K > 1:
                 ctrl += (spec_prop, spec_acc, spec_hist)
             out = (ctrl,) + tuple(pools_f)
             if moe:
                 out += (mstats,)
-            return out
+            return out + (rng,)
 
         return multitick
 
@@ -1758,40 +1792,53 @@ class ServingEngine:
                 utilization=self.moe_utilization_entropy())
 
     # -------------------------------------------------------------- run
-    def _pack(self, decode, prefills, buffers=None):
-        return pack_step(self.token_budget, self.kv.max_slots, decode,
-                         prefills, verify_width=self.draft_k + 1,
-                         reserve_region=self._sparse, buffers=buffers)
+    def _pack(self, decode, prefills):
+        """Pack a plan into the next of the two plan buffers, whole:
+        the flat tokens (`pack_step`), then a COPY of the block tables
+        as they stand now, and the per-token adapter ids. The step reads
+        this buffer and never the KV manager's live tables, so what the
+        host does to them after this cannot reach a dispatched step."""
+        buf = self._plan_buffers[self._plan_flip]
+        self._plan_flip ^= 1
+        sp = pack_step(self.token_budget, self.kv.max_slots, decode,
+                       prefills, verify_width=self.draft_k + 1,
+                       reserve_region=self._sparse, buffers=buf)
+        for name, table in zip(self.plan_layout.tables, self.kv.tables()):
+            np.copyto(getattr(buf, name), table)
+        if self.adapters is not None:
+            np.copyto(buf.adapter_ids, self._adapter_token_ids(sp))
+        return sp
 
-    def _step_args(self, sp, rng, tail=()):
+    def _step_args(self, sp, tail=(), note=None):
         """The compiled mixed step's arguments, assembled HERE and
         nowhere else: weights, pools, adapter arrays, the packed plan
-        with the block tables (one, or full and window), per-token
-        adapter ids, penalty counts, the key, the device loop's `tail`.
-        Live steps and `example_step_args()` (what the kernel check
-        traces and the fleet bundle compiles) both come through."""
-        import jax.numpy as jnp
+        (ONE buffer: flat tokens, sample index, block tables, adapter
+        ids), penalty counts, the key, the device loop's `tail`. What
+        the host made goes up in one `device_put`: the plan alone
+        unless logit processors or the device loop are on. Live steps
+        and `example_step_args()` (what the kernel check traces and the
+        fleet bundle compiles) both come through. `note` (a traced
+        step's) takes how many host arrays went up, and their bytes."""
+        host = [sp.buffers.flat]
+        if batcher.needs_history(self.sampling):
+            host.append(self._penalty_counts())
+        head = len(host)
+        host += tail
+        if note is not None:
+            note.update(h2d_arrays=len(host),
+                        h2d_bytes=sum(int(a.nbytes) for a in host))
+        dev = self._upload(host)
         args = [self._arrays] + self.kv._pools()
         if self.adapters is not None:
             args += self.adapters.device_arrays()
-        args += [jnp.asarray(sp.token_ids), jnp.asarray(sp.slot_ids),
-                 jnp.asarray(sp.positions),
-                 *(jnp.asarray(t) for t in self.kv.tables()),
-                 jnp.asarray(sp.sample_index)]
-        if self.adapters is not None:
-            args.append(jnp.asarray(self._adapter_token_ids(sp)))
-        if batcher.needs_history(self.sampling):
-            args.append(jnp.asarray(self._penalty_counts()))
-        args.append(rng)
-        args += [jnp.asarray(a) for a in tail]
-        return args
+        return args + dev[:head] + [self._rng] + dev[head:]
 
     def _multitick_tail(self, decode, n):
         """The device loop's control tail for `n` ticks over a plan's
         `decode` entries, as host arrays: n / eos / remain / cap
         [/ per-slot adapter ids] [/ draft ring, ring counts]. It
-        PREALLOCATES the ticks' blocks: build it before `_step_args`
-        snapshots the block tables."""
+        PREALLOCATES the ticks' blocks: build it before `_pack` copies
+        the block tables."""
         sch = self.scheduler
         S, K = self.kv.max_slots, self.draft_k + 1
         eos = np.full(S, -1, np.int32)
@@ -1827,14 +1874,10 @@ class ServingEngine:
         same fixed shapes every real step uses, so `fleet/export.py`
         can lower + AOT-compile the step against these without the
         engine ever serving a request (and without advancing
-        `self._rng` — boot stays deterministic)."""
-        import jax
-        sp = self._pack([], [])
-        if self._multitick:
-            return self._step_args(sp, np.asarray(self._rng),
-                                   self._multitick_tail([], 1))
-        _, sub = jax.random.split(self._rng)
-        return self._step_args(sp, sub)
+        `self._rng`: only a step that runs does — boot stays
+        deterministic)."""
+        tail = self._multitick_tail([], 1) if self._multitick else ()
+        return self._step_args(self._pack([], []), tail)
 
     def step(self):
         """One engine iteration. Returns True when any work (tokens or
@@ -1923,19 +1966,21 @@ class ServingEngine:
             self._observe(snap, record)
         return True
 
-    def _dispatch(self, plan, sp, rng, tail, trace_on):
+    def _dispatch(self, plan, sp, tail, trace_on):
         """Run the compiled step on the packed `plan`, rebind the pools
-        it returns and note the plan fed. -> (its head output, still on
-        the device; the host form with what every program leaves alike)."""
-        args = self._step_args(sp, rng, tail)
-        if trace_on:
-            self.phases.mark("engine.dispatch")
-        res = self._step_fn(*args)
-        if trace_on:
-            self.phases.mark("engine.wait")
+        and the key it returns (both stay on the device) and note the
+        plan fed. -> (its head output, still on the device; the host
+        form with what every program leaves alike)."""
         # spec: drafts proposed, drafts accepted, groups by accept length
         got = dict(verify=False, moe_stats=None, block_stats=None,
                    spec=(0, 0, ()), work=None, dispatch={})
+        args = self._step_args(sp, tail,
+                               got["dispatch"] if trace_on else None)
+        if trace_on:
+            self.phases.mark("engine.dispatch")
+        *res, self._rng = self._step_fn(*args)
+        if trace_on:
+            self.phases.mark("engine.wait")
         if self.num_experts:
             res, got["moe_stats"] = res[:-1], res[-1]
         elif self._block is not None:
@@ -1955,10 +2000,8 @@ class ServingEngine:
         (slot, tokens to emit, length to roll the slot back to or
         None), `verify` whether they were verify groups, and the
         counters only this program has."""
-        import jax
         sp = self._pack(plan.decode, plan.prefills)
-        self._rng, sub = jax.random.split(self._rng)
-        out, got = self._dispatch(plan, sp, sub, (), trace_on)
+        out, got = self._dispatch(plan, sp, (), trace_on)
         got.update(verify=bool(self.draft_k),
                    decode_tokens=sp.decode_tokens)
         if self._sparse:
@@ -2015,23 +2058,17 @@ class ServingEngine:
             gap = max(t_launch - (self._last_harvest or t_launch), 0.0)
             self._gap_ema = (gap if self._gap_ema is None
                              else 0.7 * self._gap_ema + 0.3 * gap)
-        buf = self._plan_buffers[self._plan_flip]
-        self._plan_flip ^= 1
-        sp = self._pack(plan.decode, plan.prefills, buf)
         # multi-tick only on pure-decode dispatches: a prefill chunk
         # needs the host packer next step anyway, and a prefill-role
         # engine's completions park in "handoff" — both pin n to 1
         n = self.ticks_per_dispatch if not plan.prefills else 1
         if n > 1 and self._ticks_auto:
             n = self._auto_ticks(self.ticks_per_dispatch)
+        # the tail first: it preallocates the ticks' blocks, and the
+        # pack copies the tables as they then stand
         tail = self._multitick_tail(plan.decode, n)
-        # CHAIN key, always as a HOST array: the loop splits per tick
-        # and returns the advanced chain, which harvest materializes
-        # back to host — a device-resident key would flip the arg's
-        # sharding between dispatch 1 and 2 and recompile the step
-        # (under the TP mesh a sharded key would, too)
-        ctrl, got = self._dispatch(plan, sp, np.asarray(self._rng), tail,
-                                   trace_on)
+        sp = self._pack(plan.decode, plan.prefills)
+        ctrl, got = self._dispatch(plan, sp, tail, trace_on)
         # async device_get: start the control-output copies and flush
         # the PREVIOUS dispatch's deferred observability while this
         # dispatch still runs on device
@@ -2045,10 +2082,9 @@ class ServingEngine:
         staged_np, counts_np, events_np = (np.asarray(a)
                                            for a in ctrl[:3])
         ticks_run = int(ctrl[3])
-        self._rng = np.asarray(ctrl[4])
         if K > 1:
-            got["spec"] = (int(ctrl[5]), int(ctrl[6]),
-                           [int(x) for x in np.asarray(ctrl[7])])
+            got["spec"] = (int(ctrl[4]), int(ctrl[5]),
+                           [int(x) for x in np.asarray(ctrl[6])])
         host_stall = self.clock() - hs0
         self._last_harvest = self.clock()
         self.host_stall_total += host_stall
@@ -2098,12 +2134,11 @@ class ServingEngine:
         groups = [(slot, [int(t) for t in staged_np[slot, :c]], None)
                   for slot, _, c in fed
                   if self.scheduler.slots[slot] is not None]
-        got.update(
-            first=staged_np[:, 0], groups=groups,
-            decode_tokens=sum(len(g[1]) for g in groups),
-            dispatch=dict(ticks=ticks_run, host_stall=float(host_stall),
-                          early_exit_finish=ev_finish,
-                          early_exit_overflow=ev_over))
+        got["dispatch"].update(
+            ticks=ticks_run, host_stall=float(host_stall),
+            early_exit_finish=ev_finish, early_exit_overflow=ev_over)
+        got.update(first=staged_np[:, 0], groups=groups,
+                   decode_tokens=sum(len(g[1]) for g in groups))
         return sp, got
 
     def emit(self, req, tokens, now, trace_on, verify=False):

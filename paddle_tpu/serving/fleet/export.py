@@ -4,7 +4,9 @@ A bundle is one directory per checkpoint version:
 
     <root>/<version>/
         manifest.json       model config + engine knobs + kv_meta +
-                            weight manifest + executable index
+                            weight manifest + executable index, and
+                            beside each executable the signature of
+                            the step it was compiled for
         weights.npz         canonical model-order host arrays
                             (pre-compute-dtype-cast, `w00000`, ...)
         step__<role>__tp<n>.bin
@@ -28,7 +30,11 @@ devices that engine lives on. The replica performs ZERO
 first token straight off the deserialized executable. A bundle
 exported with `include_executable=False` (or for another `(role, tp)`)
 carries config + weights only and boots through the ordinary jit path;
-`FleetBundle.has_executable` tells the two apart.
+`FleetBundle.has_executable` tells the two apart. So does a bundle
+whose executable was compiled for ANOTHER argument list than the
+booting engine passes (`step_signature`; the manifest's engine knobs
+pin the constructor, not the order of the step's arguments): its
+executable is left alone and the replica compiles afresh.
 """
 from __future__ import annotations
 
@@ -111,6 +117,20 @@ def engine_config(engine):
     return cfg
 
 
+def step_signature(engine):
+    """The compiled mixed step's argument list as the engine passes it
+    NOW: the plan layout's fields with their offsets, and after the
+    weights every argument's dtype and shape, in order. An executable
+    runs only under the signature it was compiled for."""
+    import jax
+    leaves = jax.tree.leaves(list(engine.example_step_args()[1:]))
+    return {
+        "plan": [[name, at, list(shape)] for name, (at, shape)
+                 in engine.plan_layout.fields.items()],
+        "args": [f"{np.dtype(a.dtype).name}{list(np.shape(a))}"
+                 for a in leaves]}
+
+
 def _serialize_step(engine):
     """Lower + AOT-compile the engine's jitted mixed step against its
     own example arguments and serialize the executable. The AOT path
@@ -154,6 +174,7 @@ def export_bundle(engine, path, *, version="v1", seed=0,
                      "dtype": str(a.dtype)}
                     for i, a in enumerate(arrays)],
         "executables": {},
+        "step_signatures": {},
     }
     mpath = os.path.join(bdir, MANIFEST)
     if include_executable:
@@ -163,14 +184,17 @@ def export_bundle(engine, path, *, version="v1", seed=0,
         with open(os.path.join(bdir, fname), "wb") as f:
             f.write(_serialize_step(engine))
         manifest["executables"][_exec_key(role, tp)] = fname
+        manifest["step_signatures"][_exec_key(role, tp)] = \
+            step_signature(engine)
     if os.path.exists(mpath):
         # re-export for another (role, TP): merge executable indices,
         # keep the shared config/weights freshly written above
         with open(mpath) as f:
             old = json.load(f)
-        merged = dict(old.get("executables", {}))
-        merged.update(manifest["executables"])
-        manifest["executables"] = merged
+        for index in ("executables", "step_signatures"):
+            merged = dict(old.get(index, {}))
+            merged.update(manifest[index])
+            manifest[index] = merged
     with open(mpath, "w") as f:
         json.dump(manifest, f, indent=1, sort_keys=True)
     return bdir
@@ -207,6 +231,13 @@ class FleetBundle:
 
     def has_executable(self, role="mixed", tp=1):
         return _exec_key(role, tp) in self.manifest["executables"]
+
+    def step_signature(self, role="mixed", tp=1):
+        """What `step_signature(engine)` read when the (role, tp)
+        executable was exported; None for a bundle from before the
+        manifest said."""
+        return self.manifest.get("step_signatures", {}).get(
+            _exec_key(role, tp))
 
     def executable(self, devices, role="mixed", tp=1):
         """Deserialize the (role, tp) step executable onto `devices` —
@@ -283,10 +314,13 @@ def boot_engine_from_bundle(bundle, *, aot=True, warm_prefix=None,
         from ..engine import ServingEngine
         engine = ServingEngine(model, **ecfg)
     engine.weights_version = bundle.version
-    if aot:
-        fn = bundle.executable(engine.step_devices(), role, tp)
-        if fn is not None:
-            engine.install_aot_step(fn)
+    if aot and bundle.has_executable(role, tp) and \
+            bundle.step_signature(role, tp) == step_signature(engine):
+        # an executable compiled for another argument list is not
+        # loaded: the replica compiles its own step, as with no
+        # executable in the bundle
+        engine.install_aot_step(
+            bundle.executable(engine.step_devices(), role, tp))
     if warm_prefix is not None and engine.prefix_cache is not None \
             and os.path.exists(warm_prefix):
         engine.prefix_cache.restore(warm_prefix)

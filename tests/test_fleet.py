@@ -92,6 +92,41 @@ class TestBundle:
         boot = boot_engine_from_bundle(bundle)   # ordinary jit path
         assert _gen(boot) == ref
 
+    @pytest.mark.parametrize("drift", ["before_the_field", "other_order"])
+    def test_executable_of_another_signature_is_not_loaded(self, tmp_path,
+                                                          drift):
+        """The manifest pins the constructor's knobs, not the step's
+        argument list: a bundle whose executable was compiled for
+        another list (before the plan travelled as one buffer the step
+        took five arrays in its place; such a manifest has no
+        `step_signatures`) boots through a fresh compile and serves the
+        same tokens."""
+        import json
+        eng = ServingEngine(_model(), **ENG_KW)
+        ref = _gen(eng)
+        bdir = export_bundle(eng, str(tmp_path))
+        mpath = os.path.join(bdir, "manifest.json")
+        with open(mpath) as f:
+            man = json.load(f)
+        sig = man["step_signatures"]["mixed-tp1"]
+        assert [f[0] for f in sig["plan"]] == [
+            "token_ids", "slot_ids", "positions", "sample_index",
+            "block_tables"]
+        assert sig["args"][-2:] == [
+            f"int32[{eng.plan_layout.size}]", "uint32[2]"]
+        if drift == "before_the_field":
+            del man["step_signatures"]
+        else:
+            sig["args"] = sig["args"][::-1]
+        with open(mpath, "w") as f:
+            json.dump(man, f)
+        bundle = FleetBundle(bdir)
+        assert bundle.has_executable("mixed", 1)
+        boot = boot_engine_from_bundle(bundle)
+        assert not boot._aot_step
+        assert _gen(boot) == ref
+        assert boot.step_compile_count() == 1
+
     def test_warm_boot_restores_prefix_spill(self, tmp_path):
         eng = ServingEngine(_model(), **ENG_KW)
         ref = _gen(eng)

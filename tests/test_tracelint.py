@@ -765,8 +765,8 @@ class TestGuards:
 class TestWatchdogCatchesEngineRecompile:
     def test_second_mixed_step_compile_fails_the_test(self):
         """The acceptance demo: a one-compile serving engine whose
-        mixed step is forced into a SECOND compile (an int64 where the
-        packed step always feeds int32 — exactly the signature-drift
+        mixed step is forced into a SECOND compile (an int16 where the
+        packed plan is always int32 — exactly the signature-drift
         bug class) is caught by the suite-wide conftest watchdog; the
         violation is consumed here so this test documents the failure
         instead of failing itself."""
@@ -787,14 +787,10 @@ class TestWatchdogCatchesEngineRecompile:
                             max_seq_len=32, cache_dtype="float32")
         eng.generate_batch([[5, 6, 7]], max_new_tokens=2)
         assert wd.violations == []      # one compile: in budget
-        T, S = eng.token_budget, eng.kv.max_slots
         bad = eng._step_fn(
             eng._arrays, eng.kv.k_pool, eng.kv.v_pool,
-            jnp.zeros((T,), jnp.int16),          # int32 by contract
-            jnp.full((T,), -1, jnp.int32),
-            jnp.zeros((T,), jnp.int32),
-            jnp.asarray(eng.kv.block_tables),
-            jnp.zeros((S,), jnp.int32),
+            # the packed plan: int32 by contract
+            jnp.zeros((eng.plan_layout.size,), jnp.int16),
             jax.random.PRNGKey(0))
         del bad
         v = wd.consume_violations()
