@@ -26,6 +26,7 @@ import dataclasses
 import math
 
 from ..parallel.moe_utils import dropless_expert_ffn, route_sigmoid_topk
+from .serving_block import ServingBlock
 
 SLIDING, FULL = "sliding", "full"
 DENSE, MOE = "dense", "moe"
@@ -351,16 +352,3 @@ class AfmoeForGeneration:
         and the three functions of `(arch, weights, ...)` above."""
         return ServingBlock(self.arch, self.weights, embed, layer_forward,
                             head, STAT_NAMES, fold_stats)
-
-
-@dataclasses.dataclass(frozen=True)
-class ServingBlock:
-    arch: AfmoeArch
-    weights: dict
-    embed: object           # (arch, weights, token_ids) -> h [T, D]
-    layer: object           # (arch, li, layer weights, h, positions,
-    #                          valid, attend) -> (h, stats or None)
-    head: object            # (arch, weights, h rows) -> logits
-    stat_names: tuple       # flight-record names of the step's counters
-    fold_stats: object      # (int32[len(stat_names)], a layer's stats)
-    #                          -> int32[len(stat_names)]

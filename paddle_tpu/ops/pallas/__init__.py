@@ -27,9 +27,10 @@ def interpret_mode():
     CPU rehearsal (`chip_smoke.py --rehearse`, `tools/
     tpu_tile_validate.py --rehearse`) drives the real kernel bodies and
     their block-table plumbing with no chip."""
-    from . import (flash_attention, grouped_matmul, layer_norm,
-                   paged_attention)
-    mods = (flash_attention, grouped_matmul, layer_norm, paged_attention)
+    from . import (flash_attention, gated_delta, grouped_matmul,
+                   layer_norm, paged_attention)
+    mods = (flash_attention, gated_delta, grouped_matmul, layer_norm,
+            paged_attention)
     old = [m._INTERPRET for m in mods]
     for m in mods:
         m._INTERPRET = True

@@ -77,6 +77,13 @@ def _group_width(tok):
         tok, "ndim", None) == 0 else len(tok)
 
 
+def _plan_groups(plan):
+    """(first position, tokens) of every slot a plan feeds."""
+    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
+    return groups + [(start, len(chunk))
+                     for _, chunk, start, _ in plan.prefills]
+
+
 def _attention_work(plan, block_size):
     """The attention work of one tick fed `plan`, as the flight
     record's fields. Per slot fed `n` query tokens from position
@@ -92,8 +99,7 @@ def _attention_work(plan, block_size):
     FLOPs is the benchmark's arithmetic."""
     from ..ops.pallas.paged_attention import blocks_walked
     read = pairs = needed = 0
-    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
-    groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
+    groups = _plan_groups(plan)
     for start, n in groups:
         read += start + n
         pairs += n * start + n * (n + 1) // 2
@@ -104,18 +110,19 @@ def _attention_work(plan, block_size):
                 kv_blocks_walked=int(walked))
 
 
-def _attention_work_by_kind(plan, window):
+def _attention_work_by_kind(plan, window=None):
     """`_attention_work` for a model with window and full layers: the
     work of ONE layer of each kind (the benchmark multiplies by the
     layers of the kind). A window layer's query at p reads and attends
     keys `p - window < j <= p`: a group of `n` tokens from `start`
-    reads `start + n - max(start - window + 1, 0)` tokens once."""
-    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
-    groups += [(start, len(chunk)) for _, chunk, start, _ in plan.prefills]
+    reads `start + n - max(start - window + 1, 0)` tokens once. With no
+    window (`window=None`: no layer is sliding) the `_window` fields are
+    there and 0."""
+    groups = _plan_groups(plan)
     out = {}
     for kind, w in (("window", window), ("full", None)):
         read = pairs = 0
-        for start, n in groups:
+        for start, n in groups if w or kind == "full" else ():
             read += start + n - (max(start - w + 1, 0) if w else 0)
             # a query at p attends min(p + 1, w) keys: p + 1 runs over
             # start + 1 .. start + n, capped at w
@@ -126,6 +133,17 @@ def _attention_work_by_kind(plan, window):
         out[f"kv_tokens_read_{kind}"] = int(read)
         out[f"attn_pairs_{kind}"] = int(pairs)
     return out
+
+
+def _linear_work(plan, chunk):
+    """The work of ONE linear (delta-rule) layer fed `plan`: valid
+    tokens, runs (a state read and written once each: one a slot fed)
+    and the chunks of `chunk` rows the kernel walks."""
+    groups = _plan_groups(plan)
+    return dict(lin_tokens=int(sum(n for _, n in groups)),
+                lin_runs=len(groups),
+                lin_chunks=int(sum(-(-n // chunk) for _, n in groups)),
+                lin_chunk_size=int(chunk))
 
 
 #: the longest query run the paged kernel takes at once in the step of a
@@ -153,10 +171,12 @@ class ServingEngine:
         model.eval()
         self.model = model
         # the seam: a model that brings its own block (embed, a layer
-        # body given `attend(q, k, v, layer)`, final norm + head; see
-        # `models.afmoe.ServingBlock`) is stepped through it, layer by
-        # layer, each layer on its own K/V pool. The GPT decoder has no
-        # such block: its step is the scan over stacked layers below.
+        # body given `attend(q, k, v, layer)` and, with linear layers,
+        # `recur(...)`, final norm + head; see `models.serving_block.
+        # ServingBlock`) is stepped through it, layer by layer, each
+        # layer on its own K/V pool or recurrent state. The GPT decoder
+        # has no such block: its step is the scan over stacked layers
+        # below.
         self._block = (model.serving_block()
                        if hasattr(model, "serving_block") else None)
         if self._block is not None:
@@ -171,11 +191,19 @@ class ServingEngine:
                          ticks_per_dispatch=ticks_per_dispatch != 1,
                          role=role != "mixed")
             bad = [k for k, v in asked.items() if v]
+            kinds_of = set(arch.layer_kinds)
+            if "linear" in kinds_of and (prefix_caching or draft_k):
+                raise ValueError(
+                    f"{'prefix_caching' if prefix_caching else 'draft_k'}"
+                    " with linear layers is not built: a recurrent state "
+                    "can be neither truncated nor shared by blocks")
             if prefix_caching:
                 raise ValueError(
                     "prefix_caching with window layers is not built: a "
                     "window table lets go of the blocks a cached prefix "
-                    "would share")
+                    "would share" if "sliding" in kinds_of else
+                    f"prefix_caching is not built for the step of a "
+                    f"model-provided block ({type(model).__name__})")
             if bad or batcher.needs_history(sampling or SamplingConfig()):
                 raise ValueError(
                     f"{type(model).__name__} is served through its own "
@@ -393,14 +421,39 @@ class ServingEngine:
         self._upload = functools.partial(jax.device_put, device=device)
         kinds = {}
         if self._block is not None:
-            # a window pool never runs dry: every slot a whole window
-            # and a step's tokens
-            kinds = dict(
-                num_kv_heads=arch.num_kv_heads,
-                layer_kinds=arch.layer_kinds, window=arch.window,
-                num_window_blocks=max_slots * min(mbps, -(-(
-                    arch.window + self.token_budget) // self.block_size)
-                    + 1) + 1)
+            # K/V heads that do not fill the pools' tiles (30 of 32):
+            # XLA then keeps a pool in another layout than the kernel
+            # reads and copies the WHOLE pool between the two, every
+            # step, a full layer (read on the chip: 12 copies of 1.45
+            # GB). The pools hold whole tiles of heads instead; the
+            # step pads q, k and v with zero heads and drops them again
+            Hkv = arch.num_kv_heads
+            self._kv_heads = Hkv if Hkv % 8 == 0 or Hkv != arch.num_heads \
+                else -(-Hkv // 8) * 8
+            kinds = dict(num_kv_heads=self._kv_heads,
+                         layer_kinds=arch.layer_kinds)
+            if "sliding" in arch.layer_kinds:
+                # a window pool never runs dry: every slot a whole
+                # window and a step's tokens
+                kinds.update(
+                    window=arch.window,
+                    num_window_blocks=max_slots * min(mbps, -(-(
+                        arch.window + self.token_budget)
+                        // self.block_size) + 1) + 1)
+            if "linear" in arch.layer_kinds:
+                if self.token_budget - max_slots < arch.delta_chunk:
+                    raise ValueError(
+                        f"token_budget={self.token_budget} leaves "
+                        f"{self.token_budget - max_slots} tokens beside "
+                        f"{max_slots} decoding slots: a prefill chunk is "
+                        f"cut to multiples of the delta rule's chunk "
+                        f"({arch.delta_chunk}) and could never be fed")
+                # a float32 state a head, and the convolution's last
+                # inputs, a slot a linear layer
+                kinds.update(
+                    linear_state=(arch.linear_heads, arch.linear_key_dim,
+                                  arch.linear_value_dim),
+                    conv_tail=(arch.conv_width - 1, arch.conv_channels))
         with jax.default_device(device):
             self.kv = PagedKVCache(
                 L, H, Dh, num_blocks=num_blocks,
@@ -451,7 +504,9 @@ class ServingEngine:
             device_draft=self._multitick and self.draft_k > 0,
             prefix_cache=self.prefix_cache,
             adapter_cache=self.adapters,
-            reserve_region=self._sparse)
+            reserve_region=self._sparse,
+            prefill_align=(arch.delta_chunk if self._block is not None
+                           and self.kv.linear_layers else 1))
         self.scheduler.replica = self.name
         self.eos_token_id = eos_token_id
         self.clock = clock
@@ -701,21 +756,22 @@ class ServingEngine:
     def _block_step_body(self):
         """The mixed step of a model that brings its own block: embed,
         then the layers UNROLLED — each of its own kind (window or
-        full attention, dense or expert FFN), each updating and reading
-        its own K/V pool in place — then final norm, head and sampling
-        at the slots' sample rows. The engine owns what the block must
-        not know: the paged pools, the two kinds of block table, the
-        query runs, padding. One compile: every shape is the token
-        budget's or the pool's.
+        full attention over its own K/V pool, or a linear layer over its
+        slots' recurrent states; dense or expert FFN), each updating
+        what it keeps in place — then final norm, head and sampling at
+        the slots' sample rows. The engine owns what the block must not
+        know: the paged pools, the kinds of block table, the states,
+        the query runs, padding. One compile: every shape is the token
+        budget's, the slot count's or the pool's.
 
-        step(weights, k0, v0, k1, v1, ..., plan, key)
-        -> (tokens [max_slots], k0, v0, ..., the block's counters
-        (`block.stat_names`, folded over the layers by
-        `block.fold_stats`: the engine does not know what they count),
-        float32 logits of the sample rows [max_slots, V], the key
-        advanced). `plan` is the packed buffer (`plan_layout`: flat
-        tokens, sample index, full and window tables), sliced once,
-        before the layers."""
+        step(weights, k0, v0, k1, v1, ..., s0, c0, s1, c1, ..., plan,
+        key) -> (tokens [max_slots], the pools and states in the same
+        order (`kv._pools()`), the block's counters (`block.stat_names`,
+        folded over the layers by `block.fold_stats`: the engine does
+        not know what they count), float32 logits of the sample rows
+        [max_slots, V], the key advanced). `plan` is the packed buffer
+        (`plan_layout`: flat tokens, sample index, the block tables),
+        sliced once, before the layers."""
         import jax
         import jax.numpy as jnp
 
@@ -724,14 +780,22 @@ class ServingEngine:
         block = self._block
         arch = block.arch
         kinds = arch.layer_kinds
-        L, BS, T = len(kinds), self.block_size, self.token_budget
+        BS, T = self.block_size, self.token_budget
         sc = self.sampling
         max_run = min(T, _BLOCK_MAX_RUN)
         layout = self.plan_layout
+        n_pools = len(self.kv._pools())
+        # where a layer's arrays lie among the step's pools: K, V of the
+        # i-th attention layer; state, tail of the j-th linear layer
+        at = {li: 2 * i for i, li in enumerate(self.kv.attention_layers)}
+        at.update({li: 2 * (len(at) + j) for j, li in
+                   enumerate(self.kv.linear_layers)})
+        linear = bool(self.kv.linear_layers)
+        more_heads = self._kv_heads - arch.num_kv_heads
 
         def step(weights, *rest):
-            pools = list(rest[:2 * L])
-            plan, key = rest[2 * L:]
+            pools = list(rest[:n_pools])
+            plan, key = rest[n_pools:]
             f = layout.unpack(plan)
             token_ids, slot_ids, positions, sample_index = (
                 f["token_ids"], f["slot_ids"], f["positions"],
@@ -740,8 +804,9 @@ class ServingEngine:
             valid = slot_ids >= 0
             pos = jnp.where(valid, positions, 0)
             safe_slot = jnp.where(valid, slot_ids, 0)
-            tables = {"full": f["block_tables"],
-                      "sliding": f["window_tables"]}
+            tables = {"full": f["block_tables"]}
+            if "window_tables" in f:
+                tables["sliding"] = f["window_tables"]
             # padding tokens write into the reserved NULL block
             wb = {k: jnp.where(valid, t[safe_slot, pos // BS], 0)
                   for k, t in tables.items()}
@@ -750,24 +815,32 @@ class ServingEngine:
 
             def attend(q, k, v, li):
                 kind = kinds[li]
-                kp, vp = pools[2 * li], pools[2 * li + 1]
+                kp, vp = pools[at[li]], pools[at[li] + 1]
+                if more_heads:
+                    q, k, v = (jnp.pad(a, ((0, 0), (0, more_heads),
+                                           (0, 0))) for a in (q, k, v))
                 kp = kp.at[wb[kind], wo].set(k.astype(kp.dtype))
                 vp = vp.at[wb[kind], wo].set(v.astype(vp.dtype))
-                pools[2 * li], pools[2 * li + 1] = kp, vp
+                pools[at[li]], pools[at[li] + 1] = kp, vp
                 sliding = kind == "sliding"
                 with jax.named_scope(
                         "attn_window" if sliding else "attn_full"):
-                    return ragged_paged_attention(
+                    o = ragged_paged_attention(
                         q, kp, vp, tables[kind], slot_ids, pos,
                         runs=runs, max_run=max_run,
                         window=arch.window if sliding else None)
+                return o[:, :arch.num_heads] if more_heads else o
 
+            extra = ()
+            if linear:
+                extra = (self._recur(pools, at, slot_ids, pos),)
             h = block.embed(arch, weights,
                             jnp.where(valid, token_ids, 0))
             # one array: one readback
             stats = jnp.zeros((len(block.stat_names),), jnp.int32)
             for li, lw in enumerate(weights["layers"]):
-                h, st = block.layer(arch, li, lw, h, pos, valid, attend)
+                h, st = block.layer(arch, li, lw, h, pos, valid, attend,
+                                    *extra)
                 if st is not None:
                     stats = block.fold_stats(stats, st)
             rows = h[jnp.clip(sample_index, 0, T - 1)]
@@ -776,6 +849,71 @@ class ServingEngine:
             return (tok, *pools, stats, logits.astype(jnp.float32), key)
 
         return step
+
+    def _recur(self, pools, at, slot_ids, pos):
+        """The `recur(x, g, beta, li, conv)` a linear layer of the block
+        step calls (`models.olmo_hybrid.linear_mixer`), over the step's
+        runs WITHOUT the `max_run` cut: one run a slot, so the runs
+        touch distinct slots and are independent.
+
+        (a) every token's last `conv_width` inputs side by side: its
+        own run's earlier tokens, before them the slot's tail (zeros
+        where the run starts at position 0), handed to the model's
+        `conv`; the slot's new tail is the run's last inputs (a run
+        shorter than the tail shifts it); (b) the delta rule over the
+        runs from each slot's state (zero at position 0), the state
+        read and written once a run; (c) slots with no run this step
+        keep state and tail untouched, padding tokens change nothing."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.pallas import gated_delta as gd
+        from ..ops.pallas.paged_attention import paged_runs
+        arch = self._block.arch
+        T, S, W = self.token_budget, self.kv.max_slots, arch.conv_width
+        R = min(S, T)                       # one run a slot
+        runs = paged_runs(slot_ids, pos, None)
+        chunks = gd.delta_chunks(runs, T, S, arch.delta_chunk)
+        _, start, length, rslot, first = runs
+        r, valid, off, fresh = gd.token_runs(runs, T)
+        slot = jnp.clip(rslot[r], 0, S - 1)
+        t = jnp.arange(T, dtype=jnp.int32)
+        ZERO = T + S * (W - 1)              # the row of zeros
+        # a token's input `back` positions earlier: in its own run, or
+        # in the slot's tail (rows oldest first), or before the sequence
+        src = [jnp.where(
+            ~valid | ((off < back) & fresh), ZERO,
+            jnp.where(off >= back, t - back,
+                      T + slot * (W - 1) + (W - 1) + off - back))
+            for back in range(W - 1, 0, -1)]
+        # the last W - 1 inputs of each run, the same three ways
+        i = jnp.arange(W - 1, dtype=jnp.int32)[None, :]
+        o_run = (length[:R, None] - (W - 1)) + i        # offset in the run
+        live = (jnp.arange(R) < runs[0][0])[:, None]
+        rs = jnp.clip(rslot[:R], 0, S - 1)[:, None]
+        new_src = jnp.where(
+            ~live | ((o_run < 0) & (first[:R, None] == 0)), ZERO,
+            jnp.where(o_run >= 0, start[:R, None] + o_run,
+                      T + rs * (W - 1) + (W - 1) + o_run))
+        to_slot = jnp.where(live[:, 0], rs[:, 0], S)     # S: dropped
+
+        def recur(x, g, beta, li, conv):
+            state, tail = pools[at[li]], pools[at[li] + 1]
+            with jax.named_scope("lin_conv"):
+                rows = jnp.concatenate(
+                    [x, tail.reshape(S * (W - 1), -1).astype(x.dtype),
+                     jnp.zeros((1, x.shape[1]), x.dtype)])
+                q, k, v = conv(jnp.stack(
+                    [rows[s] for s in src] + [x], axis=1))
+                pools[at[li] + 1] = tail.at[to_slot].set(
+                    rows[new_src].astype(tail.dtype), mode="drop")
+            with jax.named_scope("gated_delta"):
+                o, pools[at[li]] = gd.gated_delta_ragged(
+                    q, k, v, g, beta, runs, state,
+                    chunk=arch.delta_chunk, chunks=chunks)
+            return o
+
+        return recur
 
     def _step_body(self, cfg):
         import jax
@@ -1798,6 +1936,15 @@ class ServingEngine:
         as they stand now, and the per-token adapter ids. The step reads
         this buffer and never the KV manager's live tables, so what the
         host does to them after this cannot reach a dispatched step."""
+        if self.kv.linear_layers:
+            # a linear layer's runs must touch distinct slots: the
+            # scheduler gives a slot one decode token or one prefill
+            # chunk a step, and the recurrence depends on it
+            fed = [e[0] for e in decode] + [e[0] for e in prefills]
+            if len(set(fed)) != len(fed):
+                raise AssertionError(
+                    f"a plan feeds a slot twice in one step ({fed}): "
+                    "the linear layers take one run a slot")
         buf = self._plan_buffers[self._plan_flip]
         self._plan_flip ^= 1
         sp = pack_step(self.token_budget, self.kv.max_slots, decode,
@@ -2201,14 +2348,18 @@ class ServingEngine:
                 n_blk, self.sparse_table_width)
 
     def _plan_work(self, plan):
-        return (_attention_work(plan, self.block_size)
-                if self._block is None
-                else _attention_work_by_kind(plan, self.kv.window))
+        if self._block is None:
+            return _attention_work(plan, self.block_size)
+        work = _attention_work_by_kind(plan, self.kv.window)
+        if self.kv.linear_layers:
+            work.update(_linear_work(plan, self._block.arch.delta_chunk))
+        return work
 
     def _block_work(self, block_stats):
-        """A block model's flight fields: the expert layers' counters,
-        read back with the tokens; the two kinds of block, from the
-        allocators."""
+        """A block model's flight fields: the block's own counters,
+        read back with the tokens; the kinds of block, from the
+        allocators (no window allocator: the window fields read 0);
+        the slots whose recurrent state is live."""
         kv = self.kv
         released = kv.blocks_released_behind_window
         held, ctx = kv.window_held_tokens()
@@ -2216,10 +2367,13 @@ class ServingEngine:
             zip(self._block.stat_names,
                 (int(v) for v in np.asarray(block_stats))),
             kv_blocks_in_use_full=int(kv.allocator.num_used),
-            kv_blocks_in_use_window=int(kv.window_allocator.num_used),
+            kv_blocks_in_use_window=int(
+                kv.window_allocator.num_used if kv.has_window else 0),
             kv_blocks_released_behind_window=int(
                 released - self._released_seen),
             kv_tokens_held_window=held, kv_tokens_context=ctx)
+        if kv.linear_layers:
+            fields["state_slots_in_use"] = kv.state_slots_in_use
         self._released_seen = released
         return fields
 

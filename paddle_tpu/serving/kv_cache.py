@@ -175,7 +175,8 @@ class PagedKVCache:
                  block_size, max_slots, max_blocks_per_slot,
                  dtype="float32", kv_dtype=None, summaries=False,
                  num_kv_heads=None, layer_kinds=None, window=None,
-                 num_window_blocks=None):
+                 num_window_blocks=None, linear_state=None,
+                 conv_tail=None):
         import jax.numpy as jnp
         self.num_layers = num_layers
         # the heads the pools hold: the KV heads of a grouped-query
@@ -183,21 +184,39 @@ class PagedKVCache:
         num_heads = int(num_kv_heads or num_heads)
         self.num_heads = num_heads
         self.head_dim = head_dim
-        # window layers (`layer_kinds`: "sliding" / "full" a layer): a
-        # pool of its own a layer, one block table a slot for the full
-        # layers and one for the window layers, which lets go of the
-        # blocks behind the window (see `_init_layer_kinds`)
+        # layers of several kinds (`layer_kinds`: "full" / "sliding" /
+        # "linear" a layer): a K/V pool of its own an attention layer,
+        # one block table a slot for the full layers and one for the
+        # window layers, which lets go of the blocks behind the window;
+        # a linear layer holds no blocks but a recurrent state and the
+        # convolution's last inputs a slot (see `_init_layer_kinds`)
         self.layer_kinds = tuple(layer_kinds) if layer_kinds else None
         self.window = int(window) if window else None
+        kinds = self.layer_kinds or ()
+        self.has_window = "sliding" in kinds
+        #: layers that keep K/V blocks, layers that keep a state
+        self.linear_layers = [i for i, k in enumerate(kinds)
+                              if k == "linear"]
+        self.attention_layers = [i for i in range(num_layers)
+                                 if i not in self.linear_layers]
         if self.layer_kinds is not None:
-            if len(self.layer_kinds) != num_layers or not self.window:
+            if len(kinds) != num_layers or \
+                    set(kinds) - {"full", "sliding", "linear"}:
                 raise ValueError(
-                    "layer_kinds names every layer and needs a window")
+                    f"layer kinds {kinds}: one of 'full', 'sliding', "
+                    f"'linear' for each of the {num_layers} layers")
+            if self.has_window and not self.window:
+                raise ValueError("a 'sliding' layer needs a window")
+            if self.linear_layers and not (linear_state and conv_tail):
+                raise ValueError(
+                    "a 'linear' layer needs the shapes of its state "
+                    "(linear_state: heads, key dim, value dim) and of "
+                    "its convolution's tail (conv_tail: rows, channels)")
             if summaries or KV_DTYPES.get(
                     str(kv_dtype or dtype), (0, False))[1]:
                 raise ValueError(
                     "quantized pools and block summaries are not built "
-                    "for a cache with window layers")
+                    "for a cache with layer kinds")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.max_slots = int(max_slots)
@@ -212,8 +231,10 @@ class PagedKVCache:
         self.summaries = bool(summaries)
         self.window_allocator = None
         self.blocks_released_behind_window = 0
+        self.states, self.conv_tails = [], []
         if self.layer_kinds is not None:
-            self._init_layer_kinds(num_window_blocks)
+            self._init_layer_kinds(num_window_blocks, linear_state,
+                                   conv_tail)
         else:
             shape = (num_layers, self.num_blocks, self.block_size,
                      num_heads, head_dim)
@@ -256,39 +277,57 @@ class PagedKVCache:
         self.blocks_imported = 0
 
     # ------------------------------------------------------ window layers
-    def _init_layer_kinds(self, num_window_blocks):
-        """A cache whose layers are of two kinds. Each layer has its own
-        K and V pool `[NB_kind, BS, H, Dh]` (`k_pools[li]`): the step
-        updates and reads a layer's pool in place, no slice of a stacked
-        array. A slot has TWO block tables, both `[max_blocks_per_slot]`
-        wide with column c holding positions `[c * BS, (c + 1) * BS)`:
+    def _init_layer_kinds(self, num_window_blocks, linear_state,
+                          conv_tail):
+        """A cache whose layers are of several kinds. Each ATTENTION
+        layer has its own K and V pool `[NB_kind, BS, H, Dh]`
+        (`k_pools[i]`, i counting `attention_layers`): the step updates
+        and reads a layer's pool in place, no slice of a stacked array.
+        A slot has a block table a kind, `[max_blocks_per_slot]` wide
+        with column c holding positions `[c * BS, (c + 1) * BS)`:
         `block_tables` for the full layers, drawn from `allocator`
-        (`num_blocks` blocks a full layer), and `window_tables` for the
-        window layers, drawn from `window_allocator`
-        (`num_window_blocks` a window layer; all window layers of a
-        slot share the table). A window table gives a block back once
-        every token in it is `window` or more behind the slot's next
-        position (`release_behind_windows`, at the head of the
-        scheduler's next `plan()`): its column reads NULL again and no
-        query reaches it."""
+        (`num_blocks` blocks a full layer), and, only if a layer is
+        sliding, `window_tables` for the window layers, drawn from
+        `window_allocator` (`num_window_blocks` a window layer; all
+        window layers of a slot share the table). A window table gives
+        a block back once every token in it is `window` or more behind
+        the slot's next position (`release_behind_windows`, at the head
+        of the scheduler's next `plan()`): its column reads NULL again
+        and no query reaches it.
+
+        Each LINEAR layer holds no blocks and no table: a float32
+        recurrent state `[max_slots, *linear_state]` (`states[i]`, i
+        counting `linear_layers`) and the last inputs of its short
+        convolution `[max_slots, *conv_tail]`, float32 too
+        (`conv_tails[i]`), whatever the context. The host never writes
+        them: the step starts a run whose first position is 0 from
+        zeros and any other from what the slot holds, so a reused slot
+        and a request preempted back to position 0 see no earlier
+        owner's state. Admission and preemption count full-layer
+        blocks; the state is there for every slot always."""
         import jax.numpy as jnp
-        full = sum(k == "full" for k in self.layer_kinds)
-        if full + sum(k == "sliding" for k in self.layer_kinds) \
-                != self.num_layers:
-            raise ValueError(f"layer kinds {self.layer_kinds}: each is "
-                             "'sliding' or 'full'")
-        if not num_window_blocks:
+        if self.has_window and not num_window_blocks:
             raise ValueError("a cache with window layers is told its "
                              "window pools' size (num_window_blocks)")
-        self.num_window_blocks = int(num_window_blocks)
+        self.num_window_blocks = int(num_window_blocks) \
+            if self.has_window else 0
         dt = kv_jnp_dtype(self.kv_dtype)
         tail = (self.block_size, self.num_heads, self.head_dim)
         nb = {"full": self.num_blocks, "sliding": self.num_window_blocks}
-        self.k_pools = [jnp.zeros((nb[k],) + tail, dt)
-                        for k in self.layer_kinds]
-        self.v_pools = [jnp.zeros((nb[k],) + tail, dt)
-                        for k in self.layer_kinds]
+        attention = [self.layer_kinds[i] for i in self.attention_layers]
+        self.k_pools = [jnp.zeros((nb[k],) + tail, dt) for k in attention]
+        self.v_pools = [jnp.zeros((nb[k],) + tail, dt) for k in attention]
         self.k_pool = self.v_pool = None
+        for _ in self.linear_layers:
+            self.states.append(jnp.zeros(
+                (self.max_slots,) + tuple(linear_state), jnp.float32))
+            # float32 like the state: what one step hands the next is
+            # not rounded, so a token reads the same inputs whether its
+            # predecessors came in this step or in the one before
+            self.conv_tails.append(jnp.zeros(
+                (self.max_slots,) + tuple(conv_tail), jnp.float32))
+        if not self.has_window:
+            return
         self.window_allocator = BlockAllocator(self.num_window_blocks)
         self.window_tables = np.zeros(
             (self.max_slots, self.max_blocks_per_slot), np.int32)
@@ -320,14 +359,14 @@ class PagedKVCache:
         (nothing to do in a cache without window layers). The scheduler
         calls it at the head of `plan()`: the tables are a dispatched
         step's inputs and change only between steps."""
-        if self.layer_kinds is not None:
+        if self.has_window:
             for slot in np.flatnonzero(self.slot_lens):
                 self.release_behind_window(int(slot))
 
     def tables(self):
         """The block tables the step takes, as `[max_slots, MB]` int32
         arrays: one, or (full, window) with window layers."""
-        if self.layer_kinds is None:
+        if not self.has_window:
             return [self.block_tables]
         return [self.block_tables, self.window_tables]
 
@@ -336,7 +375,7 @@ class PagedKVCache:
         from the slot's blocks and the FREE ones (no eviction)."""
         fit = (len(self._slot_blocks[slot]) + self.allocator.num_free) \
             * self.block_size
-        if self.layer_kinds is not None:
+        if self.has_window:
             held_to = self._slot_wfirst[slot] + len(self._slot_wblocks[slot])
             fit = min(fit, (held_to + self.window_allocator.num_free)
                       * self.block_size)
@@ -346,7 +385,7 @@ class PagedKVCache:
         """(tokens of contexts held in window-layer blocks, tokens the
         same slots' contexts hold): what the window allocator keeps
         against what a table with no window would."""
-        if self.layer_kinds is None:
+        if not self.has_window:
             return 0, 0
         held = ctx = 0
         for slot in range(self.max_slots):
@@ -383,7 +422,29 @@ class PagedKVCache:
             # its block_size tokens (K only — the scorer never needs V)
             per += (2 * self.num_heads * self.head_dim * 4
                     ) // self.block_size
-        return self.num_layers * per
+        return len(self.attention_layers) * per
+
+    @property
+    def state_bytes(self):
+        """HBM bytes the linear layers' recurrent states and convolution
+        tails occupy, for every slot, whatever the context."""
+        return sum(int(a.size) * a.dtype.itemsize
+                   for a in self.states + self.conv_tails)
+
+    @property
+    def state_slots_in_use(self):
+        """Slots whose recurrent state is live: a slot that holds
+        tokens, in a cache with a linear layer. `release_slot` marks it
+        dead by the slot's length alone: nothing is written."""
+        return int(np.count_nonzero(self.slot_lens)) \
+            if self.linear_layers else 0
+
+    def _refuse_with_state(self, what):
+        if self.linear_layers:
+            raise ValueError(
+                f"{what} is not built for a cache with a linear layer: "
+                "a recurrent state can be neither truncated nor shared "
+                "by blocks")
 
     @property
     def block_bytes(self):
@@ -430,7 +491,7 @@ class PagedKVCache:
                 f"{self.block_size} caps it at {self.max_slot_tokens}")
         need = self.blocks_missing(slot, new_len)
         wneed = 0
-        if self.layer_kinds is not None:
+        if self.has_window:
             # columns from the first the slot's next query can reach
             wrow = self._slot_wblocks[slot]
             if not wrow:
@@ -485,6 +546,7 @@ class PagedKVCache:
         always be re-fed to sample the first output). Writing there
         would corrupt every other reader, so the writer gets its own
         copy first."""
+        self._refuse_with_state("cow_block")
         row = self._slot_blocks[slot]
         src = row[index]
         got = self._alloc(1)
@@ -575,9 +637,12 @@ class PagedKVCache:
 
     def _pools(self):
         if self.layer_kinds is not None:
-            # k0, v0, k1, v1, ...: a pool a layer
+            # k0, v0, k1, v1, ...: a pool an attention layer; then a
+            # state and a convolution tail a linear layer
             return [p for kv in zip(self.k_pools, self.v_pools)
-                    for p in kv]
+                    for p in kv] + \
+                [a for st in zip(self.states, self.conv_tails)
+                 for a in st]
         out = [self.k_pool, self.v_pool]
         if self.quantized:
             out += [self.k_scale, self.v_scale]
@@ -590,7 +655,9 @@ class PagedKVCache:
         jitted executable's output tuple (same fixed order)."""
         arrays = list(arrays)
         if self.layer_kinds is not None:
-            self.k_pools, self.v_pools = arrays[0::2], arrays[1::2]
+            n = 2 * len(self.attention_layers)
+            self.k_pools, self.v_pools = arrays[0:n:2], arrays[1:n:2]
+            self.states, self.conv_tails = arrays[n::2], arrays[n + 1::2]
             return
         self.k_pool, self.v_pool = arrays[:2]
         arrays = arrays[2:]
@@ -613,6 +680,7 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         from .batcher import next_pow2
+        self._refuse_with_state("export_blocks (a MigrationTicket)")
         ids = [int(b) for b in block_ids]
         if not ids:
             raise ValueError("export_blocks needs at least one block")
@@ -725,6 +793,7 @@ class PagedKVCache:
         needing; the garbage they DID write into still-owned blocks
         needs no cleanup (the position mask hides it and the next
         accepted tokens overwrite it)."""
+        self._refuse_with_state("truncate_slot")
         keep = self.blocks_for(new_len)
         row = self._slot_blocks[slot]
         if len(row) <= keep:
@@ -742,7 +811,7 @@ class PagedKVCache:
         self._slot_blocks[slot] = []
         self.block_tables[slot, :] = NULL_BLOCK
         self.slot_lens[slot] = 0
-        if self.layer_kinds is not None:
+        if self.has_window:
             if self._slot_wblocks[slot]:
                 self.window_allocator.free(self._slot_wblocks[slot])
             self._slot_wblocks[slot] = []

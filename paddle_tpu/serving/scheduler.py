@@ -106,8 +106,16 @@ class Scheduler:
     def __init__(self, kv_cache, *, max_slots, token_budget,
                  clock=time.monotonic, draft_k=0, draft_fn=None,
                  device_draft=False, prefix_cache=None,
-                 adapter_cache=None, reserve_region=False):
+                 adapter_cache=None, reserve_region=False,
+                 prefill_align=1):
         self.kv = kv_cache
+        # a model with recurrent (linear) layers: a prompt's prefill
+        # chunks end on multiples of this many tokens (all but its
+        # last), so that the chunked recurrence cuts a prompt at the
+        # same positions whatever rides with it in a step, and a
+        # request served alone, in company or again after a preemption
+        # computes the same numbers bit for bit
+        self.prefill_align = int(prefill_align)
         self.max_slots = max_slots
         self.token_budget = token_budget
         self.clock = clock
@@ -338,7 +346,10 @@ class Scheduler:
 
     def _preempt_victim(self, exclude):
         """Evict the decode holding the most blocks (tie: latest
-        arrival). Returns the victim or None."""
+        arrival). Returns the victim or None. The victim re-prefills
+        from position 0, which is also what makes a recurrent state
+        (a cache with linear layers) start from zero again: the step
+        starts a run at position 0 from zeros, nothing is reset here."""
         cands = [r for r in self.slots
                  if r is not None and r.state == "decode"
                  and r not in exclude]
@@ -463,13 +474,17 @@ class Scheduler:
                 break
             tokens = req.runtime_prompt
             remaining = len(tokens) - req.fed
-            chunk = batcher.prefill_chunk(remaining, budget_left)
+            def cut(n):     # a chunk that does not end the prompt
+                return n if n >= remaining else \
+                    n // self.prefill_align * self.prefill_align
+
+            chunk = cut(batcher.prefill_chunk(remaining, budget_left))
             # prefill only uses FREE blocks — shrink to what fits
             while chunk > 0 and not self.kv.ensure_capacity(
                     req.slot, req.fed + chunk):
                 # blocks of BOTH kinds, where the cache has two
                 fit = self.kv.fit_tokens(req.slot) - req.fed
-                chunk = min(chunk - 1, fit) if fit > 0 else 0
+                chunk = cut(min(chunk - 1, fit)) if fit > 0 else 0
             if chunk <= 0:
                 continue
             import numpy as np

@@ -7,8 +7,9 @@ tiling. This tool is the device run: it replays the paged-attention
 family (ragged / verify / decode / sparse short-table; fp32, bf16, int8
 and fp8 pools; the same entries on pools stacked over layers, read in
 place), fused add+LayerNorm and splash attention (forward and
-backward), the hand flash-forward kernel and the grouped-expert matmul
-(fp32 / int8 / int4 weights) against their pure-XLA oracles with
+backward), the hand flash-forward kernel, the grouped-expert matmul
+(fp32 / int8 / int4 weights) and the ragged chunked delta rule (a key
+dim of 96) against their pure-XLA oracles with
 interpret mode OFF. A
 kernel that compiles and is wrong fails here.
 
@@ -312,6 +313,55 @@ def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
     return cells
 
 
+def validate_gated_delta(*, H=6, dk=96, dv=192, slots=8, chunk=64,
+                         lens=(1, 3, 63, 64, 65, 200),
+                         dtypes=("float32", "bfloat16")):
+    """The ragged chunked delta-rule kernel (`gated_delta`) against the
+    token-by-token recurrence: runs of uneven lengths in one call (a
+    decode token, a run one short of a chunk, one of a chunk, one over,
+    one of several chunks), a hole of padding tokens, incoming state on
+    the runs that do not start at position 0, and a key dim of 96: not
+    a multiple of the 128 lanes, padded inside the kernel and not in
+    the stored state."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import gated_delta as gd
+    from paddle_tpu.ops.pallas.paged_attention import paged_runs
+
+    rng = np.random.RandomState(5)
+    T = -(-(sum(lens) + 12) // 8) * 8
+    slot_ids, pos = np.full(T, -1, np.int32), np.zeros(T, np.int32)
+    i = 0
+    for s, n in enumerate(lens):
+        slot_ids[i:i + n] = s + 1
+        pos[i:i + n] = (0 if s % 2 == 0 else 5 + s) + np.arange(n)
+        i += n + (5 if s == 1 else 0)
+    runs = paged_runs(jnp.asarray(slot_ids), jnp.asarray(pos), None)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa
+    g = jnp.asarray(-np.exp(rng.uniform(-5, 0, (T, H))), jnp.float32)
+    beta = jnp.asarray(2 / (1 + np.exp(-rng.randn(T, H))), jnp.float32)
+    state = jnp.asarray(rng.randn(slots, H, dk, dv), jnp.float32)
+    cells = []
+    for dtype in dtypes:
+        q, k, v = (jnp.asarray(a, dtype) for a in (
+            unit(rng.randn(T, H, dk)) / np.sqrt(dk),
+            unit(rng.randn(T, H, dk)), rng.randn(T, H, dv)))
+        want = _exact(jax.jit(gd.gated_delta_scan))(
+            q, k, v, g, beta, runs, state)
+        got = jax.jit(lambda *a: gd.gated_delta_ragged(
+            *a, chunk=chunk))(q, k, v, g, beta, runs, state)
+        tol = 2e-2 if dtype == "bfloat16" else 2e-4
+        shape = f"{dtype} H={H} dk={dk} dv={dv} chunk={chunk} " \
+            f"runs={list(lens)}"
+        cells.append(_cell(f"gated_delta o {shape}", got[0], want[0],
+                           tol, tol))
+        cells.append(_cell(f"gated_delta state {shape}", got[1], want[1],
+                           2e-4, 2e-4))
+    return cells
+
+
 def validate_add_ln(*, rows=512, d=256, dtype="bfloat16"):
     """Fused residual-add + LayerNorm against its jnp form: the forward
     pair (normalized, new residual) and the input/scale/shift grads of
@@ -458,7 +508,10 @@ def run_matrix(rehearse=False):
                  dtypes=("bfloat16", "int8")) if rehearse else {}
     return (validate_paged() + validate_paged_stacked(**small)
             + validate_paged_gqa_window()
-            + validate_ragged_expert_matmul() + validate_add_ln()
+            + validate_ragged_expert_matmul()
+            + validate_gated_delta(**(dict(H=2, lens=(1, 3, 65))
+                                      if rehearse else {}))
+            + validate_add_ln()
             + validate_splash() + validate_flash()
             + validate_grouped_matmul())
 
