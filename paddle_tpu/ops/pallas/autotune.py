@@ -520,9 +520,10 @@ def splash_candidates(seq_len):
 
 
 #: KV blocks per compute step of the run-major paged kernel: how many
-#: pool blocks one double-buffered fetch brings and one MXU product
-#: attends. Absent a cached winner the kernel picks from its shapes
-#: (`paged_attention.run_tiles`: 8 at H = 16, BS = 16 — 128 keys).
+#: pool blocks one double-buffered fetch brings and one MXU product a
+#: plane of heads attends. Absent a cached winner the kernel picks from
+#: its shapes (`paged_attention.run_tiles`: 16 at BS = 16 — 256 keys —
+#: for planes of one or two heads, whatever H).
 PAGED_KV_BLOCKS = (4, 8, 16)
 
 

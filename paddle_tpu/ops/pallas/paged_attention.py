@@ -21,11 +21,16 @@ kernel never materializes that copy, and walks each run's slot ONCE:
   group is attended: the work follows the contexts, nothing is sized
   by `max_blocks`;
 * every q tile of the run attends each fetched group while it sits in
-  VMEM. Rows are (token, head) pairs and columns (key, head) pairs, so
-  one `[rows, Dh] x [Dh, cols]` MXU product in the pools' precision
-  (fp32 accumulation) gives every head's logits with NO relayout of
-  the `[BS, H, Dh]` tiles; one int32 compare applies the
-  head-diagonal and the causal mask together;
+  VMEM, a PLANE of KV heads at a time. The fetched rows are (key,
+  head) pairs, as the `[BS, H, Dh]` pool tiles lie; a plane's rows are
+  read out of them strided, with no relayout pass (`plane_heads`: one
+  head of a 32-bit pool, two adjacent heads of a 16-bit pool through
+  the buffer's uint32 view), and meet the (token, query head) rows of
+  the plane's heads in one `[rows, Dh] x [Dh, cols]` MXU product in the
+  pools' precision (fp32 accumulation). At most half of a product is
+  masked (the other head of a pair), where one product for all H heads
+  masked `(H - 1) / H`; one int32 compare a tile applies the
+  head-diagonal within the plane and the causal mask together;
 * **online softmax** (running max / denominator / weighted accumulator
   in fp32 VMEM scratch) per run; **context-length masking** hides the
   unwritten tail of the newest block, blocks past the run's last
@@ -36,7 +41,9 @@ per-pool-entry-per-head — see `serving.kv_cache.PagedKVCache`), the
 K/V tiles arrive int8 / fp8 and each block's `BS * H` scales arrive
 as one lane row beside them: K's scales multiply the logits' (key,
 head) columns, V's the probabilities' — the tile's dequantisation,
-applied where that axis is the lane axis.
+applied where that axis is the lane axis. The scales lie beside ALL the
+(key, head) columns, so a quantized pool is one plane of H heads: the
+one product for every head, as before planes.
 
 Stacked pools: a layer scan carries `[L, NB, BS, H, Dh]` pools and
 passes them whole with `layer=li`; `layer_blocks` views them flat and
@@ -154,46 +161,129 @@ def blocks_walked(runs, block_size):
     return sum((pos + n - 1) // block_size + 1 for pos, n in runs)
 
 
-def run_tiles(H, BS, MB, Gq=1):
-    """(G, TQ) from the shapes alone: G KV blocks per compute step so
-    that a step's `G * BS * H` (key, head) rows fill ~2048 MXU columns
-    (128 keys at H = 16), TQ query tokens per q tile so that its
-    `TQ * H` (token, head) rows fill ~128 MXU rows. With `Gq` query
-    heads to each of the H KV heads a tile has `TQ * H * Gq` rows, and
-    TQ is halved until they are at most 512."""
-    G = max(1, min(2048 // (BS * H), 8, MB))
-    TQ = max(1, min(128 // H, 16))
-    while TQ > 1 and TQ * H * Gq > 512:
-        TQ //= 2
+def plane_heads(H, pool_dtype, quantized=False):
+    """KV heads one product attends, P: the fetched buffer's rows are
+    (key, head) pairs, and a PLANE of P adjacent heads is read out of
+    it with no relayout pass — 32-bit pools a head at a time (a
+    sublane-strided read, stride H); 16-bit pools two adjacent heads at
+    a time (Mosaic has no 16-bit strided read: the buffer viewed as
+    uint32 words pairs rows (key, 2i) and (key, 2i + 1), a strided read
+    of the words, stride H / 2, bitcast back, is the plane's (key,
+    head-in-pair) rows). Everything else — an odd head count in 16
+    bits, 8-bit pools, quantized pools, whose scales ride the lane axis
+    beside ALL the (key, head) columns — takes the buffer as it lies:
+    one plane of H heads."""
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    if quantized:
+        return H
+    if itemsize == 4:
+        return 1
+    if itemsize == 2 and H % 2 == 0:
+        return 2
+    return H
+
+
+def run_tiles(H, BS, MB, Gq=1, P=None):
+    """(G, TQ) from the shapes alone, for planes of P KV heads
+    (`plane_heads`; None = all H in one plane). A product's columns are
+    the plane's (key, head) pairs of one fetched group, `G * BS * P`,
+    and its rows the (token, query head) pairs of one q tile that meet
+    them, `TQ * P * Gq`:
+
+    * G KV blocks a fetch: at most 16 (256 keys at BS = 16: 2-4 MB a
+      fetch at 16-32 heads; 8 where all H heads are one plane, as
+      before planes) and a column tile at most 2048 wide. What a
+      group costs beside its keys — every plane's state loaded and
+      stored, its products' latencies, the fetch's descriptors — does
+      not shrink with the plane, so a plane of two heads wants more
+      keys a group than a plane of all H did (8 blocks, or 2048 // (BS
+      * H)): measured on the chip, PERF.md section 6, PR 32. More would
+      still be faster; the last group's overhang, which the mask pays
+      for, grows with it;
+    * TQ query tokens a tile, so that the rows fill the MXU (up to 256)
+      and the float32 logit tile stays under 1 MB (`rows * columns <=
+      2**18`), at most 128 tokens, a multiple of 8 where it can be (a
+      tile's state rows then start on a sublane tile).
+
+    The masked share of a product is `1 - 1/P`: nothing at P = 1, half
+    at P = 2, `(H - 1) / H` where the whole buffer is one plane."""
+    P = H if P is None else P
+    RT = P * Gq                           # rows a token in a plane
+    G = max(1, min(2048 // (BS * P), 16 if P < H else 8, MB))
+    TQ = min(256, 2 ** 18 // (G * BS * P)) // RT
+    TQ = max(1, min(TQ, 128))
+    if TQ >= 8:
+        TQ -= TQ % 8
+    else:
+        TQ = 1 << (TQ.bit_length() - 1)
     return G, TQ
+
+
+def tile_tokens(TQ):
+    """The q tile heights of a kernel whose tallest is TQ tokens, in
+    ascending order: a run takes the first that holds it (`run_tile`),
+    its tallest else. 1 for a decode token; 8, 32 and 64 for the short
+    runs between (a verify group, the chunks a scheduler cuts a prompt
+    into), which a tile of 128 tokens would attend at up to 16 times
+    their rows (on the chip, PR 32: 64-token chunks in 128-token tiles
+    held the GPT cells' useful share of the logits at 20%); TQ. A row's
+    arithmetic is the same in each: the height only says which rows
+    share a product."""
+    return tuple(t for t in (1, 8, 32, 64) if t < TQ) + (TQ,)
+
+
+def run_tile(n, TQ):
+    """Tokens a q tile of a run of n tokens."""
+    return next((t for t in tile_tokens(TQ) if n <= t), TQ)
+
+
+def _plane_batch(NPL, R, CP):
+    """Planes one straight-line body attends: a plane's product, its
+    softmax and its second product wait for one another, so U planes'
+    loads, products and stores are laid out together for the scheduler
+    to overlap — four at the least, and for a q tile of few rows (a
+    decode token's `P * Gq`) as many as keep the logit tiles within ~32
+    vregs. U divides NPL."""
+    cap = max(4, 2 ** 15 // (-(-R // 8) * 8 * CP))
+    return max(u for u in range(1, NPL + 1) if NPL % u == 0 and u <= cap)
 
 
 def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 bt_ref, q_ref, dmat_ref, rowtok_ref, k_hbm, v_hbm, *rest,
-                BS, H, G, TQ, quantized, mxu_dtype, Gq=1, window=None):
+                BS, H, P, G, TQ, quantized, mxu_dtype, Gq=1, window=None):
     """The whole step in one invocation: for every run, walk the run's
     slot once — `cdiv(last_pos // BS + 1, G)` double-buffered fetches
     of G KV blocks — and let every q tile of the run attend each
-    fetched group while it sits in VMEM.
+    fetched group while it sits in VMEM, a PLANE of P KV heads at a
+    time (`plane_heads`).
 
-    Rows are (token, head) pairs and columns (key, head) pairs, so one
-    `[R, Dh] x [Dh, C]` MXU product gives the logits of every head at
-    once with no relayout of the `[BS, H, Dh]` pool tiles; `dmat`
-    (column key index where the heads agree, a huge value elsewhere)
-    folds the head-diagonal and the causal mask into one compare.
-    With `Gq` query heads to a KV head the rows are (token, query
-    head), `H * Gq` a token, and the `Gq` heads of a group share the
-    fetched tile's columns of their KV head. With a `window`, a query
-    at p attends keys `p - window < j <= p`: a run's walk starts at the
-    first block its first query reaches, and a second compare masks
-    inside it.
+    The fetched buffer's rows are (key, head) pairs, as the pool's
+    `[BS, H, Dh]` tiles lie; plane p's `G * BS * P` rows (key, head in
+    plane) are read out of it strided, with no relayout pass. The rows
+    that meet them are (token, query head of the plane's heads),
+    `RT = P * Gq` a token, which the wrapper lays plane-major: q, the
+    output and the softmax state are `[H / P, rows, ...]`. One `[R, Dh]
+    x [Dh, G * BS * P]` MXU product a plane gives its heads' logits;
+    `dmat` (column key index where the row's KV head is the column's, a
+    huge value elsewhere) folds the head-diagonal within the plane and
+    the causal mask into one compare, made once a (tile, group) and
+    shared by the planes. With a `window`, a query at p attends keys
+    `p - window < j <= p`: a run's walk starts at the first block its
+    first query reaches, and a second compare masks inside it.
+
+    A row's arithmetic does not depend on its run: the columns of a
+    product and their order are the shapes' (G, BS, P), and the tile
+    height (`tile_tokens`) and the planes laid out together
+    (`_plane_batch`) only choose which independent rows and planes
+    share an instruction stream.
 
     Refs: scalar prefetch (run count, start, length, slot, first
-    position; block tables [S, MB]); q [T*H + pad, Dh] fp32, pre-scaled;
-    dmat [TQ*H, C] int32; rowtok [TQ*H, 1] int32 (row -> token of its
-    tile); pools in HBM as [NB, BS*H, Dh] (+ scales [NB, 1, BS*H]);
-    out [T*H + pad, Dh]; scratch: two KV buffers, DMA semaphores and
-    the runs' online-softmax state."""
+    position; block tables [S, MB]); q [H/P, T*RT + pad, Dh] fp32,
+    pre-scaled; dmat [TQ*RT, G*BS*P] int32; rowtok [TQ*RT, 1] int32
+    (row -> token of its tile); pools in HBM as [NB, BS*H, Dh] (+
+    scales [NB, 1, BS*H]); out [H/P, T*RT + pad, Dh] fp32; scratch: two
+    KV buffers, DMA semaphores and the runs' online-softmax state
+    [H/P, state rows, ...]."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
          m_ref, l_ref, acc_ref) = rest
@@ -201,8 +291,11 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
         ks_hbm = vs_hbm = ksbuf = vsbuf = None
     BH = BS * H
-    HQ = H * Gq                           # rows a token
-    C = G * BH
+    NPL = H // P                          # planes
+    RT = P * Gq                           # rows a token in a plane
+    GK = G * BS                           # keys a group
+    CP = GK * P                           # columns of a plane's product
+    Dh = acc_ref.shape[-1]
     n_runs = nruns_ref[0]
     last_run = rstart_ref.shape[0] - 1
 
@@ -227,24 +320,31 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
 
     def fetch(buf, slot, g, nblk, wait, lo=None):
         """Start (or wait for) the copies of group g of `slot`: only
-        the blocks the run needs (from block `lo` on, under a window);
-        the rest of the buffer keeps an older group's (finite)
+        the blocks the run needs (from block `lo` on, under a window),
+        a loop over them; the rest of the buffer keeps an older group's
         contents, which the mask hides."""
-        for j in range(G):
-            col = g * G + j
-            need = col < nblk if lo is None \
-                else (col < nblk) & (col >= lo)
+        def one(j, c):
+            for cp in copies(buf, slot, g * G + j, j):
+                cp.wait() if wait else cp.start()
+            return c
+        first = 0 if lo is None else jnp.maximum(lo - g * G, 0)
+        jax.lax.fori_loop(first, jnp.minimum(nblk - g * G, G), one, 0)
 
-            @pl.when(need)
-            def _(col=col, j=j):
-                for c in copies(buf, slot, col, j):
-                    c.wait() if wait else c.start()
+    def plane(ref, buf, p):
+        """Plane p's [CP, Dh] rows (key, head in plane) of a buffer."""
+        if P == H:
+            return ref[buf]
+        if ref.dtype.itemsize == 4:
+            return ref[buf, pl.ds(p, GK, stride=H)]
+        words = ref.bitcast(jnp.uint32)[buf, pl.ds(p, GK, stride=H // 2)]
+        return pltpu.bitcast(words, ref.dtype)
 
-    # stale buffer contents are masked, never trusted: make them finite
-    kbuf[...] = jnp.zeros_like(kbuf)
+    # stale buffer contents are masked, never trusted. A stale key only
+    # reaches a logit the mask replaces; a stale value would meet a
+    # probability of 0 in the product, and 0 x NaN is NaN: V (and its
+    # scales) start finite
     vbuf[...] = jnp.zeros_like(vbuf)
     if quantized:
-        ksbuf[...] = jnp.zeros_like(ksbuf)
         vsbuf[...] = jnp.zeros_like(vsbuf)
     # padding rows are never attended: they leave as zeros
     o_ref[...] = jnp.zeros_like(o_ref)
@@ -267,77 +367,101 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         fetch(0, rslot_ref[0], g0, run_blocks(0), wait=False, lo=lo)
 
     def attend(tq, buf, g, first, last, start, n, pos0):
-        """Every q tile (tq tokens, R = tq * HQ rows) of the run
-        against the group in buffer `buf`."""
-        R = tq * HQ
-        base = g * (G * BS)               # first key position of group
+        """Every q tile (tq tokens, R = tq * RT rows a plane) of the
+        run against the group in buffer `buf`, plane by plane."""
+        R = tq * RT
+        U = _plane_batch(NPL, R, CP)
+        short = R <= 64                   # a few vregs a plane: unroll
+        base = g * GK                     # first key position of group
 
         def tile(j, carry):
             off = j * tq
-            rs = off * HQ                 # state rows of this tile
-            rq = (start + off) * HQ       # its rows in q / out
+            rs = off * RT                 # state rows of this tile
+            rq = (start + off) * RT       # its rows in q / out
             # causal skip: the group lies past the tile's last query;
             # window skip: it lies behind the window of its first
             live = base <= pos0 + off + tq - 1
             if window is not None:
-                live &= base + G * BS - 1 > pos0 + off - window
+                live &= base + GK - 1 > pos0 + off - window
 
             @pl.when(first)
             def _init():
-                m_ref[pl.ds(rs, R)] = jnp.full((R, 1), MASK_VALUE,
-                                               jnp.float32)
-                l_ref[pl.ds(rs, R)] = jnp.zeros((R, 1), jnp.float32)
-                acc_ref[pl.ds(rs, R)] = jnp.zeros(
-                    (R, acc_ref.shape[1]), jnp.float32)
+                def one(p, c):
+                    m_ref[p, pl.ds(rs, R)] = jnp.full(
+                        (R, 1), MASK_VALUE, jnp.float32)
+                    l_ref[p, pl.ds(rs, R)] = jnp.zeros((R, 1),
+                                                       jnp.float32)
+                    acc_ref[p, pl.ds(rs, R)] = jnp.zeros((R, Dh),
+                                                         jnp.float32)
+                    return c
+                jax.lax.fori_loop(0, NPL, one, 0, unroll=short)
 
             @pl.when(live)
             def _accumulate():
-                q = q_ref[pl.ds(rq, R)].astype(mxu_dtype)     # [R, Dh]
-                k = kbuf[buf]                                 # [C, Dh]
-                v = vbuf[buf]
-                if quantized:
-                    k = k.astype(jnp.float32)
-                    v = v.astype(jnp.float32)
-                s = jax.lax.dot_general(
-                    q, k.astype(mxu_dtype), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # [R, C]
-                if quantized:
-                    s = s * ksbuf[buf]                        # [1, C]
                 # key index (heads agreeing) <= query position - base
                 tok = rowtok_ref[0:R] + off                   # [R, 1]
                 thr = jnp.where(tok < n, tok + (pos0 - base), -1)
-                keep = dmat_ref[0:R] <= thr
+                keep = dmat_ref[0:R] <= thr                   # [R, CP]
                 if window is not None:
                     keep &= dmat_ref[0:R] > thr - window
-                s = jnp.where(keep, s, MASK_VALUE)
-                m_prev = m_ref[pl.ds(rs, R)]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                # rows past the run's end in its last tile keep no key
-                # and would count the mask as probability 1: zero them
-                p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-                m_ref[pl.ds(rs, R)] = m_new
-                l_ref[pl.ds(rs, R)] = (
-                    l_ref[pl.ds(rs, R)] * alpha
-                    + jnp.sum(p, axis=-1, keepdims=True))
-                if quantized:
-                    p = p * vsbuf[buf]
-                acc_ref[pl.ds(rs, R)] = (
-                    acc_ref[pl.ds(rs, R)] * alpha
-                    + jax.lax.dot_general(
-                        p.astype(mxu_dtype), v.astype(mxu_dtype),
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+
+                def planes(i, c):
+                    """U planes from plane i * U on: every load of the
+                    state before any store, so that nothing orders the
+                    planes' products and softmaxes among themselves."""
+                    p0 = i * U
+                    st = (pl.ds(p0, U), pl.ds(rs, R))
+                    qs = q_ref[pl.ds(p0, U), pl.ds(rq, R)]    # [U, R, Dh]
+                    ms, ls, accs = m_ref[st], l_ref[st], acc_ref[st]
+                    for u in range(U):
+                        k = plane(kbuf, buf, p0 + u)          # [CP, Dh]
+                        v = plane(vbuf, buf, p0 + u)
+                        if quantized:
+                            k = k.astype(jnp.float32)
+                            v = v.astype(jnp.float32)
+                        s = jax.lax.dot_general(
+                            qs[u].astype(mxu_dtype), k.astype(mxu_dtype),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        if quantized:
+                            s = s * ksbuf[buf]                # [1, CP]
+                        s = jnp.where(keep, s, MASK_VALUE)    # [R, CP]
+                        m_new = jnp.maximum(
+                            ms[u], jnp.max(s, axis=-1, keepdims=True))
+                        alpha = jnp.exp(ms[u] - m_new)
+                        # rows past the run's end in its last tile keep
+                        # no key and would count the mask as
+                        # probability 1: zero them
+                        pr = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+                        l_new = ls[u] * alpha + jnp.sum(
+                            pr, axis=-1, keepdims=True)
+                        if quantized:
+                            pr = pr * vsbuf[buf]
+                        acc_new = accs[u] * alpha + jax.lax.dot_general(
+                            pr.astype(mxu_dtype), v.astype(mxu_dtype),
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        m_ref[p0 + u, pl.ds(rs, R)] = m_new
+                        l_ref[p0 + u, pl.ds(rs, R)] = l_new
+                        acc_ref[p0 + u, pl.ds(rs, R)] = acc_new
+                    return c
+
+                if U == NPL:
+                    planes(0, 0)
+                else:
+                    jax.lax.fori_loop(0, NPL // U, planes, 0)
 
             @pl.when(last)
             def _finalize():
                 # ascending runs: rows this tile writes past the run's
                 # end (zeros, l == 0) are the next runs' rows, rewritten
                 # when their turn comes
-                l = jnp.maximum(l_ref[pl.ds(rs, R)], 1e-30)
-                o_ref[pl.ds(rq, R)] = (
-                    acc_ref[pl.ds(rs, R)] / l).astype(o_ref.dtype)
+                def one(p, c):
+                    l = jnp.maximum(l_ref[p, pl.ds(rs, R)], 1e-30)
+                    o_ref[p, pl.ds(rq, R)] = (
+                        acc_ref[p, pl.ds(rs, R)] / l).astype(o_ref.dtype)
+                    return c
+                jax.lax.fori_loop(0, NPL, one, 0, unroll=short)
             return carry
 
         jax.lax.fori_loop(0, (n + tq - 1) // tq, tile, 0)
@@ -367,14 +491,16 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 fetch(1 - buf, rslot_ref[nxt], g0_n, run_blocks(nxt),
                       wait=False, lo=lo_n)
 
-            @pl.when(n == 1)
-            def _single():
-                attend(1, buf, g, g == g0, last, start, n, pos0)
+            # one body a tile height: the run's is the first that holds it
+            heights = tile_tokens(TQ)
+            for lo_n, tq in zip((0,) + heights, heights):
+                fits = n > lo_n
+                if tq != TQ:
+                    fits &= n <= tq
 
-            if TQ > 1:
-                @pl.when(n > 1)
-                def _multi():
-                    attend(TQ, buf, g, g == g0, last, start, n, pos0)
+                @pl.when(fits)
+                def _(tq=tq):
+                    attend(tq, buf, g, g == g0, last, start, n, pos0)
             return it + 1
 
         return jax.lax.fori_loop(g0, ngroups, group_body, it)
@@ -382,19 +508,20 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     jax.lax.fori_loop(0, n_runs, run_body, 0)
 
 
-def _mask_tables(H, BS, G, TQ, Gq=1):
-    """dmat [TQ*H*Gq, C]: the column's key index within its group where
-    the row's query head belongs to the column's KV head, a value no
-    threshold reaches elsewhere; rowtok [TQ*H*Gq, 1]: the row's token
-    within its q tile."""
+def _mask_tables(P, BS, G, TQ, Gq=1):
+    """For planes of P KV heads. dmat [TQ*P*Gq, G*BS*P]: the column's
+    key index within its group where the row's query head belongs to
+    the column's KV head, a value no threshold reaches elsewhere (no
+    such column at P = 1); rowtok [TQ*P*Gq, 1]: the row's token within
+    its q tile."""
     import numpy as np
-    HQ = H * Gq
-    rows = np.arange(TQ * HQ)
-    cols = np.arange(G * BS * H)
-    same = ((rows[:, None] % HQ) // Gq) == (cols[None, :] % H)
-    dmat = np.where(same, cols[None, :] // H, np.int32(2 ** 30))
+    RT = P * Gq
+    rows = np.arange(TQ * RT)
+    cols = np.arange(G * BS * P)
+    same = ((rows[:, None] % RT) // Gq) == (cols[None, :] % P)
+    dmat = np.where(same, cols[None, :] // P, np.int32(2 ** 30))
     return (jnp.asarray(dmat, jnp.int32),
-            jnp.asarray(rows[:, None] // HQ, jnp.int32))
+            jnp.asarray(rows[:, None] // RT, jnp.int32))
 
 
 def layer_blocks(block_tables, layer, *pools):
@@ -466,36 +593,36 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     quantized = k_scale is not None
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
-    N, K = groups or (T, 1)               # the tuner's bucket: groups
-    if kernel_name == "paged_sparse":
-        bucket = autotune.shape_bucket(N, K, H, Dh, BS, MB)
-    else:
-        bucket = autotune.shape_bucket(N, K, H, Dh, BS)
-    tuned = tuning if tuning is not None else autotune.kernel_config(
-        kernel_name, bucket, k_pool.dtype, default=None) or {}
-    G, TQ = run_tiles(H, BS, MB, Gq)
-    G = max(1, min(int(tuned.get("kv_blocks", G)), MB))
-    BH, C = BS * H, G * BS * H
+    P, G, TQ = kernel_tiles(
+        T, H, Gq, Dh, BS, MB, k_pool.dtype, quantized=quantized,
+        max_run=max_run, kernel_name=kernel_name, tuning=tuning,
+        groups=groups)
+    NPL, RT = H // P, P * Gq
+    BH, C, CP = BS * H, G * BS * H, G * BS * P
     # MXU operands in the pools' / queries' own precision (bf16 x bf16
     # on a bf16 deployment), fp32 accumulation and softmax state
     kv_float = q.dtype if quantized else k_pool.dtype
     mxu_dtype = (jnp.bfloat16 if q.dtype == kv_float == jnp.bfloat16
                  else jnp.float32)
     out_dtype = q.dtype if q.dtype != jnp.float64 else jnp.float32
-    # a token's H rows start anywhere a multiple of H: a packed dtype
-    # leaves the kernel as such only where that is a whole tile of it
-    o_dtype = (out_dtype
-               if HQ % (32 // jnp.dtype(out_dtype).itemsize) == 0
-               else jnp.float32)
     longest = T if max_run is None else min(int(max_run), T)
     if runs is None:
         runs = paged_runs(slot_ids, positions, max_run)
-    rows = -(-longest // TQ) * TQ * HQ    # state rows: the longest run
-    pad = TQ * HQ                         # a tile may overhang the axis
-    # pre-scaled in fp32; the kernel rounds each tile to the MXU dtype
-    q2 = jnp.pad((q.astype(jnp.float32) * scale).reshape(T * HQ, Dh),
-                 ((0, pad), (0, 0)))
-    dmat, rowtok = _mask_tables(H, BS, G, TQ, Gq)
+    rows = -(-longest // TQ) * TQ * RT    # state rows: the longest run
+    pad = TQ * RT                         # a tile may overhang the axis
+
+    def planes(x):
+        """[T, HQ, Dh] -> [NPL, T * RT, Dh]: a plane's rows (token,
+        query head of its KV heads) as the kernel reads them."""
+        return x.reshape(T, NPL, RT, Dh).transpose(1, 0, 2, 3).reshape(
+            NPL, T * RT, Dh)
+
+    # pre-scaled in fp32; the kernel rounds each tile to the MXU dtype.
+    # fp32 in VMEM, out too: a token's RT rows start on any multiple of
+    # RT, which a packed dtype's tiles do not allow
+    q2 = jnp.pad(planes(q.astype(jnp.float32) * scale),
+                 ((0, 0), (0, pad), (0, 0)))
+    dmat, rowtok = _mask_tables(P, BS, G, TQ, Gq)
     args = [q2, dmat, rowtok,
             k_pool.reshape(NB, BH, Dh), v_pool.reshape(NB, BH, Dh)]
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
@@ -510,14 +637,14 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         scratch += [pltpu.VMEM((2, 1, C), jnp.float32),
                     pltpu.VMEM((2, 1, C), jnp.float32)]
     scratch += [pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, Dh), jnp.float32)]
+                pltpu.VMEM((NPL, rows, 1), jnp.float32),
+                pltpu.VMEM((NPL, rows, 1), jnp.float32),
+                pltpu.VMEM((NPL, rows, Dh), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(), in_specs=in_specs,
         out_specs=vmem, scratch_shapes=scratch)
     kernel = functools.partial(
-        _run_kernel, BS=BS, H=H, G=G, TQ=TQ, quantized=quantized,
+        _run_kernel, BS=BS, H=H, P=P, G=G, TQ=TQ, quantized=quantized,
         mxu_dtype=mxu_dtype, Gq=Gq,
         window=None if window is None else int(window))
     # a full pool read once, every query against a mean slot's share
@@ -528,11 +655,13 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         ctx = min(ctx, int(window))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * HQ + pad, Dh), o_dtype),
+        out_shape=jax.ShapeDtypeStruct((NPL, T * RT + pad, Dh),
+                                       jnp.float32),
         interpret=_INTERPRET, name=kernel_name,
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(T * HQ + pad, rows, TQ * HQ, C,
-                                         Dh, k_pool.dtype.itemsize)),
+            vmem_limit_bytes=_vmem_limit(NPL * (T * RT + pad),
+                                         NPL * rows, TQ * RT, C, CP, Dh,
+                                         k_pool.dtype.itemsize)),
         cost_estimate=pl.CostEstimate(
             flops=4 * T * HQ * Dh * ctx,
             bytes_accessed=(2 * kv_tokens * H * Dh
@@ -540,19 +669,69 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                             + 2 * T * HQ * Dh * q.dtype.itemsize),
             transcendentals=T * HQ * ctx),
     )(*runs, block_tables.astype(jnp.int32), *args)
-    return out[:T * HQ].astype(out_dtype).reshape(T, HQ, Dh)
+    out = out[:, :T * RT].reshape(NPL, T, RT, Dh).transpose(1, 0, 2, 3)
+    return out.reshape(T, HQ, Dh).astype(out_dtype)
 
 
-def _vmem_limit(q_rows, state_rows, tile_rows, C, Dh, kv_itemsize):
+def kernel_tiles(T, H, Gq, Dh, BS, MB, pool_dtype, *, quantized=False,
+                 max_run=None, kernel_name="paged_ragged", tuning=None,
+                 groups=None):
+    """(P, G, TQ) of the compiled kernel for these shapes: the heads a
+    plane (`plane_heads`), the KV blocks a fetch — the tuner's cached
+    `kv_blocks` for the bucket if there is one, else `run_tiles`' — and
+    the tokens a q tile, no taller than the longest run. What the call
+    traces with and what `logits_issued` counts by."""
+    P = plane_heads(H, pool_dtype, quantized)
+    N, K = groups or (T, 1)               # the tuner's bucket: groups
+    if kernel_name == "paged_sparse":
+        bucket = autotune.shape_bucket(N, K, H, Dh, BS, MB)
+    else:
+        bucket = autotune.shape_bucket(N, K, H, Dh, BS)
+    tuned = tuning if tuning is not None else autotune.kernel_config(
+        kernel_name, bucket, jnp.dtype(pool_dtype), default=None) or {}
+    G, TQ = run_tiles(H, BS, MB, Gq, P)
+    G = max(1, min(int(tuned.get("kv_blocks", G)), MB))
+    longest = T if max_run is None else min(int(max_run), T)
+    return P, G, min(TQ, -(-longest // 8) * 8)
+
+
+def logits_issued(runs, tiles, H, Gq, block_size, window=None,
+                  max_run=None):
+    """Logits the kernel computes (and exponentiates) for `runs`,
+    (first position, tokens) pairs, with `tiles` = `kernel_tiles(...)`:
+    products x rows x columns as `_run_kernel` issues them — a run's
+    tiles by `run_tile`, runs cut at `max_run`; a tile meets a fetched group unless the group lies past its last
+    query or (window) behind its first query's window; H / P products
+    of `tq * P * Gq` rows by `G * BS * P` columns each time. The
+    useful ones among them are the (query, key) pairs x H x Gq."""
+    P, G, TQ = tiles
+    GK = G * block_size
+    issued = 0
+    for pos, n in runs:
+        for cut in range(0, n, max_run or n):
+            p0, m = pos + cut, min((max_run or n), n - cut)
+            tq = run_tile(m, TQ)
+            g0 = 0 if window is None \
+                else max(p0 - (window - 1), 0) // block_size // G
+            ngroups = -(-((p0 + m - 1) // block_size + 1) // G)
+            for off in range(0, m, tq):
+                hi = min(ngroups - 1, (p0 + off + tq - 1) // GK)
+                lo = g0 if window is None \
+                    else max(g0, (p0 + off - window + 1) // GK)
+                issued += max(hi - lo + 1, 0) * tq
+    return issued * H * Gq * GK * P
+
+
+def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize):
     """Scoped-VMEM ask of the run kernel: what it keeps resident
-    (queries and output, the mask table, two K and V buffers, the
-    softmax state) plus the logits and probabilities of one tile, with
-    headroom for Mosaic's own temporaries."""
+    (queries and output in fp32, the mask table, two K and V buffers,
+    the softmax state) plus the mask, logits and probabilities of one
+    tile, with headroom for Mosaic's own temporaries."""
     lanes = max(Dh, 128)
-    resident = (2 * q_rows * lanes * 4 + tile_rows * C * 4
+    resident = (2 * q_rows * lanes * 4 + tile_rows * CP * 4
                 + 4 * C * lanes * kv_itemsize
                 + state_rows * (2 * 128 + lanes) * 4)
-    ask = 2 * (resident + 4 * tile_rows * C * 4)
+    ask = 2 * (resident + 5 * tile_rows * CP * 4)
     return int(min(100 * 2 ** 20, max(32 * 2 ** 20, ask)))
 
 

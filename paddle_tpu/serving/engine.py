@@ -49,6 +49,7 @@ once (docs/SERVING.md).
 """
 from __future__ import annotations
 
+import functools
 import time
 
 import numpy as np
@@ -59,7 +60,7 @@ from . import batcher
 from . import metrics as smetrics
 from . import tracing as _tracing
 from .batcher import SamplingConfig, pack_step, select_token
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, kv_jnp_dtype
 from .scheduler import Plan, Scheduler
 
 STEP_FN_NAME = "serving_mixed_step"
@@ -164,8 +165,6 @@ class ServingEngine:
                  sparse_blocks=None, sparse_recent=2,
                  track_summaries=None, name=None,
                  ticks_per_dispatch=1, device=None):
-        import functools
-
         import jax
         import jax.numpy as jnp
         model.eval()
@@ -609,6 +608,7 @@ class ServingEngine:
         self._kernel_buckets = self._note_kernel_buckets()
         self._counts_seen = {}    # counter: its count, last snapshot
         self._released_seen = 0          # window blocks, since a record
+        self._logits_issued = None       # (heads, the kernel's count)
         #: the last step's float32 logits at the sample rows,
         #: [max_slots, V] on the device (a model-provided block only)
         self.sample_logits = None
@@ -2349,11 +2349,54 @@ class ServingEngine:
 
     def _plan_work(self, plan):
         if self._block is None:
-            return _attention_work(plan, self.block_size)
-        work = _attention_work_by_kind(plan, self.kv.window)
-        if self.kv.linear_layers:
-            work.update(_linear_work(plan, self._block.arch.delta_chunk))
+            work = _attention_work(plan, self.block_size)
+            layers = [(None, self.kv.num_layers, work["attn_pairs"])]
+        else:
+            work = _attention_work_by_kind(plan, self.kv.window)
+            kinds = self._block.arch.layer_kinds
+            layers = [(w, kinds.count(kind), work[f"attn_pairs_{name}"])
+                      for kind, name, w in (
+                          ("full", "full", None),
+                          ("sliding", "window", self.kv.window))]
+            if self.kv.linear_layers:
+                work.update(
+                    _linear_work(plan, self._block.arch.delta_chunk))
+        work.update(self._logit_work(plan, layers))
         return work
+
+    def _logit_work(self, plan, layers):
+        """`attn_logits_useful` and `attn_logits_issued` of the step,
+        summed over its attention layers: (query, key) pairs x the
+        model's query heads, and the logits the paged kernel computes
+        for the same runs by its own tiles
+        (`paged_attention.logits_issued`), padded heads among them.
+        `layers`: (window or None, layers of the kind, pairs a layer)."""
+        if self._logits_issued is None:
+            from ..ops.pallas import paged_attention as pa
+            H, block = self.kv.num_heads, self._block
+            # the query heads of the model, and those the kernel is
+            # given: a block's, with the heads its pools were padded by
+            heads = H if block is None else block.arch.num_heads
+            Gq = 1 if block is None else (
+                heads + H - block.arch.num_kv_heads) // H
+            max_run = None if block is None else min(
+                self.token_budget, _BLOCK_MAX_RUN)
+            self._logits_issued = heads, functools.partial(
+                pa.logits_issued, H=H, Gq=Gq, block_size=self.block_size,
+                max_run=max_run, tiles=pa.kernel_tiles(
+                    self.token_budget, H, Gq, self.kv.head_dim,
+                    self.block_size, self.kv.max_blocks_per_slot,
+                    kv_jnp_dtype(self.kv.kv_dtype),
+                    quantized=self.kv.quantized, max_run=max_run))
+        heads, issued_by = self._logits_issued
+        groups = _plan_groups(plan)
+        useful = issued = 0
+        for window, n, pairs in layers:
+            if n:
+                useful += n * pairs * heads
+                issued += n * issued_by(groups, window=window)
+        return dict(attn_logits_useful=int(useful),
+                    attn_logits_issued=int(issued))
 
     def _block_work(self, block_stats):
         """A block model's flight fields: the block's own counters,

@@ -619,6 +619,12 @@ def test_flight_record_counts_pairs_tokens_and_blocks():
         assert r["kv_tokens_held_window"] <= r["kv_tokens_context"]
         assert r["kv_blocks_in_use"] == r["kv_blocks_in_use_window"] \
             + r["kv_blocks_in_use_full"]
+        # what the paged kernel computes over the layers of both kinds
+        kinds = eng._block.arch.layer_kinds
+        assert r["attn_logits_useful"] == eng._block.arch.num_heads * (
+            kinds.count("full") * r["attn_pairs_full"]
+            + kinds.count("sliding") * r["attn_pairs_window"])
+        assert r["attn_logits_issued"] >= r["attn_logits_useful"] > 0
     assert sum(r["kv_blocks_released_behind_window"] for r in recs) == \
         eng.kv.blocks_released_behind_window > 0
     local = sum(r["moe_pairs_local"] for r in recs)

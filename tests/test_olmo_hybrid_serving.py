@@ -504,6 +504,12 @@ def test_flight_record_and_scopes():
             r["kv_blocks_in_use_window"] == 0
         assert r["kv_blocks_in_use"] == r["kv_blocks_in_use_full"]
         assert r["kv_blocks_total"] == 80
+        # the paged kernel's logits over the full layers, the model's
+        # heads kept of what its tiles (the padded heads too) compute
+        full = eng._block.arch.layer_kinds.count("full")
+        assert r["attn_logits_useful"] == \
+            full * r["attn_pairs_full"] * eng._block.arch.num_heads
+        assert r["attn_logits_issued"] >= r["attn_logits_useful"] > 0
     # the first step: one chunk of 16 from position 0
     assert (recs[0]["lin_tokens"], recs[0]["lin_runs"],
             recs[0]["lin_chunks"]) == (16, 1, 2)

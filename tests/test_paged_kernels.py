@@ -446,6 +446,215 @@ class TestStackedPools(_RunShapes):
         assert call(kp, layer=2) == call(kp[2])
 
 
+# ----------------------------------------------- a plane of heads a product
+
+
+class TestPlanes:
+    """The kernel reads a PLANE of P KV heads out of the fetched (key,
+    head) rows at a time (`pa.plane_heads`: 1 for 32-bit pools, 2 for
+    16-bit pools, all of them where neither read exists): the serve
+    cells' head counts, every run length a step holds, against the
+    gather oracle; and a row's bits whatever its run. Under
+    `_INTERPRET` the CPU takes the same reads the chip does (the
+    strided one, and the uint32 view bitcast back);
+    `tools/tpu_tile_validate.py` holds them against the oracle on the
+    chip at the cells' real shapes."""
+    Dh, BS, MB, S, MAX_RUN = 16, 8, 23, 7, 128
+    #: (slot, first position, tokens): every run length of the issue
+    #: (one past `max_run` is cut into 128 + 1) and one of 20, so that
+    #: every tile height (`pa.tile_tokens`: 1, 8, 32, 64, 128) is taken
+    RUNS = [(0, 37, 1), (1, 20, 2), (2, 3, 7), (3, 10, 64),
+            (4, 40, 128), (5, 5, 129), (6, 150, 20)]
+    T = 352
+
+    def _case(self, H, Gq, dtype, seed=0, L=None):
+        import jax.numpy as jnp
+        rng = np.random.RandomState(seed)
+        NB = self.S * self.MB + 1
+        dt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+        shape = (NB, self.BS, H, self.Dh)
+        if L is not None:
+            shape = (L,) + shape
+        kp, vp = (jnp.asarray(rng.randn(*shape).astype(np.float32))
+                  .astype(dt) for _ in range(2))
+        bt = 1 + rng.permutation(NB - 1).reshape(self.S, self.MB)
+        slots = np.full(self.T, -1, np.int32)
+        pos = np.zeros(self.T, np.int32)
+        t = 0
+        for slot, first, n in self.RUNS:
+            slots[t:t + n] = slot
+            pos[t:t + n] = np.arange(first, first + n)
+            t += n
+        q = jnp.asarray(rng.randn(self.T, H * Gq, self.Dh).astype(
+            np.float32)).astype(dt)
+        return (q, kp, vp, jnp.asarray(bt, jnp.int32),
+                jnp.asarray(slots), jnp.asarray(pos))
+
+    @staticmethod
+    def _close(got, ref, slots, dtype):
+        import jax.numpy as jnp
+        got, ref = (np.asarray(x.astype(jnp.float32)) for x in (got, ref))
+        valid = np.asarray(slots) >= 0
+        tol = 3e-2 if dtype == "bf16" else 2e-5
+        np.testing.assert_allclose(got[valid], ref[valid], rtol=tol,
+                                   atol=tol)
+        assert not got[~valid].any()
+
+    @pytest.mark.parametrize("window", [None, 48], ids=["full", "window"])
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+    @pytest.mark.parametrize("H,Gq", [(32, 1), (16, 1), (8, 6), (2, 1)])
+    def test_cells_heads_and_run_lengths(self, H, Gq, dtype, window,
+                                         _interpret_paged):
+        import jax
+        args = self._case(H, Gq, dtype)
+        assert pa.plane_heads(H, args[1].dtype) == \
+            (2 if dtype == "bf16" else 1)
+        got = jax.jit(lambda *a: pa.ragged_attend(
+            *a, window=window, max_run=self.MAX_RUN))(*args)
+        ref = fa.ragged_gather_reference(*args, window=window)
+        self._close(got, ref, args[4], dtype)
+
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+    @pytest.mark.parametrize("H,Gq", [(16, 1), (8, 6)])
+    def test_stacked_pools_traced_layer(self, H, Gq, dtype,
+                                        _interpret_paged):
+        import jax
+        import jax.numpy as jnp
+        q, kp, vp, bt, slots, pos = self._case(H, Gq, dtype, seed=1, L=3)
+        f = jax.jit(lambda li: pa.ragged_attend(
+            q, kp, vp, bt, slots, pos, max_run=self.MAX_RUN, layer=li))
+        outs = []
+        for li in range(3):
+            got = f(jnp.int32(li))
+            self._close(got, fa.ragged_gather_reference(
+                q, kp[li], vp[li], bt, slots, pos), slots, dtype)
+            outs.append(np.asarray(got.astype(jnp.float32)))
+        assert not np.allclose(outs[0], outs[1])
+
+    def test_odd_heads_in_16_bits_take_the_buffer_whole(
+            self, _interpret_paged):
+        import jax.numpy as jnp
+        args = self._case(3, 2, "bf16", seed=2)
+        assert pa.plane_heads(3, jnp.bfloat16) == 3
+        assert pa.plane_heads(8, jnp.int8, quantized=True) == 8
+        got = pa.ragged_attend(*args, max_run=self.MAX_RUN)
+        self._close(got, fa.ragged_gather_reference(*args), args[4],
+                    "bf16")
+
+    @pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+    def test_a_rows_bits_do_not_depend_on_its_run(self, dtype, window,
+                                                  _interpret_paged):
+        """One prompt of 100 tokens from position 30 of slot 2, its
+        keys in the pool: fed as one run, as the power-of-two chunks a
+        scheduler cuts (64, 32, 4; each in company of two decode runs),
+        and token by token (100 runs of one: the flat order reversed,
+        so no two tokens join), every (token, head) row leaves with the
+        same bits."""
+        import importlib.util
+        import os
+
+        import jax
+        spec = importlib.util.spec_from_file_location(
+            "tpu_tile_validate", os.path.join(os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__))), "tools",
+                "tpu_tile_validate.py"))
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        H, Gq, N = 4, 2, 100
+        q, kp, vp, bt, _, _ = self._case(H, Gq, dtype, seed=3)
+        attend = jax.jit(lambda q, slots, pos: pa.ragged_attend(
+            q, kp, vp, bt, slots, pos, window=window, max_run=self.MAX_RUN))
+        whole, chunks, singles = tool.one_run_three_ways(
+            attend, q[:N].astype("float32"), 2, 30, q.dtype,
+            company=((0, 17), (5, 60)))
+        assert np.abs(whole).max() > 0.1
+        np.testing.assert_array_equal(whole, chunks)
+        if dtype == "fp32":
+            np.testing.assert_array_equal(whole, singles)
+        else:
+            # XLA:CPU rounds a bf16 product's float32 sums by its row
+            # count (4 rows a token alone, 512 a tile; the kernel before
+            # planes read the same two elements apart): on the chip the
+            # MXU does not, and `tools/tpu_tile_validate.py` holds the
+            # three feeds to the same bits there
+            assert (whole != singles).mean() < 1e-3
+            np.testing.assert_allclose(whole, singles, atol=1e-3, rtol=0)
+
+    @pytest.mark.parametrize("window", [None, 48, 200])
+    @pytest.mark.parametrize("H,Gq,P", [(32, 1, 2), (8, 6, 2), (16, 1, 1),
+                                        (16, 1, 16)])
+    def test_logits_issued_counts_the_kernels_tiles(self, H, Gq, P,
+                                                    window):
+        """`logits_issued` against the kernel's loops written out: a
+        product of `tq * P * Gq` rows by `G * BS * P` columns a plane
+        for every (q tile, fetched group) the causal and window rules
+        let through; the useful pairs never exceed it, and are over a
+        quarter of it at P = 2 on these runs."""
+        BS, max_run = 16, 128
+        G, TQ = pa.run_tiles(H, BS, 64, Gq, P)
+        runs = [(1000, 1), (0, 1), (777, 300), (5, 64), (4095, 2)]
+        want = pairs = 0
+        for pos, n in runs:
+            for cut in range(0, n, max_run):
+                p0, m = pos + cut, min(max_run, n - cut)
+                tq = next((t for t in (1, 8, 32, 64) if m <= t < TQ), TQ)
+                nblk = (p0 + m - 1) // BS + 1
+                lo = 0 if window is None \
+                    else max(p0 - (window - 1), 0) // BS
+                for g in range(lo // G, -(-nblk // G)):
+                    base = g * G * BS
+                    for off in range(0, m, tq):
+                        live = base <= p0 + off + tq - 1
+                        if window is not None:
+                            live &= base + G * BS - 1 > p0 + off - window
+                        want += live * (H // P) * (tq * P * Gq) * (
+                            G * BS * P)
+                pairs += sum(min(p + 1, window or p + 1)
+                             for p in range(p0, p0 + m))
+        got = pa.logits_issued(runs, (P, G, TQ), H, Gq, BS,
+                               window=window, max_run=max_run)
+        assert got == want
+        assert pairs * H * Gq <= got
+        if window is None and P == 2:
+            assert pairs * H * Gq > 0.25 * got
+
+
+def test_useful_logits_reader():
+    """`kernels.paged_ragged_useful_logits_pct` on hand-made records,
+    and on the parent's (neither field): nothing, and no exception."""
+    import json
+    import os
+    import sys
+    import types
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmarks"))
+    from harness.files import load_module
+    name = "kernels.paged_ragged_useful_logits_pct"
+    read = load_module("layer_metrics", name).read
+    logged = []
+
+    def ctx(flight):
+        return types.SimpleNamespace(flight=flight, log=logged.append)
+    assert read(ctx([
+        {"attn_logits_useful": 300, "attn_logits_issued": 1000},
+        {"attn_logits_useful": 100, "attn_logits_issued": 600},
+        {"kv_tokens_read": 7}])) == pytest.approx(25.0)
+    assert "over 2 steps" in logged[-1]
+    for flight in ([], [{"kv_blocks_walked": 4, "attn_pairs": 9}]):
+        assert read(ctx(flight)) is None
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+    serve = [w["name"] for w in manifest["workloads"]
+             if w["name"].startswith("serve_")]
+    assert entry["workloads"][:4] == serve[:4] and dict(
+        entry, workloads=None) == {
+        "name": name, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "serve_tokens_per_s", "workloads": None}
+
+
 # --------------------------------------------------------- engine matrix
 
 

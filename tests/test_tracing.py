@@ -487,8 +487,13 @@ class TestHostPhases:
         assert all(r["kv_tokens_read"] > 0 for r in recs)
         # the same contexts in blocks, each walked once by the kernel
         bs = eng.block_size
+        heads = eng.kv.num_heads * eng.kv.num_layers
         for r, p in zip(recs, plans):
             assert r["kv_blocks_walked"] == r["kv_blocks_needed"] > 0
+            # what the paged kernel computes for the same runs, by its
+            # own tiles: never less than the pairs x heads it keeps
+            assert r["attn_logits_useful"] == r["attn_pairs"] * heads
+            assert r["attn_logits_issued"] >= r["attn_logits_useful"]
             if r.get("ticks", 1) == 1:
                 ends = [pos + np.atleast_1d(tok).size
                         for _, tok, pos in p.decode]

@@ -270,6 +270,143 @@ def validate_paged_gqa_window(*, H=8, Gq=6, Dh=128, BS=16, max_blocks=24,
     return cells
 
 
+def one_run_three_ways(attend, q, slot, first, dtype, T=128,
+                       company=((1, 17), (5, 60))):
+    """The outputs of one prompt's rows fed three ways through `attend(q
+    [T, HQ, Dh], slot_ids [T], positions [T])`, its keys in the pool
+    already: q [N, HQ, Dh] from position `first` of `slot` as ONE run;
+    as the power-of-two CHUNKS a scheduler cuts (64, 32, ...), each in
+    a step of its own beside the `company`'s decode tokens; and token
+    by token (N runs of one: the flat order reversed, so that no two
+    tokens join). -> (whole, chunks, singles), each [N, HQ, Dh]
+    float32: a row's bits must not depend on its run."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    q = np.asarray(q, np.float32)
+    N = len(q)
+
+    def feed(rows, order, company=()):
+        qq = np.full((T,) + q.shape[1:], 0.5, np.float32)
+        sl = np.full(T, -1, np.int32)
+        ps = np.zeros(T, np.int32)
+        for i, (s_, p_) in enumerate(company):
+            sl[i], ps[i] = s_, p_
+        at = len(company) + np.asarray(order)
+        qq[at], sl[at], ps[at] = q[rows], slot, first + np.asarray(rows)
+        out = attend(jnp.asarray(qq, dtype), jnp.asarray(sl),
+                     jnp.asarray(ps))
+        return np.asarray(out.astype(jnp.float32))[at]
+
+    rows = np.arange(N)
+    cuts, a = [], 0
+    while a < N:                        # the largest power of two left
+        cuts.append((a, a + (1 << ((N - a).bit_length() - 1))))
+        a = cuts[-1][1]
+    chunks = np.concatenate([feed(rows[a:b], np.arange(b - a), company)
+                             for a, b in cuts])
+    return feed(rows, rows), chunks, feed(rows, rows[::-1])
+
+
+def validate_paged_planes(*, Dh=128, BS=16, max_run=128, window=4096,
+                          cases=((32, 1, None, None), (8, 6, "window", None),
+                                 (16, 1, None, 3)),
+                          dtypes=("bfloat16", "float32"), singles_tol=0.0):
+    """The run kernel a PLANE of heads a product (`pa.plane_heads`: two
+    heads of a bf16 pool through the uint32 view, one of a float32 pool
+    through the strided read) at the serve cells' shapes, `cases` of
+    (KV heads, query heads a KV head, window or None, stacked layers or
+    None): Olmo's 32/1 with runs of `max_run` and one past it, Trinity's
+    8/6 past its window, GPT's 16/1 on stacked pools with the layer a
+    traced scalar; every run length a step holds in each. Then the same
+    prompt fed as one run, as power-of-two chunks in company and token
+    by token: a row's bits must not depend on its run (`singles_tol`:
+    what a rehearsal allows the runs of one token, because XLA:CPU
+    rounds a product's sums by its row count; the chip allows nothing)."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    ragged_ref = _exact(fa.ragged_gather_reference)
+    cells = []
+
+    def layout(w):
+        """(first position, tokens) a slot, the deepest past the
+        window; the flat slot ids and positions; the tables' width."""
+        deep = (w or 0) + 3 * BS + 5
+        runs = [(deep, 1), (20, 2), (3, 7), (10, 64),
+                (max(deep - max_run - 2, 0), max_run), (5, max_run + 1),
+                (0, 1)]
+        T = -(-sum(n for _, n in runs) // 8) * 8
+        slot = np.full(T, -1, np.int32)
+        pos = np.zeros(T, np.int32)
+        t = 0
+        for s_, (first, n) in enumerate(runs):
+            slot[t:t + n], pos[t:t + n] = s_, first + np.arange(n)
+            t += n
+        return runs, slot, pos, max(f + n for f, n in runs) // BS + 1
+
+    for H, Gq, w, L in cases:
+        w = window if w else None
+        runs, slot, pos, MB = layout(w)
+        T, S = len(slot), len(runs)
+        NB = S * MB + 1
+        bt = (1 + np.arange(S * MB, dtype=np.int32)).reshape(S, MB)
+        table = bt.copy()
+        if w is not None:
+            for s_, (first, _) in enumerate(runs):
+                table[s_, :max(first - w + 1, 0) // BS] = 0
+        slot, pos = jnp.asarray(slot), jnp.asarray(pos)
+        for dtype in dtypes:
+            tol = 5e-2 if dtype == "bfloat16" else 2e-2
+            rng = np.random.RandomState(17)
+            shape = (NB, BS, H, Dh) if L is None else (L, NB, BS, H, Dh)
+            kp, vp = (jnp.asarray(rng.randn(*shape), dtype)
+                      for _ in range(2))
+            q = jnp.asarray(rng.randn(T, H * Gq, Dh), dtype)
+            layer = None if L is None else L - 1
+            got = jax.jit(lambda q, kp, vp, li: pa.ragged_attend(
+                q, kp, vp, jnp.asarray(table), slot, pos, window=w,
+                max_run=max_run, layer=None if L is None else li))(
+                    q, kp, vp, jnp.int32(layer or 0))
+            # the oracle gathers a token's whole table: 32 tokens a call
+            want = jnp.concatenate([
+                ragged_ref(q[i:i + 32], kp, vp, jnp.asarray(bt),
+                           slot[i:i + 32], pos[i:i + 32], window=w,
+                           layer=layer) for i in range(0, T, 32)])
+            want = jnp.where((slot >= 0)[:, None, None], want, 0)
+            cells.append(_cell(
+                f"paged_ragged planes {dtype} Hq={H * Gq} H={H} "
+                f"P={pa.plane_heads(H, kp.dtype)} Dh={Dh} BS={BS} "
+                f"MB={MB} window={w} max_run={max_run} stacked={L}",
+                got, want, tol, tol))
+    # one prompt of N tokens deep in slot 0, fed three ways
+    H, Gq, N, first = 16, 1, 100, 3 * max_run + 5
+    MB = (first + N) // BS + 1
+    NB = 8 * MB + 1
+    bt = (1 + np.arange(8 * MB, dtype=np.int32)).reshape(8, MB)
+    for dtype in dtypes:
+        rng = np.random.RandomState(19)
+        kp, vp = (jnp.asarray(rng.randn(NB, BS, H, Dh), dtype)
+                  for _ in range(2))
+        attend = jax.jit(lambda q, sl, ps: pa.ragged_attend(
+            q, kp, vp, jnp.asarray(bt), sl, ps, max_run=max_run))
+        whole, chunks, singles = one_run_three_ways(
+            attend, rng.randn(N, H * Gq, Dh), 0, first, dtype)
+        for name, other, allowed in (("chunks", chunks, 0.0),
+                                     ("single tokens", singles,
+                                      singles_tol)):
+            err = float(np.abs(whole - other).max())
+            cells.append(Cell(
+                f"paged_ragged one run = {name}, bit for bit {dtype} "
+                f"H={H} Dh={Dh} BS={BS} N={N}",
+                err <= allowed and bool(np.abs(whole).max() > 0.01), err))
+    return cells
+
+
 def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
                                   dtypes=("float32", "bfloat16")):
     """The dropless expert layer's ragged grouped matmul, plain and
@@ -508,6 +645,10 @@ def run_matrix(rehearse=False):
                  dtypes=("bfloat16", "int8")) if rehearse else {}
     return (validate_paged() + validate_paged_stacked(**small)
             + validate_paged_gqa_window()
+            + validate_paged_planes(**(dict(
+                Dh=16, BS=8, max_run=16, window=40, singles_tol=4e-3,
+                cases=((8, 6, "window", None), (4, 1, None, 2)))
+                if rehearse else {}))
             + validate_ragged_expert_matmul()
             + validate_gated_delta(**(dict(H=2, lens=(1, 3, 65))
                                       if rehearse else {}))
