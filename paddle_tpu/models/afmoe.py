@@ -283,17 +283,21 @@ def weight_shapes(arch):
 FLOAT32_LEAVES = ("router", "expert_bias")
 
 
-def init_weights(arch, seed):
-    """The parameter tree, made on the default device in the compute
-    dtype from `seed`: norms are ones, everything else normal with the
-    layout's std (so `expert_bias` is small and non-zero: selection and
-    weight differ). One small jitted generator a distinct shape."""
+def seeded_weights(shapes, layer_groups, seed, compute_dtype,
+                   float32_leaves=()):
+    """A parameter tree made on the default device from `seed`.
+    `shapes`: {"top": {name: (shape, std)}, group: {name: (shape,
+    std)}}; `layer_groups`: the group of each layer. A std of None is a
+    gain of ones; every other leaf is normal with its std, in the
+    compute dtype (`float32_leaves` in float32). One small jitted
+    generator a distinct shape; a leaf's key is folded from the layer
+    and the leaf's name, so a tree does not depend on the order in
+    which it is made."""
     import functools
 
     import jax
     import jax.numpy as jnp
-    cd = jnp.dtype(arch.compute_dtype)
-    shapes = weight_shapes(arch)
+    cd = jnp.dtype(compute_dtype)
 
     @functools.partial(jax.jit, static_argnums=(1, 2, 3))
     def normal(key, shape, std, dtype):
@@ -306,17 +310,26 @@ def init_weights(arch, seed):
     def leaf(name, shape, std, salt):
         if std is None:
             return jnp.ones(shape, cd)
-        dtype = jnp.float32 if name in FLOAT32_LEAVES else cd
+        dtype = jnp.float32 if name in float32_leaves else cd
         key = jax.random.fold_in(jax.random.fold_in(base, salt),
                                  names.index(name))
         return normal(key, shape, float(std), dtype)
 
     w = {n: leaf(n, s, std, 0) for n, (s, std) in shapes["top"].items()}
     w["layers"] = [
-        {n: leaf(n, s, std, li + 1)
-         for n, (s, std) in shapes[spec.ffn].items()}
-        for li, spec in enumerate(arch.layers)]
+        {n: leaf(n, s, std, li + 1) for n, (s, std) in shapes[g].items()}
+        for li, g in enumerate(layer_groups)]
     return w
+
+
+def init_weights(arch, seed):
+    """The parameter tree, made on the default device in the compute
+    dtype from `seed`: norms are ones, everything else normal with the
+    layout's std (so `expert_bias` is small and non-zero: selection and
+    weight differ)."""
+    return seeded_weights(weight_shapes(arch),
+                          [spec.ffn for spec in arch.layers], seed,
+                          arch.compute_dtype, FLOAT32_LEAVES)
 
 
 class AfmoeForGeneration:

@@ -1,7 +1,10 @@
 """The seam `ServingEngine` steps a model that brings its own block
-through (`models/afmoe.py`, `models/olmo_hybrid.py`): the architecture,
-the weight tree (an argument of the jitted step) and the functions of
-`(arch, weights, ...)` that the model's eager forward calls too."""
+through (`models/afmoe.py`, `models/olmo_hybrid.py`,
+`models/sdar_moe.py`): the architecture, the weight tree (an argument
+of the jitted step) and the functions of `(arch, weights, ...)` that
+the model's eager forward calls too; and `BlockDecoding`, what an
+architecture says of itself when it generates by diffusion over blocks
+instead of a token at a time."""
 from __future__ import annotations
 
 import dataclasses
@@ -11,6 +14,8 @@ import dataclasses
 class ServingBlock:
     arch: object            # frozen description: `layers`, `layer_kinds`,
     #                          `num_heads`, `num_kv_heads`, `head_dim`, ...
+    #                          and `block_decoding` (a `BlockDecoding`)
+    #                          where the model decodes by blocks
     weights: dict
     embed: object           # (arch, weights, token_ids) -> h [T, D]
     layer: object           # (arch, li, layer weights, h, positions,
@@ -21,3 +26,61 @@ class ServingBlock:
     stat_names: tuple = ()  # flight-record names of the step's counters
     fold_stats: object = None   # (int32[len(stat_names)], a layer's
     #                              stats) -> int32[len(stat_names)]
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDecoding:
+    """How a model that generates by DIFFUSION OVER BLOCKS decodes; an
+    architecture that carries one (`arch.block_decoding`) is served a
+    block a slot instead of a token a slot.
+
+    Positions are cut into blocks of `block_length` L at multiples of L
+    from position 0, and attention is block-causal (a query sees the
+    keys up to the end of its own block). A block is generated by
+    DENOISE passes: its L positions are fed, decided ones as their
+    token, the others as `mask_token_id`; the row at position i
+    predicts token i (no shift); each masked row gives a candidate and
+    its confidence, the softmax probability of that candidate; `decide`
+    picks the masked positions that take their candidate, for good.
+    When none is masked, one more pass (the COMMIT) writes the block's
+    final K/V, and the next block starts."""
+    block_length: int
+    mask_token_id: int
+    denoising_steps: int = None         # default: one position a pass
+    rule: str = "low_confidence_static"
+    threshold: float = 0.9              # of `low_confidence_dynamic`
+
+    RULES = ("low_confidence_static", "low_confidence_dynamic")
+
+    def __post_init__(self):
+        if self.rule not in self.RULES:
+            raise ValueError(f"unmask rule {self.rule!r}: one of "
+                             f"{self.RULES}")
+        L = self.block_length
+        if L < 2 or L & (L - 1):
+            raise ValueError(f"block_length={L}: a power of two, 2 or "
+                             "more (the kernel's block-causal mask)")
+
+    def transfers(self, n_pass):
+        """Positions denoise pass `n_pass` (from 0) of a block decides
+        at the least: L spread over `denoising_steps`, the remainder on
+        the first passes; one where the schedule has run out (a block
+        that began with fewer masked positions ends sooner)."""
+        steps = self.denoising_steps or self.block_length
+        base, rem = divmod(self.block_length, steps)
+        return max(1, base + (n_pass < rem)) if n_pass < steps else 1
+
+    def decide(self, masked, confidence, n_pass):
+        """The positions (ascending) that denoise pass `n_pass` decides:
+        `masked` the block's masked positions, `confidence[i]` position
+        i's. Static: the `transfers(n_pass)` most confident (a tie goes
+        to the earlier position). Dynamic: every one above `threshold`,
+        and at least that many."""
+        n = min(self.transfers(n_pass), len(masked))
+        if self.rule == "low_confidence_dynamic":
+            high = [i for i in masked
+                    if float(confidence[i]) > self.threshold]
+            if len(high) >= n:
+                return high
+        return sorted(sorted(
+            masked, key=lambda i: (-float(confidence[i]), i))[:n])

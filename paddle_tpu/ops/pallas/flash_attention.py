@@ -520,7 +520,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
                            scale=None, kernel_name="paged_ragged",
                            runs=None, window=None, max_run=None,
-                           layer=None):
+                           layer=None, causal_block=None):
     """Flat-token attention over a block-paged KV cache — the kernel of
     the continuous-batching mixed step (`paddle_tpu.serving.engine`),
     following the Ragged-Paged-Attention shape discipline: ONE fixed
@@ -571,6 +571,17 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     never read. `max_run` cuts the kernel's query runs (see
     `paged_attention.paged_runs`); the math does not depend on it.
 
+    `causal_block` (None = causal; a power of two L): the mask is
+    BLOCK-causal, as a model that decodes by diffusion over blocks is
+    trained: positions are cut into blocks of L at multiples of L, and
+    a query at p attends every key up to the end of its own block,
+    `j <= p | (L - 1)`: causal between blocks, both ways inside one.
+    No key past the last token of the query's run is attended (what
+    lies there in the pool was not written by this sequence), so a run
+    must end on a block boundary or where the sequence ends, whatever
+    `max_run` cuts it into: the serving engine feeds whole blocks and
+    cuts prefill chunks at multiples of L.
+
     `layer` (None = the pools are one layer's, as above): the pools
     and scales are STACKED over layers, `[L, NB, BS, H, Dh]` and
     `[L, NB, BS, H]`, and layer `layer`'s blocks are read where they
@@ -589,20 +600,26 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
         return ragged_attend(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
                              kernel_name=kernel_name, runs=runs,
-                             window=window, max_run=max_run, layer=layer)
+                             window=window, max_run=max_run, layer=layer,
+                             causal_block=causal_block)
     return ragged_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
                                    v_scale, scale=scale, window=window,
-                                   layer=layer)
+                                   layer=layer, causal_block=causal_block,
+                                   max_run=max_run)
 
 
 def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
                             positions, k_scale=None, v_scale=None, *,
-                            scale=None, window=None, layer=None):
+                            scale=None, window=None, layer=None,
+                            causal_block=None, max_run=None):
     """The pure-XLA gather implementation of `ragged_paged_attention`
     — the CPU path, the kernel-parity oracle, and the admission gate
-    the autotuner holds every paged candidate against."""
-    from .paged_attention import layer_blocks
+    the autotuner holds every paged candidate against. `max_run` only
+    matters with a `causal_block`: a query attends no key past its
+    run's last token, and the runs are the kernel's."""
+    from .paged_attention import (block_end, check_causal_block,
+                                  layer_blocks, paged_runs)
     T, H, Dh = q.shape
     block_tables, (k_pool, v_pool, k_scale, v_scale) = layer_blocks(
         block_tables, layer, k_pool, v_pool, k_scale, v_scale)
@@ -616,7 +633,17 @@ def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
         T, S, Hkv, Dh)
     v = _gather_dequant(v_pool, v_scale, bt, q.dtype).reshape(
         T, S, Hkv, Dh)
-    keep = jnp.arange(S)[None, :] <= positions[:, None]   # [T, S]
+    reach = positions
+    causal_block = check_causal_block(causal_block)
+    if causal_block is not None:
+        # the end of the query's block, and no further than its run
+        _, start, length, _, first = paged_runs(slot_ids, positions,
+                                                max_run)
+        run = jnp.clip(jnp.searchsorted(start, jnp.arange(T), "right")
+                       - 1, 0, T - 1)
+        reach = jnp.minimum(block_end(positions, causal_block),
+                            first[run] + length[run] - 1)
+    keep = jnp.arange(S)[None, :] <= reach[:, None]       # [T, S]
     if window is not None:
         keep &= jnp.arange(S)[None, :] > positions[:, None] - window
     if Hkv != H:

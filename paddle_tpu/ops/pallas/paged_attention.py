@@ -161,6 +161,25 @@ def blocks_walked(runs, block_size):
     return sum((pos + n - 1) // block_size + 1 for pos, n in runs)
 
 
+def block_end(p, L):
+    """The last position of the block of L positions that holds `p`
+    (blocks start at multiples of L; L a power of two, so that the
+    kernel needs no vector division): the last key a query at `p`
+    attends under the block-causal mask."""
+    return p | (L - 1)
+
+
+def check_causal_block(L):
+    """`causal_block` as the kernel and its fallback take it: None
+    (causal), or a power of two."""
+    if L is None:
+        return None
+    L = int(L)
+    if L < 1 or L & (L - 1):
+        raise ValueError(f"causal_block={L} must be a power of two")
+    return None if L == 1 else L
+
+
 def plane_heads(H, pool_dtype, quantized=False):
     """KV heads one product attends, P: the fetched buffer's rows are
     (key, head) pairs, and a PLANE of P adjacent heads is read out of
@@ -250,7 +269,8 @@ def _plane_batch(NPL, R, CP):
 
 def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 bt_ref, q_ref, dmat_ref, rowtok_ref, k_hbm, v_hbm, *rest,
-                BS, H, P, G, TQ, quantized, mxu_dtype, Gq=1, window=None):
+                BS, H, P, G, TQ, quantized, mxu_dtype, Gq=1, window=None,
+                causal_block=None):
     """The whole step in one invocation: for every run, walk the run's
     slot once — `cdiv(last_pos // BS + 1, G)` double-buffered fetches
     of G KV blocks — and let every q tile of the run attend each
@@ -269,7 +289,12 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     the causal mask into one compare, made once a (tile, group) and
     shared by the planes. With a `window`, a query at p attends keys
     `p - window < j <= p`: a run's walk starts at the first block its
-    first query reaches, and a second compare masks inside it.
+    first query reaches, and a second compare masks inside it. With a
+    `causal_block` L the mask is block-causal (`block_end`): a query
+    at p attends the keys up to the END of its block of L positions,
+    `j <= p | (L - 1)`, but none past its run's last token (a run ends
+    on a block boundary, or where the sequence does: what lies behind
+    it in the pool is not the sequence's).
 
     A row's arithmetic does not depend on its run: the columns of a
     product and their order are the shapes' (G, BS, P), and the tile
@@ -298,6 +323,13 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     Dh = acc_ref.shape[-1]
     n_runs = nruns_ref[0]
     last_run = rstart_ref.shape[0] - 1
+
+    def reach(p, end):
+        """The last key position a query at `p` attends, in a run
+        whose last token lies at `end`."""
+        if causal_block is None:
+            return p
+        return jnp.minimum(block_end(p, causal_block), end)
 
     def copies(buf, slot, col, j):
         blk = bt_ref[slot, col]
@@ -380,7 +412,7 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
             rq = (start + off) * RT       # its rows in q / out
             # causal skip: the group lies past the tile's last query;
             # window skip: it lies behind the window of its first
-            live = base <= pos0 + off + tq - 1
+            live = base <= reach(pos0 + off + tq - 1, pos0 + n - 1)
             if window is not None:
                 live &= base + GK - 1 > pos0 + off - window
 
@@ -398,9 +430,15 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
 
             @pl.when(live)
             def _accumulate():
-                # key index (heads agreeing) <= query position - base
+                # key index (heads agreeing) <= the last key the query
+                # attends (its own position; its block's end) - base
                 tok = rowtok_ref[0:R] + off                   # [R, 1]
-                thr = jnp.where(tok < n, tok + (pos0 - base), -1)
+                if causal_block is None:
+                    thr = jnp.where(tok < n, tok + (pos0 - base), -1)
+                else:
+                    thr = jnp.where(
+                        tok < n,
+                        reach(tok + pos0, pos0 + n - 1) - base, -1)
                 keep = dmat_ref[0:R] <= thr                   # [R, CP]
                 if window is not None:
                     keep &= dmat_ref[0:R] > thr - window
@@ -548,7 +586,7 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                        positions, k_scale=None, v_scale=None, *,
                        scale=None, kernel_name="paged_ragged",
                        tuning=None, runs=None, groups=None, window=None,
-                       max_run=None, layer=None):
+                       max_run=None, layer=None, causal_block=None):
     """Run-major block-table-native attention — ONE walk per (slot,
     step).
 
@@ -564,6 +602,12 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     never fetched (the cache manager may have released them).
     `max_run` bounds the tokens of one run, and with them the softmax
     state the kernel keeps in VMEM (None = the whole token axis).
+    `causal_block` (None = causal; a power of two L): the mask is
+    block-causal, a query at p attends keys `j <= p | (L - 1)`, the end
+    of its block of L positions, and none past its run's last token:
+    every run (a cut of `max_run` too, so `max_run` a multiple of L
+    and runs from multiples of L) must end on a block boundary or at
+    its sequence's end; the caller that makes the runs sees to it.
     `layer` (None = the pools are one layer's): the pools and scales
     are STACKED, `[L, NB, ...]`, and the kernel reads layer `layer`'s
     blocks in place (`layer_blocks`); it may be a traced scalar, a
@@ -646,7 +690,8 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     kernel = functools.partial(
         _run_kernel, BS=BS, H=H, P=P, G=G, TQ=TQ, quantized=quantized,
         mxu_dtype=mxu_dtype, Gq=Gq,
-        window=None if window is None else int(window))
+        window=None if window is None else int(window),
+        causal_block=check_causal_block(causal_block))
     # a full pool read once, every query against a mean slot's share
     # of it: the work follows the contexts, not the table's width
     kv_tokens = min(NB1, S * MB) * BS
@@ -696,12 +741,14 @@ def kernel_tiles(T, H, Gq, Dh, BS, MB, pool_dtype, *, quantized=False,
 
 
 def logits_issued(runs, tiles, H, Gq, block_size, window=None,
-                  max_run=None):
+                  max_run=None, causal_block=None):
     """Logits the kernel computes (and exponentiates) for `runs`,
     (first position, tokens) pairs, with `tiles` = `kernel_tiles(...)`:
     products x rows x columns as `_run_kernel` issues them — a run's
-    tiles by `run_tile`, runs cut at `max_run`; a tile meets a fetched group unless the group lies past its last
-    query or (window) behind its first query's window; H / P products
+    tiles by `run_tile`, runs cut at `max_run`; a tile meets a fetched
+    group unless the group lies past its last query (past the end of
+    that query's block, with a `causal_block`; never past the run's
+    own end) or (window) behind its first query's window; H / P products
     of `tq * P * Gq` rows by `G * BS * P` columns each time. The
     useful ones among them are the (query, key) pairs x H x Gq."""
     P, G, TQ = tiles
@@ -715,7 +762,10 @@ def logits_issued(runs, tiles, H, Gq, block_size, window=None,
                 else max(p0 - (window - 1), 0) // block_size // G
             ngroups = -(-((p0 + m - 1) // block_size + 1) // G)
             for off in range(0, m, tq):
-                hi = min(ngroups - 1, (p0 + off + tq - 1) // GK)
+                top = p0 + off + tq - 1
+                if causal_block:
+                    top = min(block_end(top, causal_block), p0 + m - 1)
+                hi = min(ngroups - 1, top // GK)
                 lo = g0 if window is None \
                     else max(g0, (p0 + off - window + 1) // GK)
                 issued += max(hi - lo + 1, 0) * tq
@@ -741,7 +791,7 @@ def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize):
 def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
                   kernel_name="paged_ragged", runs=None, window=None,
-                  max_run=None, layer=None):
+                  max_run=None, layer=None, causal_block=None):
     """Flat-token ragged paged attention (chunked prefill + plain
     decode): q [T, H, Dh]. Signature mirrors
     `flash_attention.ragged_paged_attention`. The sparse decode region
@@ -750,7 +800,8 @@ def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
     return _paged_attend_runs(
         q, k_pool, v_pool, block_tables, slot_ids, positions,
         k_scale, v_scale, scale=scale, kernel_name=kernel_name,
-        runs=runs, window=window, max_run=max_run, layer=layer)
+        runs=runs, window=window, max_run=max_run, layer=layer,
+        causal_block=causal_block)
 
 
 def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,

@@ -414,8 +414,8 @@ def global_gather(x, local_count, global_count, group=None,
 
 
 # ---------------------------------------------------------------------
-# sigmoid top-k routing and the share-aware DROPLESS expert layer
-# (models/afmoe.py: a chip of an expert-parallel deployment holds
+# sigmoid and softmax top-k routing and the share-aware DROPLESS expert
+# layer (models/afmoe.py, models/sdar_moe.py: a chip of an expert-parallel deployment holds
 # `experts_held` of the routed experts, routes over all of them, and
 # computes its own experts' part; no capacity slot, no dropped pair)
 # ---------------------------------------------------------------------
@@ -440,6 +440,22 @@ def route_sigmoid_topk(x, w_router, select_bias, top_k, *,
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * route_scale
+
+
+def route_softmax_topk(x, w_router, top_k, *, norm_topk=True):
+    """Softmax router: `p = softmax(x W)` over ALL the experts in
+    float32, the `top_k` largest chosen and weighted by `p`,
+    renormalised over the chosen to sum 1 with `norm_topk`. x [T, D];
+    w_router [D, E]. Returns (idx [T, k] int32, weights [T, k]
+    float32), as `route_sigmoid_topk` does."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w
 
 
 def dropless_expert_ffn(x, idx, weights, valid, w_gate, w_up, w_down,

@@ -21,7 +21,10 @@ discipline — one compiled program serves a churning request mix):
     sample_index [S] int32  — per slot, the index in [0, T) of the
                               token whose hidden state samples that
                               slot's next token; -1 = no sample this
-                              step (mid-prefill)
+                              step (mid-prefill). `[S, L]` for a model
+                              that decodes by blocks of L positions: the
+                              rows of the slot's block, every one a
+                              sample row
 
 Every array has the same shape every step. In the engine they are
 views of ONE flat int32 buffer (`PlanLayout`, `PlanBuffers`) that also
@@ -338,8 +341,8 @@ class PlanLayout:
     """Where each field of a step's plan lies in ONE flat int32 buffer:
     `fields[name] = (offset, shape)`, in the order
 
-        token_ids [T], slot_ids [T], positions [T], sample_index [S],
-        each block table [S, MB] (one, or full then window),
+        token_ids [T], slot_ids [T], positions [T], sample_index [S]
+        (or [S, L]: `sample_rows`), each block table [S, MB] (one, or full then window),
         adapter_ids [T] (only with adapters registered)
 
     Built once an engine, from what it can see of itself. The packer
@@ -347,10 +350,13 @@ class PlanLayout:
     buffer) read the same object, so the two cannot drift: everything
     the host decides about a step reaches the device as one array."""
 
-    def __init__(self, token_budget, max_slots, tables, adapters=False):
+    def __init__(self, token_budget, max_slots, tables, adapters=False,
+                 sample_rows=1):
         T, S = int(token_budget), int(max_slots)
+        # the sample rows a slot: one, or a block's L (block decoding)
+        sample = (S,) if sample_rows == 1 else (S, int(sample_rows))
         shapes = [("token_ids", (T,)), ("slot_ids", (T,)),
-                  ("positions", (T,)), ("sample_index", (S,))]
+                  ("positions", (T,)), ("sample_index", sample)]
         shapes += [(name, tuple(int(d) for d in shape))
                    for name, shape in tables]
         if adapters:
@@ -410,10 +416,14 @@ def pack_step(token_budget, max_slots, decode, prefills,
               buffers: PlanBuffers = None) -> StepPlan:
     """Pack decode entries + prefill chunks into the flat-token layout.
 
-    decode: [(slot, token_or_tokens, position)] — one entry per running
-        decode. A scalar token is the plain one-token decode; a list
-        [last, d_1..d_k] is a speculative verify group (k <= draft_k
-        proposed tokens after the last accepted one).
+    decode: [(slot, tokens, first position)] — one entry per running
+        decode, as `Scheduler.plan` gives them: a list of token ids at
+        consecutive positions. One id is the plain one-token decode;
+        [last, d_1..d_k] a speculative verify group (k <= draft_k
+        proposed tokens after the last accepted one); L ids a block of a
+        model that decodes by blocks (`buffers.sample_index` is [S, L]
+        then: every row of the block is a sample row). A bare int
+        stands for a list of one.
     prefills: [(slot, chunk_tokens: ndarray, start_pos, completes)] —
         `completes` marks the chunk that reaches the end of the prompt
         (its last token's hidden state samples the slot's first output).
@@ -448,26 +458,30 @@ def pack_step(token_budget, max_slots, decode, prefills,
         slot_ids = np.full(token_budget, -1, np.int32)
         positions = np.zeros(token_budget, np.int32)
         sample_index = np.full(max_slots, -1, np.int32)
+    # rows a decode entry may feed: a verify group's, or a block's
+    width = sample_index.shape[1] if sample_index.ndim == 2 else vw
     i = 0
     decode_slots = []
     decode_entries = []
     n_decode = 0
     for slot, tok, pos in decode:
-        toks = [int(tok)] if np.isscalar(tok) or getattr(
-            tok, "ndim", None) == 0 else [int(t) for t in tok]
-        if len(toks) > max(vw, 1):
+        toks = [int(t) for t in np.atleast_1d(tok)]
+        if len(toks) > width:
             raise ValueError(
                 f"decode group of {len(toks)} tokens exceeds the "
-                f"verify width {max(vw, 1)}")
+                f"verify width {width}")
         base = slot * vw if region_on else i
         token_ids[base:base + len(toks)] = toks
         slot_ids[base:base + len(toks)] = slot
         positions[base:base + len(toks)] = np.arange(
             pos, pos + len(toks), dtype=np.int32)
-        if vw == 1:
+        if sample_index.ndim == 2:
+            sample_index[slot, :len(toks)] = np.arange(
+                base, base + len(toks), dtype=np.int32)
+        elif vw == 1:
             sample_index[slot] = base
-            if not region_on:
-                i += 1
+        if not region_on:
+            i += len(toks)
         decode_slots.append(slot)
         decode_entries.append((slot, toks, int(pos)))
         n_decode += len(toks)
@@ -486,7 +500,9 @@ def pack_step(token_budget, max_slots, decode, prefills,
         slot_ids[i:i + m] = slot
         positions[i:i + m] = np.arange(start, start + m, dtype=np.int32)
         if completes:
-            sample_index[slot] = i + m - 1
+            if sample_index.ndim == 1:
+                # (a block-decoding prefill samples nothing)
+                sample_index[slot] = i + m - 1
             prefill_done.append(slot)
         i += m
         n_prefill += m

@@ -46,6 +46,26 @@ longest sequential-greedy prefix, emits 1..draft_k+1 tokens, and rolls
 back KV blocks the rejected tail had claimed. Output stays
 token-identical to `draft_k=0`, and the step still compiles exactly
 once (docs/SERVING.md).
+
+A model that brings its own block (`models.serving_block.ServingBlock`:
+`models/afmoe.py`, `models/olmo_hybrid.py`, `models/sdar_moe.py`) is
+stepped through `_block_step_body` instead of the GPT scan: its layers
+unrolled, each on its own K/V pool or recurrent state, the same packed
+plan, the same host loop. What such a block may ask of the engine:
+window, full and linear layers, grouped queries, dropless experts,
+greedy or plain sampling, and DECODING BY BLOCKS (an architecture whose
+`block_decoding` is set generates by diffusion over blocks; docs/
+SERVING.md "Decoding by blocks"): a decode entry is the slot's current
+block of L positions, the step samples all L rows and returns the
+candidates and their confidences, `_run_block_tick` decides some of
+them by the model's rule, delivers tokens in position order, and the
+pass that feeds a block with nothing masked commits its K/V; attention
+is block-causal, in the prefill too. What stays built into the GPT scan
+only is refused with the reason: drafts, quantized pools, adapters,
+sparse decode, the device loop, disaggregated roles, penalized
+sampling; a prefix cache with window or linear layers or block
+decoding; block decoding with window or linear layers or with a
+temperature.
 """
 from __future__ import annotations
 
@@ -72,15 +92,9 @@ import itertools as _itertools  # noqa: E402
 _ENGINE_SEQ = _itertools.count()
 
 
-def _group_width(tok):
-    """Tokens a decode entry of a plan feeds: one, or a verify group."""
-    return 1 if np.isscalar(tok) or getattr(
-        tok, "ndim", None) == 0 else len(tok)
-
-
 def _plan_groups(plan):
     """(first position, tokens) of every slot a plan feeds."""
-    groups = [(pos, _group_width(tok)) for _, tok, pos in plan.decode]
+    groups = [(pos, len(toks)) for _, toks, pos in plan.decode]
     return groups + [(start, len(chunk))
                      for _, chunk, start, _ in plan.prefills]
 
@@ -111,20 +125,30 @@ def _attention_work(plan, block_size):
                 kv_blocks_walked=int(walked))
 
 
-def _attention_work_by_kind(plan, window=None):
+def _attention_work_by_kind(plan, window=None, causal_block=None):
     """`_attention_work` for a model with window and full layers: the
     work of ONE layer of each kind (the benchmark multiplies by the
     layers of the kind). A window layer's query at p reads and attends
     keys `p - window < j <= p`: a group of `n` tokens from `start`
     reads `start + n - max(start - window + 1, 0)` tokens once. With no
     window (`window=None`: no layer is sliding) the `_window` fields are
-    there and 0."""
+    there and 0. With a `causal_block` L (no window beside it) a query
+    attends the keys to the END of its block of L positions, and none
+    past its group's last token: the group reads the same `start + n`
+    tokens and attends more pairs."""
     groups = _plan_groups(plan)
     out = {}
     for kind, w in (("window", window), ("full", None)):
         read = pairs = 0
         for start, n in groups if w or kind == "full" else ():
             read += start + n - (max(start - w + 1, 0) if w else 0)
+            if causal_block and w is None:
+                # block by block: its queries x the keys to its end
+                end, L = start + n, causal_block
+                for b in range(start // L * L, end, L):
+                    pairs += (min(b + L, end) - max(b, start)) \
+                        * min(b + L, end)
+                continue
             # a query at p attends min(p + 1, w) keys: p + 1 runs over
             # start + 1 .. start + n, capped at w
             lo, hi = start + 1, start + n
@@ -191,6 +215,26 @@ class ServingEngine:
                          role=role != "mixed")
             bad = [k for k, v in asked.items() if v]
             kinds_of = set(arch.layer_kinds)
+            # a model that generates by diffusion over blocks says so in
+            # its description, as it says what its layers are
+            self._diff = getattr(arch, "block_decoding", None)
+            self._causal_block = self._diff and self._diff.block_length
+            if self._diff is not None:
+                other = kinds_of - {"full"}
+                why = (f"with {sorted(other)} layers: a block's "
+                       "provisional rows would have to be taken out of a "
+                       "window table or a recurrent state again"
+                       if other else
+                       "with prefix_caching: a cached prefix would have to "
+                       "end on a committed block" if prefix_caching else
+                       "with a temperature: the candidates inside a block "
+                       "are greedy (batcher.select_token has one key a "
+                       "step)" if (sampling or SamplingConfig()).strategy
+                       != "greedy" else None)
+                if why:
+                    raise ValueError(
+                        f"{type(model).__name__} decodes by blocks "
+                        f"(block_decoding); that is not built {why}")
             if "linear" in kinds_of and (prefix_caching or draft_k):
                 raise ValueError(
                     f"{'prefix_caching' if prefix_caching else 'draft_k'}"
@@ -219,6 +263,7 @@ class ServingEngine:
                     "shards experts itself (TPServingEngine "
                     "expert_parallel=)")
             L, H, Dh = dec.num_layers, dec.num_heads, dec.head_dim
+            self._diff = self._causal_block = None
         maxpos = model.max_position_embeddings
         max_seq_len = min(max_seq_len or maxpos, maxpos)
         if block_size == "auto":
@@ -439,6 +484,14 @@ class ServingEngine:
                     num_window_blocks=max_slots * min(mbps, -(-(
                         arch.window + self.token_budget)
                         // self.block_size) + 1) + 1)
+            if self._diff is not None \
+                    and self.token_budget <= max_slots \
+                    * self._diff.block_length:
+                raise ValueError(
+                    f"token_budget={self.token_budget} leaves nothing "
+                    f"beside {max_slots} slots' blocks of "
+                    f"{self._diff.block_length} rows: a prompt could "
+                    "never be prefilled")
             if "linear" in arch.layer_kinds:
                 if self.token_budget - max_slots < arch.delta_chunk:
                     raise ValueError(
@@ -504,8 +557,11 @@ class ServingEngine:
             prefix_cache=self.prefix_cache,
             adapter_cache=self.adapters,
             reserve_region=self._sparse,
-            prefill_align=(arch.delta_chunk if self._block is not None
-                           and self.kv.linear_layers else 1))
+            prefill_align=(
+                1 if self._block is None
+                else arch.delta_chunk if self.kv.linear_layers
+                else self._diff.block_length if self._diff else 1),
+            block_decoding=self._diff)
         self.scheduler.replica = self.name
         self.eos_token_id = eos_token_id
         self.clock = clock
@@ -549,7 +605,8 @@ class ServingEngine:
             self.token_budget, max_slots,
             [(n, t.shape) for n, t in zip(
                 ("block_tables", "window_tables"), self.kv.tables())],
-            adapters=self.adapters is not None)
+            adapters=self.adapters is not None,
+            sample_rows=self._diff.block_length if self._diff else 1)
         self._plan_buffers = (batcher.PlanBuffers(self.plan_layout),
                               batcher.PlanBuffers(self.plan_layout))
         self._plan_flip = 0
@@ -610,8 +667,15 @@ class ServingEngine:
         self._released_seen = 0          # window blocks, since a record
         self._logits_issued = None       # (heads, the kernel's count)
         #: the last step's float32 logits at the sample rows,
-        #: [max_slots, V] on the device (a model-provided block only)
+        #: [max_slots, V] on the device (a model-provided block only;
+        #: [max_slots, L, V], a row a position of the slot's block,
+        #: where the model decodes by blocks)
         self.sample_logits = None
+        #: block decoding: called for every slot pass with (request,
+        #: block's first position, ids fed, positions decided before,
+        #: positions the pass decided, their tokens); a commit decides
+        #: none. What a caller holds the engine's passes against
+        self.on_block_pass = None
         self.steps_run = 0
         # block-sparse decode accounting (host mirrors of the fixed
         # selection arithmetic — the per-step selected count is
@@ -771,7 +835,18 @@ class ServingEngine:
         not know what they count), float32 logits of the sample rows
         [max_slots, V], the key advanced). `plan` is the packed buffer
         (`plan_layout`: flat tokens, sample index, the block tables),
-        sliced once, before the layers."""
+        sliced once, before the layers.
+
+        A model that decodes by blocks (`arch.block_decoding`, block
+        length L): attention is block-causal (`causal_block=L`; a
+        prefill chunk and a block's run under the same mask), the
+        sample index is `[max_slots, L]`, the rows of each slot's block,
+        and in place of tokens the step returns int32 `[max_slots, 2,
+        L]`: each row's greedy candidate, and the bits of its float32
+        confidence (the softmax probability of the candidate): one
+        array, one readback. The logits are `[max_slots, L, V]`. Which
+        positions take their candidate is the host's (`_run_block_tick`):
+        the step does not know which rows are masked."""
         import jax
         import jax.numpy as jnp
 
@@ -792,6 +867,12 @@ class ServingEngine:
                    enumerate(self.kv.linear_layers)})
         linear = bool(self.kv.linear_layers)
         more_heads = self._kv_heads - arch.num_kv_heads
+        diff, causal_block = self._diff, self._causal_block
+        if diff and max_run % causal_block:
+            raise ValueError(
+                f"block length {causal_block} does not divide the "
+                f"kernel's longest run ({max_run}): a cut run would end "
+                "inside a block")
 
         def step(weights, *rest):
             pools = list(rest[:n_pools])
@@ -828,7 +909,8 @@ class ServingEngine:
                     o = ragged_paged_attention(
                         q, kp, vp, tables[kind], slot_ids, pos,
                         runs=runs, max_run=max_run,
-                        window=arch.window if sliding else None)
+                        window=arch.window if sliding else None,
+                        causal_block=causal_block)
                 return o[:, :arch.num_heads] if more_heads else o
 
             extra = ()
@@ -843,10 +925,21 @@ class ServingEngine:
                                     *extra)
                 if st is not None:
                     stats = block.fold_stats(stats, st)
-            rows = h[jnp.clip(sample_index, 0, T - 1)]
-            logits = block.head(arch, weights, rows)
+            rows = h[jnp.clip(sample_index.reshape(-1), 0, T - 1)]
+            logits = block.head(arch, weights, rows).astype(jnp.float32)
             tok = select_token(logits, rng, sc)
-            return (tok, *pools, stats, logits.astype(jnp.float32), key)
+            if diff:
+                with jax.named_scope("diffusion_confidence"):
+                    # the candidate's softmax probability, float32
+                    top = jnp.take_along_axis(logits, tok[:, None], 1)
+                    conf = jnp.exp(top[:, 0] - jax.nn.logsumexp(
+                        logits, axis=-1))
+                    tok = jnp.stack(
+                        [tok, jax.lax.bitcast_convert_type(
+                            conf, jnp.int32)]).reshape(
+                        2, *sample_index.shape).swapaxes(0, 1)
+                logits = logits.reshape(*sample_index.shape, -1)
+            return (tok, *pools, stats, logits, key)
 
         return step
 
@@ -1945,6 +2038,17 @@ class ServingEngine:
                 raise AssertionError(
                     f"a plan feeds a slot twice in one step ({fed}): "
                     "the linear layers take one run a slot")
+        if self._causal_block:
+            # under the block-causal mask a run sees no key past its own
+            # end: it must end on a block boundary, or where the
+            # sequence does (the scheduler cuts so; held here, where the
+            # runs are made)
+            L = self._causal_block
+            for _, chunk, start, completes in prefills:
+                if start % L or (len(chunk) % L and not completes):
+                    raise AssertionError(
+                        f"a prefill chunk of {len(chunk)} tokens from "
+                        f"{start} ends inside a block of {L}")
         buf = self._plan_buffers[self._plan_flip]
         self._plan_flip ^= 1
         sp = pack_step(self.token_budget, self.kv.max_slots, decode,
@@ -2049,7 +2153,8 @@ class ServingEngine:
             return bool(plan.expired)
         if trace_on:
             ph.mark("engine.pack")
-        run = self._run_multitick if self._multitick else self._run_tick
+        run = self._run_multitick if self._multitick else \
+            self._run_block_tick if self._diff else self._run_tick
         sp, got = run(plan, trace_on)
         now = ph.mark("engine.emit") if trace_on else self.clock()
         if trace_on:
@@ -2066,7 +2171,11 @@ class ServingEngine:
                         tokens=len(chunk), completes=bool(completes))
         for slot in sp.prefill_done:
             req = sch.slots[slot]
-            if req is not None and not self.emit(
+            if req is not None and got["first"] is None:
+                # block decoding: a prefill samples nothing, and its
+                # request feeds its first block from the next step on
+                req.state = "decode"
+            elif req is not None and not self.emit(
                     req, [int(got["first"][slot])], now, trace_on) \
                     and self.role == "prefill":
                 # prefill-role handoff point: the first token is
@@ -2152,8 +2261,8 @@ class ServingEngine:
         got.update(verify=bool(self.draft_k),
                    decode_tokens=sp.decode_tokens)
         if self._sparse:
-            self._note_sparse(pos + _group_width(tok) - 1
-                              for _, tok, pos in plan.decode)
+            self._note_sparse(pos + len(toks) - 1
+                              for _, toks, pos in plan.decode)
         if trace_on:
             # the attention work of this step, counted while the device
             # does it: host arithmetic on the plan, no readback
@@ -2192,6 +2301,73 @@ class ServingEngine:
             # contents were rejected-draft K/V columns
             groups.append((slot, emitted, pos + m + 1))
         got.update(first=tok_np, groups=groups, spec=(prop, acc, hist))
+        return sp, got
+
+    def _run_block_tick(self, plan, trace_on):
+        """`_run_tick` for a model that decodes by blocks: a decode
+        entry fed its slot's current block of L rows, and the step hands
+        back every row's candidate and confidence. Here the block's
+        state moves, by POSITION (an id equal to the mask id means
+        nothing): a slot pass that fed masked rows is a DENOISE pass,
+        the model's rule (`BlockDecoding.decide`) picks the masked
+        positions that take their candidate for good, and the block's
+        decided tokens that follow what the client already has go out,
+        in position order (`groups`; none where an earlier position is
+        still masked); a pass that fed no masked row was the COMMIT
+        (`Scheduler.note_fed` grew the slot by the block and opened the
+        next one): its outputs are unused. A pass yields 0..L tokens. A
+        completed prefill samples nothing (`first` is None)."""
+        bd, sch = self._diff, self.scheduler
+        L = bd.block_length
+        # the states the plan was made from: the dispatch notes the plan
+        # fed, and a committed block's state is the next block's by then
+        fed = [(slot, sch.slots[slot], list(sch.slots[slot].block_decided),
+                toks, pos) for slot, toks, pos in plan.decode]
+        sp = self._pack(plan.decode, plan.prefills)
+        out, got = self._dispatch(plan, sp, (), trace_on)
+        if trace_on:
+            got["work"] = self._plan_work(plan)
+        got.update(decode_tokens=sp.decode_tokens, first=None)
+        both = np.asarray(out)                  # [S, 2, L] int32
+        cand, conf = both[:, 0], both[:, 1].view(np.float32)
+        groups, masked_rows, decided, commits = [], 0, 0, 0
+        for slot, req, was, toks, pos in fed:
+            masked = [i for i in range(L) if not was[i]]
+            take = bd.decide(masked, conf[slot], req.block_passes) \
+                if masked else []
+            if self.on_block_pass is not None:
+                self.on_block_pass(req, pos, toks, was, take,
+                                   [int(cand[slot, i]) for i in take])
+            if not masked:
+                commits += 1
+                if trace_on:
+                    _tracing.TRACER.event(
+                        req.trace_id, "block_committed", replica=self.name,
+                        ts=self.clock(), start=int(pos))
+                continue
+            for i in take:
+                req.block_tokens[i] = int(cand[slot, i])
+                req.block_decided[i] = True
+            req.block_passes += 1
+            masked_rows += len(masked)
+            decided += len(take)
+            at = len(req.prompt) + len(req.output) - req.block_start
+            # no further than the horizon, nor past an EOS: what the
+            # client is handed is what `emit` appends
+            stop = min(L, at + req.max_new_tokens - len(req.output))
+            end = at
+            while end < stop and req.block_decided[end]:
+                end += 1
+                if req.block_tokens[end - 1] == req.eos_token_id:
+                    break
+            if end > at:
+                groups.append((slot, req.block_tokens[at:end], None))
+        got["groups"] = groups
+        if trace_on:
+            got["work"].update(
+                diff_block_len=L, diff_slot_passes=len(fed),
+                diff_rows_masked=masked_rows, diff_tokens_decided=decided,
+                diff_commits=commits, diff_blocks_committed=commits)
         return sp, got
 
     def _run_multitick(self, plan, trace_on):
@@ -2261,7 +2437,7 @@ class ServingEngine:
             # query at the next position. Exact without speculation;
             # with device drafting the rejected draft columns are work
             # the host never sees
-            ticked = plan.decode + [(slot, 0, pos + j) for slot, pos, c
+            ticked = plan.decode + [(slot, [0], pos + j) for slot, pos, c
                                     in fed for j in range(1, c)]
             if self._sparse:
                 self._note_sparse(pos for _, _, pos in ticked)
@@ -2306,6 +2482,13 @@ class ServingEngine:
             if _pmetrics._enabled:
                 smetrics.SERVING_INTER_TOKEN_SECONDS.observe(gap)
         req._last_token_time = now
+        # block decoding delivers several tokens at once: the first of
+        # them carries the gap (or is the first token), the others
+        # follow it at no distance
+        inside = len(tokens) - 1 if self._diff else 0
+        if inside and _pmetrics._enabled:
+            for _ in range(inside):
+                smetrics.SERVING_INTER_TOKEN_SECONDS.observe(0.0)
         if trace_on:
             # the span twins of the two histograms above: the
             # first_token event's ts minus the enqueued event's ts IS
@@ -2313,10 +2496,12 @@ class ServingEngine:
             # the same `gap` — tools/trace_smoke.py asserts the sums
             # match
             if first:
-                _tracing.on_first_token(req, self.name, ts=now)
+                _tracing.on_first_token(req, self.name, ts=now,
+                                        inside=inside)
             else:
                 _tracing.on_tokens(req, self.name, ts=now,
-                                   n=len(tokens), gap=gap, verify=verify)
+                                   n=len(tokens), gap=gap, verify=verify,
+                                   inside=inside)
         for t in tokens:
             req.output.append(t)
             if len(req.output) >= req.max_new_tokens or \
@@ -2352,7 +2537,8 @@ class ServingEngine:
             work = _attention_work(plan, self.block_size)
             layers = [(None, self.kv.num_layers, work["attn_pairs"])]
         else:
-            work = _attention_work_by_kind(plan, self.kv.window)
+            work = _attention_work_by_kind(plan, self.kv.window,
+                                           self._causal_block)
             kinds = self._block.arch.layer_kinds
             layers = [(w, kinds.count(kind), work[f"attn_pairs_{name}"])
                       for kind, name, w in (
@@ -2383,7 +2569,8 @@ class ServingEngine:
                 self.token_budget, _BLOCK_MAX_RUN)
             self._logits_issued = heads, functools.partial(
                 pa.logits_issued, H=H, Gq=Gq, block_size=self.block_size,
-                max_run=max_run, tiles=pa.kernel_tiles(
+                max_run=max_run, causal_block=self._causal_block,
+                tiles=pa.kernel_tiles(
                     self.token_budget, H, Gq, self.kv.head_dim,
                     self.block_size, self.kv.max_blocks_per_slot,
                     kv_jnp_dtype(self.kv.kv_dtype),

@@ -259,6 +259,13 @@ class PagedKVCache:
         self.block_tables = np.zeros(
             (self.max_slots, self.max_blocks_per_slot), np.int32)
         self._slot_blocks = [[] for _ in range(self.max_slots)]
+        # tokens cached a slot. Under block decoding (a model that
+        # generates by diffusion over blocks) these are COMMITTED tokens
+        # only: the current block's L rows lie past them, written every
+        # pass (provisional, overwritten in place: a block never
+        # straddles a page, the block size being a multiple of L) and
+        # counted once the commit pass has fed the block with nothing
+        # masked (`Scheduler.note_fed`)
         self.slot_lens = np.zeros(self.max_slots, np.int32)
         # optional radix prefix cache (serving.prefix_cache): when the
         # free list runs dry, refcount-0 cached leaves are evicted

@@ -27,11 +27,14 @@ _LATENCY_BUCKETS = exponential_buckets(1e-4, 4.0, 10)
 
 SERVING_TTFT_SECONDS = REGISTRY.histogram(
     "paddle_tpu_serving_ttft_seconds",
-    "Submit-to-first-token latency per request",
+    "Submit-to-first-token latency per request (the first token "
+    "DELIVERED: a model that decodes by blocks decides tokens out of "
+    "order and hands them over in position order)",
     buckets=_LATENCY_BUCKETS)
 SERVING_INTER_TOKEN_SECONDS = REGISTRY.histogram(
     "paddle_tpu_serving_inter_token_seconds",
-    "Gap between consecutive generated tokens of one request",
+    "Gap between consecutive generated tokens of one request (0 "
+    "between tokens handed over together, block decoding)",
     buckets=_LATENCY_BUCKETS)
 SERVING_QUEUE_DEPTH = REGISTRY.gauge(
     "paddle_tpu_serving_queue_depth",
@@ -59,6 +62,8 @@ SERVING_REQUESTS = REGISTRY.counter(
 SERVING_TOKENS = REGISTRY.counter(
     "paddle_tpu_serving_tokens_total",
     "Tokens processed by the mixed step", ("kind",))  # prefill|decode
+#                       (decode: ROWS fed, a block's L a slot pass where
+#                       the model decodes by blocks)
 SERVING_STEPS = REGISTRY.counter(
     "paddle_tpu_serving_steps_total",
     "Mixed-step invocations")

@@ -79,6 +79,16 @@ class Request:                     # in sets/queues across state moves
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     _last_token_time: Optional[float] = None
+    # decoding by blocks (`Scheduler.block_decoding`): the current
+    # block's first position (= the slot's committed length), its L
+    # token ids (a masked position holds the mask id), which positions
+    # are decided (by POSITION: a prompt's or a candidate's id may equal
+    # the mask id), and the denoise passes it has had. Kept across a
+    # preemption: what was decided is never decided again
+    block_start: int = -1
+    block_tokens: Optional[list] = None
+    block_decided: Optional[list] = None
+    block_passes: int = 0
 
     @property
     def runtime_prompt(self):
@@ -93,7 +103,9 @@ class Request:                     # in sets/queues across state moves
 
 @dataclasses.dataclass
 class Plan:
-    decode: list        # [(slot, token, position)]
+    decode: list        # [(slot, [token ids], first position)]: one id
+    #                     a plain decode, the last token and its drafts
+    #                     a verify group, a block's L ids (block decoding)
     prefills: list      # [(slot, chunk ndarray, start_pos, completes)]
     expired: list       # requests expired this round
 
@@ -107,8 +119,18 @@ class Scheduler:
                  clock=time.monotonic, draft_k=0, draft_fn=None,
                  device_draft=False, prefix_cache=None,
                  adapter_cache=None, reserve_region=False,
-                 prefill_align=1):
+                 prefill_align=1, block_decoding=None):
         self.kv = kv_cache
+        # a model that decodes by blocks (`models.serving_block.
+        # BlockDecoding`; None: a token at a time)
+        self.block_decoding = block_decoding
+        if block_decoding is not None:
+            L = block_decoding.block_length
+            if int(prefill_align) % L or draft_k or prefix_cache is not None:
+                raise ValueError(
+                    "block decoding: prefill chunks end on multiples of "
+                    f"the block length {L} (prefill_align={prefill_align}"
+                    "), and neither drafts nor a prefix cache are built")
         # a model with recurrent (linear) layers: a prompt's prefill
         # chunks end on multiples of this many tokens (all but its
         # last), so that the chunked recurrence cuts a prompt at the
@@ -159,6 +181,10 @@ class Scheduler:
                deadline=None, tenant="default", adapter_id=None,
                trace_id=None):
         total = len(prompt) + max_new_tokens - 1  # last token never fed
+        if self.block_decoding is not None:
+            # the last block is fed whole, whatever the horizon
+            L = self.block_decoding.block_length
+            total = -(-(len(prompt) + max_new_tokens) // L) * L
         if total > self.kv.max_slot_tokens:
             raise ValueError(
                 f"request needs {total} cached tokens; a slot holds at "
@@ -315,6 +341,16 @@ class Scheduler:
                 req.state = "prefill"
                 req.fed = 0
                 self.slots[slot] = req
+                if self.block_decoding is not None:
+                    if req.block_tokens is None:
+                        # the prompt's whole blocks are prefilled; its
+                        # tail starts the first block, decided
+                        L = self.block_decoding.block_length
+                        self._open_block(
+                            req, len(req.prompt) // L * L,
+                            req.prompt[len(req.prompt) // L * L:])
+                    if req.block_start == 0:
+                        req.state = "decode"    # nothing to prefill
                 if _tracing._enabled:
                     # a re-prefill resumes a preempted sequence (its
                     # generated prefix folds into the prompt) — a
@@ -343,6 +379,24 @@ class Scheduler:
                     req.cache_hit_tokens += hit
                     self.kv.slot_lens[slot] = hit
         return
+
+    def _open_block(self, req, start, decided=()):
+        """`req`'s current block is the one from `start`, with the
+        tokens `decided` at its first positions and the rest masked."""
+        bd = self.block_decoding
+        n = len(decided)
+        req.block_start = int(start)
+        req.block_tokens = [int(t) for t in decided] \
+            + [bd.mask_token_id] * (bd.block_length - n)
+        req.block_decided = [True] * n + [False] * (bd.block_length - n)
+        req.block_passes = 0
+
+    def prefill_target(self, req):
+        """The tokens `req`'s prefill feeds: the runtime prompt, or
+        (block decoding) what lies before its current block."""
+        if self.block_decoding is None:
+            return req.runtime_prompt
+        return req.runtime_prompt[:req.block_start]
 
     def _preempt_victim(self, exclude):
         """Evict the decode holding the most blocks (tie: latest
@@ -438,7 +492,10 @@ class Scheduler:
                 continue
             # position of the token being fed = tokens already cached
             pos = int(self.kv.slot_lens[req.slot])
-            while not self.kv.ensure_capacity(req.slot, pos + 1):
+            # a block's rows are all written, every pass
+            width = 1 if self.block_decoding is None \
+                else self.block_decoding.block_length
+            while not self.kv.ensure_capacity(req.slot, pos + width):
                 if self._preempt_victim(protected | {req}) is None:
                     # nothing left to evict: preempt THIS decode
                     self._preempt_victim(protected)
@@ -446,7 +503,13 @@ class Scheduler:
             if req.slot < 0:
                 continue
             protected.add(req)
-            if self.draft_k > 0 and not self.device_draft:
+            if self.block_decoding is not None:
+                if pos != req.block_start:
+                    raise AssertionError(
+                        f"slot {req.slot} holds {pos} committed tokens, "
+                        f"its block starts at {req.block_start}")
+                decode.append((req.slot, list(req.block_tokens), pos))
+            elif self.draft_k > 0 and not self.device_draft:
                 decode.append((req.slot,
                                self._draft_tokens(req, pos), pos))
             elif self.draft_k > 0:
@@ -455,12 +518,12 @@ class Scheduler:
                 # the verify burst, and the loop body widens the group
                 decode.append((req.slot, [req.output[-1]], pos))
             else:
-                decode.append((req.slot, req.output[-1], pos))
+                decode.append((req.slot, [req.output[-1]], pos))
 
         # with speculation (or the sparse decode region) the region is
         # RESERVED up front (see batcher.pack_step) — prefill budget
         # never depends on the mix
-        reserved = len(decode) \
+        reserved = sum(len(toks) for _, toks, _ in decode) \
             if self.draft_k == 0 and not self.reserve_region \
             else self.max_slots * (self.draft_k + 1)
         budget_left = self.token_budget - reserved
@@ -472,7 +535,7 @@ class Scheduler:
         for req in prefillers:
             if budget_left <= 0:
                 break
-            tokens = req.runtime_prompt
+            tokens = self.prefill_target(req)
             remaining = len(tokens) - req.fed
             def cut(n):     # a chunk that does not end the prompt
                 return n if n >= remaining else \
@@ -502,8 +565,17 @@ class Scheduler:
         Speculative decodes are NOT advanced here: how far a verify
         group really got is only known after the engine reads the
         accept length back, so `note_accept` owns that bookkeeping."""
-        if self.draft_k == 0:
-            for slot, _tok, pos in plan.decode:
+        if self.block_decoding is not None:
+            # a block fed with nothing masked is COMMITTED: its K/V is
+            # final, the slot grows by it and the next block opens; a
+            # denoise pass leaves the slot's length where it was
+            for slot, toks, pos in plan.decode:
+                req = self.slots[slot]
+                if req is not None and all(req.block_decided):
+                    self.kv.slot_lens[slot] = pos + len(toks)
+                    self._open_block(req, pos + len(toks))
+        elif self.draft_k == 0:
+            for slot, _toks, pos in plan.decode:
                 self.kv.slot_lens[slot] = pos + 1
         for slot, chunk, start, completes in plan.prefills:
             self.kv.slot_lens[slot] = start + len(chunk)

@@ -168,6 +168,8 @@ class Trace:
         gaps = [e.attrs.get("gap") for e in self.events
                 if e.name in ("decode_step", "verify_step")
                 and e.attrs.get("gap") is not None]
+        # tokens delivered together (block decoding) lie at gap 0
+        gaps += [0.0] * sum(e.attrs.get("inside", 0) for e in self.events)
         d = {
             "trace_id": self.trace_id,
             "tenant": self.tenant,
@@ -451,16 +453,21 @@ def on_admitted(req, replica=None, kind="prefill", ts=None):
             max(0.0, ts - req.submit_time))
 
 
-def on_first_token(req, replica=None, ts=None):
-    TRACER.event(req.trace_id, "first_token", replica=replica, ts=ts)
+def on_first_token(req, replica=None, ts=None, inside=0):
+    # `inside`: further tokens delivered with this one (a model that
+    # decodes by blocks hands over 1..L at once), each at gap 0
+    TRACER.event(req.trace_id, "first_token", replica=replica, ts=ts,
+                 **({"inside": inside} if inside else {}))
     if ts is not None:
         TRACER._notify("on_ttft", req.tenant, ts - req.submit_time, ts)
 
 
-def on_tokens(req, replica=None, ts=None, n=1, gap=None, verify=False):
+def on_tokens(req, replica=None, ts=None, n=1, gap=None, verify=False,
+              inside=0):
     TRACER.event(req.trace_id,
                  "verify_step" if verify else "decode_step",
-                 replica=replica, ts=ts, tokens=n, gap=gap)
+                 replica=replica, ts=ts, tokens=n, gap=gap,
+                 **({"inside": inside} if inside else {}))
     if gap is not None:
         TRACER._notify("on_inter_token", req.tenant, gap, ts)
 

@@ -465,7 +465,7 @@ def test_flight_fields_of_a_hand_made_plan():
     from paddle_tpu.serving.scheduler import Plan
     # a decode token at position 9, a verify-free chunk of 17 from 32
     # and a chunk of 8 from 0
-    plan = Plan([(0, 5, 9)], [(1, np.arange(17), 32, False),
+    plan = Plan([(0, [5], 9)], [(1, np.arange(17), 32, False),
                               (2, np.arange(8), 0, True)], ())
     assert _linear_work(plan, 8) == dict(
         lin_tokens=26, lin_runs=3, lin_chunks=1 + 3 + 1,

@@ -257,7 +257,7 @@ class TestRunKernel(_RunShapes):
         from paddle_tpu.serving.engine import _attention_work
         from paddle_tpu.serving.scheduler import Plan
         plan = Plan(
-            decode=[(0, 7, 17), (1, 7, 30)],
+            decode=[(0, [7], 17), (1, [7], 30)],
             prefills=[(3, list(range(20)), 6, False),   # 3 q tiles
                       (4, list(range(5)), 0, True)],
             expired=[])

@@ -6,7 +6,7 @@ the real scalar-prefetch/block-table plumbing, but not the real Mosaic
 tiling. This tool is the device run: it replays the paged-attention
 family (ragged / verify / decode / sparse short-table; fp32, bf16, int8
 and fp8 pools; the same entries on pools stacked over layers, read in
-place), fused add+LayerNorm and splash attention (forward and
+place; the block-causal rule of a model that decodes by blocks), fused add+LayerNorm and splash attention (forward and
 backward), the hand flash-forward kernel, the grouped-expert matmul
 (fp32 / int8 / int4 weights) and the ragged chunked delta rule (a key
 dim of 96) against their pure-XLA oracles with
@@ -407,6 +407,96 @@ def validate_paged_planes(*, Dh=128, BS=16, max_run=128, window=4096,
     return cells
 
 
+def validate_paged_block_causal(*, H=4, Gq=8, Dh=128, BS=16, L=4,
+                                max_run=128, N=197,
+                                dtypes=("bfloat16", "float32"),
+                                blocks_tol=0.0):
+    """The run kernel under the BLOCK-CAUSAL rule (`causal_block=L`: a
+    query attends the keys to the end of its block of L positions) at
+    the diffusion cell's heads, 4 KV x 8 query heads a group: a prompt
+    of N tokens (not a multiple of L: it ends inside a block, and what
+    lies behind it in the pool must stay unseen) as ONE run against the
+    XLA oracle under the same rule; then the same prompt in chunks of
+    64, a step each, and followed block by block (runs of L, laid in
+    reverse so that no two join): a row's bits must not depend on its
+    run (`blocks_tol`: what a rehearsal allows the runs of L, because
+    XLA:CPU rounds a product's sums by its row count)."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    ragged_ref = _exact(fa.ragged_gather_reference)
+    T = -(-N // max_run) * max_run
+    MB = N // BS + 2
+    NB = 2 * MB + 1
+    bt = (1 + np.arange(2 * MB, dtype=np.int32)).reshape(2, MB)
+    cells = []
+    for dtype in dtypes:
+        tol = 5e-2 if dtype == "bfloat16" else 2e-2
+        rng = np.random.RandomState(23)
+        kp, vp = (jnp.asarray(rng.randn(NB, BS, H, Dh), dtype)
+                  for _ in range(2))
+        q = rng.randn(N, H * Gq, Dh).astype(np.float32)
+        attend = jax.jit(lambda q, sl, ps: pa.ragged_attend(
+            q, kp, vp, jnp.asarray(bt), sl, ps, max_run=max_run,
+            causal_block=L))
+
+        def feed(runs):
+            """The prompt's rows `runs` [(first, n)] in one step, in the
+            order given."""
+            qq = np.full((T, H * Gq, Dh), 0.5, np.float32)
+            sl = np.full(T, -1, np.int32)
+            ps = np.zeros(T, np.int32)
+            at, t = [], 0
+            for first, n in runs:
+                qq[t:t + n], sl[t:t + n] = q[first:first + n], 1
+                ps[t:t + n] = first + np.arange(n)
+                at.append((first, t, n))
+                t += n
+            out = np.asarray(attend(jnp.asarray(qq, dtype), jnp.asarray(sl),
+                                    jnp.asarray(ps)).astype(jnp.float32))
+            got = np.zeros((N, H * Gq, Dh), np.float32)
+            for first, t, n in at:
+                got[first:first + n] = out[t:t + n]
+            return got, (qq, sl, ps)
+
+        whole, (qq, sl, ps) = feed([(0, N)])
+        want = np.concatenate([np.asarray(ragged_ref(
+            jnp.asarray(qq[i:i + 32], dtype), kp, vp, jnp.asarray(bt),
+            jnp.asarray(sl[i:i + 32]), jnp.asarray(ps[i:i + 32]),
+            causal_block=L, max_run=max_run).astype(jnp.float32))
+            for i in range(0, T, 32)])[:N]
+        # (the oracle's runs are cut 32 tokens a call: a multiple of L,
+        # and the last call ends where the prompt does)
+        shape = (f"{dtype} Hq={H * Gq} H={H} Dh={Dh} BS={BS} L={L} "
+                 f"N={N} max_run={max_run}")
+        cells.append(_cell(f"paged_ragged block-causal {shape}", whole,
+                           want, tol, tol))
+        chunks = np.zeros_like(whole)
+        for a in range(0, N, 64):
+            chunks[a:a + 64] = feed([(a, min(64, N - a))])[0][a:a + 64]
+        blocks = np.zeros_like(whole)
+        per = max_run // L                  # blocks a step
+        starts = list(range(0, N, L))
+        for i in range(0, len(starts), per):
+            part = [(b, min(L, N - b)) for b in starts[i:i + per]][::-1]
+            got = feed(part)[0]
+            for b, n in part:
+                blocks[b:b + n] = got[b:b + n]
+        for name, other, allowed in (("chunks of 64", chunks, 0.0),
+                                     ("block by block", blocks,
+                                      blocks_tol)):
+            err = float(np.abs(whole - other).max())
+            cells.append(Cell(
+                f"paged_ragged block-causal one run = {name}, bit for "
+                f"bit {shape}",
+                err <= allowed and bool(np.abs(whole).max() > 0.01), err))
+    return cells
+
+
 def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
                                   dtypes=("float32", "bfloat16")):
     """The dropless expert layer's ragged grouped matmul, plain and
@@ -649,6 +739,9 @@ def run_matrix(rehearse=False):
                 Dh=16, BS=8, max_run=16, window=40, singles_tol=4e-3,
                 cases=((8, 6, "window", None), (4, 1, None, 2)))
                 if rehearse else {}))
+            + validate_paged_block_causal(**(dict(
+                Dh=16, BS=8, max_run=16, N=45, blocks_tol=4e-3,
+                dtypes=("bfloat16",)) if rehearse else {}))
             + validate_ragged_expert_matmul()
             + validate_gated_delta(**(dict(H=2, lens=(1, 3, 65))
                                       if rehearse else {}))
