@@ -22,15 +22,22 @@ Three forms of the same function:
       O   = e^gamma * (Q S_0) + (tril(Q K^T) * e^{gamma_r - gamma_i}) U
       S_C = e^{gamma_C} S_0 + K^T (e^{gamma_C - gamma} * U)
   every exponent <= 0; padding rows take beta = 0, g = 0, k = 0;
-- `gated_delta_ragged`: the same with the pass that carries S through a
-  run's chunks as the Mosaic kernel `gated_delta`: grid (head group,
-  chunk), a run's state read from HBM once, held in VMEM across its
-  chunks and written once. The products that do not depend on S_0 (`A`,
-  the masked decayed `Q K^T`, the decays) are made by XLA for all
-  chunks at once (`_prologue`); the kernel solves `(I + A) Tm = I` by
-  forward substitution, only for the chunks that hold more than one
-  token; a chunk of ONE token (a decode run, or a run's last odd token)
-  takes the recurrence itself, as vector operations on the state.
+- `gated_delta_ragged`: the same as the Mosaic kernel `gated_delta`,
+  which does work, and moves bytes, for the chunks that hold tokens.
+  Grid (head group, chunk slot); a run's state is read from HBM once,
+  held in VMEM across its chunks and written once. XLA makes only what
+  is a scalar a token (`_token_scalars`: gamma, its two decays, beta,
+  from the token's OWN chunk alone) and packs q | k | v | scalars head
+  major into one array (`_pack`); a head group's rows of the whole step
+  stay in VMEM and a chunk takes its rows at the flat token the chunk
+  tables name. A chunk of two or more tokens makes `A`, the masked
+  decayed `Q K^T` and `K^T` in VMEM (float32, `HIGHEST`), solves
+  `(I + A) Tm = I` by a sweep over the columns and takes the chunkwise
+  form; a chunk of ONE token (a decode run, or a run's last odd token)
+  never enters the row layout: it takes the recurrence itself on its
+  one row, as vector operations on the state; a chunk slot that holds
+  nothing costs a grid step and no bytes (its block indices stay where
+  the last real chunk left them).
 """
 from __future__ import annotations
 
@@ -48,6 +55,11 @@ _INTERPRET = False
 _FIRST, _FRESH, _LAST, _SINGLE, _REAL = 1, 2, 4, 8, 16
 
 _HI = jax.lax.Precision.HIGHEST
+
+# a tile of rows is loaded from a multiple of 8 rows (a sublane tile:
+# Mosaic takes several rows at a dynamic start from nowhere else) and
+# rolled to its first token: it loads this many rows beside its own
+_ALIGN = 8
 
 
 def gated_delta_enabled() -> bool:
@@ -77,10 +89,14 @@ def token_runs(runs, T):
 
 
 def delta_chunks(runs, T, max_slots, chunk=CHUNK):
-    """The chunks of a step's runs, fixed shapes. -> dict: `tok [NC, C]`
-    the flat token a chunk row holds (T = padding), `slot [NC]`, `flags
-    [NC]` (first / fresh / last / single token / real), `n [1]`, and the
-    way back: `at [T]` the row of `[NC * C]` a flat token lies at."""
+    """The chunks of a step's runs, fixed shapes. -> dict: `slot [NC]`,
+    `flags [NC]` (first / fresh / last / single token / real), `t0 [NC]`
+    the flat token a chunk starts at and `rows [NC]` the tokens it
+    holds (a run is a contiguous range of flat tokens), `n [1]`; per
+    flat token `pos [T]` its row in its chunk, `last [T]` its chunk's
+    last token, `valid [T]`; and, for the `jax.numpy` form's row layout,
+    `tok [NC, C]` the flat token a chunk row holds (T = padding) and
+    the way back, `at [T]` the row of `[NC * C]` a flat token lies at."""
     C = int(chunk)
     NC = max_chunks(T, max_slots, C)
     n_runs, start, length, slot, first = runs
@@ -104,30 +120,35 @@ def delta_chunks(runs, T, max_slots, chunk=CHUNK):
     # chunks past the last real one stay on its slot (the kernel's
     # state block then never moves); a step with no run at all walks
     # chunk 0 as an all-padding run of slot 0: an identity
-    last = slot[jnp.maximum(n_runs[0] - 1, 0)]
-    cslot = jnp.clip(jnp.where(real, slot[r], last), 0, max_slots - 1)
+    last_slot = slot[jnp.maximum(n_runs[0] - 1, 0)]
+    cslot = jnp.clip(jnp.where(real, slot[r], last_slot), 0,
+                     max_slots - 1)
     empty = n == 0
     flags = jnp.where(empty & (c == 0), _FIRST | _LAST | _REAL, flags)
     cslot = jnp.where(empty, 0, cslot)
     tr, valid, off, _ = token_runs(runs, T)
     at = jnp.where(valid,
                    (cend[tr] - nch[tr] + off // C) * C + off % C, 0)
+    pos = jnp.where(valid, off % C, 0)
+    t = jnp.arange(T, dtype=jnp.int32)
+    last = jnp.where(
+        valid, t - pos + jnp.clip(length[tr] - off // C * C, 1, C) - 1, t)
     return dict(tok=tok, slot=cslot.astype(jnp.int32),
                 flags=flags.astype(jnp.int32), n=n.reshape(1), at=at,
-                valid=valid)
+                valid=valid, rows=rows.astype(jnp.int32),
+                t0=jnp.where(rows > 0, start[r] + j * C, 0).astype(
+                    jnp.int32), pos=pos, last=last)
 
 
-def _prologue(q, k, v, g, beta, chunks, solve):
-    """Everything of the chunkwise form that does not depend on the
-    incoming state, for all chunks and heads at once, float32, head
-    major: K, Q `[H, NC, C, dk]`, their transposes, V `[H, NC, C, dv]`,
-    the masked decayed `Q K^T` `[H, NC, C, C]`, `scal [H, NC, C, 3]` =
-    (e^gamma, e^{gamma_C - gamma}, beta), `dec [H, NC]` = e^{gamma_C},
-    and the triangular system: `Tm = (I + A)^{-1}` where `solve` (XLA's
-    batched solver: the fallback), else `AT = A^T` for the kernel, which
-    solves it only for the chunks that hold more than one token (on the
-    chip XLA's solver took 2.6 ms a layer for 1200 systems of 64 rows,
-    a third of the step)."""
+def _prologue(q, k, v, g, beta, chunks):
+    """The `jax.numpy` form's row layout: everything of the chunkwise
+    form that does not depend on the incoming state, for all chunk
+    slots and heads at once, float32, head major: K, Q `[H, NC, C, dk]`,
+    `KT`, V `[H, NC, C, dv]`, the masked decayed `Q K^T` `[H, NC, C,
+    C]`, the columns `eg` = e^gamma, `d` = e^{gamma_C - gamma} and `bt`
+    = beta `[H, NC, C, 1]`, `dec [H, NC]` = e^{gamma_C}, and `Tm = (I +
+    A)^{-1}` by XLA's batched triangular solver. The kernel makes none
+    of it: every array here has a row for each of the NC chunk slots."""
     tok = chunks["tok"]                                     # [NC, C]
     f32 = jnp.float32
     exact = _HI if q.dtype == f32 else None
@@ -148,30 +169,23 @@ def _prologue(q, k, v, g, beta, chunks, solve):
                     preferred_element_type=f32)
     A = B[..., :, None] * dec_lo * kk
     eye = jnp.eye(C, dtype=f32)
-    if solve:
-        system = dict(Tm=jax.lax.linalg.triangular_solve(
-            A + eye, jnp.broadcast_to(eye, A.shape), left_side=True,
-            lower=True, unit_diagonal=True))
-    else:
-        system = dict(AT=jnp.swapaxes(A, -1, -2))
+    Tm = jax.lax.linalg.triangular_solve(
+        A + eye, jnp.broadcast_to(eye, A.shape), left_side=True,
+        lower=True, unit_diagonal=True)
     qk = jnp.einsum("hnrd,hnid->hnri", Q, K, precision=exact,
                     preferred_element_type=f32)
-    P = (dec_lo + eye) * qk
-    scal = jnp.stack([jnp.exp(gam),
-                      jnp.exp(gam[..., -1:] - gam), B], axis=-1)
-    dec = jnp.exp(gam[..., -1])                             # [H, NC]
     K, Q, V = K.astype(f32), Q.astype(f32), V.astype(f32)
-    return dict(K=K, Q=Q, KT=jnp.swapaxes(K, -1, -2),
-                QT=jnp.swapaxes(Q, -1, -2), V=V, P=P, scal=scal, dec=dec,
-                **system)
+    return dict(K=K, Q=Q, KT=jnp.swapaxes(K, -1, -2), V=V, Tm=Tm,
+                P=(dec_lo + eye) * qk, eg=jnp.exp(gam)[..., None],
+                d=jnp.exp(gam[..., -1:] - gam)[..., None],
+                bt=B[..., None], dec=jnp.exp(gam[..., -1]))
 
 
-def _chunk_math(K, Q, KT, V, Tm, P, scal, dec, S0):
-    """One chunk of one head: -> (O [C, dv], S_C [dk, dv]). `dec` is
-    e^{gamma_C}, a scalar."""
+def _chunk_math(K, Q, KT, V, Tm, P, eg, d, bt, dec, S0):
+    """One chunk of one head: -> (O [C, dv], S_C [dk, dv]). `eg`, `d`,
+    `bt` are columns `[C, 1]`; `dec` is e^{gamma_C}, a scalar."""
     dot = functools.partial(jnp.dot, precision=_HI,
                             preferred_element_type=jnp.float32)
-    eg, d, bt = scal[:, 0:1], scal[:, 1:2], scal[:, 2:3]
     U = dot(Tm, bt * (V - eg * dot(K, S0)))
     O = eg * dot(Q, S0) + dot(P, U)
     return O, dec * S0 + dot(KT, d * U)
@@ -190,7 +204,7 @@ def gated_delta_chunked(q, k, v, g, beta, runs, state, *, chunk=CHUNK,
     the kernel's oracle. Arguments and result as `gated_delta_ragged`."""
     T = q.shape[0]
     chunks = chunks or delta_chunks(runs, T, state.shape[0], chunk)
-    pre = _prologue(q, k, v, g, beta, chunks, solve=True)
+    pre = _prologue(q, k, v, g, beta, chunks)
     heads = jax.vmap(_chunk_math)
 
     def walk(carry, x):
@@ -205,7 +219,7 @@ def gated_delta_chunked(q, k, v, g, beta, runs, state, *, chunk=CHUNK,
         return (state, cur), O
 
     per_chunk = [jnp.moveaxis(pre[n], 1, 0) for n in
-                 ("K", "Q", "KT", "V", "Tm", "P", "scal", "dec")]
+                 ("K", "Q", "KT", "V", "Tm", "P", "eg", "d", "bt", "dec")]
     (state, _), O = jax.lax.scan(
         walk, (state, jnp.zeros_like(state[0])),
         (chunks["slot"], chunks["flags"], *per_chunk))
@@ -238,18 +252,84 @@ def gated_delta_scan(q, k, v, g, beta, runs, state):
     return o.astype(v.dtype), state
 
 
-def _head_group(H):
-    return next(n for n in (6, 5, 4, 3, 2, 1) if H % n == 0)
+def _head_group(H, head_bytes):
+    """Heads a grid step holds: the most that divide H and whose rows
+    of the whole step (`head_bytes` a head, in and out, each buffered
+    twice) leave VMEM room for the states and the chunk's arithmetic."""
+    return next((n for n in (6, 5, 4, 3, 2) if H % n == 0
+                 and n * head_bytes <= 48 * 2 ** 20), 1)
 
 
-def _kernel(slot_ref, flags_ref, dec_ref, k_ref, q_ref, kT_ref, qT_ref,
-            v_ref, aT_ref, p_ref, scal_ref, s_in, o_ref, s_out, s_scr,
-            tm_scr, *, HG, NC, C):
+def _lanes(n):
+    return -(-n // 128) * 128
+
+
+def _row_layout(dk, dv):
+    """One token's packed row of a head: q at lane 0, k, v, and behind
+    v its four scalars (gamma, e^gamma, e^{gamma_C - gamma}, beta), q,
+    k and v each from a multiple of 128 lanes. -> (k at, v at, scalars
+    at, width). At dk 96, dv 192: 128, 256, 448, 512, the lanes the
+    three arrays would fill apart."""
+    vat = 2 * _lanes(dk)
+    return _lanes(dk), vat, vat + dv, _lanes(vat + dv + 4)
+
+
+def rows_walked(length, chunk=CHUNK):
+    """Rows of q, k, v the kernel loads for a run of `length` tokens
+    (host arithmetic, for the engine's counters): a chunk of one token
+    its one row, a chunk of more its tile of `chunk` rows and the
+    `_ALIGN` that let the load start on a sublane tile; a chunk slot
+    that holds nothing loads none."""
+    full, rest = divmod(int(length), int(chunk))
+    return (full + (rest > 1)) * (int(chunk) + _ALIGN) + (rest == 1)
+
+
+def _token_scalars(g, beta, chunks, C):
+    """-> (`[T, H, 4]` float32: gamma, e^gamma, e^{gamma_C - gamma},
+    beta a token and head; `dec [H * NC]`: e^{gamma_C} a head and chunk
+    slot). gamma is the sum of g over the token's chunk up to it, by
+    doubling steps over the chunk's own rows: a token's scalars depend
+    on its chunk alone, whatever rides in the step."""
+    f32 = jnp.float32
+    gam, pos = g.astype(f32), chunks["pos"][:, None]
+    step = 1
+    while step < C:
+        gam = gam + jnp.where(pos >= step, jnp.roll(gam, step, axis=0), 0.0)
+        step *= 2
+    scal = jnp.stack([gam, jnp.exp(gam), jnp.exp(gam[chunks["last"]] - gam),
+                      beta.astype(f32)], axis=-1)
+    rows, end = chunks["rows"], chunks["t0"] + chunks["rows"] - 1
+    dec = jnp.where((rows > 0)[:, None],
+                    jnp.exp(gam[jnp.clip(end, 0, gam.shape[0] - 1)]), 1.0)
+    return scal, dec.T.reshape(-1)
+
+
+def _pack(q, k, v, scal, C):
+    """-> `[H, TP, width]` float32: every token's row (`_row_layout`)
+    head major, behind the step's T tokens the zero rows a tile that
+    starts at the last token still loads."""
+    T, H, dk = q.shape
+    kat, _, sat, width = _row_layout(dk, v.shape[-1])
+    f32 = jnp.float32
+
+    def to(x, lanes):
+        return jnp.pad(x.astype(f32),
+                       ((0, 0), (0, 0), (0, lanes - x.shape[-1])))
+
+    row = jnp.concatenate([to(q, kat), to(k, kat), v.astype(f32),
+                           to(scal, width - sat)], axis=-1)
+    more = -(-(T + C + _ALIGN) // _ALIGN) * _ALIGN - T
+    return jnp.moveaxis(jnp.pad(row, ((0, more), (0, 0), (0, 0))), 1, 0)
+
+
+def _kernel(slot_ref, flags_ref, t0_ref, rows_ref, dec_ref, x_ref, s_in,
+            o_ref, s_out, s_scr, row_scr, *, HG, NC, C, dk, dv):
     g0, c = pl.program_id(0) * HG, pl.program_id(1)
-    fl = flags_ref[c]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-           ).astype(jnp.float32)
+    fl, t0 = flags_ref[c], t0_ref[c]
+    kat, vat, _, _ = _row_layout(dk, dv)
+    f32 = jnp.float32
+    iota = lambda shape, dim: jax.lax.broadcasted_iota(     # noqa: E731
+        jnp.int32, shape, dim)
 
     @pl.when(((fl & _FIRST) != 0) & ((fl & _FRESH) == 0))
     def _():
@@ -261,38 +341,78 @@ def _kernel(slot_ref, flags_ref, dec_ref, k_ref, q_ref, kT_ref, qT_ref,
 
     @pl.when(((fl & _REAL) != 0) & ((fl & _SINGLE) == 0))
     def _():
+        # the chunk's rows lie at t0 .. t0 + n: load the C + _ALIGN rows
+        # from the sublane tile t0 is in, roll t0 to row 0
+        n = rows_ref[c]
+        base = pl.multiple_of(t0 // _ALIGN * _ALIGN, _ALIGN)
+        lead = t0 - base
+        back = jnp.where(lead == 0, 0, C + _ALIGN - lead)
+        r, i = iota((C, C), 0), iota((C, C), 1)
+        eye = (r == i).astype(f32)
+        low = (i < r) & (r < n)
+        live = iota((C, 1), 0) < n
+        tall = iota((C + _ALIGN, 1), 0)
+        mine = (tall >= lead) & (tall < lead + n)
+        nt = functools.partial(
+            jax.lax.dot_general, precision=_HI, preferred_element_type=f32,
+            dimension_numbers=(((1,), (1,)), ((), ())))
+
+        def tile(h, at, lanes):
+            rows = x_ref[h, pl.ds(base, C + _ALIGN), at:at + lanes]
+            return jnp.where(live, pltpu.roll(rows, back, 0)[:C], 0.0)
+
         def head(h, _):
-            # Tm = (I + A)^{-1} by forward substitution, a row a step:
-            # row r = e_r - sum_{i<r} A[r, i] row i. A is strictly
-            # lower, so the rows not yet made (still e_i) add nothing
-            tm_scr[...] = eye
-            for r in range(1, C):
-                tm_scr[r:r + 1, :] = eye[r:r + 1, :] - jnp.sum(
-                    aT_ref[h, 0, :, r:r + 1] * tm_scr[...], axis=0,
-                    keepdims=True)
-            O, S = _chunk_math(
-                k_ref[h, 0], q_ref[h, 0], kT_ref[h, 0], v_ref[h, 0],
-                tm_scr[...], p_ref[h, 0], scal_ref[h, 0],
-                dec_ref[(g0 + h) * NC + c], s_scr[h])
-            o_ref[h, 0] = O
+            Q, K = tile(h, 0, dk), tile(h, kat, dk)
+            vs = tile(h, vat, dv + 4)
+            V = vs[:, :dv]
+            gam, eg, d, bt = (vs[:, dv + j:dv + j + 1] for j in range(4))
+            # gamma along the lanes, then what `_prologue` makes: rows
+            # past the chunk's n tokens are zeros, their beta 0
+            dec_lo = jnp.exp(jnp.where(
+                low, gam - jnp.sum(eye * gam, axis=0, keepdims=True),
+                -jnp.inf))
+            A = bt * dec_lo * nt(K, K)
+            P = (dec_lo + eye) * nt(Q, K)
+            # Tm = (I + A)^{-1}, a column a step: once row j is final,
+            # every later row r takes off A[r, j] row j. A is strictly
+            # lower: a tile of 8 rows above row j has nothing to take
+            tm = [eye[b:b + 8] for b in range(0, C, 8)]
+            for j in range(C - 1):
+                row = tm[j // 8][j % 8:j % 8 + 1]
+                for b in range(j // 8, C // 8):
+                    tm[b] = tm[b] - A[8 * b:8 * b + 8, j:j + 1] * row
+            O, S = _chunk_math(K, Q, K.T, V, jnp.concatenate(tm), P, eg, d,
+                               bt, dec_ref[(g0 + h) * NC + c], s_scr[h])
             s_scr[h] = S
+            # o's rows the same way back: only this chunk's are written
+            out = pltpu.roll(jnp.concatenate(
+                [O, jnp.zeros((_ALIGN, dv), f32)]), lead, 0)
+            at = (h, pl.ds(base, C + _ALIGN))
+            o_ref[at] = jnp.where(mine, out, o_ref[at])
             return 0
 
         jax.lax.fori_loop(0, HG, head, 0)
 
     @pl.when((fl & _SINGLE) != 0)
     def _():
-        # one token at row 0: the recurrence itself, on the vector unit
+        # one token, one row at its flat token: the recurrence itself,
+        # on the vector unit. The row goes through `row_scr` (Mosaic
+        # loads ONE row at any dynamic start, and broadcasts only from
+        # a static one); k and q as columns: the row against the
+        # diagonal, summed along the lanes
+        diag = iota((dk, dk), 0) == iota((dk, dk), 1)
+        col = lambda x: jnp.sum(jnp.where(diag, x, 0.0),    # noqa: E731
+                                axis=1, keepdims=True)
         for h in range(HG):
-            S0 = s_scr[h]
-            kc, qc = kT_ref[h, 0, :, 0:1], qT_ref[h, 0, :, 0:1]
-            Sd = dec_ref[(g0 + h) * NC + c] * S0
-            u = scal_ref[h, 0, 0:1, 2:3] * (
-                v_ref[h, 0, 0:1, :]
-                - jnp.sum(Sd * kc, axis=0, keepdims=True))
+            row_scr[0:1] = x_ref[h, pl.ds(t0, 1)]
+            vs = row_scr[0:1, vat:vat + dv + 4]
+            kc, qc = col(row_scr[0:1, kat:kat + dk]), col(row_scr[0:1, :dk])
+            Sd = dec_ref[(g0 + h) * NC + c] * s_scr[h]
+            u = vs[:, dv + 3:dv + 4] * (
+                vs[:, :dv] - jnp.sum(Sd * kc, axis=0, keepdims=True))
             S1 = Sd + kc * u
             s_scr[h] = S1
-            o_ref[h, 0, 0:1, :] = jnp.sum(S1 * qc, axis=0, keepdims=True)
+            o_ref[h, pl.ds(t0, 1)] = jnp.sum(S1 * qc, axis=0, keepdims=True)
 
     @pl.when((fl & _LAST) != 0)
     def _():
@@ -313,41 +433,40 @@ def gated_delta_ragged(q, k, v, g, beta, runs, state, *, chunk=CHUNK,
                                    chunk=chunk, chunks=chunks)
     T, H, dk = q.shape
     dv = v.shape[-1]
-    S = state.shape[0]
     C = int(chunk)
-    chunks = chunks or delta_chunks(runs, T, S, C)
-    pre = _prologue(q, k, v, g, beta, chunks, solve=False)
-    NC = chunks["tok"].shape[0]
-    HG = _head_group(H)
+    chunks = chunks or delta_chunks(runs, T, state.shape[0], C)
+    scal, dec = _token_scalars(g, beta, chunks, C)
+    x = _pack(q, k, v, scal, C)
+    NC = chunks["slot"].shape[0]
+    TP, width = x.shape[1:]
+    head_bytes = 4 * 2 * TP * (width + _lanes(dv))
+    HG = _head_group(H, head_bytes)
 
-    def rows(d0, d1):
-        return pl.BlockSpec((HG, 1, d0, d1),
-                            lambda h, c, sl, fl, dec: (h, c, 0, 0))
+    def whole(lanes):           # a head group's rows of the whole step
+        return pl.BlockSpec((HG, TP, lanes), lambda h, c, *_: (h, 0, 0))
 
     st = pl.BlockSpec((1, HG, dk, dv),
-                      lambda h, c, sl, fl, dec: (sl[c], h, 0, 0))
-    pad = lambda n, m: -(-n // m) * m                       # noqa: E731
-    vmem = 4 * HG * (2 * (2 * C * pad(dk, 128) + 2 * pad(dk, 8) * 128
-                          + 2 * C * pad(dv, 128) + 3 * C * 128)
-                     + 5 * pad(dk, 8) * pad(dv, 128))
+                      lambda h, c, sl, *_: (sl[c], h, 0, 0))
+    vmem = HG * (head_bytes + 4 * 5 * -(-dk // 8) * 8 * _lanes(dv)) \
+        + 8 * 2 ** 20
     O, state = pl.pallas_call(
-        functools.partial(_kernel, HG=HG, NC=NC, C=C),
+        functools.partial(_kernel, HG=HG, NC=NC, C=C, dk=dk, dv=dv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(H // HG, NC),
-            in_specs=[rows(C, dk), rows(C, dk), rows(dk, C), rows(dk, C),
-                      rows(C, dv), rows(C, C), rows(C, C), rows(C, 3),
-                      st],
-            out_specs=[rows(C, dv), st],
+            num_scalar_prefetch=5, grid=(H // HG, NC),
+            in_specs=[whole(width), st],
+            out_specs=[whole(dv), st],
             scratch_shapes=[pltpu.VMEM((HG, dk, dv), jnp.float32),
-                            pltpu.VMEM((C, C), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((H, NC, C, dv), jnp.float32),
+                            pltpu.VMEM((8, width), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((H, TP, dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, jnp.float32)],
-        input_output_aliases={11: 1},
+        input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=int(min(100 * 2 ** 20,
-                                     max(32 * 2 ** 20, 2 * vmem)))),
+                                     max(32 * 2 ** 20, vmem)))),
         interpret=_INTERPRET, name="gated_delta",
-    )(chunks["slot"], chunks["flags"], pre["dec"].reshape(-1), pre["K"], pre["Q"], pre["KT"],
-      pre["QT"], pre["V"], pre["AT"], pre["P"], pre["scal"], state)
-    return _back(O, chunks, v.dtype), state
+    )(chunks["slot"], chunks["flags"], chunks["t0"], chunks["rows"], dec,
+      x, state)
+    o = jnp.moveaxis(O[:, :T], 0, 1)
+    return jnp.where(chunks["valid"][:, None, None], o, 0).astype(
+        v.dtype), state

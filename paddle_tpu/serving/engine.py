@@ -162,13 +162,20 @@ def _attention_work_by_kind(plan, window=None, causal_block=None):
 
 def _linear_work(plan, chunk):
     """The work of ONE linear (delta-rule) layer fed `plan`: valid
-    tokens, runs (a state read and written once each: one a slot fed)
-    and the chunks of `chunk` rows the kernel walks."""
-    groups = _plan_groups(plan)
-    return dict(lin_tokens=int(sum(n for _, n in groups)),
-                lin_runs=len(groups),
-                lin_chunks=int(sum(-(-n // chunk) for _, n in groups)),
-                lin_chunk_size=int(chunk))
+    tokens; runs (a state read and written once each: one a slot fed)
+    and those of them that hold ONE token (they take the recurrence on
+    their one row); the chunks of `chunk` rows the runs make (what
+    the traffic is under 64-row chunking, whatever the kernel does with
+    it) and the rows of q, k, v the kernel really loads for them
+    (`gated_delta.rows_walked`: 1 a one-token chunk, a tile a chunk of
+    more, nothing a chunk slot that stays empty)."""
+    from ..ops.pallas.gated_delta import rows_walked
+    lens = [n for _, n in _plan_groups(plan)]
+    return dict(lin_tokens=int(sum(lens)), lin_runs=len(lens),
+                lin_single_runs=sum(n == 1 for n in lens),
+                lin_chunks=int(sum(-(-n // chunk) for n in lens)),
+                lin_chunk_size=int(chunk),
+                lin_rows_walked=sum(rows_walked(n, chunk) for n in lens))
 
 
 #: the longest query run the paged kernel takes at once in the step of a
@@ -955,8 +962,12 @@ class ServingEngine:
         `conv`; the slot's new tail is the run's last inputs (a run
         shorter than the tail shifts it); (b) the delta rule over the
         runs from each slot's state (zero at position 0), the state
-        read and written once a run; (c) slots with no run this step
-        keep state and tail untouched, padding tokens change nothing."""
+        read and written once a run: `gated_delta_ragged` takes q, k, v
+        token major as the convolution leaves them and the step's chunk
+        tables (`delta_chunks`, made once for all the linear layers);
+        what it costs follows the chunks that hold tokens, a run of one
+        token its one row; (c) slots with no run this step keep state
+        and tail untouched, padding tokens change nothing."""
         import jax
         import jax.numpy as jnp
 

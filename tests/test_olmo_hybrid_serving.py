@@ -467,9 +467,12 @@ def test_flight_fields_of_a_hand_made_plan():
     # and a chunk of 8 from 0
     plan = Plan([(0, [5], 9)], [(1, np.arange(17), 32, False),
                               (2, np.arange(8), 0, True)], ())
+    # the kernel loads the decode run's one row, two tiles of 8 + the 8
+    # rows that align a tile's start and the 17th token's row, one tile
     assert _linear_work(plan, 8) == dict(
-        lin_tokens=26, lin_runs=3, lin_chunks=1 + 3 + 1,
-        lin_chunk_size=8)
+        lin_tokens=26, lin_runs=3, lin_single_runs=1,
+        lin_chunks=1 + 3 + 1, lin_chunk_size=8,
+        lin_rows_walked=1 + (2 * 16 + 1) + 16)
     assert _linear_work(plan, 64)["lin_chunks"] == 3
     work = _attention_work_by_kind(plan)
     assert work["kv_tokens_read_window"] == work["attn_pairs_window"] == 0
@@ -490,7 +493,7 @@ def test_flight_record_and_scopes():
         tracing.disable()
     recs = list(eng.flight.records)
     fields = ("lin_tokens", "lin_runs", "lin_chunks", "lin_chunk_size",
-              "state_slots_in_use", "kv_tokens_read_full",
+              "lin_single_runs", "lin_rows_walked", "state_slots_in_use", "kv_tokens_read_full",
               "attn_pairs_full", "kv_blocks_in_use_full",
               "kv_tokens_read_window", "attn_pairs_window",
               "kv_blocks_in_use_window")
@@ -500,6 +503,9 @@ def test_flight_record_and_scopes():
         assert r["lin_runs"] <= 3 and r["lin_chunk_size"] == 8
         assert r["lin_runs"] <= r["lin_chunks"] <= \
             r["lin_runs"] + r["lin_tokens"] // 8
+        assert r["lin_single_runs"] <= r["lin_runs"]
+        assert r["lin_tokens"] <= r["lin_rows_walked"] <= \
+            r["lin_single_runs"] + 16 * r["lin_chunks"]
         assert r["kv_tokens_read_window"] == r["attn_pairs_window"] == \
             r["kv_blocks_in_use_window"] == 0
         assert r["kv_blocks_in_use"] == r["kv_blocks_in_use_full"]

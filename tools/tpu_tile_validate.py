@@ -540,45 +540,67 @@ def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
     return cells
 
 
+def _delta_case(rng, lens, firsts, T, H, dk, dv, slots, dtype="float32",
+                gaps=None, slot_of=None):
+    """q, k, v, g, beta, runs, state of one step: run i of `lens[i]`
+    tokens from position `firsts[i]` in slot `slot_of[i]` (default i +
+    1: slot 0 stays idle), `gaps` padding tokens behind the runs they
+    name."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.paged_attention import paged_runs
+    slot_ids, pos = np.full(T, -1, np.int32), np.zeros(T, np.int32)
+    i = 0
+    for s, (n, f) in enumerate(zip(lens, firsts)):
+        slot_ids[i:i + n] = (s + 1) % slots if slot_of is None \
+            else slot_of[s]
+        pos[i:i + n] = f + np.arange(n)
+        i += n + (gaps or {}).get(s, 0)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa
+    q, k, v = (jnp.asarray(a, dtype) for a in (
+        unit(rng.randn(T, H, dk)) / np.sqrt(dk),
+        unit(rng.randn(T, H, dk)), rng.randn(T, H, dv)))
+    g = jnp.asarray(-np.exp(rng.uniform(-5, 0, (T, H))), jnp.float32)
+    beta = jnp.asarray(2 / (1 + np.exp(-rng.randn(T, H))), jnp.float32)
+    state = jnp.asarray(rng.randn(slots, H, dk, dv), jnp.float32)
+    return (q, k, v, g, beta,
+            paged_runs(jnp.asarray(slot_ids), jnp.asarray(pos), None),
+            state)
+
+
 def validate_gated_delta(*, H=6, dk=96, dv=192, slots=8, chunk=64,
                          lens=(1, 3, 63, 64, 65, 200),
-                         dtypes=("float32", "bfloat16")):
+                         dtypes=("float32", "bfloat16"),
+                         step=dict(H=30, T=512, slots=32)):
     """The ragged chunked delta-rule kernel (`gated_delta`) against the
     token-by-token recurrence: runs of uneven lengths in one call (a
     decode token, a run one short of a chunk, one of a chunk, one over,
     one of several chunks), a hole of padding tokens, incoming state on
     the runs that do not start at position 0, and a key dim of 96: not
     a multiple of the 128 lanes, padded inside the kernel and not in
-    the stored state."""
+    the stored state. Then, at the heads, slots and token budget of
+    `step` (the Olmo cell's): the mixes a serving step can hold (one
+    token a slot and nothing else; two tokens a slot, the most partial
+    chunks there can be; odd lengths, fresh and continued; no run at
+    all; a run that ends on the budget's last token), and one prompt
+    as one run = in chunks of 64 beside decode runs, bit for bit."""
     import numpy as np
 
     import jax
-    import jax.numpy as jnp
     from paddle_tpu.ops.pallas import gated_delta as gd
-    from paddle_tpu.ops.pallas.paged_attention import paged_runs
 
+    ragged = jax.jit(lambda *a: gd.gated_delta_ragged(*a, chunk=chunk))
+    scan = _exact(jax.jit(gd.gated_delta_scan))
     rng = np.random.RandomState(5)
     T = -(-(sum(lens) + 12) // 8) * 8
-    slot_ids, pos = np.full(T, -1, np.int32), np.zeros(T, np.int32)
-    i = 0
-    for s, n in enumerate(lens):
-        slot_ids[i:i + n] = s + 1
-        pos[i:i + n] = (0 if s % 2 == 0 else 5 + s) + np.arange(n)
-        i += n + (5 if s == 1 else 0)
-    runs = paged_runs(jnp.asarray(slot_ids), jnp.asarray(pos), None)
-    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa
-    g = jnp.asarray(-np.exp(rng.uniform(-5, 0, (T, H))), jnp.float32)
-    beta = jnp.asarray(2 / (1 + np.exp(-rng.randn(T, H))), jnp.float32)
-    state = jnp.asarray(rng.randn(slots, H, dk, dv), jnp.float32)
     cells = []
     for dtype in dtypes:
-        q, k, v = (jnp.asarray(a, dtype) for a in (
-            unit(rng.randn(T, H, dk)) / np.sqrt(dk),
-            unit(rng.randn(T, H, dk)), rng.randn(T, H, dv)))
-        want = _exact(jax.jit(gd.gated_delta_scan))(
-            q, k, v, g, beta, runs, state)
-        got = jax.jit(lambda *a: gd.gated_delta_ragged(
-            *a, chunk=chunk))(q, k, v, g, beta, runs, state)
+        case = _delta_case(
+            rng, lens, [0 if s % 2 == 0 else 5 + s for s in
+                        range(len(lens))], T, H, dk, dv, slots, dtype,
+            gaps={1: 5})
+        want, got = scan(*case), ragged(*case)
         tol = 2e-2 if dtype == "bfloat16" else 2e-4
         shape = f"{dtype} H={H} dk={dk} dv={dv} chunk={chunk} " \
             f"runs={list(lens)}"
@@ -586,6 +608,41 @@ def validate_gated_delta(*, H=6, dk=96, dv=192, slots=8, chunk=64,
                            tol, tol))
         cells.append(_cell(f"gated_delta state {shape}", got[1], want[1],
                            2e-4, 2e-4))
+    H, T, S = step["H"], step["T"], step["slots"]
+    odd = (1, 3, chunk, chunk + 1, 2 * chunk + 1)
+    mixes = {
+        f"{S} runs of 1": ([1] * S, [7 + s for s in range(S)]),
+        f"{S} runs of 2": ([2] * S, [s % 2 * 9 for s in range(S)]),
+        f"runs {list(odd)}": (odd, [0, 4, 0, chunk, 0]),
+        "no run": ((), ()),
+        f"a run to the last of {T} tokens": ((3, T - 3), (11, 0))}
+    for name, (ls, firsts) in mixes.items():
+        case = _delta_case(rng, ls, firsts, T, H, dk, dv, S)
+        want, got = scan(*case), ragged(*case)
+        shape = f"H={H} dk={dk} dv={dv} T={T} slots={S}: {name}"
+        for what, i in (("o", 0), ("state", 1)):
+            cells.append(_cell(f"gated_delta {what} {shape}", got[i],
+                               want[i], 2e-4, 2e-4))
+    # one prompt of 2 chunks + 1 token in slot 1: as ONE run, and chunk
+    # by chunk behind the decode tokens of 13 other slots (a start that
+    # is no multiple of 8), the state carried from step to step
+    N, d = 2 * chunk + 1, 13
+    whole = _delta_case(rng, (N,), (0,), T, H, dk, dv, S)
+    o_whole, s_whole = ragged(*whole)
+    state, o_parts = whole[6], []
+    for at, n in ((0, chunk), (chunk, chunk), (2 * chunk, 1)):
+        part = _delta_case(rng, [1] * d + [n], [3] * d + [at], T, H, dk,
+                           dv, S, slot_of=list(range(2, d + 2)) + [1])
+        fed = [x.at[d:d + n].set(w[at:at + n])
+               for x, w in zip(part[:5], whole[:5])]
+        o, state = ragged(*fed, part[5], state)
+        o_parts.append(o[d:d + n])
+    shape = f"H={H} T={T}: {N} tokens as one run = in chunks beside " \
+        f"{d} decode runs"
+    cells.append(_cell(f"gated_delta o {shape}", np.concatenate(o_parts),
+                       o_whole[:N], 0, 0))
+    cells.append(_cell(f"gated_delta state {shape}", state[1], s_whole[1],
+                       0, 0))
     return cells
 
 
@@ -743,8 +800,9 @@ def run_matrix(rehearse=False):
                 Dh=16, BS=8, max_run=16, N=45, blocks_tol=4e-3,
                 dtypes=("bfloat16",)) if rehearse else {}))
             + validate_ragged_expert_matmul()
-            + validate_gated_delta(**(dict(H=2, lens=(1, 3, 65))
-                                      if rehearse else {}))
+            + validate_gated_delta(**(dict(
+                H=2, lens=(1, 3, 65), step=dict(H=2, T=264, slots=16))
+                if rehearse else {}))
             + validate_add_ln()
             + validate_splash() + validate_flash()
             + validate_grouped_matmul())
