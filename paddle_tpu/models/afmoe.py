@@ -146,9 +146,11 @@ def embed(arch, w, token_ids):
     """h0 = embed[ids] * sqrt(hidden): [T] -> [T, D] in the compute
     dtype."""
     import jax.numpy as jnp
-    x = w["embed"][token_ids].astype(jnp.float32)
-    return (x * math.sqrt(arch.hidden_size)).astype(
-        jnp.dtype(arch.compute_dtype))
+    import jax
+    with jax.named_scope("embed"):
+        x = w["embed"][token_ids].astype(jnp.float32)
+        return (x * math.sqrt(arch.hidden_size)).astype(
+            jnp.dtype(arch.compute_dtype))
 
 
 def moe_ffn(arch, lw, x, valid):
@@ -179,26 +181,32 @@ def layer_forward(arch, li, lw, h, positions, valid, attend):
     T = h.shape[0]
     spec = arch.layers[li]
     Hq, Hkv, Dh = arch.num_heads, arch.num_kv_heads, arch.head_dim
-    x = _rms(h, lw["norm_in"], arch.eps)
-    q = _mm(x, lw["wq"]).reshape(T, Hq, Dh)
-    k = _mm(x, lw["wk"]).reshape(T, Hkv, Dh)
-    v = _mm(x, lw["wv"]).reshape(T, Hkv, Dh)
-    gate = _mm(x, lw["wg"])
-    q = _rms(q, lw["q_norm"], arch.eps)
-    k = _rms(k, lw["k_norm"], arch.eps)
-    if spec.attention == SLIDING:       # full layers carry no positions
-        q = _rope(q, positions, arch.rope_theta)
-        k = _rope(k, positions, arch.rope_theta)
+    # every operation under one scope of `serving.tracing.DEVICE_SCOPES`
+    with jax.named_scope("attn_qkv"):
+        x = _rms(h, lw["norm_in"], arch.eps)
+        q = _mm(x, lw["wq"]).reshape(T, Hq, Dh)
+        k = _mm(x, lw["wk"]).reshape(T, Hkv, Dh)
+        v = _mm(x, lw["wv"]).reshape(T, Hkv, Dh)
+        gate = _mm(x, lw["wg"])
+        q = _rms(q, lw["q_norm"], arch.eps)
+        k = _rms(k, lw["k_norm"], arch.eps)
+        if spec.attention == SLIDING:   # full layers carry no positions
+            q = _rope(q, positions, arch.rope_theta)
+            k = _rope(k, positions, arch.rope_theta)
     a = attend(q, k, v, li).reshape(T, Hq * Dh)
-    a = _mm(a * jax.nn.sigmoid(gate), lw["wo"])
-    h = h + _rms(a, lw["norm_post_attn"], arch.eps)
-    x = _rms(h, lw["norm_pre_mlp"], arch.eps)
+    with jax.named_scope("attn_out"):
+        a = _mm(a * jax.nn.sigmoid(gate), lw["wo"])
+        h = h + _rms(a, lw["norm_post_attn"], arch.eps)
     stats = None
-    if spec.ffn == DENSE:
-        m = _swiglu(x, lw["w_gate"], lw["w_up"], lw["w_down"])
-    else:
-        m, stats = moe_ffn(arch, lw, x, valid)
-    return h + _rms(m, lw["norm_post_mlp"], arch.eps), stats
+    # the norms either side of the FFN count with `mlp`; an expert
+    # layer's router, experts and shared expert keep their own names
+    with jax.named_scope("mlp"):
+        x = _rms(h, lw["norm_pre_mlp"], arch.eps)
+        if spec.ffn == DENSE:
+            m = _swiglu(x, lw["w_gate"], lw["w_up"], lw["w_down"])
+        else:
+            m, stats = moe_ffn(arch, lw, x, valid)
+        return h + _rms(m, lw["norm_post_mlp"], arch.eps), stats
 
 
 #: the expert layers' counters of a step, under their flight-record names
@@ -218,10 +226,12 @@ def fold_stats(acc, st):
 
 def head(arch, w, h):
     """logits over the held vocabulary rows: norm_f(h) W_head."""
+    import jax
     import jax.numpy as jnp
-    x = _rms(h, w["norm_f"], arch.eps)
-    return jnp.dot(x, w["head"].astype(x.dtype),
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms(h, w["norm_f"], arch.eps)
+        return jnp.dot(x, w["head"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
 
 
 def dense_attend(arch, positions):

@@ -121,8 +121,10 @@ def embed(arch, w, token_ids):
     """h0 = embed[ids], unscaled: [T] -> [T, D] float32 (the residual
     stream and everything between two products stay float32; the
     products take their operands in the compute dtype)."""
+    import jax
     import jax.numpy as jnp
-    return w["embed"][token_ids].astype(jnp.float32)
+    with jax.named_scope("embed"):
+        return w["embed"][token_ids].astype(jnp.float32)
 
 
 def short_conv(arch, conv_w, windows):
@@ -173,15 +175,20 @@ def linear_mixer(arch, li, lw, x, recur):
 
 
 def full_mixer(arch, li, lw, x, attend):
+    import jax
     T = x.shape[0]
     Hh, Dh = arch.num_heads, arch.head_dim
     # the norm runs over all the heads' dims at once, before the split
     cd = lw["wq"].dtype     # attention and its cache: the compute dtype
-    q = _rms(_mm(x, lw["wq"]), lw["q_norm"], arch.eps).reshape(T, Hh, Dh)
-    k = _rms(_mm(x, lw["wk"]), lw["k_norm"], arch.eps).reshape(T, Hh, Dh)
-    v = _mm(x, lw["wv"]).reshape(T, Hh, Dh)
-    a = attend(q.astype(cd), k.astype(cd), v.astype(cd), li)
-    return _mm(a.reshape(T, Hh * Dh), lw["wo"])
+    with jax.named_scope("attn_qkv"):
+        q = _rms(_mm(x, lw["wq"]), lw["q_norm"],
+                 arch.eps).reshape(T, Hh, Dh).astype(cd)
+        k = _rms(_mm(x, lw["wk"]), lw["k_norm"],
+                 arch.eps).reshape(T, Hh, Dh).astype(cd)
+        v = _mm(x, lw["wv"]).reshape(T, Hh, Dh).astype(cd)
+    a = attend(q, k, v, li)
+    with jax.named_scope("attn_out"):
+        return _mm(a.reshape(T, Hh * Dh), lw["wo"])
 
 
 def layer_forward(arch, li, lw, h, positions, valid, attend, recur):
@@ -193,16 +200,22 @@ def layer_forward(arch, li, lw, h, positions, valid, attend, recur):
         a = linear_mixer(arch, li, lw, h, recur)
     else:
         a = full_mixer(arch, li, lw, h, attend)
-    h = h + _rms(a, lw["norm_attn"], arch.eps)
-    m = _mm(jax.nn.silu(_mm(h, lw["w_gate"])) * _mm(h, lw["w_up"]),
-            lw["w_down"])
-    return h + _rms(m, lw["norm_mlp"], arch.eps), None
+    # a mixer's output norm and residual count with what made the
+    # output: `lin_gate_out` or `attn_out`
+    with jax.named_scope("lin_gate_out" if arch.layer_kinds[li] == LINEAR
+                         else "attn_out"):
+        h = h + _rms(a, lw["norm_attn"], arch.eps)
+    with jax.named_scope("mlp"):
+        m = _mm(jax.nn.silu(_mm(h, lw["w_gate"])) * _mm(h, lw["w_up"]),
+                lw["w_down"])
+        return h + _rms(m, lw["norm_mlp"], arch.eps), None
 
 
 def head(arch, w, h):
     """logits = norm_f(h) W_head (untied), float32."""
-    import jax.numpy as jnp
-    return _mm(_rms(h, w["norm_f"], arch.eps), w["head"])
+    import jax
+    with jax.named_scope("head"):
+        return _mm(_rms(h, w["norm_f"], arch.eps), w["head"])
 
 
 def dense_attend(arch, positions):

@@ -100,8 +100,11 @@ def arch_from_config(cfg, *, generation, max_positions=None,
 
 
 def embed(arch, w, token_ids):
+    import jax
     import jax.numpy as jnp
-    return w["embed"][token_ids].astype(jnp.dtype(arch.compute_dtype))
+    with jax.named_scope("embed"):
+        return w["embed"][token_ids].astype(
+            jnp.dtype(arch.compute_dtype))
 
 
 def moe_ffn(arch, lw, x, valid):
@@ -122,24 +125,37 @@ def layer_forward(arch, li, lw, h, positions, valid, attend):
     step's k, v [T, Hkv, Dh] and returns the BLOCK-CAUSAL attention
     output [T, Hq, Dh] of layer `li`: the caller owns the cache and the
     mask. Returns (h, MoE stats)."""
+    import jax
     T = h.shape[0]
     Hq, Hkv, Dh = arch.num_heads, arch.num_kv_heads, arch.head_dim
-    x = _rms(h, lw["norm_attn"], arch.eps)
-    q = _rms(_mm(x, lw["wq"]).reshape(T, Hq, Dh), lw["q_norm"], arch.eps)
-    k = _rms(_mm(x, lw["wk"]).reshape(T, Hkv, Dh), lw["k_norm"], arch.eps)
-    v = _mm(x, lw["wv"]).reshape(T, Hkv, Dh)
-    q = _rope(q, positions, arch.rope_theta)
-    k = _rope(k, positions, arch.rope_theta)
-    h = h + _mm(attend(q, k, v, li).reshape(T, Hq * Dh), lw["wo"])
-    m, stats = moe_ffn(arch, lw, _rms(h, lw["norm_mlp"], arch.eps), valid)
-    return h + m, stats
+    # every operation under one scope of `serving.tracing.DEVICE_SCOPES`
+    with jax.named_scope("attn_qkv"):
+        x = _rms(h, lw["norm_attn"], arch.eps)
+        q = _rms(_mm(x, lw["wq"]).reshape(T, Hq, Dh), lw["q_norm"],
+                 arch.eps)
+        k = _rms(_mm(x, lw["wk"]).reshape(T, Hkv, Dh), lw["k_norm"],
+                 arch.eps)
+        v = _mm(x, lw["wv"]).reshape(T, Hkv, Dh)
+        q = _rope(q, positions, arch.rope_theta)
+        k = _rope(k, positions, arch.rope_theta)
+    a = attend(q, k, v, li)
+    with jax.named_scope("attn_out"):
+        h = h + _mm(a.reshape(T, Hq * Dh), lw["wo"])
+    # the expert layer's norm and residual count with `mlp`; router and
+    # experts keep their own names
+    with jax.named_scope("mlp"):
+        m, stats = moe_ffn(arch, lw, _rms(h, lw["norm_mlp"], arch.eps),
+                           valid)
+        return h + m, stats
 
 
 def head(arch, w, h):
+    import jax
     import jax.numpy as jnp
-    x = _rms(h, w["norm_f"], arch.eps)
-    return jnp.dot(x, w["head"].astype(x.dtype),
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms(h, w["norm_f"], arch.eps)
+        return jnp.dot(x, w["head"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
 
 
 def dense_attend(arch, positions):

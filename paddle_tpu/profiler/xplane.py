@@ -13,6 +13,72 @@ from __future__ import annotations
 import collections
 import glob
 import os
+import re
+
+_HLO_INSTRUCTION = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$')
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r'(?:calls|to_apply|body)=%?([\w.\-]+)')
+_HLO_COMPUTATION = re.compile(r'^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$')
+_HLO_NAME = re.compile(r'%([\w.\-]+)')
+
+
+def hlo_op_names(text):
+    """{instruction name: its `op_name`} of an HLO module's text
+    (`compiled.as_text()`). A v5e trace names a device event after its
+    instruction and carries no `op_name`; the compiled module's
+    metadata does, and with it the `jax.named_scope`s the operation was
+    traced under."""
+    out = {}
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        name = m and _HLO_OP_NAME.search(m.group(2))
+        if name:
+            out[m.group(1)] = name.group(1)
+    return out
+
+
+def hlo_op_scopes(text, scope_of, none):
+    """{instruction name: scope} of an HLO module's text: `scope_of(its
+    op_name)` (`none` where that names no scope). An instruction the
+    COMPILER made (a layout copy, a rewritten reduction: no `op_name`
+    at all, so no `jax.named_scope` could reach it) takes the first
+    scope among the instructions of the computation it calls, else of
+    the first scoped instruction that uses it: it exists for their
+    sake. One JAX emitted outside every scope stays `none`."""
+    scopes, made, calls, inside, users = {}, [], {}, {}, {}
+    computation = None
+    for line in text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        inside.setdefault(computation, []).append(name)
+        op_name = _HLO_OP_NAME.search(rest)
+        scopes[name] = scope_of(op_name.group(1)) if op_name else none
+        if not op_name:
+            made.append(name)
+        called = _HLO_CALLS.search(rest)
+        if called:
+            calls[name] = called.group(1)
+        for operand in _HLO_NAME.findall(rest.split(", metadata=")[0]):
+            users.setdefault(operand, []).append(name)
+    for _ in range(4):              # through a short chain of such
+        left = []
+        for name in made:
+            near = inside.get(calls.get(name), []) + users.get(name, [])
+            scope = next((scopes[n] for n in near
+                          if scopes.get(n, none) != none), none)
+            if scope == none:
+                left.append(name)
+            scopes[name] = scope
+        if len(left) == len(made):
+            break
+        made = left
+    return scopes
 
 
 def load_xplane(trace_dir):

@@ -700,12 +700,15 @@ class ServingEngine:
         # record per step, noted only while tracing is enabled;
         # registered so profiler chrome export / summary() merge it
         self.flight = _tracing.StepFlightRecorder(self.name, self.role)
-        _tracing.register_flight_recorder(self.flight)
+        _tracing.register_flight_recorder(self.flight,
+                                          self.step_op_scopes)
+        self._op_scopes = None           # `step_op_scopes()`, kept
         # where the host's time goes (tracing.HOST_PHASES): marked by
         # step() and by the frontend's step loop, only while tracing
         # is enabled; `_step_end` is when the last traced step ended
         self.phases = _tracing.PhaseMarker(self.clock)
         self._step_end = None
+        self._trace_self = 0.0
 
     def _flight_extra(self):
         """Extra per-step flight-recorder fields; TPServingEngine
@@ -882,33 +885,41 @@ class ServingEngine:
                 "inside a block")
 
         def step(weights, *rest):
+            # every operation under one scope of `tracing.DEVICE_SCOPES`
+            # (the block's own functions set theirs): HLO metadata only
             pools = list(rest[:n_pools])
             plan, key = rest[n_pools:]
-            f = layout.unpack(plan)
-            token_ids, slot_ids, positions, sample_index = (
-                f["token_ids"], f["slot_ids"], f["positions"],
-                f["sample_index"])
-            key, rng = jax.random.split(key)
-            valid = slot_ids >= 0
-            pos = jnp.where(valid, positions, 0)
-            safe_slot = jnp.where(valid, slot_ids, 0)
-            tables = {"full": f["block_tables"]}
-            if "window_tables" in f:
-                tables["sliding"] = f["window_tables"]
-            # padding tokens write into the reserved NULL block
-            wb = {k: jnp.where(valid, t[safe_slot, pos // BS], 0)
-                  for k, t in tables.items()}
-            wo = pos % BS
-            runs = paged_runs(slot_ids, pos, max_run)
+            with jax.named_scope("plan_unpack"):
+                f = layout.unpack(plan)
+                token_ids, slot_ids, positions, sample_index = (
+                    f["token_ids"], f["slot_ids"], f["positions"],
+                    f["sample_index"])
+                valid = slot_ids >= 0
+                pos = jnp.where(valid, positions, 0)
+                safe_slot = jnp.where(valid, slot_ids, 0)
+                tables = {"full": f["block_tables"]}
+                if "window_tables" in f:
+                    tables["sliding"] = f["window_tables"]
+                # padding tokens write into the reserved NULL block
+                wb = {k: jnp.where(valid, t[safe_slot, pos // BS], 0)
+                      for k, t in tables.items()}
+                wo = pos % BS
+                runs = paged_runs(slot_ids, pos, max_run)
+                ids = jnp.where(valid, token_ids, 0)
+                rows_at = jnp.clip(sample_index.reshape(-1), 0, T - 1)
+            with jax.named_scope("sample"):
+                key, rng = jax.random.split(key)
 
             def attend(q, k, v, li):
                 kind = kinds[li]
                 kp, vp = pools[at[li]], pools[at[li] + 1]
-                if more_heads:
-                    q, k, v = (jnp.pad(a, ((0, 0), (0, more_heads),
-                                           (0, 0))) for a in (q, k, v))
-                kp = kp.at[wb[kind], wo].set(k.astype(kp.dtype))
-                vp = vp.at[wb[kind], wo].set(v.astype(vp.dtype))
+                with jax.named_scope("kv_write"):
+                    if more_heads:
+                        q, k, v = (jnp.pad(a, ((0, 0), (0, more_heads),
+                                               (0, 0)))
+                                   for a in (q, k, v))
+                    kp = kp.at[wb[kind], wo].set(k.astype(kp.dtype))
+                    vp = vp.at[wb[kind], wo].set(v.astype(vp.dtype))
                 pools[at[li]], pools[at[li] + 1] = kp, vp
                 sliding = kind == "sliding"
                 with jax.named_scope(
@@ -918,23 +929,25 @@ class ServingEngine:
                         runs=runs, max_run=max_run,
                         window=arch.window if sliding else None,
                         causal_block=causal_block)
-                return o[:, :arch.num_heads] if more_heads else o
+                    return o[:, :arch.num_heads] if more_heads else o
 
             extra = ()
             if linear:
                 extra = (self._recur(pools, at, slot_ids, pos),)
-            h = block.embed(arch, weights,
-                            jnp.where(valid, token_ids, 0))
+            h = block.embed(arch, weights, ids)
             # one array: one readback
             stats = jnp.zeros((len(block.stat_names),), jnp.int32)
             for li, lw in enumerate(weights["layers"]):
                 h, st = block.layer(arch, li, lw, h, pos, valid, attend,
                                     *extra)
                 if st is not None:
-                    stats = block.fold_stats(stats, st)
-            rows = h[jnp.clip(sample_index.reshape(-1), 0, T - 1)]
-            logits = block.head(arch, weights, rows).astype(jnp.float32)
-            tok = select_token(logits, rng, sc)
+                    with jax.named_scope("moe_experts"):
+                        stats = block.fold_stats(stats, st)
+            with jax.named_scope("head"):
+                logits = block.head(arch, weights, h[rows_at]).astype(
+                    jnp.float32)
+            with jax.named_scope("sample"):
+                tok = select_token(logits, rng, sc)
             if diff:
                 with jax.named_scope("diffusion_confidence"):
                     # the candidate's softmax probability, float32
@@ -945,7 +958,7 @@ class ServingEngine:
                         [tok, jax.lax.bitcast_convert_type(
                             conf, jnp.int32)]).reshape(
                         2, *sample_index.shape).swapaxes(0, 1)
-                logits = logits.reshape(*sample_index.shape, -1)
+                    logits = logits.reshape(*sample_index.shape, -1)
             return (tok, *pools, stats, logits, key)
 
         return step
@@ -976,30 +989,32 @@ class ServingEngine:
         arch = self._block.arch
         T, S, W = self.token_budget, self.kv.max_slots, arch.conv_width
         R = min(S, T)                       # one run a slot
-        runs = paged_runs(slot_ids, pos, None)
-        chunks = gd.delta_chunks(runs, T, S, arch.delta_chunk)
-        _, start, length, rslot, first = runs
-        r, valid, off, fresh = gd.token_runs(runs, T)
-        slot = jnp.clip(rslot[r], 0, S - 1)
-        t = jnp.arange(T, dtype=jnp.int32)
-        ZERO = T + S * (W - 1)              # the row of zeros
-        # a token's input `back` positions earlier: in its own run, or
-        # in the slot's tail (rows oldest first), or before the sequence
-        src = [jnp.where(
-            ~valid | ((off < back) & fresh), ZERO,
-            jnp.where(off >= back, t - back,
-                      T + slot * (W - 1) + (W - 1) + off - back))
-            for back in range(W - 1, 0, -1)]
-        # the last W - 1 inputs of each run, the same three ways
-        i = jnp.arange(W - 1, dtype=jnp.int32)[None, :]
-        o_run = (length[:R, None] - (W - 1)) + i        # offset in the run
-        live = (jnp.arange(R) < runs[0][0])[:, None]
-        rs = jnp.clip(rslot[:R], 0, S - 1)[:, None]
-        new_src = jnp.where(
-            ~live | ((o_run < 0) & (first[:R, None] == 0)), ZERO,
-            jnp.where(o_run >= 0, start[:R, None] + o_run,
-                      T + rs * (W - 1) + (W - 1) + o_run))
-        to_slot = jnp.where(live[:, 0], rs[:, 0], S)     # S: dropped
+        with jax.named_scope("plan_unpack"):
+            runs = paged_runs(slot_ids, pos, None)
+            chunks = gd.delta_chunks(runs, T, S, arch.delta_chunk)
+            _, start, length, rslot, first = runs
+            r, valid, off, fresh = gd.token_runs(runs, T)
+            slot = jnp.clip(rslot[r], 0, S - 1)
+            t = jnp.arange(T, dtype=jnp.int32)
+            ZERO = T + S * (W - 1)              # the row of zeros
+            # a token's input `back` positions earlier: in its own run,
+            # or in the slot's tail (rows oldest first), or before the
+            # sequence
+            src = [jnp.where(
+                ~valid | ((off < back) & fresh), ZERO,
+                jnp.where(off >= back, t - back,
+                          T + slot * (W - 1) + (W - 1) + off - back))
+                for back in range(W - 1, 0, -1)]
+            # the last W - 1 inputs of each run, the same three ways
+            i = jnp.arange(W - 1, dtype=jnp.int32)[None, :]
+            o_run = (length[:R, None] - (W - 1)) + i   # offset in the run
+            live = (jnp.arange(R) < runs[0][0])[:, None]
+            rs = jnp.clip(rslot[:R], 0, S - 1)[:, None]
+            new_src = jnp.where(
+                ~live | ((o_run < 0) & (first[:R, None] == 0)), ZERO,
+                jnp.where(o_run >= 0, start[:R, None] + o_run,
+                          T + rs * (W - 1) + (W - 1) + o_run))
+            to_slot = jnp.where(live[:, 0], rs[:, 0], S)     # S: dropped
 
         def recur(x, g, beta, li, conv):
             state, tail = pools[at[li]], pools[at[li] + 1]
@@ -1157,234 +1172,11 @@ class ServingEngine:
             pos_c = (cnt * BS + pos_r % BS).astype(jnp.int32)
             return short_bt, pos_c
 
-        def step(arrays, k_pool, v_pool, *rest):
-            # static signature variants (one compile each way):
-            # quantized pools add (k_scale, v_scale) after the pools
-            # and summary-tracking pools (k_sum_min, k_sum_max) after
-            # those — the kv_cache._pools() order; adapter slot
-            # tensors follow them; then the packed plan (`plan_layout`:
-            # flat tokens, sample index, block table, per-token adapter
-            # ids), sliced ONCE here, before the layer scan; active
-            # logit processors add the [S, Vb] token-count histogram
-            # before the key (ISSUE 19: the count form replaces the
-            # [S, W] history tensor so the multi-tick loop can advance
-            # it per accepted token). The key is the carried CHAIN: the
-            # step splits it and returns the advanced chain last
-            rest = list(rest)
-            k_scale = v_scale = counts = None
-            k_sum_min = k_sum_max = None
-            if quant:
-                k_scale, v_scale = rest[:2]
-                rest = rest[2:]
-            if track:
-                k_sum_min, k_sum_max = rest[:2]
-                rest = rest[2:]
-            ad_arrays = ()
-            if lora:
-                ad_arrays = rest[:len(ad_names)]
-                rest = rest[len(ad_names):]
-            f = layout.unpack(rest.pop(0))
-            token_ids, slot_ids, positions, sample_index = (
-                f["token_ids"], f["slot_ids"], f["positions"],
-                f["sample_index"])
-            block_tables = f["block_tables"]
-            adapter_ids = f["adapter_ids"] if lora else None
-            if use_hist:
-                counts = rest.pop(0)
-            (key,) = rest
-            key, rng = jax.random.split(key)
-            n_dec = len(names)
-            we, pe = arrays[0], arrays[1]
-            dec_arrays = arrays[2:2 + n_dec]
-            lnw, lnb, head = arrays[-3], arrays[-2], arrays[-1]
-            params = dict(zip(names, dec_arrays))
-            if lora:
-                # the [L, K, ...] slot tensors join the scanned params
-                # so each layer's xs slice carries its own adapter
-                # rows; ONE [T, K] one-hot feeds every layer's deltas
-                params.update(dict(zip(ad_names, ad_arrays)))
-                lora_oh = jax.nn.one_hot(adapter_ids, K_ad,
-                                         dtype=jnp.float32)
-            else:
-                lora_oh = None
-            valid = slot_ids >= 0
-            pos = jnp.where(valid, positions, 0)
-            x = model._embed(we, pe, token_ids, pos)          # [T, D]
-            safe_slot = jnp.where(valid, slot_ids, 0)
-            # padding tokens write into the reserved NULL block
-            wb = jnp.where(valid, block_tables[safe_slot, pos // BS], 0)
-            wo = pos % BS
-            # the query runs the ragged kernel walks (a decode token a
-            # run of 1, a prefill chunk one run): the same for every
-            # layer, so derived here, once a step
-            r0 = R if region_on else 0
-            runs = paged_runs(slot_ids[r0:], pos[r0:])
-
-            def layer(carry, xs):
-                at = 3
-                h, kp, vp = carry[:3]
-                ksc = vsc = smin = smax = None
-                if quant:
-                    ksc, vsc = carry[at:at + 2]
-                    at += 2
-                if track:
-                    smin, smax = carry[at:at + 2]
-                    at += 2
-                ms = carry[-1] if moe else None
-                pl, li = xs
-                hn = _ln(h, pl["ln_s"], pl["ln_b"], cfg.epsilon)
-                q, k, v = _qkv(cfg, pl, hn[None], lora_oh=lora_oh)
-                q, k, v = q[0], k[0], v[0]                  # [T, H, Dh]
-                if quant:
-                    # quantize-on-append: int8/fp8 payload + per-entry
-                    # scales land at the same (block, offset) coords
-                    kq, ks_new = quantize(k)
-                    vq, vs_new = quantize(v)
-                    kp = kp.at[li, wb, wo].set(kq)
-                    vp = vp.at[li, wb, wo].set(vq)
-                    ksc = ksc.at[li, wb, wo].set(ks_new)
-                    vsc = vsc.at[li, wb, wo].set(vs_new)
-                else:
-                    kp = kp.at[li, wb, wo].set(k.astype(kp.dtype))
-                    vp = vp.at[li, wb, wo].set(v.astype(vp.dtype))
-                if track:
-                    # summary update on append: the offset-0 write of
-                    # a block RESETS its row first (non-first tokens
-                    # aim the reset at the NULL row), then one
-                    # scatter-min/max folds every appended key in —
-                    # well-defined even when one prefill chunk writes
-                    # many entries of the same block, and a freed-
-                    # then-reused block can never leak its previous
-                    # owner's statistics
-                    ksf = k.astype(jnp.float32)
-                    rb = jnp.where(valid & (wo == 0), wb, 0)
-                    smin = smin.at[li, rb].set(SUMMARY_INIT)
-                    smax = smax.at[li, rb].set(-SUMMARY_INIT)
-                    wbs = jnp.where(valid, wb, 0)
-                    smin = smin.at[li, wbs].min(ksf)
-                    smax = smax.at[li, wbs].max(ksf)
-                if sparse:
-                    # region queries attend the SHORTENED tables: the
-                    # kernels read Bt blocks per slot instead of the
-                    # whole context, and the compacted positions keep
-                    # the causal mask exact; prefill chunks (whose
-                    # queries sit mid-prompt) keep the dense path
-                    q_r = q[:R].reshape(S, K, cfg.num_heads,
-                                        cfg.head_dim)
-                    pos_r = pos[:R].reshape(S, K)
-                    short_bt, pos_c = select_blocks(
-                        q_r, pos_r, block_tables, smin, smax, li)
-                    if K == 1:
-                        ar = ragged_paged_attention(
-                            q[:R], kp, vp, short_bt,
-                            slot_ids[:R], pos_c[:, 0], ksc, vsc,
-                            kernel_name="paged_sparse", layer=li)
-                    else:
-                        ar = verify_paged_attention(
-                            q_r, kp, vp, short_bt,
-                            jnp.arange(S, dtype=jnp.int32), pos_c,
-                            ksc, vsc, kernel_name="paged_sparse",
-                            layer=li).reshape(
-                            R, cfg.num_heads, cfg.head_dim)
-                    ap = ragged_paged_attention(
-                        q[R:], kp, vp, block_tables,
-                        slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
-                        layer=li)
-                    attn = jnp.concatenate(
-                        [ar.reshape(R, cfg.num_heads, cfg.head_dim),
-                         ap], axis=0)
-                elif K == 1:
-                    attn = ragged_paged_attention(
-                        q, kp, vp, block_tables, slot_ids, pos,
-                        ksc, vsc, runs=runs, layer=li)
-                else:
-                    # the fixed verify region (slot s owns flat tokens
-                    # [s*K, (s+1)*K)) runs through the verify-shaped
-                    # entry — ONE block-table gather per slot instead of
-                    # one per flat token; prefill chunks keep the
-                    # flat-token ragged path
-                    qv = q[:R].reshape(S, K, cfg.num_heads, cfg.head_dim)
-                    av = verify_paged_attention(
-                        qv, kp, vp, block_tables,
-                        jnp.arange(S, dtype=jnp.int32),
-                        pos[:R].reshape(S, K), ksc, vsc, layer=li)
-                    ap = ragged_paged_attention(
-                        q[R:], kp, vp, block_tables,
-                        slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
-                        layer=li)
-                    attn = jnp.concatenate(
-                        [av.reshape(R, cfg.num_heads, cfg.head_dim),
-                         ap], axis=0)
-                attn = attn.reshape(T, cfg.num_heads * cfg.head_dim)
-                out = _mm(cfg, attn, pl["out_w"], pl.get("out_s"))
-                if lora_oh is not None:
-                    # row-parallel LoRA: A holds this shard's head
-                    # slice of the in axis, so the delta is a partial
-                    # product that joins the psum right below
-                    out = out + _lora_delta(attn, pl["lora_out_a"],
-                                            pl["lora_out_b"], lora_oh)
-                # row-parallel reduction under TP (no-op when
-                # cfg.mp_axis is None): each shard holds the partial
-                # product of its own head slice; _ffn_dense below does
-                # the same for its row-parallel ffn2
-                out = _maybe_psum(cfg, out)
-                out = out + pl["out_b"].astype(out.dtype)
-                h = h + out
-                hn = _ln(h, pl["ffn_ln_s"], pl["ffn_ln_b"], cfg.epsilon)
-                if moe:
-                    # per-token top-k routing into fixed capacity slots
-                    # (padding tokens masked out by `valid`); overflow
-                    # rides the residual — shapes never change, so the
-                    # one-compile rule holds with MoE exactly as dense
-                    f, st = _ffn_moe_tokens(cfg, pl, hn, valid)
-                    h = h + f
-                    ms = jax.tree.map(jnp.add, ms, st)
-                else:
-                    h = h + _ffn_dense(cfg, pl, hn, lora_oh=lora_oh)
-                new_carry = (h, kp, vp)
-                if quant:
-                    new_carry += (ksc, vsc)
-                if track:
-                    new_carry += (smin, smax)
-                if moe:
-                    new_carry += (ms,)
-                return new_carry, None
-
-            carry0 = (x, k_pool, v_pool)
-            if quant:
-                carry0 += (k_scale, v_scale)
-            if track:
-                carry0 += (k_sum_min, k_sum_max)
-            if moe:
-                carry0 += ({"counts": jnp.zeros((cfg.num_experts,),
-                                                jnp.float32),
-                            "dropped": jnp.zeros((), jnp.float32),
-                            "aux": jnp.zeros((), jnp.float32)},)
-            carry, _ = jax.lax.scan(layer, carry0,
-                                    (params, jnp.arange(L)))
-            moe_stats = carry[-1] if moe else None
-            if moe:
-                # aux reported as the per-layer mean balance loss
-                moe_stats = dict(moe_stats,
-                                 aux=moe_stats["aux"] / float(L))
-            n_pool = 2 + (2 if quant else 0) + (2 if track else 0)
-            x = carry[0]
-            pools = tuple(carry[1:1 + n_pool])
-            if moe:
-                pools += (moe_stats,)
-            xf = _ln(x, lnw, lnb, cfg.epsilon)
-            sidx = jnp.clip(sample_index, 0, T - 1)
-            h_last = xf[sidx]                          # [max_slots, D]
-            logits = jnp.matmul(h_last, head.astype(h_last.dtype))
-            if spec_sampling:
-                rng, rng_u, rng_res, rng_bonus = jax.random.split(
-                    rng, 4)
-            tok = select_token(logits, rng, sc, counts=counts)
-            if K == 1:
-                return (tok,) + pools + (key,)
-            hv = xf[:R].reshape(S, K, -1)
-            logits_v = jnp.matmul(hv, head.astype(hv.dtype))
-            lv = logits_v.astype(jnp.float32)
+        def verify_tokens(lv, tok, pools, key, token_ids, counts, rngs):
+            """The step's outputs with a verify region: `lv` [S, K, V]
+            the float32 logits of every verify position, `tok` the
+            sample rows' tokens, `rngs` the three keys of rejection
+            sampling (None for greedy verification)."""
             fed = token_ids[:R].reshape(S, K)
             if use_hist:
                 # per-position count PRIORS (ISSUE 19): verify
@@ -1427,17 +1219,284 @@ class ServingEngine:
             probs = jax.nn.softmax(fl, axis=-1)
             p_draft = jnp.take_along_axis(
                 probs, nxt[..., None], axis=-1)[..., 0]  # [S, K]
-            u = jax.random.uniform(rng_u, (S, K))
+            u = jax.random.uniform(rngs[0], (S, K))
             acc = u < p_draft
             # residual resample: p with the rejected draft removed
             res_mask = jax.nn.one_hot(nxt, fl.shape[-1],
                                       dtype=jnp.bool_)
             tok_res = jax.random.categorical(
-                rng_res, jnp.where(res_mask, -1e9, fl),
+                rngs[1], jnp.where(res_mask, -1e9, fl),
                 axis=-1).astype(jnp.int32)
             tok_v = jax.random.categorical(
-                rng_bonus, fl, axis=-1).astype(jnp.int32)
+                rngs[2], fl, axis=-1).astype(jnp.int32)
             return ((tok, tok_v, tok_res, acc),) + pools + (key,)
+
+        def step(arrays, k_pool, v_pool, *rest):
+            # static signature variants (one compile each way):
+            # quantized pools add (k_scale, v_scale) after the pools
+            # and summary-tracking pools (k_sum_min, k_sum_max) after
+            # those — the kv_cache._pools() order; adapter slot
+            # tensors follow them; then the packed plan (`plan_layout`:
+            # flat tokens, sample index, block table, per-token adapter
+            # ids), sliced ONCE here, before the layer scan; active
+            # logit processors add the [S, Vb] token-count histogram
+            # before the key (ISSUE 19: the count form replaces the
+            # [S, W] history tensor so the multi-tick loop can advance
+            # it per accepted token). The key is the carried CHAIN: the
+            # step splits it and returns the advanced chain last
+            # every operation under one scope of `tracing.DEVICE_SCOPES`:
+            # HLO metadata only, the program is the same
+            rest = list(rest)
+            k_scale = v_scale = counts = None
+            k_sum_min = k_sum_max = None
+            if quant:
+                k_scale, v_scale = rest[:2]
+                rest = rest[2:]
+            if track:
+                k_sum_min, k_sum_max = rest[:2]
+                rest = rest[2:]
+            ad_arrays = ()
+            if lora:
+                ad_arrays = rest[:len(ad_names)]
+                rest = rest[len(ad_names):]
+            plan = rest.pop(0)
+            if use_hist:
+                counts = rest.pop(0)
+            (key,) = rest
+            with jax.named_scope("sample"):
+                key, rng = jax.random.split(key)
+            n_dec = len(names)
+            we, pe = arrays[0], arrays[1]
+            dec_arrays = arrays[2:2 + n_dec]
+            lnw, lnb, head = arrays[-3], arrays[-2], arrays[-1]
+            params = dict(zip(names, dec_arrays))
+            with jax.named_scope("plan_unpack"):
+                f = layout.unpack(plan)
+                token_ids, slot_ids, positions, sample_index = (
+                    f["token_ids"], f["slot_ids"], f["positions"],
+                    f["sample_index"])
+                block_tables = f["block_tables"]
+                adapter_ids = f["adapter_ids"] if lora else None
+                if lora:
+                    # the [L, K, ...] slot tensors join the scanned
+                    # params so each layer's xs slice carries its own
+                    # adapter rows; ONE [T, K] one-hot feeds every
+                    # layer's deltas
+                    params.update(dict(zip(ad_names, ad_arrays)))
+                    lora_oh = jax.nn.one_hot(adapter_ids, K_ad,
+                                             dtype=jnp.float32)
+                else:
+                    lora_oh = None
+                valid = slot_ids >= 0
+                pos = jnp.where(valid, positions, 0)
+                safe_slot = jnp.where(valid, slot_ids, 0)
+                # padding tokens write into the reserved NULL block
+                wb = jnp.where(valid,
+                               block_tables[safe_slot, pos // BS], 0)
+                wo = pos % BS
+                # the query runs the ragged kernel walks (a decode token
+                # a run of 1, a prefill chunk one run): the same for
+                # every layer, so derived here, once a step
+                r0 = R if region_on else 0
+                runs = paged_runs(slot_ids[r0:], pos[r0:])
+                sidx = jnp.clip(sample_index, 0, T - 1)
+            with jax.named_scope("embed"):
+                x = model._embed(we, pe, token_ids, pos)      # [T, D]
+
+            def attention(q, kp, vp, ksc, vsc, smin, smax, li):
+                """Layer `li`'s attention of the flat tokens over the
+                pools just appended to -> [T, H, Dh]."""
+                if sparse:
+                    # region queries attend the SHORTENED tables: the
+                    # kernels read Bt blocks per slot instead of the
+                    # whole context, and the compacted positions keep
+                    # the causal mask exact; prefill chunks (whose
+                    # queries sit mid-prompt) keep the dense path
+                    q_r = q[:R].reshape(S, K, cfg.num_heads,
+                                        cfg.head_dim)
+                    pos_r = pos[:R].reshape(S, K)
+                    short_bt, pos_c = select_blocks(
+                        q_r, pos_r, block_tables, smin, smax, li)
+                    if K == 1:
+                        ar = ragged_paged_attention(
+                            q[:R], kp, vp, short_bt,
+                            slot_ids[:R], pos_c[:, 0], ksc, vsc,
+                            kernel_name="paged_sparse", layer=li)
+                    else:
+                        ar = verify_paged_attention(
+                            q_r, kp, vp, short_bt,
+                            jnp.arange(S, dtype=jnp.int32), pos_c,
+                            ksc, vsc, kernel_name="paged_sparse",
+                            layer=li).reshape(
+                            R, cfg.num_heads, cfg.head_dim)
+                    ap = ragged_paged_attention(
+                        q[R:], kp, vp, block_tables,
+                        slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
+                        layer=li)
+                    return jnp.concatenate(
+                        [ar.reshape(R, cfg.num_heads, cfg.head_dim),
+                         ap], axis=0)
+                if K == 1:
+                    return ragged_paged_attention(
+                        q, kp, vp, block_tables, slot_ids, pos,
+                        ksc, vsc, runs=runs, layer=li)
+                # the fixed verify region (slot s owns flat tokens
+                # [s*K, (s+1)*K)) runs through the verify-shaped
+                # entry — ONE block-table gather per slot instead of
+                # one per flat token; prefill chunks keep the
+                # flat-token ragged path
+                qv = q[:R].reshape(S, K, cfg.num_heads, cfg.head_dim)
+                av = verify_paged_attention(
+                    qv, kp, vp, block_tables,
+                    jnp.arange(S, dtype=jnp.int32),
+                    pos[:R].reshape(S, K), ksc, vsc, layer=li)
+                ap = ragged_paged_attention(
+                    q[R:], kp, vp, block_tables,
+                    slot_ids[R:], pos[R:], ksc, vsc, runs=runs,
+                    layer=li)
+                return jnp.concatenate(
+                    [av.reshape(R, cfg.num_heads, cfg.head_dim),
+                     ap], axis=0)
+
+            def layer(carry, xs):
+                at = 3
+                h, kp, vp = carry[:3]
+                ksc = vsc = smin = smax = None
+                if quant:
+                    ksc, vsc = carry[at:at + 2]
+                    at += 2
+                if track:
+                    smin, smax = carry[at:at + 2]
+                    at += 2
+                ms = carry[-1] if moe else None
+                pl, li = xs
+                with jax.named_scope("attn_qkv"):
+                    hn = _ln(h, pl["ln_s"], pl["ln_b"], cfg.epsilon)
+                    q, k, v = _qkv(cfg, pl, hn[None], lora_oh=lora_oh)
+                    q, k, v = q[0], k[0], v[0]              # [T, H, Dh]
+                with jax.named_scope("kv_write"):
+                    if quant:
+                        # quantize-on-append: int8/fp8 payload + per-
+                        # entry scales land at the same (block, offset)
+                        # coords
+                        kq, ks_new = quantize(k)
+                        vq, vs_new = quantize(v)
+                        kp = kp.at[li, wb, wo].set(kq)
+                        vp = vp.at[li, wb, wo].set(vq)
+                        ksc = ksc.at[li, wb, wo].set(ks_new)
+                        vsc = vsc.at[li, wb, wo].set(vs_new)
+                    else:
+                        kp = kp.at[li, wb, wo].set(k.astype(kp.dtype))
+                        vp = vp.at[li, wb, wo].set(v.astype(vp.dtype))
+                    if track:
+                        # summary update on append: the offset-0 write
+                        # of a block RESETS its row first (non-first
+                        # tokens aim the reset at the NULL row), then
+                        # one scatter-min/max folds every appended key
+                        # in — well-defined even when one prefill chunk
+                        # writes many entries of the same block, and a
+                        # freed-then-reused block can never leak its
+                        # previous owner's statistics
+                        ksf = k.astype(jnp.float32)
+                        rb = jnp.where(valid & (wo == 0), wb, 0)
+                        smin = smin.at[li, rb].set(SUMMARY_INIT)
+                        smax = smax.at[li, rb].set(-SUMMARY_INIT)
+                        wbs = jnp.where(valid, wb, 0)
+                        smin = smin.at[li, wbs].min(ksf)
+                        smax = smax.at[li, wbs].max(ksf)
+                with jax.named_scope("attn_full"):
+                    attn = attention(q, kp, vp, ksc, vsc, smin, smax, li)
+                with jax.named_scope("attn_out"):
+                    attn = attn.reshape(T, cfg.num_heads * cfg.head_dim)
+                    out = _mm(cfg, attn, pl["out_w"], pl.get("out_s"))
+                    if lora_oh is not None:
+                        # row-parallel LoRA: A holds this shard's head
+                        # slice of the in axis, so the delta is a
+                        # partial product that joins the psum right
+                        # below
+                        out = out + _lora_delta(
+                            attn, pl["lora_out_a"], pl["lora_out_b"],
+                            lora_oh)
+                    # row-parallel reduction under TP (no-op when
+                    # cfg.mp_axis is None): each shard holds the partial
+                    # product of its own head slice; _ffn_dense below
+                    # does the same for its row-parallel ffn2
+                    out = _maybe_psum(cfg, out)
+                    out = out + pl["out_b"].astype(out.dtype)
+                    h = h + out
+                # the capacity-routed experts of a GPT-MoE stack (router
+                # included) read `moe_experts`; their norm and residual,
+                # like a dense FFN's, `mlp`
+                with jax.named_scope("mlp"):
+                    hn = _ln(h, pl["ffn_ln_s"], pl["ffn_ln_b"],
+                             cfg.epsilon)
+                    if moe:
+                        # per-token top-k routing into fixed capacity
+                        # slots (padding tokens masked out by `valid`);
+                        # overflow rides the residual — shapes never
+                        # change, so the one-compile rule holds with MoE
+                        # exactly as dense
+                        with jax.named_scope("moe_experts"):
+                            f, st = _ffn_moe_tokens(cfg, pl, hn, valid)
+                            ms = jax.tree.map(jnp.add, ms, st)
+                        h = h + f
+                    else:
+                        h = h + _ffn_dense(cfg, pl, hn, lora_oh=lora_oh)
+                new_carry = (h, kp, vp)
+                if quant:
+                    new_carry += (ksc, vsc)
+                if track:
+                    new_carry += (smin, smax)
+                if moe:
+                    new_carry += (ms,)
+                return new_carry, None
+
+            carry0 = (x, k_pool, v_pool)
+            if quant:
+                carry0 += (k_scale, v_scale)
+            if track:
+                carry0 += (k_sum_min, k_sum_max)
+            if moe:
+                carry0 += ({"counts": jnp.zeros((cfg.num_experts,),
+                                                jnp.float32),
+                            "dropped": jnp.zeros((), jnp.float32),
+                            "aux": jnp.zeros((), jnp.float32)},)
+            # what the scan itself does (a layer's matrices sliced out
+            # of the stacked weights, the loop's counter) reads
+            # `attn_qkv`: on the chip the slice that costs is the qkv
+            # matrix's; the layer's operations keep their inner scopes
+            with jax.named_scope("attn_qkv"):
+                carry, _ = jax.lax.scan(layer, carry0,
+                                        (params, jnp.arange(L)))
+            moe_stats = carry[-1] if moe else None
+            if moe:
+                # aux reported as the per-layer mean balance loss
+                moe_stats = dict(moe_stats,
+                                 aux=moe_stats["aux"] / float(L))
+            n_pool = 2 + (2 if quant else 0) + (2 if track else 0)
+            x = carry[0]
+            pools = tuple(carry[1:1 + n_pool])
+            if moe:
+                pools += (moe_stats,)
+            with jax.named_scope("head"):
+                xf = _ln(x, lnw, lnb, cfg.epsilon)
+                h_last = xf[sidx]                      # [max_slots, D]
+                logits = jnp.matmul(h_last, head.astype(h_last.dtype))
+            with jax.named_scope("sample"):
+                rngs = None
+                if spec_sampling:
+                    rng, *rngs = jax.random.split(rng, 4)
+                tok = select_token(logits, rng, sc, counts=counts)
+            if K == 1:
+                return (tok,) + pools + (key,)
+            with jax.named_scope("head"):
+                hv = xf[:R].reshape(S, K, -1)
+                logits_v = jnp.matmul(hv, head.astype(hv.dtype))
+                lv = logits_v.astype(jnp.float32)
+            with jax.named_scope("sample"):
+                return verify_tokens(lv, tok, pools, key, token_ids,
+                                     counts, rngs)
+
 
         return step
 
@@ -1789,7 +1848,14 @@ class ServingEngine:
                 out += (mstats,)
             return out + (rng,)
 
-        return multitick
+        def scoped(*args):
+            # what the loop does beside the base step (staging, counts,
+            # events, the draft ring) reads `tick_control`; the base
+            # step's operations keep their own, inner scopes
+            with jax.named_scope("tick_control"):
+                return multitick(*args)
+
+        return scoped
 
     # ------------------------------------------------------------ intake
     def register_adapter(self, adapter_id, weights):
@@ -2153,6 +2219,10 @@ class ServingEngine:
         trace_on = _tracing._enabled
         ph = self.phases
         t0 = ph.mark("engine.plan", self.steps_run) if trace_on else None
+        if trace_on:
+            # seconds of this step's summed phases spent in tracing code
+            # (the flight field `trace_self`)
+            self._trace_self = 0.0
         plan = sch.plan()
         if _pmetrics._enabled and plan.expired:
             smetrics.SERVING_REQUESTS.labels("expired").inc(
@@ -2161,6 +2231,7 @@ class ServingEngine:
             self._flush_deferred()
             if trace_on:
                 ph.close()
+                _tracing.TRACER.flush()
             return bool(plan.expired)
         if trace_on:
             ph.mark("engine.pack")
@@ -2169,6 +2240,7 @@ class ServingEngine:
         sp, got = run(plan, trace_on)
         now = ph.mark("engine.emit") if trace_on else self.clock()
         if trace_on:
+            t = self.clock()
             # one prefill_chunk span per planned chunk: slot residents
             # are stable between plan() and here (admissions happen
             # only inside plan), so sch.slots[slot] is the chunk's
@@ -2176,10 +2248,11 @@ class ServingEngine:
             for slot, chunk, start, completes in plan.prefills:
                 req = sch.slots[slot]
                 if req is not None:
-                    _tracing.TRACER.event(
+                    _tracing.TRACER.queue(
                         req.trace_id, "prefill_chunk",
                         replica=self.name, ts=now, start=int(start),
                         tokens=len(chunk), completes=bool(completes))
+            self._trace_self += self.clock() - t
         for slot in sp.prefill_done:
             req = sch.slots[slot]
             if req is not None and got["first"] is None:
@@ -2214,23 +2287,26 @@ class ServingEngine:
         if _pmetrics._enabled:
             snap = self._snapshot(sp.prefill_tokens, got)
         if trace_on:
-            # flight-recorder note: every field is a host int/float the
-            # loop already holds — no jit input, and no readback but
-            # the block model's counters, which came with the tokens
-            work = got["work"]
-            if got["block_stats"] is not None:
-                work.update(self._block_work(got["block_stats"]))
-            record = self._step_record(
-                t0, prefill_tokens=int(sp.prefill_tokens),
-                decode_tokens=int(got["decode_tokens"]), **work,
-                **got["dispatch"])
-        if self._multitick and sch.has_work:
+            # flight-recorder note: what has to be read NOW is (host
+            # ints the loop already holds, the allocators' counts, the
+            # clock; a block model's counters came with the tokens);
+            # the record is made of it later
+            record = self._step_record(t0, sp, got)
+        if snap is not None and not self._multitick:
+            # the registry's metrics are not tracing's: published now
+            self._observe(snap, None)
+            snap = None
+        if sch.has_work and (snap is not None or record is not None):
+            if self._deferred is not None:      # tracing went off and on
+                self._flush_deferred()
             # deferred observability: every value was captured NOW; it
             # publishes after the next dispatch launches (or at the
-            # idle / flush points)
+            # idle / flush points), behind the device
             self._deferred = (snap, record)
         else:
             self._observe(snap, record)
+            if trace_on:
+                _tracing.TRACER.flush()
         return True
 
     def _dispatch(self, plan, sp, tail, trace_on):
@@ -2248,6 +2324,8 @@ class ServingEngine:
         *res, self._rng = self._step_fn(*args)
         if trace_on:
             self.phases.mark("engine.wait")
+            if not self._multitick:     # the device loop flushes later
+                self._flush_deferred()
         if self.num_experts:
             res, got["moe_stats"] = res[:-1], res[-1]
         elif self._block is not None:
@@ -2279,7 +2357,13 @@ class ServingEngine:
             # does it: host arithmetic on the plan, no readback
             got["work"] = self._plan_work(plan)
         if not self.draft_k:
-            tok_np = np.asarray(out)
+            if trace_on and got["block_stats"] is not None:
+                # a block model's counters with the tokens: one readback
+                import jax
+                tok_np, got["block_stats"] = jax.device_get(
+                    (out, got["block_stats"]))
+            else:
+                tok_np = np.asarray(out)
             got.update(first=tok_np, groups=[
                 (slot, [int(tok_np[slot])], None)
                 for slot in sp.decode_slots])
@@ -2336,10 +2420,15 @@ class ServingEngine:
                 toks, pos) for slot, toks, pos in plan.decode]
         sp = self._pack(plan.decode, plan.prefills)
         out, got = self._dispatch(plan, sp, (), trace_on)
-        if trace_on:
-            got["work"] = self._plan_work(plan)
         got.update(decode_tokens=sp.decode_tokens, first=None)
-        both = np.asarray(out)                  # [S, 2, L] int32
+        if trace_on:
+            import jax
+            got["work"] = self._plan_work(plan)
+            # the block's counters with the candidates: one readback
+            both, got["block_stats"] = jax.device_get(
+                (out, got["block_stats"]))
+        else:
+            both = np.asarray(out)              # [S, 2, L] int32
         cand, conf = both[:, 0], both[:, 1].view(np.float32)
         groups, masked_rows, decided, commits = [], 0, 0, 0
         for slot, req, was, toks, pos in fed:
@@ -2352,7 +2441,7 @@ class ServingEngine:
             if not masked:
                 commits += 1
                 if trace_on:
-                    _tracing.TRACER.event(
+                    _tracing.TRACER.queue(
                         req.trace_id, "block_committed", replica=self.name,
                         ts=self.clock(), start=int(pos))
                 continue
@@ -2411,7 +2500,7 @@ class ServingEngine:
                 a.copy_to_host_async()
             except Exception:
                 pass
-        self._flush_deferred(trace_on)
+        self._flush_deferred()
         hs0 = self.clock()
         staged_np, counts_np, events_np = (np.asarray(a)
                                            for a in ctrl[:3])
@@ -2506,6 +2595,7 @@ class ServingEngine:
             # `now - req.submit_time`, and decode/verify events carry
             # the same `gap` — tools/trace_smoke.py asserts the sums
             # match
+            t = self.clock()
             if first:
                 _tracing.on_first_token(req, self.name, ts=now,
                                         inside=inside)
@@ -2513,6 +2603,7 @@ class ServingEngine:
                 _tracing.on_tokens(req, self.name, ts=now,
                                    n=len(tokens), gap=gap, verify=verify,
                                    inside=inside)
+            self._trace_self += self.clock() - t
         for t in tokens:
             req.output.append(t)
             if len(req.output) >= req.max_new_tokens or \
@@ -2596,26 +2687,33 @@ class ServingEngine:
         return dict(attn_logits_useful=int(useful),
                     attn_logits_issued=int(issued))
 
-    def _block_work(self, block_stats):
-        """A block model's flight fields: the block's own counters,
-        read back with the tokens; the kinds of block, from the
-        allocators (no window allocator: the window fields read 0);
+    def _block_now(self):
+        """What a block model's flight fields need of the cache NOW:
+        the kinds of block from the allocators (no window allocator: the
+        window fields read 0), what `window_held_tokens` will count,
         the slots whose recurrent state is live."""
         kv = self.kv
         released = kv.blocks_released_behind_window
-        held, ctx = kv.window_held_tokens()
-        fields = dict(
-            zip(self._block.stat_names,
-                (int(v) for v in np.asarray(block_stats))),
-            kv_blocks_in_use_full=int(kv.allocator.num_used),
-            kv_blocks_in_use_window=int(
-                kv.window_allocator.num_used if kv.has_window else 0),
-            kv_blocks_released_behind_window=int(
-                released - self._released_seen),
-            kv_tokens_held_window=held, kv_tokens_context=ctx)
-        if kv.linear_layers:
-            fields["state_slots_in_use"] = kv.state_slots_in_use
+        now = (kv.allocator.num_used,
+               kv.window_allocator.num_used if kv.has_window else 0,
+               released - self._released_seen, kv.window_held_state(),
+               kv.state_slots_in_use if kv.linear_layers else None)
         self._released_seen = released
+        return now
+
+    def _block_fields(self, block_stats, now):
+        """A block model's flight fields, of its own counters (read
+        back with the tokens) and `_block_now()`."""
+        full, window, released, held_at, state_slots = now
+        held, ctx = self.kv.window_held_tokens(held_at)
+        fields = dict(
+            zip(self._block.stat_names, (int(v) for v in block_stats)),
+            kv_blocks_in_use_full=int(full),
+            kv_blocks_in_use_window=int(window),
+            kv_blocks_released_behind_window=int(released),
+            kv_tokens_held_window=held, kv_tokens_context=ctx)
+        if state_slots is not None:
+            fields["state_slots_in_use"] = state_slots
         return fields
 
     def _snapshot(self, prefill_tokens, got):
@@ -2686,56 +2784,74 @@ class ServingEngine:
                 for _ in range(groups):
                     smetrics.SERVING_ACCEPT_LENGTH.observe(b + 1)
         if record is not None:
-            self.flight.note(**record)
+            self.flight.note(**record())
 
-    def _step_record(self, t0, **fields):
-        """The flight record of the step that began at `t0`, from the
-        engine's state now: every value a host int or float. The step
-        ends here, where its last phase does, so the `ph_*` fields of
-        this step sum to `dur`; the frontend's ran in the gap before
-        it."""
-        sch = self.scheduler
-        fields.update(
-            active_slots=int(sch.num_active),
-            queue_depth=len(sch.queue),
-            sparse_skip_ratio=(
-                1.0 - self.sparse_selected_blocks
-                / self.sparse_candidate_blocks
-                if self._sparse and self.sparse_candidate_blocks
-                else 0.0),
-            blocks_imported=int(self.kv.blocks_imported),
-            compile_cache_size=self.step_compile_count(),
-            kv_blocks_in_use=int(self.kv.blocks_in_use),
-            kv_blocks_total=int(self.kv.blocks_total),
-            preemptions=int(sch.preemption_count),
-            **self._flight_extra())
+    def _step_record(self, t0, sp, got):
+        """The flight record of the step that began at `t0`, in two
+        halves. NOW, inside `engine.note`: the engine's state (host
+        ints, the allocators' counts) and the clock; the step ends
+        here, where its last phase does, so the `ph_*` fields of this
+        step sum to `dur` (the frontend's ran in the gap before it),
+        and `trace_self` is closed: the seconds of this step's summed
+        phases (`tracing.SUMMED_PHASES`) that went into tracing code,
+        this block and the marks among them. LATER (`_observe`, after
+        the next dispatch has launched): -> the function that makes the
+        record of it, every value a host int or float."""
+        t = self.clock()
+        sch, kv = self.scheduler, self.kv
+        sel, cand = self.sparse_selected_blocks, \
+            self.sparse_candidate_blocks
+        state = (sch.num_active, len(sch.queue), kv.blocks_imported,
+                 self.step_compile_count(), kv.blocks_in_use,
+                 kv.blocks_total, sch.preemption_count)
+        fed = int(sp.prefill_tokens), int(got["decode_tokens"])
+        block = self._block_now() if got["block_stats"] is not None \
+            else None
+        extra = self._flight_extra()
         end = self.phases.close()
-        fields.update(self.phases.take(), ts=t0, dur=end - t0)
-        if self._step_end is not None:
-            fields["gap_before"] = t0 - self._step_end
-        self._step_end = end
-        return fields
+        phases = self.phases.take()
+        gap, self._step_end = self._step_end, end
+        trace_self = self._trace_self + end - t + self.phases.take_own(
+            _tracing.SUMMED_PHASES)
+
+        def record():
+            fields = dict(got["work"], prefill_tokens=fed[0],
+                          decode_tokens=fed[1], **got["dispatch"])
+            if block is not None:
+                fields.update(
+                    self._block_fields(got["block_stats"], block))
+            fields.update(zip(
+                ("active_slots", "queue_depth", "blocks_imported",
+                 "compile_cache_size", "kv_blocks_in_use",
+                 "kv_blocks_total", "preemptions"), map(int, state)))
+            fields.update(
+                extra, sparse_skip_ratio=(
+                    1.0 - sel / cand if self._sparse and cand else 0.0),
+                ts=t0, dur=end - t0, trace_self=trace_self, **phases)
+            if gap is not None:
+                fields["gap_before"] = t0 - gap
+            return fields
+
+        return record
 
     # ------------------------------------- multi-tick dispatch (ISSUE 18)
-    def _flush_deferred(self, trace_on=False):
-        """Publish the last multi-tick dispatch's deferred notes. Inside
-        a traced step that is `engine.note` time, taken out of the
-        phase it interrupts (`engine.wait`, where it hides behind the
-        device)."""
+    def _flush_deferred(self):
+        """Publish what the last step parked (a multi-tick dispatch's
+        metrics; a traced step's flight record, made here, and the span
+        events the tracer queued). A step calls it once its own dispatch
+        has launched, inside `engine.wait`: it hides behind the
+        device."""
         parked, self._deferred = self._deferred, None
         if parked is not None:
-            if trace_on:
-                resume = self.phases.name
-                self.phases.mark("engine.note")
             self._observe(*parked)
-            if trace_on:
-                self.phases.mark(resume)
+            if parked[1] is not None:
+                _tracing.TRACER.flush()
 
     def flush_observability(self):
-        """Flush the deferred observability of the LAST multi-tick
-        dispatch (its metrics/flight record normally publish after the
-        NEXT dispatch launches, overlapping device execution). No-op on
-        single-tick engines; the frontend calls this when going idle."""
+        """Flush the deferred observability of the LAST step (a
+        multi-tick dispatch's metrics and a traced step's flight record
+        normally publish after the NEXT dispatch launches, overlapping
+        device execution). The frontend calls this when going idle."""
         self._flush_deferred()
 
     def _auto_ticks(self, n_max):
@@ -2801,6 +2917,33 @@ class ServingEngine:
         step (`instrumented_jit`'s count; the contract is 1 after the
         first step and 1 for ever after)."""
         return 0 if self._aot_step else self._step_fn.compile_count()
+
+    def step_op_scopes(self):
+        """{HLO instruction name: scope of `tracing.DEVICE_SCOPES`, or
+        `tracing.NO_SCOPE`} of the executable the mixed step runs: what
+        names a device trace's events, which carry an instruction's
+        name and no `op_name` (`benchmarks/harness/device_scopes.py`,
+        `tools/parse_xplane.py --by-scope`).
+
+        Built on the first call and kept: the step lowered at the
+        engine's own shapes (`example_step_args()`: nothing is donated,
+        no pool is read) and compiled, which hands back the executable
+        a step that already ran holds (else the persistent cache's, else
+        a new one). NEVER called from `step()`: nothing is built or
+        loaded inside a measured window, and nothing here goes through
+        the wrapper that `step_compile_count()` counts. An AOT step
+        (`install_aot_step`) gives the text of the executable it
+        loaded."""
+        if self._op_scopes is None:
+            from ..profiler.xplane import hlo_op_scopes
+            if self._aot_step:
+                text = self._step_fn.as_text()
+            else:
+                text = self._step_fn._jitted.lower(
+                    *self.example_step_args()).compile().as_text()
+            self._op_scopes = hlo_op_scopes(
+                text, _tracing.scope_of, _tracing.NO_SCOPE)
+        return self._op_scopes
 
     def _prep_swap_arrays(self, arrays):
         """Host-side staging for `swap_weights`. The base engine takes

@@ -388,20 +388,26 @@ class PagedKVCache:
                       * self.block_size)
         return fit
 
-    def window_held_tokens(self):
+    def window_held_tokens(self, at=None):
         """(tokens of contexts held in window-layer blocks, tokens the
         same slots' contexts hold): what the window allocator keeps
-        against what a table with no window would."""
+        against what a table with no window would. `at`: the
+        `window_held_state()` of an earlier moment, counted now."""
         if not self.has_window:
             return 0, 0
+        lens, wfirst = at or (self.slot_lens, self._slot_wfirst)
         held = ctx = 0
         for slot in range(self.max_slots):
-            n = int(self.slot_lens[slot])
+            n = int(lens[slot])
             if n:
                 ctx += n
-                held += n - min(n, self._slot_wfirst[slot]
-                                * self.block_size)
+                held += n - min(n, wfirst[slot] * self.block_size)
         return held, ctx
+
+    def window_held_state(self):
+        """What `window_held_tokens` reads, copied: two short rows."""
+        return (self.slot_lens.copy(), list(self._slot_wfirst)) \
+            if self.has_window else None
 
     # ------------------------------------------------------------ sizing
     @property

@@ -1,7 +1,8 @@
 """Fleet-wide request tracing for the serving engine (ISSUE 16).
 
 Two host-side event stores, both bounded, both branch-gated like
-`profiler.metrics._enabled`:
+`profiler.metrics._enabled`, the host phases of a serving cycle, and
+the device scopes of the step:
 
 * **Request traces** (`TRACER`, a `RequestTracer`) — one stitched
   span/event timeline per request: enqueued → admitted → prefill
@@ -27,6 +28,18 @@ Two host-side event stores, both bounded, both branch-gated like
   that sit on `/host:CPU` beside the device planes of a
   `jax.profiler` trace, which is the only clock device ops share.
 
+* **Device scopes** (`DEVICE_SCOPES`) — the other half of the same
+  timeline: the layer map's names for what `serving_mixed_step` does
+  on the device, set as `jax.named_scope`s on every operation of the
+  step. They are HLO metadata (no flag, no run-time cost); an engine
+  gives its compiled step's table of instruction -> scope
+  (`ServingEngine.step_op_scopes`), and `step_op_scopes()` here hands
+  the live engines' tables to a reader of a device trace.
+* **The tracer's own cost** — `RequestTracer.queue` lets a hot path
+  pay one append an event (recorded, in order, at the next `flush`);
+  `PhaseMarker` times its own marks; the engine sums both with its
+  record's reads into the flight field `trace_self`.
+
 Both stores register with the profiler's provider hooks
 (`profiler.register_chrome_source` / `register_summary_section`), so
 `profiler.export_chrome_tracing` and `profiler.summary()` merge them
@@ -36,8 +49,11 @@ serving import, the dependency points the other way.
 Hot-path discipline: every call site in engine/scheduler/router/
 transport guards with ``if tracing._enabled:`` so recording off costs
 one branch; recording on touches only host ints/floats already
-computed by the step loop — no device readbacks, no new jit inputs,
-zero extra compiles (tests/test_tracing.py's overhead contract).
+computed by the step loop — no readback of its own (a block model's
+counters come with the tokens), no new jit inputs, zero extra compiles
+(tests/test_tracing.py's overhead contract), and what is only
+assembled from those values (the flight record, the span events'
+bookkeeping) is done after the next dispatch has launched.
 
 Env knobs: ``PADDLE_TPU_TRACE=1`` enables at import,
 ``PADDLE_TPU_TRACE_CAPACITY`` bounds the retained-trace table
@@ -59,9 +75,9 @@ from . import metrics as _smetrics
 
 __all__ = [
     "TRACER", "RequestTracer", "Trace", "TraceEvent",
-    "StepFlightRecorder", "PhaseMarker", "HOST_PHASES", "enable",
-    "disable", "enabled", "register_flight_recorder",
-    "flight_recorders",
+    "StepFlightRecorder", "PhaseMarker", "HOST_PHASES", "DEVICE_SCOPES",
+    "NO_SCOPE", "scope_of", "enable", "disable", "enabled",
+    "register_flight_recorder", "flight_recorders", "step_op_scopes",
 ]
 
 _enabled = os.environ.get(
@@ -198,6 +214,10 @@ class Trace:
                 "events": [e.as_dict() for e in self.events]}
 
 
+#: a queue this long records itself: nobody flushed for a long while
+_PENDING_MAX = 4096
+
+
 class RequestTracer:
     """Process-global trace table + observer fan-out.
 
@@ -220,6 +240,9 @@ class RequestTracer:
         self.clock = clock
         self._traces = collections.OrderedDict()
         self._lock = threading.Lock()
+        # `queue`d events wait here, in the order they were made, until
+        # `flush()` records them
+        self._pending = collections.deque()
         self._seq = itertools.count()
         self._observers = []
         self._open = 0   # incremental: scanning the table per event
@@ -254,39 +277,31 @@ class RequestTracer:
             self.dropped_traces += 1
 
     def event(self, trace_id, name, replica=None, ts=None, **attrs):
-        """Record one span event. Unknown ids get a shell trace (late
-        enable / post-eviction stitching stays lossy-but-safe); events
-        after a terminal are dropped unless `name` reopens the trace."""
+        """Record one span event, now (after whatever `queue` holds).
+        Unknown ids get a shell trace (late enable / post-eviction
+        stitching stays lossy-but-safe); events after a terminal are
+        dropped unless `name` reopens the trace."""
         if not _enabled or trace_id is None:
             return
         if ts is None:
             ts = self.clock()
-        with self._lock:
-            tr = self._traces.get(trace_id)
-            if tr is None:
-                tr = Trace(trace_id, str(attrs.get("tenant", "default")))
-                self._traces[trace_id] = tr
-                self._open += 1
-                self._evict_locked()
-            if tr.done:
-                if name in _REOPEN_EVENTS:
-                    tr.done = False
-                    tr.outcome = None
-                    self._open += 1
-                else:
-                    return
-            if len(tr.events) >= self.max_events:
-                tr.dropped_events += 1
-                if _pmetrics._enabled:
-                    _smetrics.SERVING_TRACE_EVENTS_DROPPED.inc()
-                return
-            if tr._last_ts is not None and ts < tr._last_ts:
-                ts = tr._last_ts
-            tr._last_ts = ts
-            tr.events.append(TraceEvent(name, ts, replica, attrs))
-        if _pmetrics._enabled:
-            _smetrics.SERVING_TRACE_EVENTS.labels(name).inc()
-        self._set_active_gauge()
+        self.flush((False, trace_id, name, replica, ts, attrs))
+
+    def queue(self, trace_id, name, replica=None, ts=None, **attrs):
+        """`event` for a hot path: the event waits, with its timestamp,
+        for the next `flush()` (or the next `event` / `finish` / query,
+        which flush first, so the order in which events were MADE is the
+        order in which they are recorded). The engine queues a step's
+        token and chunk events and flushes once its next dispatch has
+        launched: the table's lock, the clamps and the counters then
+        run while the device does, and the call site pays one append."""
+        if not _enabled or trace_id is None:
+            return
+        if ts is None:
+            ts = self.clock()
+        self._pending.append((False, trace_id, name, replica, ts, attrs))
+        if len(self._pending) >= _PENDING_MAX:
+            self.flush()
 
     def finish(self, trace_id, outcome, replica=None, ts=None, **attrs):
         """Close a trace with a terminal outcome. Idempotent: the first
@@ -297,21 +312,66 @@ class RequestTracer:
             return
         if ts is None:
             ts = self.clock()
+        self.flush((True, trace_id, outcome, replica, ts, attrs))
+
+    def flush(self, then=None):
+        """Record what `queue` holds, oldest first, then `then`: (is it
+        a terminal, trace id, name or outcome, replica, ts, attrs)."""
+        if not self._pending and then is None:
+            return
         with self._lock:
-            tr = self._traces.get(trace_id)
-            if tr is None or tr.done:
+            while True:
+                try:
+                    item = self._pending.popleft()
+                except IndexError:
+                    item, then = then, None
+                    if item is None:
+                        break
+                record = self._finish_locked if item[0] \
+                    else self._event_locked
+                record(*item[1:])
+        self._set_active_gauge()
+
+    def _event_locked(self, trace_id, name, replica, ts, attrs):
+        tr = self._traces.get(trace_id)
+        if tr is None:
+            tr = Trace(trace_id, str(attrs.get("tenant", "default")))
+            self._traces[trace_id] = tr
+            self._open += 1
+            self._evict_locked()
+        if tr.done:
+            if name in _REOPEN_EVENTS:
+                tr.done = False
+                tr.outcome = None
+                self._open += 1
+            else:
                 return
-            if tr._last_ts is not None and ts < tr._last_ts:
-                ts = tr._last_ts
-            tr._last_ts = ts
-            # the terminal event always lands, even past max_events
-            tr.events.append(TraceEvent(outcome, ts, replica, attrs))
-            tr.done = True
-            tr.outcome = outcome
-            self._open -= 1
+        if len(tr.events) >= self.max_events:
+            tr.dropped_events += 1
+            if _pmetrics._enabled:
+                _smetrics.SERVING_TRACE_EVENTS_DROPPED.inc()
+            return
+        if tr._last_ts is not None and ts < tr._last_ts:
+            ts = tr._last_ts
+        tr._last_ts = ts
+        tr.events.append(TraceEvent(name, ts, replica, attrs))
+        if _pmetrics._enabled:
+            _smetrics.SERVING_TRACE_EVENTS.labels(name).inc()
+
+    def _finish_locked(self, trace_id, outcome, replica, ts, attrs):
+        tr = self._traces.get(trace_id)
+        if tr is None or tr.done:
+            return
+        if tr._last_ts is not None and ts < tr._last_ts:
+            ts = tr._last_ts
+        tr._last_ts = ts
+        # the terminal event always lands, even past max_events
+        tr.events.append(TraceEvent(outcome, ts, replica, attrs))
+        tr.done = True
+        tr.outcome = outcome
+        self._open -= 1
         if _pmetrics._enabled:
             _smetrics.SERVING_TRACES.labels(outcome).inc()
-        self._set_active_gauge()
 
     def _set_active_gauge(self):
         if _pmetrics._enabled:
@@ -319,21 +379,25 @@ class RequestTracer:
 
     # ------------------------------------------------------- queries
     def get(self, trace_id):
+        self.flush()
         with self._lock:
             return self._traces.get(trace_id)
 
     def traces(self):
+        self.flush()
         with self._lock:
             return list(self._traces.values())
 
     def active(self):
         """Open traces — the smoke tool's orphan check: after a clean
         drain this must be empty."""
+        self.flush()
         with self._lock:
             return [t for t in self._traces.values() if not t.done]
 
     def reset(self):
         with self._lock:
+            self._pending.clear()
             self._traces.clear()
             self._open = 0
             self.dropped_traces = 0
@@ -456,7 +520,7 @@ def on_admitted(req, replica=None, kind="prefill", ts=None):
 def on_first_token(req, replica=None, ts=None, inside=0):
     # `inside`: further tokens delivered with this one (a model that
     # decodes by blocks hands over 1..L at once), each at gap 0
-    TRACER.event(req.trace_id, "first_token", replica=replica, ts=ts,
+    TRACER.queue(req.trace_id, "first_token", replica=replica, ts=ts,
                  **({"inside": inside} if inside else {}))
     if ts is not None:
         TRACER._notify("on_ttft", req.tenant, ts - req.submit_time, ts)
@@ -464,7 +528,7 @@ def on_first_token(req, replica=None, ts=None, inside=0):
 
 def on_tokens(req, replica=None, ts=None, n=1, gap=None, verify=False,
               inside=0):
-    TRACER.event(req.trace_id,
+    TRACER.queue(req.trace_id,
                  "verify_step" if verify else "decode_step",
                  replica=replica, ts=ts, tokens=n, gap=gap,
                  **({"inside": inside} if inside else {}))
@@ -512,6 +576,40 @@ HOST_PHASES = (
 )
 
 
+#: the other half of the timeline: what `serving_mixed_step` does on the
+#: DEVICE, by the layer map's names. Every operation of the step (the
+#: GPT step and the block step of `serving/engine.py`, the layer
+#: functions of `models/*.py` behind `models/serving_block.py`) is traced
+#: under exactly one of these `jax.named_scope`s; the compiled step's
+#: HLO metadata carries them (`ServingEngine.step_op_scopes`), a device
+#: trace's events do not. docs/OBSERVABILITY.md says what each holds.
+DEVICE_SCOPES = (
+    "plan_unpack", "embed", "attn_qkv", "kv_write", "attn_window",
+    "attn_full", "attn_out", "mlp", "moe_router", "moe_experts",
+    "moe_shared", "lin_proj", "lin_conv", "gated_delta", "lin_gate_out",
+    "head", "sample", "diffusion_confidence", "tick_control",
+)
+#: what an instruction reads whose `op_name` holds none of them
+NO_SCOPE = "(none)"
+
+
+def scope_of(op_name):
+    """The scope of `DEVICE_SCOPES` an instruction was traced under, by
+    its HLO `op_name` (`jit(serving_mixed_step)/.../attn_qkv/dot_general`),
+    innermost first; `NO_SCOPE` where it names none."""
+    for part in reversed(op_name.split("/")):
+        if part in DEVICE_SCOPES:
+            return part
+    return NO_SCOPE
+
+
+#: the engine's phases that `mixed_step.host_ms_per_step` sums (the wait
+#: for the device left out), and so those whose tracing code the flight
+#: field `trace_self` counts
+SUMMED_PHASES = tuple(p for p in HOST_PHASES
+                      if p.startswith("engine.") and p != "engine.wait")
+
+
 def _phase_field(name):
     """`engine.plan` -> `ph_plan`: the flat flight-record field."""
     return "ph_" + name.split(".", 1)[1]
@@ -536,15 +634,20 @@ class PhaseMarker:
     Seconds per phase accumulate until `take()`, which the engine calls
     once per step for the flight record. Call sites guard with
     ``if trace_on:`` like every other tracing hook, so with tracing off
-    no annotation is ever built."""
+    no annotation is ever built. The marker times ITSELF too: what a
+    `mark` costs after the boundary it set lies in the phase it opened,
+    and `take_own` hands those seconds out by phase (the flight field
+    `trace_self` counts them)."""
 
-    __slots__ = ("clock", "step", "name", "_seconds", "_t0", "_note")
+    __slots__ = ("clock", "step", "name", "_seconds", "_own", "_t0",
+                 "_note")
 
     def __init__(self, clock=time.monotonic):
         self.clock = clock
         self.step = 0
         self.name = None            # the open phase
         self._seconds = {}
+        self._own = {}
         self._t0 = self._note = None
 
     def mark(self, name, step=None):
@@ -557,6 +660,7 @@ class PhaseMarker:
         self.name, self._t0 = name, now
         self._note = jax.profiler.TraceAnnotation(name, step=self.step)
         self._note.__enter__()
+        self._own[name] = self._own.get(name, 0.0) + self.clock() - now
         return now
 
     def close(self):
@@ -576,17 +680,41 @@ class PhaseMarker:
         self._seconds = {}
         return out
 
+    def take_own(self, phases):
+        """Seconds the marks themselves took inside `phases` since the
+        last call."""
+        own, self._own = self._own, {}
+        return sum(own.get(p, 0.0) for p in phases)
+
 
 # ------------------------------------------------- step flight recorder
 _FLIGHT = weakref.WeakSet()
 
 
-def register_flight_recorder(rec):
+def register_flight_recorder(rec, op_scopes=None):
+    """`op_scopes`: the engine's `step_op_scopes` (bound method), held
+    weakly beside its recorder, so that a reader of a device trace can
+    ask a live engine for its table without importing the engine."""
     _FLIGHT.add(rec)
+    if op_scopes is not None:
+        rec.op_scopes = weakref.WeakMethod(op_scopes)
 
 
 def flight_recorders():
     return list(_FLIGHT)
+
+
+def step_op_scopes():
+    """{engine name: {HLO instruction name: scope}} of every live
+    engine's compiled step (`ServingEngine.step_op_scopes`). The first
+    call for an engine lowers its step and loads or builds the
+    executable: call it outside any measured window."""
+    out = {}
+    for rec in flight_recorders():
+        method = rec.op_scopes and rec.op_scopes()
+        if method is not None:
+            out[rec.engine_name] = method()
+    return out
 
 
 class StepFlightRecorder:
@@ -606,6 +734,7 @@ class StepFlightRecorder:
         self.records = collections.deque(maxlen=self.maxlen)
         self.dropped = 0
         self.steps = 0
+        self.op_scopes = None   # `register_flight_recorder` sets it
 
     def note(self, **fields):
         if len(self.records) == self.maxlen:

@@ -188,7 +188,8 @@ def test_reader_of_the_rows_walked():
     assert reader.read(ctx([])) is None
     import json
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    entry = manifest["per_layer"][-1]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "linear_attn.rows_walked_fill_pct")
     assert entry == dict(
         name="linear_attn.rows_walked_fill_pct", unit="%", better="higher",
         source="program_counter", layer="linear_attn",

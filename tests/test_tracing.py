@@ -511,14 +511,16 @@ class TestHostPhases:
     def test_pool_counters_read_at_note_time(self):
         eng = _engine(_model(), token_budget=8)
         eng.generate_batch([[7, 7]], max_new_tokens=1)
+        # the record is MADE later (after the next dispatch has
+        # launched), of what `_step_record` read inside `engine.note`
         seen = []
-        note = eng.flight.note
+        step_record = eng._step_record
 
-        def spy(**fields):
+        def spy(*args):
             seen.append((eng.kv.blocks_in_use,
                          eng.scheduler.preemption_count))
-            note(**fields)
-        eng.flight.note = spy
+            return step_record(*args)
+        eng._step_record = spy
         tracing.enable()
         eng.generate_batch([_prompt(n, seed=n) for n in (6, 11, 19)],
                            max_new_tokens=7)

@@ -15,7 +15,10 @@ Two tables. The first is the benchmark's own reduction
 HOST_PHASES)`): every gap goes, whole, to the phase that overlaps it
 most. The second splits each gap among all the phases that overlap it:
 where a gap spans emit, note, the two executor hops and the next pack,
-it says how much of it each took. `--rehearse` reads a CPU trace (its
+it says how much of it each took. `HOST_PHASES` is the HOST half of one
+timeline; the DEVICE half, what the chip did while it was busy, is
+`serving.tracing.DEVICE_SCOPES` (`tools/parse_xplane.py --by-scope`,
+and the table a traced benchmark run prints). `--rehearse` reads a CPU trace (its
 host events with an `hlo_op` stat stand in for device ops): it debugs
 the tool and measures nothing.
 

@@ -2,7 +2,8 @@
 
 Usage: python tools/parse_xplane.py <trace_dir> [n_steps] [top_k]
        python tools/parse_xplane.py <trace_dir> --by-scope SCOPE[,SCOPE...]
-                                    [--hlo compiled_step.txt]
+                                    [--hlo compiled_step.txt |
+                                     --config <configuration> [--rehearse]]
 
 Finds the newest .xplane.pb under <trace_dir>, sums duration by HLO op
 name on the TPU device plane's "XLA Ops" line, and prints a per-step
@@ -11,26 +12,42 @@ per-kernel time; a host clock around one async dispatch is not.
 The trace is read with `jax.profiler.ProfileData`: nothing but jax.
 
 `--by-scope` sums SELF time (a `while` keeps what its body's ops leave)
-by the `jax.named_scope` an op was traced under: an event goes to the
-first of the given scopes that its instruction's `op_name` contains,
-else to `(none)`; per execution of the program that ran most often on
-the device. A v5e trace's events carry no `op_name` (their stats are
-offsets and durations): `--hlo` names the program's compiled HLO text
-(`jitted.trace(*args).lower().compile().as_text()`, made by the same
-code at the same shapes), whose `metadata={op_name="..."}` of the
-instruction an event is named after is read instead; without it only
-an event's own name is searched (a kernel's).
+by the `jax.named_scope` an op was traced under, per execution of the
+program that ran most often on the device. The scopes of a serving step
+are `serving.tracing.DEVICE_SCOPES` (give `all` for the whole list):
+the DEVICE half of the one timeline whose HOST half is
+`serving.tracing.HOST_PHASES` (`tools/host_gaps.py` lays the device's
+idle gaps over those). A v5e trace's events carry no `op_name` (their
+stats are offsets and durations), so the scopes come from the compiled
+program's HLO metadata:
+
+- `--hlo FILE`: the program's compiled HLO text
+  (`jitted.trace(*args).lower().compile().as_text()`, made by the same
+  code at the same shapes); an event goes to the first of the given
+  scopes that its instruction's `op_name` contains, else to `(none)`;
+- `--config NAME`: the step of the benchmark configuration
+  `benchmarks/configs/NAME.json`, built and lowered here through the
+  cell's own driver, and the engine's own table
+  (`ServingEngine.step_op_scopes()`: innermost scope first, an
+  instruction the compiler made takes its user's); on the machine the
+  trace was made on, since the table has to be the running executable's;
+- neither: only an event's own name is searched (a kernel's).
+
+A traced run of the benchmark prints the same table itself
+(`benchmarks/layer_metrics/device.named_busy_pct.py`).
 """
 import collections
 import os
 import re
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 from paddle_tpu.profiler.xplane import (  # noqa: E402,F401
-    load_xplane, device_op_times)
+    device_op_times, hlo_op_names, load_xplane)
+from paddle_tpu.serving.tracing import DEVICE_SCOPES  # noqa: E402
 
 
 def bucket(name):
@@ -55,21 +72,39 @@ def bucket(name):
     return "other"
 
 
-def hlo_op_names(path):
-    """{instruction name: its `op_name`} of an HLO module's text."""
-    line = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*'
-                      r'metadata=\{[^}]*op_name="([^"]*)"')
-    with open(path) as f:
-        return dict(m.groups() for m in map(line.match, f) if m)
+def config_table(name, rehearse=False):
+    """{instruction: scope} of the mixed step of the benchmark
+    configuration `name`, by the engine its cell's driver builds
+    (`rehearse`: at the rehearsal's tiny sizes, to try the tool)."""
+    import types
+
+    from harness import traffic
+    from harness.files import BENCH, load_json, load_module
+    cell = next(w for w in load_json(ROOT, "BENCHMARK.json")["workloads"]
+                if w["config"] == name)
+    config = traffic.with_rehearsal(
+        load_json(BENCH, "configs", name + ".json"), rehearse)
+    env = types.SimpleNamespace(
+        config=config, config_name=name, seed=0, chips=1,
+        rehearse=rehearse, traffic=traffic.with_rehearsal(
+            load_json(BENCH, "traffic", cell["traffic"] + ".json"),
+            rehearse),
+        log=lambda msg: print(f"parse_xplane: {msg}", flush=True))
+    driver = load_module("drivers", config["driver"]).Driver(env)
+    driver.setup()
+    return driver.engine.step_op_scopes()
 
 
-def by_scope(trace_dir, scopes, hlo=None, top=12):
+def by_scope(trace_dir, scopes, hlo=None, top=12, table=None):
     """Print device self time by named scope, and the largest
-    operations of each scope."""
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
+    operations of each scope. `table`: {instruction: scope}, the
+    engine's own; else `hlo`, a compiled module's text file."""
     from harness import trace_reduce
-    op_names = hlo_op_names(hlo) if hlo else {}
+    if hlo:
+        with open(hlo) as f:
+            op_names = hlo_op_names(f.read())
+    else:
+        op_names = {}
     device, _ = trace_reduce.read_profile(
         trace_reduce.find_xplane(trace_dir))
     for plane, lines in device.items():
@@ -84,7 +119,8 @@ def by_scope(trace_dir, scopes, hlo=None, top=12):
         per_scope = collections.defaultdict(collections.Counter)
         for name, self_ns in trace_reduce._self_times(events):
             text = op_names.get(name, name)
-            scope = next((s for s in scopes if s in text), "(none)")
+            scope = table.get(name, "(none)") if table else next(
+                (s for s in scopes if s in text), "(none)")
             per_scope[scope][name] += self_ns
         print(f"{plane}: {steps} executions of {program}; window "
               f"{window / 1e6 / steps:.3f} ms, busy {busy:.3f} ms an "
@@ -100,10 +136,16 @@ def by_scope(trace_dir, scopes, hlo=None, top=12):
 
 def main():
     if "--by-scope" in sys.argv:
-        at = sys.argv.index("--by-scope")
-        hlo = sys.argv[sys.argv.index("--hlo") + 1] \
-            if "--hlo" in sys.argv else None
-        return by_scope(sys.argv[1], sys.argv[at + 1].split(","), hlo)
+        def option(flag):
+            return sys.argv[sys.argv.index(flag) + 1] \
+                if flag in sys.argv else None
+        scopes = option("--by-scope")
+        scopes = DEVICE_SCOPES if scopes == "all" else scopes.split(",")
+        config = option("--config")
+        return by_scope(
+            sys.argv[1], scopes, option("--hlo"),
+            table=config and config_table(
+                config, rehearse="--rehearse" in sys.argv))
     trace_dir = sys.argv[1]
     n_steps = int(sys.argv[2]) if len(sys.argv) > 2 else 1
     top_k = int(sys.argv[3]) if len(sys.argv) > 3 else 40
