@@ -173,6 +173,21 @@ def select_token(logits, key, sc: SamplingConfig, history=None,
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+#: the id of a decode token that is still on the device: the step takes
+#: it from the sampled tokens of the step before (`prev_tokens[slot]`)
+PREV_TOKEN = -1
+
+
+def take_prev_tokens(token_ids, prev_tokens, slots):
+    """The step's flat token ids [T] with every `PREV_TOKEN` replaced by
+    its slot's entry of `prev_tokens` [S], what the step before sampled
+    (`slots` [T]: each token's slot, clipped into range): one gather and
+    one select, on the device."""
+    import jax.numpy as jnp
+    return jnp.where(token_ids == PREV_TOKEN, prev_tokens[slots],
+                     token_ids)
+
+
 def next_pow2(n, lo=16):
     p = lo
     while p < n:
@@ -418,7 +433,8 @@ def pack_step(token_budget, max_slots, decode, prefills,
 
     decode: [(slot, tokens, first position)] — one entry per running
         decode, as `Scheduler.plan` gives them: a list of token ids at
-        consecutive positions. One id is the plain one-token decode;
+        consecutive positions. One id is the plain one-token decode
+        (`PREV_TOKEN` where the host has not read the token back yet);
         [last, d_1..d_k] a speculative verify group (k <= draft_k
         proposed tokens after the last accepted one); L ids a block of a
         model that decodes by blocks (`buffers.sample_index` is [S, L]

@@ -208,6 +208,11 @@ class TPServingEngine(ServingEngine):
         self._upload = functools.partial(jax.device_put,
                                          device=replicated)
         self._rng = jax.device_put(self._rng, replicated)
+        if self._ahead:
+            # the tokens of the step before: the step hands them back
+            # replicated, and takes them so
+            self._prev_tokens = jax.device_put(self._prev_tokens,
+                                               replicated)
         psh = NamedSharding(self.mesh, self._pool_spec())
         ssh = NamedSharding(self.mesh, self._summary_spec())
 
@@ -352,7 +357,10 @@ class TPServingEngine(ServingEngine):
         # outputs replicate too, and so does the advanced key, the last
         # output (check_vma=False: the checker can't see through the
         # scanned psum)
-        n_data = 2 + (1 if batcher.needs_history(self.sampling) else 0)
+        # (an engine that can dispatch ahead: the sampled tokens of
+        # the step before, between the plan and the key)
+        n_data = 2 + (1 if batcher.needs_history(self.sampling) else 0) \
+            + (1 if self._ahead else 0)
         data_in = (rep,) * n_data
         # spec-sampling adds the residual-resample + accept matrices
         # to the verify outputs (engine._step_body) — all replicated,

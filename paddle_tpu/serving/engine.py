@@ -17,7 +17,11 @@ attention through `ops.pallas.flash_attention.ragged_paged_attention`
 Host loop per `step()`:
   scheduler.plan()  →  pack_step()  →  jitted mixed step  →  sample
   bookkeeping (TTFT / inter-token metrics, EOS + length termination,
-  block release).
+  block release). The loop is a PIPELINE ONE STEP DEEP where the engine
+  can be (docs/SERVING.md "Dispatching ahead"): step k+1 is planned,
+  packed and dispatched before step k is read back, a decode row whose
+  token is still on the device takes it there (`batcher.PREV_TOKEN`),
+  and the host's work runs behind the chip instead of in front of it.
 
 MoE decoder stacks (`GPTForGeneration(moe=...)`) serve through the
 same step: per-token top-k routing into FIXED expert-capacity slots
@@ -182,6 +186,21 @@ def _linear_work(plan, chunk):
 #: model-provided block: its softmax state is `max_run x query heads`
 #: rows of VMEM, and a longer prefill chunk walks its slot once a cut
 _BLOCK_MAX_RUN = 128
+
+
+class _Flight:
+    """One dispatched step: the plan as packed (`sp`), the requests it
+    fed by slot (`reqs`: emit goes by THEM, a slot may hold another
+    request by the time the tokens are read), its head output still on
+    the device (`out`), the host form the readback fills in (`got`),
+    whether the `step()` call that dispatched it was traced, and the
+    requests that count a token of it as in flight until it is read
+    (`owed`: none for a runner that reads back what it dispatched)."""
+    __slots__ = ("sp", "got", "out", "reqs", "traced", "owed")
+
+    def __init__(self, sp, got, out, reqs, traced, owed=()):
+        self.sp, self.got, self.out = sp, got, out
+        self.reqs, self.traced, self.owed = reqs, traced, owed
 
 
 class ServingEngine:
@@ -576,6 +595,27 @@ class ServingEngine:
         # returns the advanced chain, `_dispatch` rebinds it like the
         # pools and nothing reads it back
         self._rng = commit(jax.random.PRNGKey(int(seed)))
+        # the host loop is a pipeline one step deep (docs/SERVING.md
+        # "Dispatching ahead") where nothing the NEXT plan needs exists
+        # only on the device: not with host drafting (the drafter reads
+        # tokens), not with penalized sampling (the counts are host
+        # arrays), not in the device loop or the decide loop of block
+        # diffusion (they have loops of their own), not on a prefill
+        # replica (its requests park at their first token). Decided
+        # here, once, from what the engine sees of itself. `_ahead`
+        # shapes the compiled step (one more argument: the sampled
+        # tokens of the step before, which never leave the device);
+        # `_depth` is how far the loop runs ahead NOW: 1, or 0, which is
+        # the synchronous order (dispatch, read back, emit)
+        self._ahead = (self.draft_k == 0 and not self._multitick
+                       and self._diff is None and role != "prefill"
+                       and not batcher.needs_history(self.sampling))
+        self._depth = int(self._ahead)
+        self._prev_tokens = commit(jnp.zeros((max_slots,), jnp.int32)) \
+            if self._ahead else None
+        self._inflight = None       # the `_Flight` not read back yet
+        self.steps_ahead = 0        # steps dispatched over an unread one
+        self.ahead_wasted_rows = 0  # rows fed to a request that had ended
         # cast float params to the compute dtype ONCE (same discipline
         # as generation.generate: a per-step astype re-reads the full
         # parameter set every token)
@@ -839,7 +879,9 @@ class ServingEngine:
         budget's, the slot count's or the pool's.
 
         step(weights, k0, v0, k1, v1, ..., s0, c0, s1, c1, ..., plan,
-        key) -> (tokens [max_slots], the pools and states in the same
+        [the step before's tokens [max_slots], where the engine can
+        dispatch ahead,] key) -> (tokens [max_slots], the pools and
+        states in the same
         order (`kv._pools()`), the block's counters (`block.stat_names`,
         folded over the layers by `block.fold_stats`: the engine does
         not know what they count), float32 logits of the sample rows
@@ -878,6 +920,7 @@ class ServingEngine:
         linear = bool(self.kv.linear_layers)
         more_heads = self._kv_heads - arch.num_kv_heads
         diff, causal_block = self._diff, self._causal_block
+        ahead = self._ahead
         if diff and max_run % causal_block:
             raise ValueError(
                 f"block length {causal_block} does not divide the "
@@ -888,7 +931,7 @@ class ServingEngine:
             # every operation under one scope of `tracing.DEVICE_SCOPES`
             # (the block's own functions set theirs): HLO metadata only
             pools = list(rest[:n_pools])
-            plan, key = rest[n_pools:]
+            plan, *prev, key = rest[n_pools:]
             with jax.named_scope("plan_unpack"):
                 f = layout.unpack(plan)
                 token_ids, slot_ids, positions, sample_index = (
@@ -897,6 +940,11 @@ class ServingEngine:
                 valid = slot_ids >= 0
                 pos = jnp.where(valid, positions, 0)
                 safe_slot = jnp.where(valid, slot_ids, 0)
+                if ahead:
+                    # a decode token the host never saw: the step before
+                    # sampled it, and hands it over on the device
+                    token_ids = batcher.take_prev_tokens(
+                        token_ids, prev[0], safe_slot)
                 tables = {"full": f["block_tables"]}
                 if "window_tables" in f:
                     tables["sliding"] = f["window_tables"]
@@ -1075,6 +1123,7 @@ class ServingEngine:
         ad_names = tuple(self.adapters.array_names) if lora else ()
         K_ad = self.adapters.max_adapters if lora else 0
         layout = self.plan_layout
+        ahead = self._ahead
 
         def quantize(x):
             """[T, H, Dh] fp -> (quantized values, [T, H] fp32
@@ -1238,7 +1287,10 @@ class ServingEngine:
             # those — the kv_cache._pools() order; adapter slot
             # tensors follow them; then the packed plan (`plan_layout`:
             # flat tokens, sample index, block table, per-token adapter
-            # ids), sliced ONCE here, before the layer scan; active
+            # ids), sliced ONCE here, before the layer scan; an engine
+            # that can dispatch ahead adds the [S] tokens the step
+            # before sampled (a decode token whose id is the sentinel
+            # `batcher.PREV_TOKEN` is taken from them); active
             # logit processors add the [S, Vb] token-count histogram
             # before the key (ISSUE 19: the count form replaces the
             # [S, W] history tensor so the multi-tick loop can advance
@@ -1260,6 +1312,7 @@ class ServingEngine:
                 ad_arrays = rest[:len(ad_names)]
                 rest = rest[len(ad_names):]
             plan = rest.pop(0)
+            prev = rest.pop(0) if ahead else None
             if use_hist:
                 counts = rest.pop(0)
             (key,) = rest
@@ -1290,6 +1343,11 @@ class ServingEngine:
                 valid = slot_ids >= 0
                 pos = jnp.where(valid, positions, 0)
                 safe_slot = jnp.where(valid, slot_ids, 0)
+                if ahead:
+                    # a decode token the host never saw: the step before
+                    # sampled it, and hands it over on the device
+                    token_ids = batcher.take_prev_tokens(
+                        token_ids, prev, safe_slot)
                 # padding tokens write into the reserved NULL block
                 wb = jnp.where(valid,
                                block_tables[safe_slot, pos // BS], 0)
@@ -1903,7 +1961,10 @@ class ServingEngine:
 
     def cancel(self, req):
         """Abort a request (frontend cancellation). Blocks and prefix
-        locks are reclaimed immediately."""
+        locks are reclaimed immediately. A token of it in flight is read
+        back first: it was computed before the cancellation."""
+        if req.in_flight:
+            self.drain()
         ok = self.scheduler.cancel(req)
         if ok and _pmetrics._enabled:
             smetrics.SERVING_REQUESTS.labels("cancelled").inc()
@@ -1943,7 +2004,9 @@ class ServingEngine:
         `submit_migrated` consumes. Greedy parity contract: the ticket
         carries bit-exact KV (scales included) and the full token
         history, so the destination continues the stream exactly as
-        this engine would have (docs/SERVING.md)."""
+        this engine would have (docs/SERVING.md). A step in flight is
+        read back first: the ticket carries every token computed."""
+        self.drain()
         if req.slot < 0 or req.state not in ("decode", "handoff"):
             raise ValueError(
                 f"request {req.req_id} not extractable "
@@ -1978,7 +2041,9 @@ class ServingEngine:
         geometry against this engine's, then queues the ticket — the
         scheduler imports its blocks into a slot at the next plan (so
         the mixed step's shapes, and its one-compile contract, are
-        untouched by the admission). Returns the Request handle."""
+        untouched by the admission). Returns the Request handle. A
+        step in flight is read back first."""
+        self.drain()
         mine = self.kv.kv_meta()
         theirs = dict(ticket.kv_meta or {})
         if theirs != mine:
@@ -2141,7 +2206,9 @@ class ServingEngine:
         """The compiled mixed step's arguments, assembled HERE and
         nowhere else: weights, pools, adapter arrays, the packed plan
         (ONE buffer: flat tokens, sample index, block tables, adapter
-        ids), penalty counts, the key, the device loop's `tail`. What
+        ids), the tokens the step before sampled (an engine that can
+        dispatch ahead: `_prev_tokens`, which never left the device),
+        penalty counts, the key, the device loop's `tail`. What
         the host made goes up in one `device_put`: the plan alone
         unless logit processors or the device loop are on. Live steps
         and `example_step_args()` (what the kernel check traces and the
@@ -2159,7 +2226,9 @@ class ServingEngine:
         args = [self._arrays] + self.kv._pools()
         if self.adapters is not None:
             args += self.adapters.device_arrays()
-        return args + dev[:head] + [self._rng] + dev[head:]
+        prev = [self._prev_tokens] if self._ahead else []
+        return args + dev[:1] + prev + dev[1:head] + [self._rng] \
+            + dev[head:]
 
     def _multitick_tail(self, decode, n):
         """The device loop's control tail for `n` ticks over a plan's
@@ -2205,14 +2274,27 @@ class ServingEngine:
         `self._rng`: only a step that runs does — boot stays
         deterministic)."""
         tail = self._multitick_tail([], 1) if self._multitick else ()
-        return self._step_args(self._pack([], []), tail)
+        sp = self._pack([], [])
+        # the buffer goes back to the packer: the OTHER one may be a
+        # dispatched step's, not read back yet
+        self._plan_flip ^= 1
+        return self._step_args(sp, tail)
 
     def step(self):
         """One engine iteration. Returns True when any work (tokens or
         expiries) happened, False when the engine is idle/starved.
         Pack, dispatch and readback are the device program's own
-        (`_run_tick`, `_run_multitick`); what they hand back is one
-        host form, and from `engine.emit` on there is one loop."""
+        (`_launch_tick` and `_land_tick`, `_run_block_tick`,
+        `_run_multitick`); what they hand back is one host form, and
+        from `engine.emit` on there is one loop.
+
+        At depth 1 (`_depth`; docs/SERVING.md "Dispatching ahead") the
+        call is: plan k+1 -> pack -> dispatch k+1 -> read back step k ->
+        emit step k -> note. The step it dispatches stays in flight
+        (`_inflight`) until the next call reads it back, so the host's
+        work lies behind the chip; a call that finds nothing to plan
+        reads the step in flight back and emits it. At depth 0 the call
+        reads back what it dispatched, as it always did."""
         sch = self.scheduler
         # tracing state is sampled ONCE per step: recording stays
         # consistent across the step even if a monitor attaches midway
@@ -2223,45 +2305,159 @@ class ServingEngine:
             # seconds of this step's summed phases spent in tracing code
             # (the flight field `trace_self`)
             self._trace_self = 0.0
-        plan = sch.plan()
+        had = self._inflight is not None
+        # (with nothing in flight `plan` is called as it always was:
+        # callers wrap it, the benchmark's planted faults among them)
+        plan = sch.plan(self.drain) if had else sch.plan()
         if _pmetrics._enabled and plan.expired:
             smetrics.SERVING_REQUESTS.labels("expired").inc(
                 len(plan.expired))
+        prev = self._inflight       # None where the plan had to drain
         if plan.empty:
+            # nothing to dispatch: the step in flight is the work
+            self.drain()
             self._flush_deferred()
             if trace_on:
                 ph.close()
                 _tracing.TRACER.flush()
-            return bool(plan.expired)
+            return had or bool(plan.expired)
         if trace_on:
             ph.mark("engine.pack")
-        run = self._run_multitick if self._multitick else \
-            self._run_block_tick if self._diff else self._run_tick
-        sp, got = run(plan, trace_on)
+        if self._multitick or self._diff:
+            # loops of their own: dispatched AND read back in there
+            cur = (self._run_multitick if self._multitick
+                   else self._run_block_tick)(plan, trace_on)
+            hold, landed = False, [cur]
+        else:
+            cur = self._launch_tick(plan, trace_on)
+            # the wait: for the step BEFORE, where one is in flight (its
+            # parked flight record takes its counters from the readback,
+            # so it publishes after it); else for this one, with what
+            # the last step parked published while the device runs
+            hold = bool(self._depth)
+            landed = [fl for fl in (prev, None if hold else cur)
+                      if fl is not None]
+            if prev is not None:
+                self._land_tick(prev)
+            self._flush_deferred()
+            if not hold:
+                self._land_tick(cur)
+        self._hold(cur if hold else None)
         now = ph.mark("engine.emit") if trace_on else self.clock()
         if trace_on:
             t = self.clock()
-            # one prefill_chunk span per planned chunk: slot residents
-            # are stable between plan() and here (admissions happen
-            # only inside plan), so sch.slots[slot] is the chunk's
-            # request
+            # one prefill_chunk span per planned chunk, by the requests
+            # the step was dispatched for
             for slot, chunk, start, completes in plan.prefills:
-                req = sch.slots[slot]
+                req = cur.reqs.get(slot)
                 if req is not None:
                     _tracing.TRACER.queue(
                         req.trace_id, "prefill_chunk",
                         replica=self.name, ts=now, start=int(start),
                         tokens=len(chunk), completes=bool(completes))
             self._trace_self += self.clock() - t
+        wasted = sum(self._emit_step(fl, now, trace_on) for fl in landed)
+        if trace_on:
+            ph.mark("engine.note")
+        for fl in landed:
+            self._note_landed(fl)
+        got, sp = cur.got, cur.sp
+        if prev is not None:
+            self.steps_ahead += 1
+            self.ahead_wasted_rows += wasted
+        got["dispatch"].update(ahead=int(prev is not None),
+                               ahead_wasted_rows=wasted)
+        snap = record = None
+        if _pmetrics._enabled:
+            snap = self._snapshot(sp.prefill_tokens, got)
+        if trace_on:
+            # flight-recorder note: what has to be read NOW is (host
+            # ints the loop already holds, the allocators' counts, the
+            # clock; a block model's counters come with the tokens);
+            # the record is made of it later. It describes the plan this
+            # call DISPATCHED, and `dur` is this call's wall time
+            record = self._step_record(t0, sp, got)
+        if snap is not None and not self._multitick:
+            # the registry's metrics are not tracing's: published now
+            self._observe(snap, None)
+            snap = None
+        if sch.has_work and (snap is not None or record is not None):
+            if self._deferred is not None:      # tracing went off and on
+                self._flush_deferred()
+            # deferred observability: every value was captured NOW (a
+            # step in flight: its counters come with its tokens); it
+            # publishes after the next dispatch launches (or at the
+            # idle / flush points), behind the device
+            self._deferred = (snap, record)
+        else:
+            self._observe(snap, record)
+            if trace_on:
+                _tracing.TRACER.flush()
+        return True
+
+    def _hold(self, flight):
+        """`flight` (or None) is the step in flight from here on; the
+        scheduler counts it as work (`has_work`)."""
+        self._inflight = flight
+        self.scheduler.step_in_flight = flight is not None
+
+    def drain(self):
+        """Read the step in flight back and emit its tokens, if there is
+        one: after this the engine is where the synchronous loop would
+        be. What needs a step's OUTCOME before it runs calls it first:
+        a plan that has to preempt or to expire a request with a token
+        in flight (`Scheduler.plan`), `cancel`, `extract_request`,
+        `submit_migrated`, `swap_weights`, `close`, the end of `run`;
+        `step` itself when it finds nothing to dispatch."""
+        fl = self._inflight
+        if fl is None:
+            return
+        self._hold(None)
+        trace_on = _tracing._enabled
+        ph = self.phases
+        # the caller's phase (the plan's, the frontend's) goes on after
+        was = ph.name
+        if trace_on:
+            ph.mark("engine.wait")
+        self._land_tick(fl)
+        self._flush_deferred()
+        now = ph.mark("engine.emit") if trace_on else self.clock()
+        self._emit_step(fl, now, trace_on)
+        if trace_on:
+            ph.mark("engine.note")
+        self._note_landed(fl)
+        if trace_on:
+            if was is not None:
+                ph.mark(was)
+            else:
+                ph.close()
+            _tracing.TRACER.flush()
+
+    def _emit_step(self, fl, now, trace_on):
+        """Hand a step that was read back to its requests: the ones it
+        was DISPATCHED for (`fl.reqs`), whatever the slots hold now. A
+        request that has ended since (on EOS, one step before the host
+        learned it) was fed a row too many: its token is dropped here.
+        -> the rows of LATER dispatches that this emit found wasted, the
+        same way: a request that ends here with a token still in
+        flight."""
+        sp, got, reqs = fl.sp, fl.got, fl.reqs
+        if got["logits"] is not None:
+            # after `step()` returns, `sample_logits` is what the tokens
+            # just emitted were taken from
+            self.sample_logits = got["logits"]
+        wasted = 0
         for slot in sp.prefill_done:
-            req = sch.slots[slot]
-            if req is not None and got["first"] is None:
+            req = reqs.get(slot)
+            if req is None or req.done:
+                continue
+            if got["first"] is None:
                 # block decoding: a prefill samples nothing, and its
                 # request feeds its first block from the next step on
                 req.state = "decode"
-            elif req is not None and not self.emit(
-                    req, [int(got["first"][slot])], now, trace_on) \
-                    and self.role == "prefill":
+            elif self.emit(req, [int(got["first"][slot])], now, trace_on):
+                wasted += req.in_flight
+            elif self.role == "prefill":
                 # prefill-role handoff point: the first token is
                 # sampled, every prompt token's K/V is written — the
                 # request parks until the frontend extracts it toward a
@@ -2272,51 +2468,38 @@ class ServingEngine:
                     _tracing.TRACER.event(req.trace_id, "handoff",
                                           replica=self.name, ts=now)
         for slot, tokens, accepted in got["groups"]:
-            req = sch.slots[slot]
-            if req is not None and not self.emit(
-                    req, tokens, now, trace_on, got["verify"]) \
-                    and accepted is not None:
+            req = reqs.get(slot)
+            if req is None or req.done:
+                continue
+            if self.emit(req, tokens, now, trace_on, got["verify"]):
+                wasted += req.in_flight
+            elif accepted is not None:
                 self._note_accept(slot, accepted)
-        if trace_on:
-            ph.mark("engine.note")
+        return wasted
+
+    def _note_landed(self, fl):
+        """The counters a step's readback brought with the tokens."""
+        got = fl.got
         if got["moe_stats"] is not None:
             self._note_moe_stats(got["moe_stats"])
         self.spec_proposed_total += got["spec"][0]
         self.spec_accepted_total += got["spec"][1]
-        snap = record = None
-        if _pmetrics._enabled:
-            snap = self._snapshot(sp.prefill_tokens, got)
-        if trace_on:
-            # flight-recorder note: what has to be read NOW is (host
-            # ints the loop already holds, the allocators' counts, the
-            # clock; a block model's counters came with the tokens);
-            # the record is made of it later
-            record = self._step_record(t0, sp, got)
-        if snap is not None and not self._multitick:
-            # the registry's metrics are not tracing's: published now
-            self._observe(snap, None)
-            snap = None
-        if sch.has_work and (snap is not None or record is not None):
-            if self._deferred is not None:      # tracing went off and on
-                self._flush_deferred()
-            # deferred observability: every value was captured NOW; it
-            # publishes after the next dispatch launches (or at the
-            # idle / flush points), behind the device
-            self._deferred = (snap, record)
-        else:
-            self._observe(snap, record)
-            if trace_on:
-                _tracing.TRACER.flush()
-        return True
+
+    def _fed_requests(self, plan):
+        """{slot: request} of the slots `plan` feeds, as they stand at
+        its dispatch."""
+        slots = self.scheduler.slots
+        return {e[0]: slots[e[0]] for e in plan.decode + plan.prefills}
 
     def _dispatch(self, plan, sp, tail, trace_on):
-        """Run the compiled step on the packed `plan`, rebind the pools
-        and the key it returns (both stay on the device) and note the
-        plan fed. -> (its head output, still on the device; the host
-        form with what every program leaves alike)."""
+        """Run the compiled step on the packed `plan`, rebind the pools,
+        the key and (dispatching ahead) the sampled tokens it returns,
+        all of which stay on the device, and note the plan fed. -> (its
+        head output, still on the device; the host form with what every
+        program leaves alike)."""
         # spec: drafts proposed, drafts accepted, groups by accept length
         got = dict(verify=False, moe_stats=None, block_stats=None,
-                   spec=(0, 0, ()), work=None, dispatch={})
+                   logits=None, spec=(0, 0, ()), work=None, dispatch={})
         args = self._step_args(sp, tail,
                                got["dispatch"] if trace_on else None)
         if trace_on:
@@ -2324,31 +2507,44 @@ class ServingEngine:
         *res, self._rng = self._step_fn(*args)
         if trace_on:
             self.phases.mark("engine.wait")
-            if not self._multitick:     # the device loop flushes later
-                self._flush_deferred()
         if self.num_experts:
             res, got["moe_stats"] = res[:-1], res[-1]
         elif self._block is not None:
             # the sample rows' logits stay on the device, a row a slot:
             # nothing reads them back but a caller who asks
-            res, got["block_stats"], self.sample_logits = \
+            # (`sample_logits`, rebound when the step's tokens are
+            # emitted)
+            res, got["block_stats"], got["logits"] = \
                 res[:-2], res[-2], res[-1]
+        if self._ahead:
+            self._prev_tokens = res[0]
         self.kv._set_pools(res[1:])
         self.scheduler.note_fed(plan)
         self.steps_run += 1
         return res[0], got
 
-    def _run_tick(self, plan, trace_on):
-        """Pack, dispatch and read back one tick of the mixed step.
-        -> (packed plan, host form): `first` the sampled token a slot
-        (what a completed prefill emits), `groups` a decode slot's
-        (slot, tokens to emit, length to roll the slot back to or
-        None), `verify` whether they were verify groups, and the
-        counters only this program has."""
+    def _launch_tick(self, plan, trace_on):
+        """Pack and dispatch one tick of the mixed step; nothing is read
+        back. -> the `_Flight`. The requests it will hand a token (a
+        decode slot's, a completed prefill's) count it as in flight
+        from here, and a completed prefill decodes from here: the next
+        plan may be made before this step is read."""
+        reqs = self._fed_requests(plan)
         sp = self._pack(plan.decode, plan.prefills)
         out, got = self._dispatch(plan, sp, (), trace_on)
         got.update(verify=bool(self.draft_k),
                    decode_tokens=sp.decode_tokens)
+        owed = [reqs[slot] for slot in sp.decode_slots + sp.prefill_done]
+        for req in owed:
+            req.in_flight += 1
+            if req.state == "prefill":
+                req.state = "decode"
+        if self._depth:
+            # the copy to the host starts when the step ends, not when
+            # the next call asks for it
+            stats = got["block_stats"] if trace_on else None
+            for a in (out,) if stats is None else (out, stats):
+                a.copy_to_host_async()
         if self._sparse:
             self._note_sparse(pos + len(toks) - 1
                               for _, toks, pos in plan.decode)
@@ -2356,10 +2552,26 @@ class ServingEngine:
             # the attention work of this step, counted while the device
             # does it: host arithmetic on the plan, no readback
             got["work"] = self._plan_work(plan)
+        return _Flight(sp, got, out, reqs, trace_on, owed)
+
+    def _land_tick(self, fl):
+        """Read a dispatched tick back, into its host form (`fl.got`):
+        `first` the sampled token a slot (what a completed prefill
+        emits), `groups` a decode slot's (slot, tokens to emit, length
+        to roll the slot back to or None), and the counters only this
+        program has."""
+        import jax
+        sp, got, out = fl.sp, fl.got, fl.out
+        for req in fl.owed:
+            req.in_flight -= 1
+        # the step has ended on EVERY device, not on the first alone
+        # (`np.asarray` of a replicated array waits for one shard): on
+        # the CPU backend a device reads its plan buffer in place, and
+        # that buffer is packed again once this step has been read
+        jax.block_until_ready(out)
         if not self.draft_k:
-            if trace_on and got["block_stats"] is not None:
+            if fl.traced and got["block_stats"] is not None:
                 # a block model's counters with the tokens: one readback
-                import jax
                 tok_np, got["block_stats"] = jax.device_get(
                     (out, got["block_stats"]))
             else:
@@ -2367,14 +2579,12 @@ class ServingEngine:
             got.update(first=tok_np, groups=[
                 (slot, [int(tok_np[slot])], None)
                 for slot in sp.decode_slots])
-            return sp, got
+            return
         from .draft import accept_length, accept_length_sampled
         tok_np, tokv_np, *sampled = (np.asarray(t) for t in out)
         groups, prop, acc = [], 0, 0
         hist = [0] * (self.draft_k + 1)
         for slot, toks, pos in sp.decode_entries:
-            if self.scheduler.slots[slot] is None:
-                continue
             g = tokv_np[slot]
             if self.spec_sampling:
                 # rejection-sampling acceptance: accepted drafts
@@ -2396,10 +2606,10 @@ class ServingEngine:
             # contents were rejected-draft K/V columns
             groups.append((slot, emitted, pos + m + 1))
         got.update(first=tok_np, groups=groups, spec=(prop, acc, hist))
-        return sp, got
 
     def _run_block_tick(self, plan, trace_on):
-        """`_run_tick` for a model that decodes by blocks: a decode
+        """`_launch_tick` and `_land_tick` in one, for a model that
+        decodes by blocks (-> the `_Flight`, read back): a decode
         entry fed its slot's current block of L rows, and the step hands
         back every row's candidate and confidence. Here the block's
         state moves, by POSITION (an id equal to the mask id means
@@ -2418,8 +2628,10 @@ class ServingEngine:
         # fed, and a committed block's state is the next block's by then
         fed = [(slot, sch.slots[slot], list(sch.slots[slot].block_decided),
                 toks, pos) for slot, toks, pos in plan.decode]
+        reqs = self._fed_requests(plan)
         sp = self._pack(plan.decode, plan.prefills)
         out, got = self._dispatch(plan, sp, (), trace_on)
+        self._flush_deferred()      # behind the device
         got.update(decode_tokens=sp.decode_tokens, first=None)
         if trace_on:
             import jax
@@ -2468,10 +2680,11 @@ class ServingEngine:
                 diff_block_len=L, diff_slot_passes=len(fed),
                 diff_rows_masked=masked_rows, diff_tokens_decided=decided,
                 diff_commits=commits, diff_blocks_committed=commits)
-        return sp, got
+        return _Flight(sp, got, None, reqs, trace_on)
 
     def _run_multitick(self, plan, trace_on):
-        """`_run_tick` for the device loop: preallocate tick capacity,
+        """`_launch_tick` and `_land_tick` in one, for the device loop
+        (-> the `_Flight`, read back): preallocate tick capacity,
         launch the while_loop dispatch, harvest the staging buffer into
         the same host form, so the emitted tokens replay through the
         host bookkeeping a 1-tick engine runs per step."""
@@ -2490,6 +2703,7 @@ class ServingEngine:
         # the tail first: it preallocates the ticks' blocks, and the
         # pack copies the tables as they then stand
         tail = self._multitick_tail(plan.decode, n)
+        reqs = self._fed_requests(plan)
         sp = self._pack(plan.decode, plan.prefills)
         ctrl, got = self._dispatch(plan, sp, tail, trace_on)
         # async device_get: start the control-output copies and flush
@@ -2562,7 +2776,7 @@ class ServingEngine:
             early_exit_finish=ev_finish, early_exit_overflow=ev_over)
         got.update(first=staged_np[:, 0], groups=groups,
                    decode_tokens=sum(len(g[1]) for g in groups))
-        return sp, got
+        return _Flight(sp, got, None, reqs, trace_on)
 
     def emit(self, req, tokens, now, trace_on, verify=False):
         """Append generated tokens; returns True when the request
@@ -2881,8 +3095,11 @@ class ServingEngine:
                     f"{self.block_size}) cannot cover the resident "
                     "working set; raise num_blocks or lower max_slots")
             steps += 1
-        # the last dispatch's observability may still be parked in the
+        # a step still in flight (`max_steps` cut the loop) is read
+        # back: `run` hands back what the steps it ran computed; the
+        # last dispatch's observability may still be parked in the
         # deferred lane — publish before handing control back
+        self.drain()
         self._flush_deferred()
         return steps
 
@@ -2971,6 +3188,7 @@ class ServingEngine:
         (drained) — in-flight requests would otherwise mix versions
         mid-sequence."""
         import jax.numpy as jnp
+        self.drain()
         if self._moe_weight_bits:
             raise ValueError(
                 "live weight swap on an engine-side quantized MoE "
@@ -3011,6 +3229,7 @@ class ServingEngine:
         docs/DEPLOYMENT.md). Returns the number of blocks spilled.
         Idempotent; the engine must be drained (no resident
         requests)."""
+        self.drain()
         self._flush_deferred()
         spilled = 0
         if self.prefix_cache is not None:

@@ -372,7 +372,14 @@ class ServingFrontend:
         the same engine-mutation discipline as cancellation). Tokens
         generated before the flag were published by the previous
         `_publish`, so the migration sentinel is strictly ordered
-        after every delivered token."""
+        after every delivered token. An engine that dispatches ahead
+        holds a step's tokens unread between steps: they are read back
+        and published first, so the ticket carries, and the client
+        has, every token computed."""
+        if any(h.extract_requested and not h.terminal
+               for h in self._live):
+            self.engine.drain()
+            self._publish()
         for handle in list(self._live):
             if not handle.extract_requested or handle.terminal:
                 continue

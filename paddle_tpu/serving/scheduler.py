@@ -89,6 +89,11 @@ class Request:                     # in sets/queues across state moves
     block_tokens: Optional[list] = None
     block_decided: Optional[list] = None
     block_passes: int = 0
+    # tokens of this request that a dispatched step has sampled and the
+    # host has not read back yet (0 or 1: the engine's pipeline is one
+    # step deep; docs/SERVING.md "Dispatching ahead"). The engine counts
+    # it up at dispatch and down at readback
+    in_flight: int = 0
 
     @property
     def runtime_prompt(self):
@@ -175,6 +180,9 @@ class Scheduler:
         # replica label the tracing hooks stamp on span events; the
         # owning engine overwrites it with its own name
         self.replica = None
+        # a dispatched step whose tokens the engine has not read back
+        # (the engine sets it): work, whatever the slots hold
+        self.step_in_flight = False
 
     # ---------------------------------------------------------- intake
     def submit(self, prompt, max_new_tokens, eos_token_id=None,
@@ -242,7 +250,8 @@ class Scheduler:
 
     @property
     def has_work(self):
-        return bool(self.queue) or self.num_active > 0
+        return bool(self.queue) or self.num_active > 0 \
+            or self.step_in_flight
 
     # ------------------------------------------------------- internals
     def _free_slot(self, req):
@@ -467,20 +476,41 @@ class Scheduler:
         return pos + 1 + max(k, 0)
 
     # ------------------------------------------------------------ plan
-    def plan(self) -> Plan:
+    def plan(self, drain=None) -> Plan:
         """One engine iteration's work. Mutates scheduler/cache state
-        (admissions, block allocation, preemptions, expiries)."""
+        (admissions, block allocation, preemptions, expiries).
+
+        `drain`: given while a dispatched step is unread (the engine's
+        `drain`: read it back, emit its tokens). A decode slot whose
+        newest token is in that step (`Request.in_flight`) is fed the
+        sentinel `batcher.PREV_TOKEN` at its next position, and the
+        compiled step takes the token from the step before it, on the
+        device; a request whose horizon the tokens in flight reach is
+        not fed again. What needs the step's OUTCOME first calls
+        `drain()` and then runs as it always did: the expiry of a
+        request with a token in flight, and a preemption."""
         now = self.clock()
+        if drain is not None and any(
+                r is not None and r.in_flight and r.deadline is not None
+                and now > r.deadline for r in self.slots):
+            drain()
+            drain = None        # nothing is in flight any more
         expired = self._expire(now)
         # window layers: what lies behind every slot's window goes back
-        # first. HERE, before anything is allotted and while no step is
-        # in flight: the tables are a step's inputs (on the CPU backend
-        # jax reads the numpy buffer in place)
+        # first, before anything is allotted. A block released here and
+        # allotted again below, while the step before is still running,
+        # is safe: that step reads its OWN copy of the tables (the
+        # engine's `_pack` copied them into its plan buffer), so the
+        # host's tables are no step's input; and the next step's writes
+        # to the block follow that step's reads of it, because the next
+        # step takes the pools the step before hands back (donated: one
+        # buffer, in program order on the device)
         self.kv.release_behind_windows()
         self._admit()
 
         decode = []
         protected = set()
+        drained = False
         # decodes first, oldest arrival first: block pressure falls on
         # the youngest/longest sequences, never the queue head
         decoders = sorted(
@@ -490,12 +520,26 @@ class Scheduler:
         for req in decoders:
             if req.slot < 0:    # preempted by an earlier iteration
                 continue
+            if req.in_flight and len(req.output) + req.in_flight \
+                    >= req.max_new_tokens:
+                # the token in flight ends it by LENGTH: the host can
+                # count, and the slot is not fed again
+                continue
             # position of the token being fed = tokens already cached
             pos = int(self.kv.slot_lens[req.slot])
             # a block's rows are all written, every pass
             width = 1 if self.block_decoding is None \
                 else self.block_decoding.block_length
             while not self.kv.ensure_capacity(req.slot, pos + width):
+                if drain is not None:
+                    # the pool is dry with a step unread: a request it
+                    # ends gives blocks back, and a victim must carry
+                    # every token it was given. Read it back, try again
+                    drain()
+                    drain, drained = None, True
+                    if req.slot < 0:
+                        break
+                    continue
                 if self._preempt_victim(protected | {req}) is None:
                     # nothing left to evict: preempt THIS decode
                     self._preempt_victim(protected)
@@ -518,7 +562,14 @@ class Scheduler:
                 # the verify burst, and the loop body widens the group
                 decode.append((req.slot, [req.output[-1]], pos))
             else:
-                decode.append((req.slot, [req.output[-1]], pos))
+                # the newest token: the host's, or still on the device
+                last = batcher.PREV_TOKEN if req.in_flight \
+                    else req.output[-1]
+                decode.append((req.slot, [last], pos))
+        if drained:
+            # the drain ended requests (by EOS) that were planned before
+            # it: their slots are free
+            decode = [e for e in decode if self.slots[e[0]] is not None]
 
         # with speculation (or the sparse decode region) the region is
         # RESERVED up front (see batcher.pack_step) — prefill budget
@@ -602,8 +653,12 @@ class Scheduler:
                 and req.adapter_id is None:
             # publish prompt + generated history (chat-turn reuse);
             # only tokens whose K/V was actually written count — the
-            # last emitted token never fed the step
-            n = int(self.kv.slot_lens[req.slot])
+            # last emitted token never fed the step. Cut at the tokens
+            # EMITTED: a request that ended on EOS with the next step
+            # already dispatched was fed one row more (`slot_lens`
+            # counts it), whose K/V belongs to no request
+            n = min(int(self.kv.slot_lens[req.slot]),
+                    len(req.prompt) + len(req.output) - 1)
             self.prefix_cache.insert(req.slot,
                                      (req.prompt + req.output)[:n])
         self._free_slot(req)

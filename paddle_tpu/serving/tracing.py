@@ -59,7 +59,7 @@ Env knobs: ``PADDLE_TPU_TRACE=1`` enables at import,
 ``PADDLE_TPU_TRACE_CAPACITY`` bounds the retained-trace table
 (default 2048, oldest finished evicted first),
 ``PADDLE_TPU_TRACE_EVENTS_MAX`` bounds events per trace (default 512),
-``PADDLE_TPU_FLIGHT_STEPS`` bounds each flight ring (default 4096).
+``PADDLE_TPU_FLIGHT_STEPS`` bounds each flight ring (default 16384).
 """
 from __future__ import annotations
 
@@ -721,13 +721,16 @@ class StepFlightRecorder:
     """Bounded per-engine ring of per-step records (ISSUE 16 tentpole
     (b)). The engine notes one record per `step()` — host ints/floats
     it already holds — only when tracing is enabled; the ring is sized
-    by PADDLE_TPU_FLIGHT_STEPS (default 4096) so a long-lived replica
-    keeps a recent flight window, not unbounded history."""
+    by PADDLE_TPU_FLIGHT_STEPS (default 16384: two minutes and a half
+    of 10 ms steps; it was 4096 until an engine that dispatches ahead
+    ran the benchmark's 45 traced seconds in 4,600 steps) so a
+    long-lived replica keeps a recent flight window, not unbounded
+    history."""
 
     def __init__(self, engine_name, role, maxlen=None):
         if maxlen is None:
             maxlen = int(os.environ.get(
-                "PADDLE_TPU_FLIGHT_STEPS", 4096))
+                "PADDLE_TPU_FLIGHT_STEPS", 16384))
         self.engine_name = engine_name
         self.role = role
         self.maxlen = max(1, int(maxlen))

@@ -326,7 +326,8 @@ def test_plan_entries_are_lists_from_a_first_position():
         plan = eng.scheduler.plan()
         seen += plan.decode
         # (drive the planned step by hand: what step() does)
-        sp, got = eng._run_block_tick(plan, False)
+        flight = eng._run_block_tick(plan, False)
+        sp, got = flight.sp, flight.got
         for slot in sp.prefill_done:
             eng.scheduler.slots[slot].state = "decode"
         for slot, tokens, _ in got["groups"]:

@@ -190,7 +190,10 @@ class TestEngineHandoff:
         for _ in range(3):
             dec.step()
         assert dr.state == "decode" and dr.ticket is None
-        # force a preemption of the migrant
+        # force a preemption of the migrant (as the plan does it: the
+        # step in flight is read back first, a victim carries every
+        # token it was given)
+        dec.drain()
         dec.scheduler._preempt_victim(set())
         assert dr.state == "queued" and dr.slot == -1
         dec.run()

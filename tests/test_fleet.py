@@ -112,8 +112,11 @@ class TestBundle:
         assert [f[0] for f in sig["plan"]] == [
             "token_ids", "slot_ids", "positions", "sample_index",
             "block_tables"]
-        assert sig["args"][-2:] == [
-            f"int32[{eng.plan_layout.size}]", "uint32[2]"]
+        # the plan, the tokens of the step before (PR 36: the engine
+        # dispatches ahead), the key
+        assert sig["args"][-3:] == [
+            f"int32[{eng.plan_layout.size}]",
+            f"int32[{eng.kv.max_slots}]", "uint32[2]"]
         if drift == "before_the_field":
             del man["step_signatures"]
         else:

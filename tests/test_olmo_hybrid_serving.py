@@ -356,8 +356,8 @@ def test_alone_and_in_company_bit_for_bit(interpret, dtype):
     from paddle_tpu.serving.scheduler import Scheduler
     plan = Scheduler.plan
 
-    def watch(self):
-        out = plan(self)
+    def watch(self, *args):
+        out = plan(self, *args)
         fed.extend((start, len(chunk), done)
                    for _, chunk, start, done in out.prefills)
         return out
@@ -520,7 +520,11 @@ def test_flight_record_and_scopes():
     assert (recs[0]["lin_tokens"], recs[0]["lin_runs"],
             recs[0]["lin_chunks"]) == (16, 1, 2)
     assert max(r["state_slots_in_use"] for r in recs) == 3
-    assert recs[-1]["state_slots_in_use"] == 0
+    # a record is noted by the call that DISPATCHED its step: the last
+    # request's last token is still in flight then (its slot is live),
+    # and the call that reads it back dispatches nothing
+    assert recs[-1]["state_slots_in_use"] == 1
+    assert eng.kv.state_slots_in_use == 0
     txt = eng._step_fn._jitted.trace(
         *eng.example_step_args()).lower().as_text(debug_info=True)
     for scope in ("lin_proj", "lin_conv", "gated_delta", "lin_gate_out",

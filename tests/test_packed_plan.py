@@ -104,8 +104,18 @@ def submit_mix(eng, case):
 
 
 def serve(case, kind):
-    """-> (engine, each request's tokens after STEPS engine steps)."""
+    """-> (engine, each request's tokens after STEPS engine steps).
+    Seeded sampling in the SYNCHRONOUS order (`_depth` 0: dispatch, read
+    back, emit; the compiled step is the same): a sample is drawn with
+    its step's key, and six requests on four slots make the parent's
+    steps only where a slot that frees is filled in the very next plan.
+    An engine that dispatches ahead (ISSUE 36) learns the end of a
+    request one step later and fills the slot one step later: the same
+    distribution under other keys. Greedy tokens are the parent's in
+    either order, and are served in the engine's own."""
     eng = build(case, kind)
+    if kind != "greedy":
+        eng._depth = 0
     reqs = submit_mix(eng, case)
     for _ in range(STEPS):
         if not eng.scheduler.has_work:
@@ -297,6 +307,43 @@ def test_uploads_reader():
         "better": "lower", "source": "program_counter",
         "layer": "mixed_step", "moves": "serve_tokens_per_s",
         "workloads": None}
+
+
+def test_dispatch_ahead_reader():
+    """`mixed_step.dispatch_ahead_pct` on hand-made records, and on the
+    parent's (no `ahead`): nothing, and no exception."""
+    import types
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    from harness.files import load_module
+    read = load_module("layer_metrics", "mixed_step.dispatch_ahead_pct").read
+
+    def ctx(flight):
+        logged = []
+        return types.SimpleNamespace(flight=flight, log=logged.append), \
+            logged
+    c, logged = ctx([{"ahead": 0, "ahead_wasted_rows": 0},
+                     {"ahead": 1, "ahead_wasted_rows": 2},
+                     {"ahead": 1, "ahead_wasted_rows": 0},
+                     {"ahead": 1, "ahead_wasted_rows": 1}])
+    assert read(c) == pytest.approx(75.0)
+    assert "3 of 4 steps" in logged[-1] and "0.750 rows a step" in logged[-1]
+    c, logged = ctx([{"ahead": 0, "ahead_wasted_rows": 0}] * 3)
+    assert read(c) == 0.0
+    for flight in ([], [{"ts": 1.0, "dur": 0.02, "h2d_arrays": 1}]):
+        c, logged = ctx(flight)
+        assert read(c) is None and not logged
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "mixed_step.dispatch_ahead_pct")
+    assert entry == {
+        "name": "mixed_step.dispatch_ahead_pct", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "mixed_step", "moves": "serve_tokens_per_s",
+        "workloads": [
+            "serve_gpt3_1p3b_closed", "serve_gpt3_1p3b_closed_b",
+            "serve_trinity_ep8_mixed_len", "serve_olmo_hybrid_mixed_len",
+            "serve_sdar_30b_a3b_mixed_len"]}
 
 
 # ------------------------------- (d) one compile across example args

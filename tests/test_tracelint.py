@@ -791,6 +791,9 @@ class TestWatchdogCatchesEngineRecompile:
             eng._arrays, eng.kv.k_pool, eng.kv.v_pool,
             # the packed plan: int32 by contract
             jnp.zeros((eng.plan_layout.size,), jnp.int16),
+            # the tokens of the step before (an engine that dispatches
+            # ahead takes them beside the plan)
+            eng._prev_tokens,
             jax.random.PRNGKey(0))
         del bad
         v = wd.consume_violations()
