@@ -119,12 +119,36 @@ def moe_ffn(arch, lw, x, valid):
                                    lw["e_up"], lw["e_down"])
 
 
+def indexer_proj(arch, lw, x, positions):
+    """The indexer's three products of a "sparse" layer
+    (`serving_block.LearnedSelection`) over the normed rows x [T, D]:
+    -> (qI [T, J, Di] and kI [T, Di] in the compute dtype, the first
+    `rope_dims` dims of each rotated at the token's position; w [T, J]
+    float32, the constant scale folded in). kI is layer-normed (a gain,
+    no bias) before the rotary embedding."""
+    import jax.numpy as jnp
+    sel = arch.selection
+    T, J, Di, R = x.shape[0], sel.num_heads, sel.head_dim, sel.rope_dims
+    qI = _mm(x, lw["idx_wq"]).reshape(T, J, Di)
+    kf = _mm(x, lw["idx_wk"]).astype(jnp.float32)
+    kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+    kI = _rms(kf, lw["idx_k_norm"], sel.eps).astype(x.dtype)[:, None, :]
+    qI, kI = (jnp.concatenate(
+        [_rope(a[..., :R], positions, arch.rope_theta), a[..., R:]], -1)
+        for a in (qI, kI))
+    w = jnp.dot(x.astype(jnp.float32), lw["idx_ww"]) * sel.scale
+    return qI, kI[:, 0], w
+
+
 def layer_forward(arch, li, lw, h, positions, valid, attend):
     """One decoder layer over flat tokens: h [T, D], positions [T],
     valid [T] bool. `attend(q, k, v, li)` takes q [T, Hq, Dh] and this
-    step's k, v [T, Hkv, Dh] and returns the BLOCK-CAUSAL attention
-    output [T, Hq, Dh] of layer `li`: the caller owns the cache and the
-    mask. Returns (h, MoE stats)."""
+    step's k, v [T, Hkv, Dh] and returns the attention output [T, Hq,
+    Dh] of layer `li`: the caller owns the cache and the mask
+    (block-causal for SDAR). A layer that holds an indexer (`idx_wq`
+    among its weights: a "sparse" layer, `models/keye_vl2.py`) hands
+    `attend` its `indexer_proj` as a fifth argument, and the caller
+    owns the selection too. Returns (h, MoE stats)."""
     import jax
     T = h.shape[0]
     Hq, Hkv, Dh = arch.num_heads, arch.num_kv_heads, arch.head_dim
@@ -138,7 +162,11 @@ def layer_forward(arch, li, lw, h, positions, valid, attend):
         v = _mm(x, lw["wv"]).reshape(T, Hkv, Dh)
         q = _rope(q, positions, arch.rope_theta)
         k = _rope(k, positions, arch.rope_theta)
-    a = attend(q, k, v, li)
+    idx = ()
+    if "idx_wq" in lw:
+        with jax.named_scope("idx_proj"):
+            idx = (indexer_proj(arch, lw, x, positions),)
+    a = attend(q, k, v, li, *idx)
     with jax.named_scope("attn_out"):
         h = h + _mm(a.reshape(T, Hq * Dh), lw["wo"])
     # the expert layer's norm and residual count with `mlp`; router and

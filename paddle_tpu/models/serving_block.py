@@ -2,9 +2,10 @@
 through (`models/afmoe.py`, `models/olmo_hybrid.py`,
 `models/sdar_moe.py`): the architecture, the weight tree (an argument
 of the jitted step) and the functions of `(arch, weights, ...)` that
-the model's eager forward calls too; and `BlockDecoding`, what an
+the model's eager forward calls too; `BlockDecoding`, what an
 architecture says of itself when it generates by diffusion over blocks
-instead of a token at a time."""
+instead of a token at a time; and `LearnedSelection`, what it says of
+the indexer of its "sparse" layers (attention through a selection)."""
 from __future__ import annotations
 
 import dataclasses
@@ -15,13 +16,16 @@ class ServingBlock:
     arch: object            # frozen description: `layers`, `layer_kinds`,
     #                          `num_heads`, `num_kv_heads`, `head_dim`, ...
     #                          and `block_decoding` (a `BlockDecoding`)
-    #                          where the model decodes by blocks
+    #                          where the model decodes by blocks,
+    #                          `selection` (a `LearnedSelection`) where
+    #                          a layer's kind is "sparse"
     weights: dict
     embed: object           # (arch, weights, token_ids) -> h [T, D]
     layer: object           # (arch, li, layer weights, h, positions,
     #                          valid, attend[, recur]) -> (h, stats or
     #                          None); `recur` only where the architecture
-    #                          has "linear" layers
+    #                          has "linear" layers; a "sparse" layer
+    #                          calls `attend(q, k, v, li, (qI, kI, w))`
     head: object            # (arch, weights, h rows) -> logits
     stat_names: tuple = ()  # flight-record names of the step's counters
     fold_stats: object = None   # (int32[len(stat_names)], a layer's
@@ -84,3 +88,25 @@ class BlockDecoding:
                 return high
         return sorted(sorted(
             masked, key=lambda i: (-float(confidence[i]), i))[:n])
+
+
+@dataclasses.dataclass(frozen=True)
+class LearnedSelection:
+    """What the "sparse" layers of an architecture say of their INDEXER
+    (DeepSeek Sparse Attention): beside q, k, v a layer makes, a token,
+    `num_heads` indexer queries qI [num_heads, head_dim], ONE indexer
+    key kI [head_dim] (the only part that is cached) and `num_heads`
+    float32 weights w; a query at t scores every key s <= t
+
+        I(t, s) = sum_j  w_t[j] * scale * relu(qI_t[j] . kI_s)
+
+    in float32, and attends the `min(topk, t + 1)` keys with the largest
+    scores, equal scores going to the LOWER position; every attention
+    head shares the selection. `rope_dims` leading dims of qI and kI
+    carry the rotary embedding, `eps` is the key norm's."""
+    num_heads: int
+    head_dim: int
+    topk: int
+    rope_dims: int
+    scale: float
+    eps: float = 1e-6

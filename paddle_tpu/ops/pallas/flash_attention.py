@@ -520,7 +520,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                            positions, k_scale=None, v_scale=None, *,
                            scale=None, kernel_name="paged_ragged",
                            runs=None, window=None, max_run=None,
-                           layer=None, causal_block=None):
+                           layer=None, causal_block=None, select=None):
     """Flat-token attention over a block-paged KV cache — the kernel of
     the continuous-batching mixed step (`paddle_tpu.serving.engine`),
     following the Ragged-Paged-Attention shape discipline: ONE fixed
@@ -582,6 +582,15 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
     `max_run` cuts it into: the serving engine feeds whole blocks and
     cuts prefill chunks at multiples of L.
 
+    `select` (None = every key the mask allows; bool `[T, >= MB *
+    BS]`, a row a query, a column a key position): the query attends
+    only the keys whose entry is True, of those the mask allows. The
+    selection is made by the caller (a learned indexer's exact top-k:
+    `ops.pallas.topk_select`) and applied here as data, on the kernel
+    path as packed bits; `runs` may then leave runs out (a caller that
+    attends its one-token runs over a gathered selection hands over
+    the others), and the rows of no run leave as zeros.
+
     `layer` (None = the pools are one layer's, as above): the pools
     and scales are STACKED over layers, `[L, NB, BS, H, Dh]` and
     `[L, NB, BS, H]`, and layer `layer`'s blocks are read where they
@@ -601,18 +610,18 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, slot_ids,
                              positions, k_scale, v_scale, scale=scale,
                              kernel_name=kernel_name, runs=runs,
                              window=window, max_run=max_run, layer=layer,
-                             causal_block=causal_block)
+                             causal_block=causal_block, select=select)
     return ragged_gather_reference(q, k_pool, v_pool, block_tables,
                                    slot_ids, positions, k_scale,
                                    v_scale, scale=scale, window=window,
                                    layer=layer, causal_block=causal_block,
-                                   max_run=max_run)
+                                   max_run=max_run, select=select)
 
 
 def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
                             positions, k_scale=None, v_scale=None, *,
                             scale=None, window=None, layer=None,
-                            causal_block=None, max_run=None):
+                            causal_block=None, max_run=None, select=None):
     """The pure-XLA gather implementation of `ragged_paged_attention`
     — the CPU path, the kernel-parity oracle, and the admission gate
     the autotuner holds every paged candidate against. `max_run` only
@@ -646,6 +655,8 @@ def ragged_gather_reference(q, k_pool, v_pool, block_tables, slot_ids,
     keep = jnp.arange(S)[None, :] <= reach[:, None]       # [T, S]
     if window is not None:
         keep &= jnp.arange(S)[None, :] > positions[:, None] - window
+    if select is not None:
+        keep &= select[:, :S]
     if Hkv != H:
         # grouped queries: Gq query heads read each KV head
         qg = q.reshape(T, Hkv, H // Hkv, Dh)

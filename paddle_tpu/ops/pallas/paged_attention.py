@@ -270,7 +270,7 @@ def _plane_batch(NPL, R, CP):
 def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 bt_ref, q_ref, dmat_ref, rowtok_ref, k_hbm, v_hbm, *rest,
                 BS, H, P, G, TQ, quantized, mxu_dtype, Gq=1, window=None,
-                causal_block=None):
+                causal_block=None, select_words=0):
     """The whole step in one invocation: for every run, walk the run's
     slot once — `cdiv(last_pos // BS + 1, G)` double-buffered fetches
     of G KV blocks — and let every q tile of the run attend each
@@ -294,7 +294,13 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     at p attends the keys up to the END of its block of L positions,
     `j <= p | (L - 1)`, but none past its run's last token (a run ends
     on a block boundary, or where the sequence does: what lies behind
-    it in the pool is not the sequence's).
+    it in the pool is not the sequence's). With a selection
+    (`select_words` NW > 0: `select_bits`) a query attends, of the keys
+    the rules above allow, those whose bit is set in its row of packed
+    words: the run's rows of words come in once a run, and a (tile,
+    group) reads its word plane, takes its bit and lays it over the
+    tile's rows. The bits are data: nothing of the selection is
+    computed here.
 
     A row's arithmetic does not depend on its run: the columns of a
     product and their order are the shapes' (G, BS, P), and the tile
@@ -309,6 +315,10 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
     scales [NB, 1, BS*H]); out [H/P, T*RT + pad, Dh] fp32; scratch: two
     KV buffers, DMA semaphores and the runs' online-softmax state
     [H/P, state rows, ...]."""
+    dup_ref = sel_hbm = selbuf = selsem = None
+    if select_words:
+        dup_ref, sel_hbm, *rest = rest
+        *rest, selbuf, selsem = rest
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
          m_ref, l_ref, acc_ref) = rest
@@ -442,6 +452,29 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
                 keep = dmat_ref[0:R] <= thr                   # [R, CP]
                 if window is not None:
                     keep &= dmat_ref[0:R] > thr - window
+                if select_words:
+                    # the tile's tokens' words of this group's plane,
+                    # a row a token; the group's bit, over the token's
+                    # RT rows
+                    # (a strided read wants 128 lanes a row: a plane's
+                    # GK lanes, one a key, lie as GK / 128 rows)
+                    Q = GK // selbuf.shape[1]
+                    row = (off * select_words + g % select_words) * Q
+                    words = jnp.concatenate([
+                        selbuf[pl.ds(row + c, tq, stride=select_words * Q)]
+                        for c in range(Q)], axis=1)
+                    bit = (words >> (g // select_words)) & 1  # [tq, GK]
+                    if P > 1:
+                        # a key's bit onto its P columns (key, head in
+                        # plane): a product with the 0 / 1 table, exact
+                        bit = jnp.broadcast_to(
+                            bit.astype(jnp.float32)[None],
+                            (-(-8 // tq), tq, GK)).reshape(-1, GK)
+                        bit = jnp.dot(
+                            bit, dup_ref[...],
+                            preferred_element_type=jnp.float32)[:tq]
+                    keep &= jnp.broadcast_to(
+                        bit[:, None, :], (tq, RT, CP)).reshape(R, CP) != 0
 
                 def planes(i, c):
                     """U planes from plane i * U on: every load of the
@@ -512,6 +545,15 @@ def _run_kernel(nruns_ref, rstart_ref, rlen_ref, rslot_ref, rpos_ref,
         lo, g0 = run_first(r)
         nxt = jnp.minimum(r + 1, last_run)
         more_runs = r + 1 < n_runs
+        if select_words:
+            # the run's rows of selection words (a whole number of
+            # sublane tiles a token), for all its tiles and groups
+            rows, lanes = selbuf.shape
+            cp = pltpu.make_async_copy(
+                sel_hbm.at[pl.ds(start * select_words * (GK // lanes),
+                                 rows)], selbuf, selsem.at[0])
+            cp.start()
+            cp.wait()
 
         def group_body(g, it):
             buf = it % 2
@@ -562,6 +604,31 @@ def _mask_tables(P, BS, G, TQ, Gq=1):
             jnp.asarray(rows[:, None] // RT, jnp.int32))
 
 
+def select_bits(select, T, MB, BS, G, longest):
+    """The selection `select [T, >= MB * BS] bool` (may a query attend
+    the key at this position, beside what the mask allows) as the
+    kernel reads it: int32 words, NW word planes a token (a multiple of
+    8: a token's planes are whole sublane tiles), a plane the `G * BS`
+    keys of a fetched group, a lane a key, laid as rows of 128 lanes
+    (`[(T + longest) * NW * GK / 128, 128]`; one row a plane where GK
+    is no multiple of 128). A fetched group g of G blocks is bit `g //
+    NW` of plane `g % NW`: lane c of that plane holds the selection of
+    key `g * G * BS + c`. -> (words, NW)."""
+    GK = G * BS
+    NG = -(-MB // G)
+    NW = 8 * -(-NG // 256)
+    nbits = -(-NG // NW)
+    x = select[:, :MB * BS]
+    x = jnp.pad(x, ((0, longest), (0, nbits * NW * GK - x.shape[1])))
+    x = x.reshape(T + longest, nbits, NW, GK)
+    # bit by bit, elementwise: a reduction over the bits would have XLA
+    # lay them along the lanes, padded to 128
+    words = sum(x[:, b].astype(jnp.uint32) << jnp.uint32(b)
+                for b in range(nbits))
+    words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    return words.reshape(-1, GK if GK % 128 else 128), NW
+
+
 def layer_blocks(block_tables, layer, *pools):
     """The ONE rule by which a layer's blocks are addressed in STACKED
     pools `[L, NB, ...]`: the pools viewed flat, `[L * NB, ...]`
@@ -586,7 +653,8 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                        positions, k_scale=None, v_scale=None, *,
                        scale=None, kernel_name="paged_ragged",
                        tuning=None, runs=None, groups=None, window=None,
-                       max_run=None, layer=None, causal_block=None):
+                       max_run=None, layer=None, causal_block=None,
+                       select=None):
     """Run-major block-table-native attention — ONE walk per (slot,
     step).
 
@@ -611,7 +679,11 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     `layer` (None = the pools are one layer's): the pools and scales
     are STACKED, `[L, NB, ...]`, and the kernel reads layer `layer`'s
     blocks in place (`layer_blocks`); it may be a traced scalar, a
-    scan's layer index.
+    scan's layer index. `select` (None = every key the mask allows;
+    bool `[T, >= MB * BS]`): a query attends only the keys whose entry
+    is True, of those the mask allows: a per-(query row, key) selection
+    made elsewhere and applied here as data (`select_bits`). None
+    traces the kernel without it.
 
     `kernel_name` names the Mosaic call (what a device trace and the
     benchmark's kernel check read) and keys the autotuner lookup: the
@@ -674,6 +746,14 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
     in_specs = [vmem, vmem, vmem, hbm, hbm]
     scratch = [pltpu.VMEM((2, C, Dh), k_pool.dtype),
                pltpu.VMEM((2, C, Dh), v_pool.dtype)]
+    NW = 0
+    if select is not None:
+        words, NW = select_bits(select, T, MB, BS, G, longest)
+        # key -> its P columns (key, head in plane) of a product
+        dup = (jnp.arange(CP)[None, :] // P
+               == jnp.arange(G * BS)[:, None]).astype(jnp.float32)
+        args += [dup, words]
+        in_specs += [vmem, hbm]
     if quantized:
         args += [k_scale.astype(jnp.float32).reshape(NB, 1, BH),
                  v_scale.astype(jnp.float32).reshape(NB, 1, BH)]
@@ -684,6 +764,10 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
                 pltpu.VMEM((NPL, rows, 1), jnp.float32),
                 pltpu.VMEM((NPL, rows, 1), jnp.float32),
                 pltpu.VMEM((NPL, rows, Dh), jnp.float32)]
+    if NW:
+        scratch += [pltpu.VMEM((longest * NW * G * BS // words.shape[1],
+                                words.shape[1]), jnp.int32),
+                    pltpu.SemaphoreType.DMA((1,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(), in_specs=in_specs,
         out_specs=vmem, scratch_shapes=scratch)
@@ -691,7 +775,7 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         _run_kernel, BS=BS, H=H, P=P, G=G, TQ=TQ, quantized=quantized,
         mxu_dtype=mxu_dtype, Gq=Gq,
         window=None if window is None else int(window),
-        causal_block=check_causal_block(causal_block))
+        causal_block=check_causal_block(causal_block), select_words=NW)
     # a full pool read once, every query against a mean slot's share
     # of it: the work follows the contexts, not the table's width
     kv_tokens = min(NB1, S * MB) * BS
@@ -706,7 +790,9 @@ def _paged_attend_runs(q, k_pool, v_pool, block_tables, slot_ids,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(NPL * (T * RT + pad),
                                          NPL * rows, TQ * RT, C, CP, Dh,
-                                         k_pool.dtype.itemsize)),
+                                         k_pool.dtype.itemsize,
+                                         (longest * NW + CP) * G * BS
+                                         * 4)),
         cost_estimate=pl.CostEstimate(
             flops=4 * T * HQ * Dh * ctx,
             bytes_accessed=(2 * kv_tokens * H * Dh
@@ -772,7 +858,8 @@ def logits_issued(runs, tiles, H, Gq, block_size, window=None,
     return issued * H * Gq * GK * P
 
 
-def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize):
+def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize,
+                select_bytes=0):
     """Scoped-VMEM ask of the run kernel: what it keeps resident
     (queries and output in fp32, the mask table, two K and V buffers,
     the softmax state) plus the mask, logits and probabilities of one
@@ -780,7 +867,7 @@ def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize):
     lanes = max(Dh, 128)
     resident = (2 * q_rows * lanes * 4 + tile_rows * CP * 4
                 + 4 * C * lanes * kv_itemsize
-                + state_rows * (2 * 128 + lanes) * 4)
+                + state_rows * (2 * 128 + lanes) * 4 + select_bytes)
     ask = 2 * (resident + 5 * tile_rows * CP * 4)
     return int(min(100 * 2 ** 20, max(32 * 2 ** 20, ask)))
 
@@ -791,7 +878,8 @@ def _vmem_limit(q_rows, state_rows, tile_rows, C, CP, Dh, kv_itemsize):
 def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
                   k_scale=None, v_scale=None, *, scale=None,
                   kernel_name="paged_ragged", runs=None, window=None,
-                  max_run=None, layer=None, causal_block=None):
+                  max_run=None, layer=None, causal_block=None,
+                  select=None):
     """Flat-token ragged paged attention (chunked prefill + plain
     decode): q [T, H, Dh]. Signature mirrors
     `flash_attention.ragged_paged_attention`. The sparse decode region
@@ -801,7 +889,7 @@ def ragged_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,
         q, k_pool, v_pool, block_tables, slot_ids, positions,
         k_scale, v_scale, scale=scale, kernel_name=kernel_name,
         runs=runs, window=window, max_run=max_run, layer=layer,
-        causal_block=causal_block)
+        causal_block=causal_block, select=select)
 
 
 def verify_attend(q, k_pool, v_pool, block_tables, slot_ids, positions,

@@ -74,6 +74,7 @@ temperature.
 from __future__ import annotations
 
 import functools
+import math
 import time
 
 import numpy as np
@@ -129,7 +130,8 @@ def _attention_work(plan, block_size):
                 kv_blocks_walked=int(walked))
 
 
-def _attention_work_by_kind(plan, window=None, causal_block=None):
+def _attention_work_by_kind(plan, window=None, causal_block=None,
+                            groups=None):
     """`_attention_work` for a model with window and full layers: the
     work of ONE layer of each kind (the benchmark multiplies by the
     layers of the kind). A window layer's query at p reads and attends
@@ -139,8 +141,10 @@ def _attention_work_by_kind(plan, window=None, causal_block=None):
     there and 0. With a `causal_block` L (no window beside it) a query
     attends the keys to the END of its block of L positions, and none
     past its group's last token: the group reads the same `start + n`
-    tokens and attends more pairs."""
-    groups = _plan_groups(plan)
+    tokens and attends more pairs. `groups` (default: every slot the
+    plan feeds): the (first position, tokens) the kernel walks, where
+    the step leaves some of the plan's rows out of it."""
+    groups = _plan_groups(plan) if groups is None else groups
     out = {}
     for kind, w in (("window", window), ("full", None)):
         read = pairs = 0
@@ -186,6 +190,223 @@ def _linear_work(plan, chunk):
 #: model-provided block: its softmax state is `max_run x query heads`
 #: rows of VMEM, and a longer prefill chunk walks its slot once a cut
 _BLOCK_MAX_RUN = 128
+
+
+class _SparseLayers:
+    """What the block step does for a layer that attends THROUGH A
+    LEARNED SELECTION (`layer_kinds` "sparse", `arch.selection`; docs/
+    SERVING.md "Attention through a learned selection"): beside K and V
+    the layer writes its rows' indexer keys into its indexer-key pool,
+    at the K/V's (block, offset); every row scores its slot's cached
+    indexer keys up to its own position, `[rows, context]` float32, and
+    keeps the exact `topk` best (`ops.pallas.topk_select`: equal scores
+    to the lower position); then
+
+    * a row that is a run of ONE token (a decode row; the odd last
+      token of a cut chunk) attends over its GATHERED selection: the
+      K/V it reads is `min(topk, position + 1)` tokens, whatever its
+      context (`_sparse_work`: `sparse_kv_tokens_read`);
+    * the rows of longer runs (prefill chunks) go through the run
+      kernel over their slot's pages, the selection applied as data
+      (`ragged_paged_attention(select=)`); the one-token runs are left
+      out of the kernel's runs, so it does not walk their contexts.
+
+    Every shape is the token budget's, the slot count's, `topk`'s or
+    the table's: one compile. The one-token runs are at most one a
+    slot (a slot is fed one run a step; a cut at `max_run` leaves one
+    odd token at most)."""
+
+    #: pages of indexer keys a chunk run scores at a time
+    PAGES = 256
+
+    def __init__(self, engine, max_run):
+        kv = engine.kv
+        self.sel = engine._select
+        self.T, self.S = engine.token_budget, kv.max_slots
+        self.BS, self.MB = engine.block_size, kv.max_blocks_per_slot
+        self.C = self.MB * self.BS
+        self.max_run = max_run
+        self.pages = min(self.PAGES, self.MB)
+
+    def split(self, runs, table, pos, valid, rows_at):
+        """The step's runs (`paged_runs`, cut at `max_run`) parted into
+        the one-token runs, compacted to a row a slot, and the others,
+        as the run kernel takes them; the candidates of both; where the
+        sample rows lie among them. Once a step, for all its layers."""
+        import jax.numpy as jnp
+        T, S, C = self.T, self.S, self.C
+        n_runs, start, length, rslot, first = runs
+        r = jnp.arange(T, dtype=jnp.int32)
+        live = r < n_runs[0]
+        single = live & (length == 1)
+        order = jnp.argsort(~single, stable=True)[:S]
+        d_live = single[order]
+        d_row = jnp.where(d_live, start[order], 0)
+        d_slot = jnp.where(d_live, rslot[order], 0)
+        d_pos = jnp.where(d_live, first[order], 0)
+        chunk = live & (length > 1)
+        order = jnp.argsort(~chunk, stable=True)
+        n_chunk = jnp.sum(chunk, dtype=jnp.int32)
+        kruns = (n_chunk.reshape(1), start[order],
+                 jnp.where(r < n_chunk, length[order], 0), rslot[order],
+                 first[order])
+        keys = jnp.arange(C, dtype=jnp.int32)[None, :]
+        # where a flat row lies among the one-token runs (S: nowhere)
+        d_of = jnp.full((T,), S, jnp.int32).at[
+            jnp.where(d_live, d_row, T)].set(
+            jnp.arange(S, dtype=jnp.int32), mode="drop")
+        return dict(
+            kruns=kruns, d_row=d_row, d_table=table[d_slot],
+            d_to=jnp.where(d_live, d_row, T),
+            cand_d=(keys <= d_pos[:, None]) & d_live[:, None],
+            cand_c=(keys <= pos[:, None]) & valid[:, None],
+            samp_d=d_of[rows_at], samp_row=rows_at)
+
+    def chunk_scores(self, qI, w, ip, table, kruns):
+        """I(t, s) [T, C] float32 of the rows of the runs `kruns` over
+        their slots' cached indexer keys, a run and `pages` pages at a
+        time, only as far as the run's last position; the rows of no
+        run and the keys past a run's reach hold whatever: no candidate
+        lies there. A run's tile of `max_run` rows may overhang into
+        the next runs' rows, which are written after it (ascending)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.pallas.topk_select import index_scores
+        T, R, BS = self.T, self.max_run, self.BS
+        pages = self.pages
+        KC = pages * BS
+        n_kc = -(-self.MB // pages)
+        table = jnp.pad(table, ((0, 0), (0, n_kc * pages - self.MB)))
+        qI = jnp.pad(qI, ((0, R), (0, 0), (0, 0)))
+        w = jnp.pad(w, ((0, R), (0, 0)))
+        n_chunk, c_start, c_len, c_slot, c_first = kruns
+
+        def run(r, scores):
+            start = c_start[r]
+            qr = jax.lax.dynamic_slice_in_dim(qI, start, R)
+            wr = jax.lax.dynamic_slice_in_dim(w, start, R)
+            row = table[c_slot[r]]
+
+            def keys(c, scores):
+                kc = ip[jax.lax.dynamic_slice_in_dim(
+                    row, c * pages, pages)].reshape(KC, -1)
+                return jax.lax.dynamic_update_slice(
+                    scores, index_scores(qr, wr, kc), (start, c * KC))
+
+            return jax.lax.fori_loop(
+                0, (c_first[r] + c_len[r] - 1) // KC + 1, keys, scores)
+
+        scores = jax.lax.fori_loop(
+            0, n_chunk[0], run,
+            jnp.zeros((T + R, n_kc * KC), jnp.float32))
+        return scores[:T, :self.C]
+
+    def attend(self, pools, at, ix, q, k, v, idx, wb, wo, slot_ids, pos,
+               table, sp):
+        """One sparse layer of the step: -> (attention output [T, Hq,
+        Dh], the sample rows' selections [S, C] bool). `pools[at]`,
+        `pools[at + 1]`: the layer's K and V pool; `pools[ix]`: its
+        indexer-key pool; all three updated in place."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.pallas.flash_attention import ragged_paged_attention
+        from ..ops.pallas.topk_select import (index_scores,
+                                              mask_positions, topk_mask)
+        S, C = self.S, self.C
+        topk = self.sel.topk
+        qI, kI, w = idx
+        kp, vp, ip = pools[at], pools[at + 1], pools[ix]
+        lanes = ip.shape[-1] - kI.shape[-1]
+        with jax.named_scope("kv_write"):
+            kp = kp.at[wb, wo].set(k.astype(kp.dtype))
+            vp = vp.at[wb, wo].set(v.astype(vp.dtype))
+            # a row of the pool is a whole lane tile: zeros past the key
+            ip = ip.at[wb, wo].set(
+                jnp.pad(kI, ((0, 0), (0, lanes))).astype(ip.dtype))
+        pools[at], pools[at + 1], pools[ix] = kp, vp, ip
+        with jax.named_scope("idx_score"):
+            qI = jnp.pad(qI.astype(ip.dtype), ((0, 0), (0, 0), (0, lanes)))
+            # a one-token run: one query row over its slot's pages
+            score_d = jax.vmap(index_scores)(
+                qI[sp["d_row"]][:, None], w[sp["d_row"]][:, None],
+                ip[sp["d_table"]].reshape(S, C, -1))[:, 0]
+            score_c = self.chunk_scores(qI, w, ip, table, sp["kruns"])
+        with jax.named_scope("idx_select"):
+            keep_d = topk_mask(score_d, topk, sp["cand_d"])
+            at_d = mask_positions(keep_d, topk)
+            keep_c = topk_mask(score_c, topk, sp["cand_c"])
+            kept = jnp.where(
+                (sp["samp_d"] < S)[:, None],
+                keep_d[jnp.minimum(sp["samp_d"], S - 1)],
+                keep_c[sp["samp_row"]])
+        with jax.named_scope("attn_full"):
+            o = ragged_paged_attention(
+                q, kp, vp, table, slot_ids, pos, runs=sp["kruns"],
+                max_run=self.max_run, select=keep_c)
+        with jax.named_scope("attn_sparse"):
+            # the one-token runs over their gathered selection
+            od = attend_gathered(q[sp["d_row"]], kp, vp, sp["d_table"],
+                                 at_d)
+            o = o.at[sp["d_to"]].set(od.astype(o.dtype), mode="drop")
+        return o, kept
+
+
+def attend_gathered(q, k_pool, v_pool, tables, at):
+    """Attention of one query a row over the keys at the positions `at`
+    of its own block table: q [S, Hq, Dh]; pools [NB, BS, Hkv, Dh];
+    tables [S, MB]; at [S, K] int32 positions (-1: none). The K and V
+    of the K positions are gathered a token at a time, and nothing else
+    of the context is read. Grouped queries, 1/sqrt(Dh), float32
+    logits and sums, the products' operands in q's dtype. -> [S, Hq,
+    Dh] float32."""
+    import jax
+    import jax.numpy as jnp
+    S, Hq, Dh = q.shape
+    BS, Hkv = k_pool.shape[1], k_pool.shape[2]
+    safe = jnp.maximum(at, 0)
+    blk = jnp.take_along_axis(tables, safe // BS, axis=1)
+    kg, vg = k_pool[blk, safe % BS], v_pool[blk, safe % BS]
+    qg = q.reshape(S, Hkv, Hq // Hkv, Dh)
+    s = jnp.einsum("shgd,skhd->shgk", qg, kg.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    s = jnp.where((at >= 0)[:, None, None, :], s / math.sqrt(Dh), -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("shgk,skhd->shgd", p, vg.astype(q.dtype),
+                      preferred_element_type=jnp.float32).reshape(
+        S, Hq, Dh)
+
+
+def _sparse_work(plan, topk, max_run):
+    """The work of ONE sparse layer fed `plan`, as the flight record's
+    fields (host arithmetic on the plan; FLIGHT_FIELDS_SPARSE.md): a
+    slot's tokens are cut at `max_run` as the step cuts them; a piece
+    of one token is a DECODE row (it attends over its gathered
+    selection: `sparse_kv_tokens_context` the keys dense attention
+    would read for it, `sparse_kv_tokens_read` those it reads), the
+    rows of longer pieces are CHUNK rows (`sparse_pairs_causal` the
+    (query, key) pairs the causal rule allows them, `sparse_pairs_kept`
+    those the selection keeps). `idx_keys_scored`: rows x candidate
+    keys, both kinds. -> (fields, the groups the run kernel walks)."""
+    dec = ctx = read = rows = scored = causal = kept = 0
+    walked = []
+    for start, n in _plan_groups(plan):
+        lo, hi = start + 1, start + n          # keys a row sees: lo..hi
+        scored += (lo + hi) * n // 2
+        if n % max_run == 1:
+            dec, ctx, read = dec + 1, ctx + hi, read + min(topk, hi)
+            n, hi = n - 1, hi - 1
+        if n:
+            walked.append((start, n))
+            rows += n
+            causal += (lo + hi) * n // 2
+            cap = min(hi, max(topk, lo - 1))   # rows that keep them all
+            kept += (lo + cap) * (cap - lo + 1) // 2 + topk * (hi - cap)
+    return dict(sparse_rows_decode=dec, sparse_rows_chunk=rows,
+                sparse_kv_tokens_context=ctx, sparse_kv_tokens_read=read,
+                idx_keys_scored=scored, sparse_pairs_causal=causal,
+                sparse_pairs_kept=kept), walked
 
 
 class _Flight:
@@ -261,6 +482,22 @@ class ServingEngine:
                     raise ValueError(
                         f"{type(model).__name__} decodes by blocks "
                         f"(block_decoding); that is not built {why}")
+            # a model whose layers attend through a learned selection
+            # says so in its description too (`layer_kinds` "sparse",
+            # `selection`: a `serving_block.LearnedSelection`)
+            self._select = arch.selection if "sparse" in kinds_of \
+                else None
+            if self._select is not None and (
+                    prefix_caching or "full" in kinds_of):
+                raise ValueError(
+                    "attention through a learned selection is not built "
+                    + ("with prefix_caching: a shared block's indexer "
+                       "keys would have to follow `cow_block`, which a "
+                       "cache with an indexer-key pool refuses"
+                       if prefix_caching else
+                       "beside full layers: the flight fields "
+                       "`kv_tokens_read_full` / `attn_pairs_full` count "
+                       "one kind of layer"))
             if "linear" in kinds_of and (prefix_caching or draft_k):
                 raise ValueError(
                     f"{'prefix_caching' if prefix_caching else 'draft_k'}"
@@ -289,7 +526,7 @@ class ServingEngine:
                     "shards experts itself (TPServingEngine "
                     "expert_parallel=)")
             L, H, Dh = dec.num_layers, dec.num_heads, dec.head_dim
-            self._diff = self._causal_block = None
+            self._diff = self._causal_block = self._select = None
         maxpos = model.max_position_embeddings
         max_seq_len = min(max_seq_len or maxpos, maxpos)
         if block_size == "auto":
@@ -532,6 +769,14 @@ class ServingEngine:
                     linear_state=(arch.linear_heads, arch.linear_key_dim,
                                   arch.linear_value_dim),
                     conv_tail=(arch.conv_width - 1, arch.conv_channels))
+            if self._select is not None:
+                if self._kv_heads != Hkv:
+                    raise ValueError(
+                        "attention through a selection is not built "
+                        f"with padded K/V heads ({Hkv} -> "
+                        f"{self._kv_heads})")
+                # an indexer key a token a sparse layer, beside its K/V
+                kinds.update(indexer_dim=self._select.head_dim)
         with jax.default_device(device):
             self.kv = PagedKVCache(
                 L, H, Dh, num_blocks=num_blocks,
@@ -718,6 +963,12 @@ class ServingEngine:
         #: [max_slots, L, V], a row a position of the slot's block,
         #: where the model decodes by blocks)
         self.sample_logits = None
+        #: a model with sparse layers: the selections of the same rows,
+        #: bool [sparse layers, max_slots, max_slot_tokens] on the
+        #: device (True: the row attended the key at this position);
+        #: read by nothing but a caller who holds them against a
+        #: reference
+        self.sample_selection = None
         #: block decoding: called for every slot pass with (request,
         #: block's first position, ids fed, positions decided before,
         #: positions the pass decided, their tokens); a commit decides
@@ -921,6 +1172,12 @@ class ServingEngine:
         more_heads = self._kv_heads - arch.num_kv_heads
         diff, causal_block = self._diff, self._causal_block
         ahead = self._ahead
+        sel = self._select
+        if sel is not None:
+            sparse = _SparseLayers(self, max_run)
+            # a sparse layer's indexer-key pool: the last of the pools
+            ix = {li: n_pools - len(self.kv.sparse_layers) + j
+                  for j, li in enumerate(self.kv.sparse_layers)}
         if diff and max_run % causal_block:
             raise ValueError(
                 f"block length {causal_block} does not divide the "
@@ -955,12 +1212,22 @@ class ServingEngine:
                 runs = paged_runs(slot_ids, pos, max_run)
                 ids = jnp.where(valid, token_ids, 0)
                 rows_at = jnp.clip(sample_index.reshape(-1), 0, T - 1)
+                if sel is not None:
+                    split = sparse.split(runs, tables["full"], pos, valid,
+                                         rows_at)
             with jax.named_scope("sample"):
                 key, rng = jax.random.split(key)
+            selections = []
 
-            def attend(q, k, v, li):
+            def attend(q, k, v, li, idx=None):
                 kind = kinds[li]
                 kp, vp = pools[at[li]], pools[at[li] + 1]
+                if kind == "sparse":
+                    o, kept = sparse.attend(
+                        pools, at[li], ix[li], q, k, v, idx, wb["full"],
+                        wo, slot_ids, pos, tables["full"], split)
+                    selections.append(kept)
+                    return o
                 with jax.named_scope("kv_write"):
                     if more_heads:
                         q, k, v = (jnp.pad(a, ((0, 0), (0, more_heads),
@@ -1007,6 +1274,9 @@ class ServingEngine:
                             conf, jnp.int32)]).reshape(
                         2, *sample_index.shape).swapaxes(0, 1)
                     logits = logits.reshape(*sample_index.shape, -1)
+            if sel is not None:
+                return (tok, *pools, stats, logits,
+                        jnp.stack(selections), key)
             return (tok, *pools, stats, logits, key)
 
         return step
@@ -2446,6 +2716,7 @@ class ServingEngine:
             # after `step()` returns, `sample_logits` is what the tokens
             # just emitted were taken from
             self.sample_logits = got["logits"]
+            self.sample_selection = got["selection"]
         wasted = 0
         for slot in sp.prefill_done:
             req = reqs.get(slot)
@@ -2499,7 +2770,8 @@ class ServingEngine:
         program leaves alike)."""
         # spec: drafts proposed, drafts accepted, groups by accept length
         got = dict(verify=False, moe_stats=None, block_stats=None,
-                   logits=None, spec=(0, 0, ()), work=None, dispatch={})
+                   logits=None, selection=None, spec=(0, 0, ()),
+                   work=None, dispatch={})
         args = self._step_args(sp, tail,
                                got["dispatch"] if trace_on else None)
         if trace_on:
@@ -2510,6 +2782,8 @@ class ServingEngine:
         if self.num_experts:
             res, got["moe_stats"] = res[:-1], res[-1]
         elif self._block is not None:
+            if self._select is not None:
+                res, got["selection"] = res[:-1], res[-1]
             # the sample rows' logits stay on the device, a row a slot:
             # nothing reads them back but a caller who asks
             # (`sample_logits`, rebound when the step's tokens are
@@ -2849,24 +3123,36 @@ class ServingEngine:
                 n_blk, self.sparse_table_width)
 
     def _plan_work(self, plan):
+        groups = None
         if self._block is None:
             work = _attention_work(plan, self.block_size)
             layers = [(None, self.kv.num_layers, work["attn_pairs"])]
         else:
+            sparse = {}
+            if self._select is not None:
+                # the `_full` fields count what the run kernel walks
+                # under the causal rule: the chunk rows' groups
+                sparse, groups = _sparse_work(
+                    plan, self._select.topk,
+                    min(self.token_budget, _BLOCK_MAX_RUN))
+                sparse["idx_pool_bytes"] = self.kv.idx_bytes_per_token \
+                    * self.kv.num_blocks * self.block_size
             work = _attention_work_by_kind(plan, self.kv.window,
-                                           self._causal_block)
+                                           self._causal_block, groups)
+            work.update(sparse)
             kinds = self._block.arch.layer_kinds
             layers = [(w, kinds.count(kind), work[f"attn_pairs_{name}"])
                       for kind, name, w in (
                           ("full", "full", None),
+                          ("sparse", "full", None),
                           ("sliding", "window", self.kv.window))]
             if self.kv.linear_layers:
                 work.update(
                     _linear_work(plan, self._block.arch.delta_chunk))
-        work.update(self._logit_work(plan, layers))
+        work.update(self._logit_work(plan, layers, groups))
         return work
 
-    def _logit_work(self, plan, layers):
+    def _logit_work(self, plan, layers, groups=None):
         """`attn_logits_useful` and `attn_logits_issued` of the step,
         summed over its attention layers: (query, key) pairs x the
         model's query heads, and the logits the paged kernel computes
@@ -2892,7 +3178,7 @@ class ServingEngine:
                     kv_jnp_dtype(self.kv.kv_dtype),
                     quantized=self.kv.quantized, max_run=max_run))
         heads, issued_by = self._logits_issued
-        groups = _plan_groups(plan)
+        groups = _plan_groups(plan) if groups is None else groups
         useful = issued = 0
         for window, n, pairs in layers:
             if n:

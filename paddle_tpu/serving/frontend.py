@@ -165,6 +165,10 @@ class ServingFrontend:
                 self.engine.cancel(handle.req)
             self._finish_handle(handle, err)
         self._live.clear()
+        # what the stopped cycle and the cancels' drain marked belongs
+        # to no later step: none of it stays for the next flight record
+        self.engine.phases.close()
+        self.engine.phases.take()
 
     async def __aenter__(self):
         return await self.start()

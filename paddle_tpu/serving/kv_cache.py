@@ -41,6 +41,9 @@ KV_DTYPES = {
 #: values past it cast to NaN, so quantize clips first
 FP8_MAX = 448.0
 
+#: lanes a row of an indexer-key pool is padded to (a whole lane tile)
+INDEXER_LANES = 128
+
 #: "empty" sentinel for the min summary rows (max rows use the
 #: negation): large but finite — far above any real key magnitude, far
 #: enough below float32 max that score products stay finite — so a
@@ -176,7 +179,7 @@ class PagedKVCache:
                  dtype="float32", kv_dtype=None, summaries=False,
                  num_kv_heads=None, layer_kinds=None, window=None,
                  num_window_blocks=None, linear_state=None,
-                 conv_tail=None):
+                 conv_tail=None, indexer_dim=None):
         import jax.numpy as jnp
         self.num_layers = num_layers
         # the heads the pools hold: the KV heads of a grouped-query
@@ -185,11 +188,14 @@ class PagedKVCache:
         self.num_heads = num_heads
         self.head_dim = head_dim
         # layers of several kinds (`layer_kinds`: "full" / "sliding" /
-        # "linear" a layer): a K/V pool of its own an attention layer,
-        # one block table a slot for the full layers and one for the
-        # window layers, which lets go of the blocks behind the window;
-        # a linear layer holds no blocks but a recurrent state and the
-        # convolution's last inputs a slot (see `_init_layer_kinds`)
+        # "linear" / "sparse" a layer): a K/V pool of its own an
+        # attention layer, one block table a slot for the full layers
+        # and one for the window layers, which lets go of the blocks
+        # behind the window; a linear layer holds no blocks but a
+        # recurrent state and the convolution's last inputs a slot; a
+        # sparse layer (attention through a learned selection) is a
+        # full layer that keeps an INDEXER-KEY pool beside its K/V, on
+        # the same block table (see `_init_layer_kinds`)
         self.layer_kinds = tuple(layer_kinds) if layer_kinds else None
         self.window = int(window) if window else None
         kinds = self.layer_kinds or ()
@@ -199,12 +205,16 @@ class PagedKVCache:
                               if k == "linear"]
         self.attention_layers = [i for i in range(num_layers)
                                  if i not in self.linear_layers]
+        self.sparse_layers = [i for i, k in enumerate(kinds)
+                              if k == "sparse"]
+        self.idx_pools = []
         if self.layer_kinds is not None:
             if len(kinds) != num_layers or \
-                    set(kinds) - {"full", "sliding", "linear"}:
+                    set(kinds) - {"full", "sliding", "linear", "sparse"}:
                 raise ValueError(
                     f"layer kinds {kinds}: one of 'full', 'sliding', "
-                    f"'linear' for each of the {num_layers} layers")
+                    f"'linear', 'sparse' for each of the {num_layers} "
+                    "layers")
             if self.has_window and not self.window:
                 raise ValueError("a 'sliding' layer needs a window")
             if self.linear_layers and not (linear_state and conv_tail):
@@ -212,6 +222,9 @@ class PagedKVCache:
                     "a 'linear' layer needs the shapes of its state "
                     "(linear_state: heads, key dim, value dim) and of "
                     "its convolution's tail (conv_tail: rows, channels)")
+            if self.sparse_layers and not indexer_dim:
+                raise ValueError("a 'sparse' layer needs the width of "
+                                 "its indexer key (indexer_dim)")
             if summaries or KV_DTYPES.get(
                     str(kv_dtype or dtype), (0, False))[1]:
                 raise ValueError(
@@ -234,7 +247,7 @@ class PagedKVCache:
         self.states, self.conv_tails = [], []
         if self.layer_kinds is not None:
             self._init_layer_kinds(num_window_blocks, linear_state,
-                                   conv_tail)
+                                   conv_tail, indexer_dim)
         else:
             shape = (num_layers, self.num_blocks, self.block_size,
                      num_heads, head_dim)
@@ -285,7 +298,7 @@ class PagedKVCache:
 
     # ------------------------------------------------------ window layers
     def _init_layer_kinds(self, num_window_blocks, linear_state,
-                          conv_tail):
+                          conv_tail, indexer_dim=None):
         """A cache whose layers are of several kinds. Each ATTENTION
         layer has its own K and V pool `[NB_kind, BS, H, Dh]`
         (`k_pools[i]`, i counting `attention_layers`): the step updates
@@ -311,7 +324,22 @@ class PagedKVCache:
         zeros and any other from what the slot holds, so a reused slot
         and a request preempted back to position 0 see no earlier
         owner's state. Admission and preemption count full-layer
-        blocks; the state is there for every slot always."""
+        blocks; the state is there for every slot always.
+
+        Each SPARSE layer is a full layer (K/V pool of `num_blocks`
+        blocks, the full layers' table) that also keeps the INDEXER key
+        of every cached token: `idx_pools[i]` (i counting
+        `sparse_layers`), `[num_blocks, BS, INDEXER_LANES]` in the
+        pools' dtype, addressed by the SAME (block, offset) as the
+        layer's K/V: no table and no allocator of its own, so whatever
+        moves table entries (`truncate_slot`, `release_slot`,
+        preemption) carries it. A key is `indexer_dim` numbers; a row
+        of the pool is a whole lane tile (128), the rest zeros, so that
+        XLA keeps the pool in the layout the step reads (a last dim of
+        64 is half a tile; the zeros add nothing to a score). A block
+        that is freed and reused keeps its old keys: positions past a
+        slot's length are never candidates of a selection, the rule
+        that already holds for K/V, so nothing is written at reuse."""
         import jax.numpy as jnp
         if self.has_window and not num_window_blocks:
             raise ValueError("a cache with window layers is told its "
@@ -320,11 +348,18 @@ class PagedKVCache:
             if self.has_window else 0
         dt = kv_jnp_dtype(self.kv_dtype)
         tail = (self.block_size, self.num_heads, self.head_dim)
-        nb = {"full": self.num_blocks, "sliding": self.num_window_blocks}
+        nb = {"full": self.num_blocks, "sparse": self.num_blocks,
+              "sliding": self.num_window_blocks}
         attention = [self.layer_kinds[i] for i in self.attention_layers]
         self.k_pools = [jnp.zeros((nb[k],) + tail, dt) for k in attention]
         self.v_pools = [jnp.zeros((nb[k],) + tail, dt) for k in attention]
         self.k_pool = self.v_pool = None
+        if self.sparse_layers:
+            self.indexer_dim = int(indexer_dim)
+            lanes = -(-self.indexer_dim // INDEXER_LANES) * INDEXER_LANES
+            self.idx_pools = [
+                jnp.zeros((self.num_blocks, self.block_size, lanes), dt)
+                for _ in self.sparse_layers]
         for _ in self.linear_layers:
             self.states.append(jnp.zeros(
                 (self.max_slots,) + tuple(linear_state), jnp.float32))
@@ -435,7 +470,15 @@ class PagedKVCache:
             # its block_size tokens (K only — the scorer never needs V)
             per += (2 * self.num_heads * self.head_dim * 4
                     ) // self.block_size
-        return len(self.attention_layers) * per
+        return len(self.attention_layers) * per \
+            + self.idx_bytes_per_token
+
+    @property
+    def idx_bytes_per_token(self):
+        """HBM bytes the indexer keys of one cached token take over the
+        sparse layers (a padded row a layer)."""
+        return sum(int(p.shape[-1]) * p.dtype.itemsize
+                   for p in self.idx_pools)
 
     @property
     def state_bytes(self):
@@ -458,6 +501,14 @@ class PagedKVCache:
                 f"{what} is not built for a cache with a linear layer: "
                 "a recurrent state can be neither truncated nor shared "
                 "by blocks")
+
+    def _refuse_with_indexer(self, what):
+        if self.sparse_layers:
+            raise ValueError(
+                f"{what} is not built for a cache with a sparse layer: "
+                "the copy and transport executables index STACKED pools "
+                "at axis 1, and a layer's indexer-key pool beside its "
+                "K/V would have to ride every frame of the codec")
 
     @property
     def block_bytes(self):
@@ -560,6 +611,7 @@ class PagedKVCache:
         would corrupt every other reader, so the writer gets its own
         copy first."""
         self._refuse_with_state("cow_block")
+        self._refuse_with_indexer("cow_block (prefix sharing)")
         row = self._slot_blocks[slot]
         src = row[index]
         got = self._alloc(1)
@@ -655,7 +707,7 @@ class PagedKVCache:
             return [p for kv in zip(self.k_pools, self.v_pools)
                     for p in kv] + \
                 [a for st in zip(self.states, self.conv_tails)
-                 for a in st]
+                 for a in st] + list(self.idx_pools)
         out = [self.k_pool, self.v_pool]
         if self.quantized:
             out += [self.k_scale, self.v_scale]
@@ -670,7 +722,10 @@ class PagedKVCache:
         if self.layer_kinds is not None:
             n = 2 * len(self.attention_layers)
             self.k_pools, self.v_pools = arrays[0:n:2], arrays[1:n:2]
-            self.states, self.conv_tails = arrays[n::2], arrays[n + 1::2]
+            m = n + 2 * len(self.linear_layers)
+            self.states, self.conv_tails = arrays[n:m:2], \
+                arrays[n + 1:m:2]
+            self.idx_pools = arrays[m:]
             return
         self.k_pool, self.v_pool = arrays[:2]
         arrays = arrays[2:]
@@ -694,6 +749,7 @@ class PagedKVCache:
 
         from .batcher import next_pow2
         self._refuse_with_state("export_blocks (a MigrationTicket)")
+        self._refuse_with_indexer("export_blocks (a MigrationTicket)")
         ids = [int(b) for b in block_ids]
         if not ids:
             raise ValueError("export_blocks needs at least one block")
@@ -805,7 +861,9 @@ class PagedKVCache:
         may have forced block allocations their K/V never ended up
         needing; the garbage they DID write into still-owned blocks
         needs no cleanup (the position mask hides it and the next
-        accepted tokens overwrite it)."""
+        accepted tokens overwrite it). A sparse layer's indexer keys
+        follow: they lie at the K/V's (block, offset), and a position
+        past the slot's length is no candidate of a selection."""
         self._refuse_with_state("truncate_slot")
         keep = self.blocks_for(new_len)
         row = self._slot_blocks[slot]

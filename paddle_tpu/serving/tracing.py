@@ -588,6 +588,10 @@ DEVICE_SCOPES = (
     "attn_full", "attn_out", "mlp", "moe_router", "moe_experts",
     "moe_shared", "lin_proj", "lin_conv", "gated_delta", "lin_gate_out",
     "head", "sample", "diffusion_confidence", "tick_control",
+    # a layer that attends through a learned selection: the indexer's
+    # three products, its scores, the exact top-k, and the one-token
+    # runs' gather and attention (the chunk rows' kernel: `attn_full`)
+    "idx_proj", "idx_score", "idx_select", "attn_sparse",
 )
 #: what an instruction reads whose `op_name` holds none of them
 NO_SCOPE = "(none)"

@@ -331,18 +331,21 @@ def test_manifest_entries():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     entries = {m["name"]: m for m in manifest["per_layer"]}
+    # the cells of PR 35, and the cell PR 37 appended to the lists
+    keye = "serve_keye_vl2_30b_a3b_longctx"
+    serve = SERVE_CELLS + [keye]
     want = {
-        "device.named_busy_pct": ("%", "higher", "device", SERVE_CELLS),
+        "device.named_busy_pct": ("%", "higher", "device", serve),
         "model.attn_proj_ms_per_step": ("ms/step", "lower", "model",
-                                        SERVE_CELLS),
+                                        serve),
         "model.kv_write_ms_per_step": ("ms/step", "lower", "model",
-                                       SERVE_CELLS),
+                                       serve),
         "model.mlp_ms_per_step": ("ms/step", "lower", "model",
                                   SERVE_CELLS[:4]),
         "model.head_sample_ms_per_step": ("ms/step", "lower", "model",
-                                          SERVE_CELLS),
+                                          serve),
         "moe.route_ms_per_step": ("ms/step", "lower", "moe",
-                                  [SERVE_CELLS[2], SERVE_CELLS[4]]),
+                                  [SERVE_CELLS[2], SERVE_CELLS[4], keye]),
         "linear_attn.mixer_xla_ms_per_step": (
             "ms/step", "lower", "linear_attn", [SERVE_CELLS[3]]),
     }
@@ -355,7 +358,7 @@ def test_manifest_entries():
     assert entries["mixed_step.tracer_ms_per_step"] == dict(
         name="mixed_step.tracer_ms_per_step", unit="ms", better="lower",
         source="program_counter", layer="mixed_step",
-        moves="serve_tokens_per_s", workloads=SERVE_CELLS)
+        moves="serve_tokens_per_s", workloads=serve)
 
 
 # --------------------------------------------- the tracer's own cost

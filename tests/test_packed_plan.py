@@ -343,7 +343,8 @@ def test_dispatch_ahead_reader():
         "workloads": [
             "serve_gpt3_1p3b_closed", "serve_gpt3_1p3b_closed_b",
             "serve_trinity_ep8_mixed_len", "serve_olmo_hybrid_mixed_len",
-            "serve_sdar_30b_a3b_mixed_len"]}
+            "serve_sdar_30b_a3b_mixed_len",
+            "serve_keye_vl2_30b_a3b_longctx"]}
 
 
 # ------------------------------- (d) one compile across example args

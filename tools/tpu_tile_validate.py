@@ -497,6 +497,131 @@ def validate_paged_block_causal(*, H=4, Gq=8, Dh=128, BS=16, L=4,
     return cells
 
 
+def validate_paged_selection(*, H=4, Gq=8, Dh=128, BS=16, max_run=128,
+                             first=2350, N=197, topk=512, rows=16,
+                             dtypes=("bfloat16", "float32"),
+                             singles_tol=0.0):
+    """Attention through a SELECTION at the sparse cell's heads, 4 KV x
+    8 query heads a group. (a) The run kernel under `select=` (a packed
+    bit mask a row): N rows from position `first` (ten fetched groups
+    of 256 keys behind them: both bits of the mask's word planes) as
+    ONE run against the XLA oracle under the same selection; the same
+    rows in chunks of 64, a step each, and token by token (laid in
+    reverse so that no two join): a row's bits must not depend on its
+    run; and a selection of EVERYTHING is today's kernel, bit for bit.
+    (b) What attends the decode rows (`engine.attend_gathered`): `rows`
+    queries at contexts up to the table's width over `topk` gathered
+    positions each, against the same oracle."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.serving.engine import attend_gathered
+
+    ragged_ref = _exact(fa.ragged_gather_reference)
+    T = -(-N // max_run) * max_run
+    S = first + N
+    MB = -(-S // BS) + 1
+    NB = rows * MB + 1
+    bt = (1 + np.arange(rows * MB, dtype=np.int32)).reshape(rows, MB)
+    cells = []
+    for dtype in dtypes:
+        tol = 5e-2 if dtype == "bfloat16" else 2e-2
+        rng = np.random.RandomState(29)
+        kp, vp = (jnp.asarray(rng.randn(NB, BS, H, Dh), dtype)
+                  for _ in range(2))
+        q = rng.randn(N, H * Gq, Dh).astype(np.float32)
+        sel = rng.rand(N, MB * BS) < 0.25
+        sel[np.arange(N), first + np.arange(N)] = True
+        attend = jax.jit(lambda q, sl, ps, m: pa.ragged_attend(
+            q, kp, vp, jnp.asarray(bt), sl, ps, max_run=max_run,
+            select=m))
+        plain = jax.jit(lambda q, sl, ps: pa.ragged_attend(
+            q, kp, vp, jnp.asarray(bt), sl, ps, max_run=max_run))
+
+        def feed(runs, every=False):
+            """The rows `runs` [(offset, n)] in one step, in the order
+            given. -> (their outputs by offset, the step's inputs)."""
+            qq = np.full((T, H * Gq, Dh), 0.5, np.float32)
+            sl = np.full(T, -1, np.int32)
+            ps = np.zeros(T, np.int32)
+            mm = np.ones((T, MB * BS), bool)
+            at, t = [], 0
+            for a, n in runs:
+                qq[t:t + n], sl[t:t + n] = q[a:a + n], 1
+                ps[t:t + n] = first + a + np.arange(n)
+                if not every:
+                    mm[t:t + n] = sel[a:a + n]
+                at.append((a, t, n))
+                t += n
+            out = np.asarray(attend(
+                jnp.asarray(qq, dtype), jnp.asarray(sl), jnp.asarray(ps),
+                jnp.asarray(mm)).astype(jnp.float32))
+            got = np.zeros((N, H * Gq, Dh), np.float32)
+            for a, t, n in at:
+                got[a:a + n] = out[t:t + n]
+            return got, (qq, sl, ps, mm)
+
+        whole, (qq, sl, ps, mm) = feed([(0, N)])
+        want = np.concatenate([np.asarray(ragged_ref(
+            jnp.asarray(qq[i:i + 32], dtype), kp, vp, jnp.asarray(bt),
+            jnp.asarray(sl[i:i + 32]), jnp.asarray(ps[i:i + 32]),
+            select=jnp.asarray(mm[i:i + 32])).astype(jnp.float32))
+            for i in range(0, T, 32)])[:N]
+        shape = (f"{dtype} Hq={H * Gq} H={H} Dh={Dh} BS={BS} "
+                 f"positions {first}..{S - 1} max_run={max_run}")
+        cells.append(_cell(f"paged_ragged under a selection {shape}",
+                           whole, want, tol, tol))
+        chunks = np.zeros_like(whole)
+        for a in range(0, N, 64):
+            chunks[a:a + 64] = feed([(a, min(64, N - a))])[0][a:a + 64]
+        singles = np.zeros_like(whole)
+        for a in range(0, N, max_run):
+            part = [(i, 1) for i in range(a, min(a + max_run, N))][::-1]
+            got = feed(part)[0]
+            for i, _ in part:
+                singles[i] = got[i]
+        for name, other, allowed in (("chunks of 64", chunks, 0.0),
+                                     ("token by token", singles,
+                                      singles_tol)):
+            err = float(np.abs(whole - other).max())
+            cells.append(Cell(
+                f"paged_ragged under a selection one run = {name}, bit "
+                f"for bit {shape}",
+                err <= allowed and bool(np.abs(whole).max() > 0.01), err))
+        every, (qq, sl, ps, _) = feed([(0, N)], every=True)
+        today = np.asarray(plain(
+            jnp.asarray(qq, dtype), jnp.asarray(sl),
+            jnp.asarray(ps)).astype(jnp.float32))[:N]
+        err = float(np.abs(every - today).max())
+        cells.append(Cell(
+            f"paged_ragged a selection of everything = no selection, "
+            f"bit for bit {shape}", err == 0.0, err))
+        # (b) the decode rows: one query a slot over gathered positions
+        ctx = rng.randint(topk // 2, MB * BS, size=rows)
+        ctx[0], ctx[1] = MB * BS - 1, topk // 2      # longest, under topk
+        at = np.full((rows, topk), -1, np.int32)
+        mask = np.zeros((rows, MB * BS), bool)
+        for r in range(rows):
+            n = min(topk, ctx[r] + 1)
+            at[r, :n] = np.sort(rng.permutation(ctx[r] + 1)[:n])
+            mask[r, at[r, :n]] = True
+        qd = jnp.asarray(rng.randn(rows, H * Gq, Dh), dtype)
+        got = jax.jit(attend_gathered)(qd, kp, vp, jnp.asarray(bt),
+                                       jnp.asarray(at))
+        want = ragged_ref(qd, kp, vp, jnp.asarray(bt),
+                          jnp.arange(rows, dtype=jnp.int32),
+                          jnp.asarray(ctx, jnp.int32),
+                          select=jnp.asarray(mask)).astype(jnp.float32)
+        cells.append(_cell(
+            f"attend_gathered {rows} rows x {topk} positions {dtype} "
+            f"Hq={H * Gq} H={H} Dh={Dh} contexts to {MB * BS}", got,
+            want, tol, tol))
+    return cells
+
+
 def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
                                   dtypes=("float32", "bfloat16")):
     """The dropless expert layer's ragged grouped matmul, plain and
@@ -799,6 +924,10 @@ def run_matrix(rehearse=False):
             + validate_paged_block_causal(**(dict(
                 Dh=16, BS=8, max_run=16, N=45, blocks_tol=4e-3,
                 dtypes=("bfloat16",)) if rehearse else {}))
+            + validate_paged_selection(**(dict(
+                Dh=16, BS=8, max_run=16, first=300, N=45, topk=32, rows=4,
+                singles_tol=4e-3, dtypes=("bfloat16",))
+                if rehearse else {}))
             + validate_ragged_expert_matmul()
             + validate_gated_delta(**(dict(
                 H=2, lens=(1, 3, 65), step=dict(H=2, T=264, slots=16))
