@@ -28,9 +28,9 @@ def interpret_mode():
     tpu_tile_validate.py --rehearse`) drives the real kernel bodies and
     their block-table plumbing with no chip."""
     from . import (flash_attention, gated_delta, grouped_matmul,
-                   layer_norm, paged_attention)
+                   layer_norm, paged_attention, topk_select)
     mods = (flash_attention, gated_delta, grouped_matmul, layer_norm,
-            paged_attention)
+            paged_attention, topk_select)
     old = [m._INTERPRET for m in mods]
     for m in mods:
         m._INTERPRET = True

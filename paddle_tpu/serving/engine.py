@@ -255,20 +255,26 @@ class _SparseLayers:
         d_of = jnp.full((T,), S, jnp.int32).at[
             jnp.where(d_live, d_row, T)].set(
             jnp.arange(S, dtype=jnp.int32), mode="drop")
+        # a one-token run's flat row is selected among the one-token
+        # runs; its row of the chunk selection is read by nothing
+        chunk_row = valid & (d_of == S)
         return dict(
             kruns=kruns, d_row=d_row, d_table=table[d_slot],
             d_to=jnp.where(d_live, d_row, T),
             cand_d=(keys <= d_pos[:, None]) & d_live[:, None],
-            cand_c=(keys <= pos[:, None]) & valid[:, None],
+            cand_c=(keys <= pos[:, None]) & chunk_row[:, None],
             samp_d=d_of[rows_at], samp_row=rows_at)
 
     def chunk_scores(self, qI, w, ip, table, kruns):
-        """I(t, s) [T, C] float32 of the rows of the runs `kruns` over
-        their slots' cached indexer keys, a run and `pages` pages at a
-        time, only as far as the run's last position; the rows of no
-        run and the keys past a run's reach hold whatever: no candidate
-        lies there. A run's tile of `max_run` rows may overhang into
-        the next runs' rows, which are written after it (ascending)."""
+        """I(t, s) float32 of the rows of the runs `kruns` over their
+        slots' cached indexer keys, a run and `pages` pages at a time,
+        only as far as the run's last position; the rows of no run and
+        the keys past a run's reach hold whatever: no candidate lies
+        there. A run's tile of `max_run` rows may overhang into the
+        next runs' rows, which are written after it (ascending). ->
+        the padded buffer `[T + max_run, >= C]`, whose first T rows and
+        C columns are the scores: the selection reads them in place (a
+        slice would be a copy of the whole `[T, C]`)."""
         import jax
         import jax.numpy as jnp
 
@@ -297,10 +303,9 @@ class _SparseLayers:
             return jax.lax.fori_loop(
                 0, (c_first[r] + c_len[r] - 1) // KC + 1, keys, scores)
 
-        scores = jax.lax.fori_loop(
+        return jax.lax.fori_loop(
             0, n_chunk[0], run,
             jnp.zeros((T + R, n_kc * KC), jnp.float32))
-        return scores[:T, :self.C]
 
     def attend(self, pools, at, ix, q, k, v, idx, wb, wo, slot_ids, pos,
                table, sp):
@@ -378,7 +383,7 @@ def attend_gathered(q, k_pool, v_pool, tables, at):
         S, Hq, Dh)
 
 
-def _sparse_work(plan, topk, max_run):
+def _sparse_work(plan, topk, max_run, rows_chunk, rows_decode, width):
     """The work of ONE sparse layer fed `plan`, as the flight record's
     fields (host arithmetic on the plan; FLIGHT_FIELDS_SPARSE.md): a
     slot's tokens are cut at `max_run` as the step cuts them; a piece
@@ -388,14 +393,26 @@ def _sparse_work(plan, topk, max_run):
     rows of longer pieces are CHUNK rows (`sparse_pairs_causal` the
     (query, key) pairs the causal rule allows them, `sparse_pairs_kept`
     those the selection keeps). `idx_keys_scored`: rows x candidate
-    keys, both kinds. -> (fields, the groups the run kernel walks)."""
+    keys, both kinds. `idx_cols_walked`: the columns the selection
+    walks (`topk_select.cols_walked`) over the step's `rows_chunk` flat
+    rows, a chunk row reaching its position + 1, and its `rows_decode`
+    one-token runs in flat order; `idx_cols_width`: both row counts x
+    the table's `width`, what a pass over every column walks. -> (fields,
+    the groups the run kernel walks)."""
+    from ..ops.pallas.topk_select import cols_walked
     dec = ctx = read = rows = scored = causal = kept = 0
     walked = []
+    reach_c, reach_d = [0] * rows_chunk, []
+    at = 0                                     # the group's first flat row
     for start, n in _plan_groups(plan):
         lo, hi = start + 1, start + n          # keys a row sees: lo..hi
         scored += (lo + hi) * n // 2
+        reach_c[at:at + n] = range(lo, hi + 1)
+        at += n
         if n % max_run == 1:
             dec, ctx, read = dec + 1, ctx + hi, read + min(topk, hi)
+            reach_c[at - 1] = 0
+            reach_d.append(hi)
             n, hi = n - 1, hi - 1
         if n:
             walked.append((start, n))
@@ -403,10 +420,14 @@ def _sparse_work(plan, topk, max_run):
             causal += (lo + hi) * n // 2
             cap = min(hi, max(topk, lo - 1))   # rows that keep them all
             kept += (lo + cap) * (cap - lo + 1) // 2 + topk * (hi - cap)
+    reach_d += [0] * (rows_decode - len(reach_d))
     return dict(sparse_rows_decode=dec, sparse_rows_chunk=rows,
                 sparse_kv_tokens_context=ctx, sparse_kv_tokens_read=read,
                 idx_keys_scored=scored, sparse_pairs_causal=causal,
-                sparse_pairs_kept=kept), walked
+                sparse_pairs_kept=kept,
+                idx_cols_walked=(cols_walked(reach_c, width)
+                                 + cols_walked(reach_d, width)),
+                idx_cols_width=(rows_chunk + rows_decode) * width), walked
 
 
 class _Flight:
@@ -3134,7 +3155,9 @@ class ServingEngine:
                 # under the causal rule: the chunk rows' groups
                 sparse, groups = _sparse_work(
                     plan, self._select.topk,
-                    min(self.token_budget, _BLOCK_MAX_RUN))
+                    min(self.token_budget, _BLOCK_MAX_RUN),
+                    self.token_budget, self.kv.max_slots,
+                    self.kv.max_blocks_per_slot * self.block_size)
                 sparse["idx_pool_bytes"] = self.kv.idx_bytes_per_token \
                     * self.kv.num_blocks * self.block_size
             work = _attention_work_by_kind(plan, self.kv.window,

@@ -2,7 +2,9 @@
 step (interpreted, on the CPU): what it computes against the recurrence
 token by token, what it leaves alone, that a request's bits do not
 depend on what rides with it, and the host's count of the rows it
-loads with the benchmark's reader of that count."""
+loads with the benchmark's reader of that count. Last, the repo's
+kernels through the TPU's own compilers with no chip attached: this
+kernel and the exact top-k `topk_select` at their cells' shapes."""
 import functools
 import os
 import sys
@@ -250,3 +252,38 @@ def test_mosaic_takes_the_kernel_at_the_cell_s_shapes(one_chip,
     assert "gated_delta" in text
     # no array in the 64-row layout of every chunk slot is made by XLA
     assert f"f32[{H},{gd.max_chunks(T, slots)},{gd.CHUNK}" not in text
+
+
+def test_mosaic_takes_the_top_k_kernel_at_the_keye_cell_s_shapes(
+        one_chip, monkeypatch):
+    """The Keye cell's two selections, `[512, 33792]` chunk rows (their
+    scores in the engine's padded buffer `[640, 36864]`) and `[16,
+    33792]` decode rows of float32 scores with k = 2048, through the
+    TPU's own compilers: the int8 candidates and mask, the copies of a
+    tile's strips, the counting passes' loops of a length read at run
+    time. Nothing runs."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.core import place
+    from paddle_tpu.ops.pallas import topk_select as ts
+    monkeypatch.setattr(place, "on_tpu_backend", lambda: True)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for rows, buffer in ((512, (640, 36864)), (16, (16, 33792))):
+            args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                    for shape, dtype in ((buffer, jnp.float32),
+                                         ((rows, 33792), jnp.bool_))]
+            compiled = jax.jit(lambda s, c: ts.topk_mask(s, 2048, c)).trace(
+                *args).lower(lowering_platforms=("tpu",)).compile()
+            text = compiled.as_text()
+            assert "topk_select" in text
+            # no [rows, 33792] array of 32-bit keys is made by XLA, and
+            # the padded buffer is read in place, not sliced into a copy
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < rows * 33792
+            assert (f"f32[{rows},33792]" in text) == (buffer[0] == rows)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()

@@ -216,6 +216,43 @@ def test_the_two_orders_of_the_host_loop_give_the_same_tokens():
     assert eng.step_compile_count() == 1
 
 
+def test_the_selection_kernel_serves_what_the_jnp_form_does(monkeypatch):
+    """The step with the exact top-k as the kernel `topk_select`
+    (interpreted; every other operation as on the CPU) against the same
+    step with its `jax.numpy` form: the same tokens, the same rows of
+    logits and the same selections, bit for bit."""
+    from paddle_tpu.ops.pallas import topk_select as ts
+    prompts = prompts_of((3 * TOPK + 5, 70, TOPK - 4), seed=4)
+    want = serve(shared_engine(), prompts, 6)
+    traced, kernel = [], ts._topk_select
+    monkeypatch.setattr(ts, "_INTERPRET", True)
+    monkeypatch.setattr(ts, "_topk_select", lambda *a: traced.append(
+        a[0].shape) or kernel(*a))
+    got = serve(engine(model()), prompts, 6)
+    # both selections of both layers, traced once
+    assert len(traced) == 4
+    assert got[0] == want[0]
+    for a, b in zip(got[1:], want[1:]):
+        for ra, rb in zip(a, b):
+            assert all((x == y).all() for x, y in zip(ra, rb))
+
+
+def test_a_patched_topk_mask_reaches_the_step(monkeypatch):
+    """The step looks `topk_mask` up when it is traced (the benchmark's
+    controls patch it): dense attention in its place keeps every
+    candidate, and the selections show it."""
+    from paddle_tpu.ops.pallas import topk_select as ts
+    prompts = prompts_of((3 * TOPK + 5, 50), seed=6)
+    _, _, want = serve(shared_engine(), prompts, 4)
+    monkeypatch.setattr(ts, "topk_mask", lambda scores, k, cand: cand)
+    _, _, got = serve(engine(model()), prompts, 4)
+    members = [s.sum(-1).max() for sels in got for s in sels]
+    assert max(members) > TOPK
+    assert max(s.sum(-1).max() for sels in want for s in sels) == TOPK
+    assert any((a != b).any() for x, y in zip(got, want)
+               for a, b in zip(x, y))
+
+
 def test_through_the_frontend_s_configuration():
     """`inference.create_serving_frontend` builds the same engine: no
     keyword of its own."""
@@ -280,17 +317,45 @@ def brute_work(groups, topk, max_run):
                 sparse_pairs_kept=kept)
 
 
+def walked_by_the_step(plan, max_run, T, S, C):
+    """Columns the kernel walks for the candidates the step itself makes
+    of `plan` (`_SparseLayers.split` over its packed flat tokens): the
+    chunk rows' and the one-token runs', each tile of rows as far as its
+    largest candidate column + 1 (test_topk_select_kernel.brute_walk)."""
+    from test_topk_select_kernel import brute_walk
+
+    from paddle_tpu.ops.pallas import topk_select as ts
+    from paddle_tpu.ops.pallas.paged_attention import paged_runs
+    from paddle_tpu.serving.batcher import pack_step
+    sp = pack_step(T, S, plan.decode, plan.prefills)
+    valid = sp.slot_ids >= 0
+    pos = jnp.asarray(np.where(valid, sp.positions, 0))
+    layers = object.__new__(engine_mod._SparseLayers)
+    layers.T, layers.S, layers.C = T, S, C
+    split = layers.split(paged_runs(jnp.asarray(sp.slot_ids), pos, max_run),
+                         jnp.zeros((S, 1), jnp.int32), pos,
+                         jnp.asarray(valid), jnp.zeros((S,), jnp.int32))
+    return sum(brute_walk(np.asarray(split[c]), ts.ROWS, ts.strip_of(C))
+               for c in ("cand_c", "cand_d"))
+
+
 @pytest.mark.parametrize("groups", (
     [(100, 1), (7, 1), (0, 32)], [(0, 33)], [(40, 65), (3, 2)],
     [(2047, 1), (2048, 1), (2040, 16)], [(10, 5), (500, 1)]))
 def test_sparse_work_is_the_brute_count(groups):
     plan = engine_mod.Plan(
-        decode=[(0, [1] * n, s) for s, n in groups if n == 1],
-        prefills=[(1, [1] * n, s, False) for s, n in groups if n > 1],
+        decode=[(i, [1] * n, s) for i, (s, n) in enumerate(groups)
+                if n == 1],
+        prefills=[(8 + i, [1] * n, s, False)
+                  for i, (s, n) in enumerate(groups) if n > 1],
         expired=[])
+    T, S, C = 96, 16, 2560
     for topk, max_run in ((16, 32), (2048, 128)):
-        got, walked = engine_mod._sparse_work(plan, topk, max_run)
+        got, walked = engine_mod._sparse_work(plan, topk, max_run, T, S, C)
         want = brute_work(engine_mod._plan_groups(plan), topk, max_run)
+        want.update(idx_cols_walked=walked_by_the_step(plan, max_run, T,
+                                                       S, C),
+                    idx_cols_width=(T + S) * C)
         assert got == want, (topk, max_run)
         assert sum(n for _, n in walked) == want["sparse_rows_chunk"]
 

@@ -8,8 +8,9 @@ family (ragged / verify / decode / sparse short-table; fp32, bf16, int8
 and fp8 pools; the same entries on pools stacked over layers, read in
 place; the block-causal rule of a model that decodes by blocks), fused add+LayerNorm and splash attention (forward and
 backward), the hand flash-forward kernel, the grouped-expert matmul
-(fp32 / int8 / int4 weights) and the ragged chunked delta rule (a key
-dim of 96) against their pure-XLA oracles with
+(fp32 / int8 / int4 weights), the ragged chunked delta rule (a key
+dim of 96) and the exact top-k selection (bit for bit) against their
+pure-XLA oracles with
 interpret mode OFF. A
 kernel that compiles and is wrong fails here.
 
@@ -771,6 +772,41 @@ def validate_gated_delta(*, H=6, dk=96, dv=192, slots=8, chunk=64,
     return cells
 
 
+def validate_topk_select(*, shapes=((512, 33792), (16, 33792)), k=2048,
+                         seed=3):
+    """The exact top-k kernel (`topk_select`) against its `jax.numpy`
+    form, bit for bit, at the Keye cell's selections (chunk rows and
+    decode rows of a 33,792-column table, k = 2048): rows whose
+    candidates end anywhere (most inside a strip), rows with none and
+    rows with fewer than k; scores of float32 noise, and a quarter of
+    the rows rounded to a few values so that the k-th score is shared
+    by many keys."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import topk_select as ts
+
+    rng = np.random.RandomState(seed)
+    kernel = jax.jit(lambda s, c: ts.topk_mask(s, k, c))
+    oracle = jax.jit(lambda s, c: ts.topk_mask_jnp(s, k, c))
+    cells = []
+    for R, C in shapes:
+        score = rng.randn(R, C).astype(np.float32)
+        score[::4] = np.round(score[::4] * 2) / 2
+        score[1::8, ::3] = -0.0
+        reach = rng.randint(0, C + 1, size=R)
+        reach[::5] = 0
+        reach[1::5] = rng.randint(1, k, size=len(reach[1::5]))
+        cand = np.arange(C)[None, :] < reach[:, None]
+        s, c = jnp.asarray(score), jnp.asarray(cand)
+        got, want = kernel(s, c), oracle(s, c)
+        cells.append(_cell(f"topk_select [{R}, {C}] k={k}",
+                           got.astype(jnp.float32),
+                           want.astype(jnp.float32), 0, 0))
+    return cells
+
+
 def validate_add_ln(*, rows=512, d=256, dtype="bfloat16"):
     """Fused residual-add + LayerNorm against its jnp form: the forward
     pair (normalized, new residual) and the input/scale/shift grads of
@@ -932,6 +968,8 @@ def run_matrix(rehearse=False):
             + validate_gated_delta(**(dict(
                 H=2, lens=(1, 3, 65), step=dict(H=2, T=264, slots=16))
                 if rehearse else {}))
+            + validate_topk_select(**(dict(
+                shapes=((45, 1300), (16, 1300)), k=64) if rehearse else {}))
             + validate_add_ln()
             + validate_splash() + validate_flash()
             + validate_grouped_matmul())
