@@ -41,6 +41,8 @@ class MoESpec:
     expert_rank: int = 0        # which share: [held * rank, held * (rank + 1))
     route_scale: float = 1.0
     route_norm: bool = True
+    n_group: int = None         # DeepSeek-V3's group limit (None: none)
+    topk_group: int = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,13 +157,15 @@ def embed(arch, w, token_ids):
 
 def moe_ffn(arch, lw, x, valid):
     """shared(x) + the held experts' part of the routed sum; stats as
-    `dropless_expert_ffn` counts them."""
+    `dropless_expert_ffn` counts them. A layer whose weights hold no
+    `expert_bias` chooses by the scores alone."""
     import jax
     m = arch.moe
     with jax.named_scope("moe_router"):
         idx, wts = route_sigmoid_topk(
-            x, lw["router"], lw["expert_bias"], m.top_k,
-            route_norm=m.route_norm, route_scale=m.route_scale)
+            x, lw["router"], lw.get("expert_bias"), m.top_k,
+            route_norm=m.route_norm, route_scale=m.route_scale,
+            n_group=m.n_group, topk_group=m.topk_group)
     with jax.named_scope("moe_experts"):
         routed, stats = dropless_expert_ffn(
             x, idx, wts, valid, lw["e_gate"], lw["e_up"], lw["e_down"],

@@ -18,7 +18,8 @@ class ServingBlock:
     #                          and `block_decoding` (a `BlockDecoding`)
     #                          where the model decodes by blocks,
     #                          `selection` (a `LearnedSelection`) where
-    #                          a layer's kind is "sparse"
+    #                          a layer's kind is "sparse", `latent` (a
+    #                          `LatentAttention`) where it is "latent"
     weights: dict
     embed: object           # (arch, weights, token_ids) -> h [T, D]
     layer: object           # (arch, li, layer weights, h, positions,
@@ -110,3 +111,25 @@ class LearnedSelection:
     rope_dims: int
     scale: float
     eps: float = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """What the "latent" layers of an architecture say of their cache
+    (multi-head latent attention, DeepSeek-V2): a token caches ONE row
+    shared by every query head, its latent `c` (`value_dim` numbers,
+    which is also what the values are made of) and its rotary key
+    (`rope_dim`), in `lanes` lanes (whole lane tiles, zeros past the
+    two). A latent layer calls `attend(qa, row, None, li)`: `qa [T, H,
+    lanes]` its absorbed query rows, `row [T, lanes]` its tokens'
+    latent rows; the engine appends the rows and returns, for every
+    query row, `softmax(scale * qa . rows) . rows[:, :value_dim]`
+    over the slot's keys up to the row's position, `[T, H,
+    value_dim]`, which the layer up-projects itself."""
+    value_dim: int
+    rope_dim: int
+    scale: float
+
+    @property
+    def lanes(self):
+        return -(-(self.value_dim + self.rope_dim) // 128) * 128

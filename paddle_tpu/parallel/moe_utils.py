@@ -422,24 +422,48 @@ def global_gather(x, local_count, global_count, group=None,
 
 
 def route_sigmoid_topk(x, w_router, select_bias, top_k, *,
-                       route_norm=True, route_scale=1.0):
+                       route_norm=True, route_scale=1.0, n_group=None,
+                       topk_group=None):
     """Sigmoid router with a selection-only bias.
 
-    x [T, D]; w_router [D, E]; select_bias [E]. Scores
-    `s = sigmoid(x W)` in float32; the experts are CHOSEN by
+    x [T, D]; w_router [D, E]; select_bias [E] or None (no bias).
+    Scores `s = sigmoid(x W)` in float32; the experts are CHOSEN by
     `top_k(s + select_bias)` and WEIGHTED by `s` alone, normalised over
-    the chosen (`route_norm`) and scaled. Returns (idx [T, k] int32,
-    weights [T, k] float32)."""
+    the chosen (`route_norm`) and scaled. With `n_group` / `topk_group`
+    (DeepSeek-V3's group limit) the E experts are `n_group` groups of
+    consecutive ids, a group scores the sum of its two largest
+    selection scores, and only the experts of the `topk_group` best
+    groups are candidates of the top-k (`group_limit`). Returns (idx
+    [T, k] int32, weights [T, k] float32)."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + select_bias.astype(jnp.float32), top_k)
+    sel = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    if n_group is not None:
+        sel = group_limit(sel, n_group, topk_group)
+    _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * route_scale
+
+
+def group_limit(sel, n_group, topk_group):
+    """The selection scores `sel [T, E]` with every expert outside the
+    `topk_group` best of `n_group` groups (consecutive ids, E /
+    n_group a group) set to -inf: a group scores the sum of its two
+    largest scores; equal group scores go to the lower group."""
+    import jax
+    import jax.numpy as jnp
+    T, E = sel.shape
+    g = sel.reshape(T, n_group, E // n_group)
+    top2, _ = jax.lax.top_k(g, 2)
+    _, keep = jax.lax.top_k(jnp.sum(top2, axis=-1), topk_group)
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    return jnp.where(kept[:, :, None], g, -jnp.inf).reshape(T, E)
 
 
 def route_softmax_topk(x, w_router, top_k, *, norm_topk=True):

@@ -519,6 +519,15 @@ class ServingEngine:
                        "beside full layers: the flight fields "
                        "`kv_tokens_read_full` / `attn_pairs_full` count "
                        "one kind of layer"))
+            # a model whose layers attend by multi-head latent attention:
+            # `layer_kinds` "latent", `latent` (a serving_block.
+            # LatentAttention) says what a cached row holds
+            self._latent = arch.latent if "latent" in kinds_of else None
+            if self._latent is not None and kinds_of != {"latent"}:
+                raise ValueError(
+                    "latent attention is not built beside other kinds of "
+                    f"layer ({sorted(kinds_of - {'latent'})}): the flight "
+                    "fields of a step count one kind of attention layer")
             if "linear" in kinds_of and (prefix_caching or draft_k):
                 raise ValueError(
                     f"{'prefix_caching' if prefix_caching else 'draft_k'}"
@@ -548,6 +557,7 @@ class ServingEngine:
                     "expert_parallel=)")
             L, H, Dh = dec.num_layers, dec.num_heads, dec.head_dim
             self._diff = self._causal_block = self._select = None
+            self._latent = None
         maxpos = model.max_position_embeddings
         max_seq_len = min(max_seq_len or maxpos, maxpos)
         if block_size == "auto":
@@ -798,6 +808,9 @@ class ServingEngine:
                         f"{self._kv_heads})")
                 # an indexer key a token a sparse layer, beside its K/V
                 kinds.update(indexer_dim=self._select.head_dim)
+            if self._latent is not None:
+                # one latent row a token a latent layer, and no K/V
+                kinds.update(latent_dim=self._latent.lanes)
         with jax.default_device(device):
             self.kv = PagedKVCache(
                 L, H, Dh, num_blocks=num_blocks,
@@ -1175,6 +1188,7 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ..ops.pallas.flash_attention import ragged_paged_attention
+        from ..ops.pallas.mla_attention import mla_attend
         from ..ops.pallas.paged_attention import paged_runs
         block = self._block
         arch = block.arch
@@ -1189,6 +1203,10 @@ class ServingEngine:
         at = {li: 2 * i for i, li in enumerate(self.kv.attention_layers)}
         at.update({li: 2 * (len(at) + j) for j, li in
                    enumerate(self.kv.linear_layers)})
+        # a latent layer's pool: after every other pool
+        at.update({li: n_pools - len(self.kv.latent_layers) + j
+                   for j, li in enumerate(self.kv.latent_layers)})
+        latent = self._latent
         linear = bool(self.kv.linear_layers)
         more_heads = self._kv_heads - arch.num_kv_heads
         diff, causal_block = self._diff, self._causal_block
@@ -1242,6 +1260,19 @@ class ServingEngine:
 
             def attend(q, k, v, li, idx=None):
                 kind = kinds[li]
+                if kind == "latent":
+                    # q: the absorbed query rows, k: the tokens' latent
+                    # rows; one pool, read for the logits and the values
+                    with jax.named_scope("kv_write"):
+                        lp = pools[at[li]].at[wb["full"], wo].set(
+                            k.astype(pools[at[li]].dtype))
+                    pools[at[li]] = lp
+                    with jax.named_scope("attn_latent"):
+                        return mla_attend(
+                            q, lp, tables["full"], slot_ids, pos,
+                            scale=latent.scale,
+                            value_dim=latent.value_dim, runs=runs,
+                            max_run=max_run)
                 kp, vp = pools[at[li]], pools[at[li] + 1]
                 if kind == "sparse":
                     o, kept = sparse.attend(
@@ -3172,7 +3203,25 @@ class ServingEngine:
             if self.kv.linear_layers:
                 work.update(
                     _linear_work(plan, self._block.arch.delta_chunk))
+            if self._latent is not None:
+                work.update(self._latent_work(plan))
         work.update(self._logit_work(plan, layers, groups))
+        return work
+
+    def _latent_work(self, plan):
+        """The latent layers' flight fields (FLIGHT_FIELDS_MLA.md): one
+        layer's counts of the runs the step makes (`mla_attention.
+        mla_work`, cut at the block step's `max_run`) times the latent
+        layers; `mla_logits_useful` the causal pairs x the query
+        heads."""
+        from ..ops.pallas.mla_attention import mla_work
+        n, H = len(self.kv.latent_layers), self._block.arch.num_heads
+        work = mla_work(_plan_groups(plan), H, self.block_size,
+                        self.kv.max_blocks_per_slot,
+                        min(self.token_budget, _BLOCK_MAX_RUN))
+        work = {k: n * v for k, v in work.items()}
+        work["mla_logits_useful"] = H * (work["mla_pairs_decode"]
+                                         + work["mla_pairs_chunk"])
         return work
 
     def _logit_work(self, plan, layers, groups=None):

@@ -179,7 +179,7 @@ class PagedKVCache:
                  dtype="float32", kv_dtype=None, summaries=False,
                  num_kv_heads=None, layer_kinds=None, window=None,
                  num_window_blocks=None, linear_state=None,
-                 conv_tail=None, indexer_dim=None):
+                 conv_tail=None, indexer_dim=None, latent_dim=None):
         import jax.numpy as jnp
         self.num_layers = num_layers
         # the heads the pools hold: the KV heads of a grouped-query
@@ -195,7 +195,8 @@ class PagedKVCache:
         # recurrent state and the convolution's last inputs a slot; a
         # sparse layer (attention through a learned selection) is a
         # full layer that keeps an INDEXER-KEY pool beside its K/V, on
-        # the same block table (see `_init_layer_kinds`)
+        # the same block table; a latent layer keeps ONE pool of latent
+        # rows on that table and no K/V (see `_init_layer_kinds`)
         self.layer_kinds = tuple(layer_kinds) if layer_kinds else None
         self.window = int(window) if window else None
         kinds = self.layer_kinds or ()
@@ -203,18 +204,21 @@ class PagedKVCache:
         #: layers that keep K/V blocks, layers that keep a state
         self.linear_layers = [i for i, k in enumerate(kinds)
                               if k == "linear"]
+        self.latent_layers = [i for i, k in enumerate(kinds)
+                              if k == "latent"]
         self.attention_layers = [i for i in range(num_layers)
-                                 if i not in self.linear_layers]
+                                 if i not in self.linear_layers
+                                 and i not in self.latent_layers]
         self.sparse_layers = [i for i, k in enumerate(kinds)
                               if k == "sparse"]
-        self.idx_pools = []
+        self.idx_pools, self.latent_pools = [], []
         if self.layer_kinds is not None:
-            if len(kinds) != num_layers or \
-                    set(kinds) - {"full", "sliding", "linear", "sparse"}:
+            if len(kinds) != num_layers or set(kinds) - {
+                    "full", "sliding", "linear", "sparse", "latent"}:
                 raise ValueError(
                     f"layer kinds {kinds}: one of 'full', 'sliding', "
-                    f"'linear', 'sparse' for each of the {num_layers} "
-                    "layers")
+                    f"'linear', 'sparse', 'latent' for each of the "
+                    f"{num_layers} layers")
             if self.has_window and not self.window:
                 raise ValueError("a 'sliding' layer needs a window")
             if self.linear_layers and not (linear_state and conv_tail):
@@ -225,6 +229,9 @@ class PagedKVCache:
             if self.sparse_layers and not indexer_dim:
                 raise ValueError("a 'sparse' layer needs the width of "
                                  "its indexer key (indexer_dim)")
+            if self.latent_layers and not latent_dim:
+                raise ValueError("a 'latent' layer needs the lanes of "
+                                 "its latent row (latent_dim)")
             if summaries or KV_DTYPES.get(
                     str(kv_dtype or dtype), (0, False))[1]:
                 raise ValueError(
@@ -247,7 +254,7 @@ class PagedKVCache:
         self.states, self.conv_tails = [], []
         if self.layer_kinds is not None:
             self._init_layer_kinds(num_window_blocks, linear_state,
-                                   conv_tail, indexer_dim)
+                                   conv_tail, indexer_dim, latent_dim)
         else:
             shape = (num_layers, self.num_blocks, self.block_size,
                      num_heads, head_dim)
@@ -298,7 +305,7 @@ class PagedKVCache:
 
     # ------------------------------------------------------ window layers
     def _init_layer_kinds(self, num_window_blocks, linear_state,
-                          conv_tail, indexer_dim=None):
+                          conv_tail, indexer_dim=None, latent_dim=None):
         """A cache whose layers are of several kinds. Each ATTENTION
         layer has its own K and V pool `[NB_kind, BS, H, Dh]`
         (`k_pools[i]`, i counting `attention_layers`): the step updates
@@ -339,7 +346,15 @@ class PagedKVCache:
         64 is half a tile; the zeros add nothing to a score). A block
         that is freed and reused keeps its old keys: positions past a
         slot's length are never candidates of a selection, the rule
-        that already holds for K/V, so nothing is written at reuse."""
+        that already holds for K/V, so nothing is written at reuse.
+
+        Each LATENT layer (multi-head latent attention) keeps no K/V:
+        ONE pool `latent_pools[i]` (i counting `latent_layers`),
+        `[num_blocks, BS, latent_dim]` in the pools' dtype, a row a
+        token that every query head reads (the latent c and the
+        rotary key, zeros to a whole number of lane tiles), on the
+        full layers' block table and allocator, so that whatever moves
+        table entries carries it as it carries K/V."""
         import jax.numpy as jnp
         if self.has_window and not num_window_blocks:
             raise ValueError("a cache with window layers is told its "
@@ -360,6 +375,10 @@ class PagedKVCache:
             self.idx_pools = [
                 jnp.zeros((self.num_blocks, self.block_size, lanes), dt)
                 for _ in self.sparse_layers]
+        self.latent_dim = int(latent_dim or 0)
+        self.latent_pools = [
+            jnp.zeros((self.num_blocks, self.block_size, self.latent_dim),
+                      dt) for _ in self.latent_layers]
         for _ in self.linear_layers:
             self.states.append(jnp.zeros(
                 (self.max_slots,) + tuple(linear_state), jnp.float32))
@@ -471,7 +490,7 @@ class PagedKVCache:
             per += (2 * self.num_heads * self.head_dim * 4
                     ) // self.block_size
         return len(self.attention_layers) * per \
-            + self.idx_bytes_per_token
+            + self.idx_bytes_per_token + self.latent_bytes_per_token
 
     @property
     def idx_bytes_per_token(self):
@@ -479,6 +498,13 @@ class PagedKVCache:
         sparse layers (a padded row a layer)."""
         return sum(int(p.shape[-1]) * p.dtype.itemsize
                    for p in self.idx_pools)
+
+    @property
+    def latent_bytes_per_token(self):
+        """HBM bytes the latent rows of one cached token take over the
+        latent layers (a padded row a layer)."""
+        return sum(int(p.shape[-1]) * p.dtype.itemsize
+                   for p in self.latent_pools)
 
     @property
     def state_bytes(self):
@@ -503,12 +529,17 @@ class PagedKVCache:
                 "by blocks")
 
     def _refuse_with_indexer(self, what):
-        if self.sparse_layers:
-            raise ValueError(
-                f"{what} is not built for a cache with a sparse layer: "
-                "the copy and transport executables index STACKED pools "
-                "at axis 1, and a layer's indexer-key pool beside its "
-                "K/V would have to ride every frame of the codec")
+        for layers, kind, pool in (
+                (self.sparse_layers, "sparse", "indexer-key pool beside "
+                 "its K/V"),
+                (self.latent_layers, "latent", "latent pool in place of "
+                 "K/V")):
+            if layers:
+                raise ValueError(
+                    f"{what} is not built for a cache with a {kind} "
+                    "layer: the copy and transport executables index "
+                    f"STACKED pools at axis 1, and a layer's {pool} "
+                    "would have to ride every frame of the codec")
 
     @property
     def block_bytes(self):
@@ -707,7 +738,8 @@ class PagedKVCache:
             return [p for kv in zip(self.k_pools, self.v_pools)
                     for p in kv] + \
                 [a for st in zip(self.states, self.conv_tails)
-                 for a in st] + list(self.idx_pools)
+                 for a in st] + list(self.idx_pools) \
+                + list(self.latent_pools)
         out = [self.k_pool, self.v_pool]
         if self.quantized:
             out += [self.k_scale, self.v_scale]
@@ -725,7 +757,8 @@ class PagedKVCache:
             m = n + 2 * len(self.linear_layers)
             self.states, self.conv_tails = arrays[n:m:2], \
                 arrays[n + 1:m:2]
-            self.idx_pools = arrays[m:]
+            i = m + len(self.sparse_layers)
+            self.idx_pools, self.latent_pools = arrays[m:i], arrays[i:]
             return
         self.k_pool, self.v_pool = arrays[:2]
         arrays = arrays[2:]
@@ -861,9 +894,10 @@ class PagedKVCache:
         may have forced block allocations their K/V never ended up
         needing; the garbage they DID write into still-owned blocks
         needs no cleanup (the position mask hides it and the next
-        accepted tokens overwrite it). A sparse layer's indexer keys
-        follow: they lie at the K/V's (block, offset), and a position
-        past the slot's length is no candidate of a selection."""
+        accepted tokens overwrite it). A sparse layer's indexer keys and
+        a latent layer's rows follow: they lie at the K/V's (block,
+        offset), and a position past the slot's length is attended by
+        no query."""
         self._refuse_with_state("truncate_slot")
         keep = self.blocks_for(new_len)
         row = self._slot_blocks[slot]

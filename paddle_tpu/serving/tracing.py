@@ -592,6 +592,10 @@ DEVICE_SCOPES = (
     # three products, its scores, the exact top-k, and the one-token
     # runs' gather and attention (the chunk rows' kernel: `attn_full`)
     "idx_proj", "idx_score", "idx_select", "attn_sparse",
+    # a layer that attends by multi-head latent attention: the products
+    # with the up-projections W_UK and W_UV, and the attention over the
+    # latent pool (its kernel `mla_attend` inside it keeps its name)
+    "mla_absorb", "attn_latent",
 )
 #: what an instruction reads whose `op_name` holds none of them
 NO_SCOPE = "(none)"

@@ -37,6 +37,8 @@ MUST = {
     "afmoe": ("attn_window", "moe_router", "moe_experts", "moe_shared"),
     "olmo_hybrid": ("lin_proj", "lin_conv", "gated_delta", "lin_gate_out"),
     "sdar": ("moe_router", "moe_experts", "diffusion_confidence"),
+    "axk1": ("attn_latent", "mla_absorb", "moe_router", "moe_experts",
+             "moe_shared"),
 }
 PROMPTS = [[3, 14, 15, 9, 2, 6, 5], [7, 8, 1]]
 
@@ -57,6 +59,9 @@ def build(which, **kw):
             cache_dtype="float32", **kw)
     if which == "olmo_hybrid":
         from test_olmo_hybrid_serving import engine, model
+        return engine(model(), **kw)
+    if which == "axk1":
+        from test_axk1_mla import engine, model
         return engine(model(), **kw)
     from test_block_diffusion_serving import engine, model
     return engine(model(), **kw)
@@ -104,7 +109,10 @@ def test_every_operation_of_the_step_has_a_scope(which):
             *eng.example_step_args()).compile().as_text()
     named = set(table.values())
     assert named <= set(tracing.DEVICE_SCOPES) | {tracing.NO_SCOPE}
-    for scope in EVERY_STEP + MUST[which]:
+    # a latent model's attention is `attn_latent`, none is `attn_full`
+    every = tuple(x for x in EVERY_STEP
+                  if which != "axk1" or x != "attn_full")
+    for scope in every + MUST[which]:
         assert scope in named, (which, scope)
     # the table is the running executable's: the same text gives it
     assert table == hlo_op_scopes(text, tracing.scope_of,
@@ -331,9 +339,10 @@ def test_manifest_entries():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     entries = {m["name"]: m for m in manifest["per_layer"]}
-    # the cells of PR 35, and the cell PR 37 appended to the lists
-    keye = "serve_keye_vl2_30b_a3b_longctx"
-    serve = SERVE_CELLS + [keye]
+    # the serve cells the metrics began with, and the two long-context
+    # cells appended to the lists since
+    keye, axk1 = "serve_keye_vl2_30b_a3b_longctx", "serve_axk1_ep16_longctx"
+    serve = SERVE_CELLS + [keye, axk1]
     want = {
         "device.named_busy_pct": ("%", "higher", "device", serve),
         "model.attn_proj_ms_per_step": ("ms/step", "lower", "model",
@@ -341,11 +350,12 @@ def test_manifest_entries():
         "model.kv_write_ms_per_step": ("ms/step", "lower", "model",
                                        serve),
         "model.mlp_ms_per_step": ("ms/step", "lower", "model",
-                                  SERVE_CELLS[:4]),
+                                  SERVE_CELLS[:4] + [axk1]),
         "model.head_sample_ms_per_step": ("ms/step", "lower", "model",
                                           serve),
         "moe.route_ms_per_step": ("ms/step", "lower", "moe",
-                                  [SERVE_CELLS[2], SERVE_CELLS[4], keye]),
+                                  [SERVE_CELLS[2], SERVE_CELLS[4], keye,
+                                   axk1]),
         "linear_attn.mixer_xla_ms_per_step": (
             "ms/step", "lower", "linear_attn", [SERVE_CELLS[3]]),
     }
@@ -390,6 +400,8 @@ BLOCK_FIELDS = {
                     ("FLIGHT_FIELDS_LINEAR.md", None)],
     "sdar": [("FLIGHT_FIELDS.md", slice(6, 7)),
              ("FLIGHT_FIELDS_DIFFUSION.md", None)],
+    "axk1": [("FLIGHT_FIELDS.md", slice(4, 7)),
+             ("FLIGHT_FIELDS_MLA.md", None)],
 }
 COMMON = ("ts", "dur", "prefill_tokens", "decode_tokens", "active_slots",
           "queue_depth", "kv_blocks_in_use", "kv_blocks_total",

@@ -344,7 +344,7 @@ def test_dispatch_ahead_reader():
             "serve_gpt3_1p3b_closed", "serve_gpt3_1p3b_closed_b",
             "serve_trinity_ep8_mixed_len", "serve_olmo_hybrid_mixed_len",
             "serve_sdar_30b_a3b_mixed_len",
-            "serve_keye_vl2_30b_a3b_longctx"]}
+            "serve_keye_vl2_30b_a3b_longctx", "serve_axk1_ep16_longctx"]}
 
 
 # ------------------------------- (d) one compile across example args

@@ -9,7 +9,8 @@ and fp8 pools; the same entries on pools stacked over layers, read in
 place; the block-causal rule of a model that decodes by blocks), fused add+LayerNorm and splash attention (forward and
 backward), the hand flash-forward kernel, the grouped-expert matmul
 (fp32 / int8 / int4 weights), the ragged chunked delta rule (a key
-dim of 96) and the exact top-k selection (bit for bit) against their
+dim of 96), the exact top-k selection (bit for bit) and the absorbed
+latent attention (`mla_attend`, run by run) against their
 pure-XLA oracles with
 interpret mode OFF. A
 kernel that compiles and is wrong fails here.
@@ -651,9 +652,12 @@ def validate_ragged_expert_matmul(*, sizes=(9, 0, 70, 1), D=256, F=384,
         w, w2 = (jnp.asarray(rng.randn(E, D, F) / 16, dtype)
                  for _ in range(2))
         with jax.default_matmul_precision("highest"):
+            # group by group: a weight matrix a row would not fit at the
+            # widths of an expert layer
             xf = x[rows].astype(jnp.float32)
-            a = jnp.einsum("rd,rdf->rf", xf, w.astype(jnp.float32)[group])
-            b = jnp.einsum("rd,rdf->rf", xf, w2.astype(jnp.float32)[group])
+            a, b = (jnp.concatenate([
+                xf[group == e] @ m[e].astype(jnp.float32)
+                for e in range(E)]) for m in (w, w2))
         tol = 5e-2 if dtype == "bfloat16" else 2e-2
         cells.append(_cell(
             f"moe_experts ragged {dtype} sizes={list(sizes)} D={D} F={F}",
@@ -804,6 +808,78 @@ def validate_topk_select(*, shapes=((512, 33792), (16, 33792)), k=2048,
         cells.append(_cell(f"topk_select [{R}, {C}] k={k}",
                            got.astype(jnp.float32),
                            want.astype(jnp.float32), 0, 0))
+    return cells
+
+
+def validate_mla_attend(*, H=64, W=640, Dv=512, BS=16, slots=16, T=512,
+                        max_ctx=33792, max_run=128, decode=10, seed=5,
+                        dtype="bfloat16", tol=3e-2):
+    """The absorbed latent attention kernel (`mla_attend`) against a
+    float32 oracle run by run, at the A.X-K1 cell's shapes: `slots`
+    slots, `decode` of them decoding one token at contexts up to
+    `max_ctx`, the rest of the `T` tokens prefill chunks (cut at
+    `max_run`) deep in long prompts, rows of W lanes whose first Dv are
+    the value; the blocks of a slot scattered over the pool."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import mla_attention as ma
+    from paddle_tpu.ops.pallas.paged_attention import paged_runs
+
+    rng = np.random.RandomState(seed)
+    runs = [(int(p), 1) for p in rng.randint(max_ctx // 8, max_ctx - 1,
+                                             size=decode)]
+    left = T - decode - 2
+    chunks = slots - decode
+    for i in range(chunks):
+        n = left // (chunks - i)
+        left -= n
+        runs.append((int(rng.randint(0, max_ctx - n)) if i else
+                     max_ctx - n, n))
+    MB = -(-max_ctx // BS)
+    need = [(p + n - 1) // BS + 1 for p, n in runs]
+    NB = sum(need) + 1
+    perm = 1 + rng.permutation(NB - 1)
+    tables = np.zeros((slots, MB), np.int32)
+    at = 0
+    for s, k in enumerate(need):
+        tables[s, :k] = perm[at:at + k]
+        at += k
+    slot_ids = np.concatenate([np.full(n, s) for s, (_, n) in
+                               enumerate(runs)] + [[-1, -1]])
+    pos = np.concatenate([np.arange(p, p + n) for p, n in runs] + [[0, 0]])
+    pool = jnp.asarray(rng.randn(NB, BS, W).astype(np.float32), dtype)
+    q = rng.randn(T, H, W).astype(np.float32)
+    q[..., Dv + 64:] = 0
+    q = jnp.asarray(q, dtype)
+    scale = 0.13
+    sl, ps = jnp.asarray(slot_ids, jnp.int32), jnp.asarray(pos, jnp.int32)
+    got = np.asarray(jax.jit(lambda q, p, t, s, x: ma.mla_attend(
+        q, p, t, s, x, scale=scale, value_dim=Dv, max_run=max_run,
+        runs=paged_runs(s, x, max_run)))(q, pool, jnp.asarray(tables),
+                                         sl, ps), np.float32)
+
+    @_exact
+    def oracle(qr, ctx, pr):
+        s = jnp.einsum("thw,cw->htc", qr.astype(jnp.float32),
+                       ctx.astype(jnp.float32)) * scale
+        keep = jnp.arange(ctx.shape[0])[None, :] <= pr[:, None]
+        s = jnp.where(keep[None], s, -jnp.inf)
+        return jnp.einsum("htc,cv->thv", jax.nn.softmax(s, -1),
+                          ctx[:, :Dv].astype(jnp.float32))
+
+    cells, at = [], 0
+    for s, ((p, n), k) in enumerate(zip(runs, need)):
+        ctx = pool[jnp.asarray(tables[s, :k])].reshape(-1, W)[:p + n]
+        want = oracle(q[at:at + n], ctx, ps[at:at + n])
+        cells.append(_cell(
+            f"mla_attend {dtype} H={H} W={W} Dv={Dv} run of {n} from "
+            f"position {p} (T={T}, {slots} slots, max_run {max_run})",
+            got[at:at + n], want, tol, tol))
+        at += n
+    cells.append(_cell("mla_attend padding rows", got[at:],
+                       np.zeros_like(got[at:]), 0, 0))
     return cells
 
 
@@ -965,6 +1041,14 @@ def run_matrix(rehearse=False):
                 singles_tol=4e-3, dtypes=("bfloat16",))
                 if rehearse else {}))
             + validate_ragged_expert_matmul()
+            + validate_ragged_expert_matmul(**(dict(
+                sizes=(5, 0, 9, 1), D=128, F=64, dtypes=("bfloat16",))
+                if rehearse else dict(
+                    sizes=(30, 0, 65, 1, 12, 40, 3, 0, 22, 17, 9, 50),
+                    D=7168, F=2048, dtypes=("bfloat16",))))
+            + validate_mla_attend(**(dict(
+                H=8, W=128, Dv=32, BS=8, slots=5, T=48, max_ctx=300,
+                max_run=16, decode=2) if rehearse else {}))
             + validate_gated_delta(**(dict(
                 H=2, lens=(1, 3, 65), step=dict(H=2, T=264, slots=16))
                 if rehearse else {}))
