@@ -18,9 +18,11 @@ One layer, x = RMSNorm(h), heads h, a query at t, keys s <= t:
 The two forms are equal in exact arithmetic. The eager `forward` runs
 the EXPANDED one (`layer_expanded`: per-head K and V made from the
 latent, dense causal attention); the serving layer (`layer_forward`)
-the ABSORBED one through the engine's `attend`, which caches the 576
-numbers `[c | kpe]` a token and returns the latent outputs. The two
-share every other line: projections, rotary, FFN, router.
+goes through the engine's `attend`, which caches the 576 numbers `[c |
+kpe]` a token and attends each row in the form the engine chose for its
+chunk: ABSORBED (the latent outputs, up-projected here) or EXPANDED
+from the cached latent rows (the head outputs). The two share every
+other line: projections, rotary, FFN, router.
 
 The rotary embedding is YaRN (`yarn_inv_freq`) over the `Dr` rope
 dims, rotating the INTERLEAVED pairs (2i, 2i+1) as DeepSeek's code does
@@ -99,7 +101,8 @@ class AXK1Arch:
     @property
     def latent(self):
         return LatentAttention(value_dim=self.kv_lora_rank,
-                               rope_dim=self.rope_dim, scale=self.scale)
+                               rope_dim=self.rope_dim, scale=self.scale,
+                               nope_dim=self.nope_dim, v_dim=self.v_dim)
 
 
 def arch_from_config(cfg, *, experts_held=None, expert_rank=0,
@@ -253,12 +256,14 @@ def attn_out(arch, lw, h, o):
 
 
 def layer_forward(arch, li, lw, h, positions, valid, attend):
-    """One layer over flat tokens in the ABSORBED form: the query rows
-    `[W_UK q_nope | q_pe | zeros]` and the tokens' latent rows
-    `[c | kpe | zeros]` (`arch.latent.lanes` lanes) go to `attend(qa,
-    row, None, li)`, which owns the cache and returns the latent
-    outputs [T, H, Dc]; they are up-projected by W_UV here. -> (h,
-    MoE stats or None)."""
+    """One layer over flat tokens, in the form the engine chose a row:
+    the query rows `[W_UK q_nope | q_pe | zeros]` and the tokens' latent
+    rows `[c | kpe | zeros]` (`arch.latent.lanes` lanes) go to
+    `attend(qa, row, None, li, (q_nope, q_pe, kv_b))`, which owns the
+    cache and returns the latent outputs [T, H, Dc] of the rows it
+    attended ABSORBED, up-projected by W_UV here, and the head outputs
+    of the rows it attended EXPANDED, taken as they are
+    (`serving_block.LatentAttention`). -> (h, MoE stats or None)."""
     import jax
     import jax.numpy as jnp
     q_nope, q_pe, c, kpe = project(arch, lw, h, positions)
@@ -273,10 +278,12 @@ def layer_forward(arch, li, lw, h, positions, valid, attend):
     with jax.named_scope("attn_qkv"):
         row = jnp.concatenate(
             [c, kpe, jnp.zeros((c.shape[0], pad), c.dtype)], -1)
-    lat = attend(qa, row, None, li)
+    lat, o_x, rows_x = attend(qa, row, None, li, (q_nope, q_pe, lw["kv_b"]))
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("thc,chv->thv", lat, w_uv.astype(lat.dtype),
                        preferred_element_type=jnp.float32).astype(h.dtype)
+        if rows_x is not None:
+            o = jnp.where(rows_x[:, None, None], o_x.astype(h.dtype), o)
     return ffn(arch, li, lw, attn_out(arch, lw, h, o), valid)
 
 

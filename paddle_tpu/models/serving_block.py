@@ -120,15 +120,23 @@ class LatentAttention:
     shared by every query head, its latent `c` (`value_dim` numbers,
     which is also what the values are made of) and its rotary key
     (`rope_dim`), in `lanes` lanes (whole lane tiles, zeros past the
-    two). A latent layer calls `attend(qa, row, None, li)`: `qa [T, H,
-    lanes]` its absorbed query rows, `row [T, lanes]` its tokens'
-    latent rows; the engine appends the rows and returns, for every
-    query row, `softmax(scale * qa . rows) . rows[:, :value_dim]`
-    over the slot's keys up to the row's position, `[T, H,
-    value_dim]`, which the layer up-projects itself."""
+    two). A latent layer calls `attend(qa, row, None, li, (q_nope,
+    q_pe, kv_b))`: `qa [T, H, lanes]` its absorbed query rows, `row [T,
+    lanes]` its tokens' latent rows, and for the EXPANDED form the query
+    heads `[T, H, nope_dim]`, `[T, H, rope_dim]` and the up-projection
+    `kv_b [value_dim, H (nope_dim + v_dim)]` (a head's W_UK columns,
+    then its W_UV). The engine appends the rows and returns `(lat, o,
+    rows)`: for the rows it attended absorbed, `lat [T, H, value_dim]`
+    = `softmax(scale * qa . rows) . rows[:, :value_dim]` over the
+    slot's keys up to the row's position, which the layer up-projects
+    itself; for the rows of `rows [T]` bool, `o [T, H, v_dim]` the
+    head outputs of the expanded form (`o` and `rows` None where no row
+    took it)."""
     value_dim: int
     rope_dim: int
     scale: float
+    nope_dim: int
+    v_dim: int
 
     @property
     def lanes(self):

@@ -261,6 +261,14 @@ def prefill_chunk(remaining, budget_left):
     return p
 
 
+def fill_chunk(remaining, budget_left):
+    """Chunk size that fills the budget: the whole remainder when it
+    fits, else all the budget left, which the scheduler cuts to whole
+    blocks of its `prefill_align` (a model whose arithmetic is chosen a
+    block at a time: latent attention's expanded walk)."""
+    return max(0, min(int(remaining), int(budget_left)))
+
+
 class FairQueue:
     """Bounded round-robin admission queue across tenants.
 

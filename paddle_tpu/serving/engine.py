@@ -818,6 +818,15 @@ class ServingEngine:
                 max_blocks_per_slot=mbps, dtype=dtype, kv_dtype=kv_dtype,
                 summaries=self._track_summaries, **kinds)
         self.kv._set_pools([commit(p) for p in self.kv._pools()])
+        # which rows of a step a latent layer attends in the expanded
+        # form (`mla_attention.ExpandRule`), from the widths alone
+        self._expand = None
+        if self._latent is not None:
+            from ..ops.pallas.mla_attention import expand_rule
+            lat = self._latent
+            self._expand = expand_rule(
+                lat.value_dim, lat.nope_dim, lat.v_dim, self.token_budget,
+                max_slots)
         # radix prefix cache: cross-request KV reuse for shared prompt
         # heads (system prompts, few-shot templates, chat history) —
         # registers itself as the kv cache's eviction backstop
@@ -865,7 +874,11 @@ class ServingEngine:
             prefill_align=(
                 1 if self._block is None
                 else arch.delta_chunk if self.kv.linear_layers
-                else self._diff.block_length if self._diff else 1),
+                else self._diff.block_length if self._diff
+                # a block of the expanded walk comes in one step
+                else self._expand.rows if self._expand else 1),
+            # as many whole blocks as the budget holds
+            prefill_chunk=batcher.fill_chunk if self._expand else None,
             block_decoding=self._diff)
         self.scheduler.replica = self.name
         self.eos_token_id = eos_token_id
@@ -1188,7 +1201,8 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ..ops.pallas.flash_attention import ragged_paged_attention
-        from ..ops.pallas.mla_attention import mla_attend
+        from ..ops.pallas.mla_attention import (mla_attend,
+                                                mla_expand_attend)
         from ..ops.pallas.paged_attention import paged_runs
         block = self._block
         arch = block.arch
@@ -1207,6 +1221,7 @@ class ServingEngine:
         at.update({li: n_pools - len(self.kv.latent_layers) + j
                    for j, li in enumerate(self.kv.latent_layers)})
         latent = self._latent
+        expand = self._expand
         linear = bool(self.kv.linear_layers)
         more_heads = self._kv_heads - arch.num_kv_heads
         diff, causal_block = self._diff, self._causal_block
@@ -1254,6 +1269,14 @@ class ServingEngine:
                 if sel is not None:
                     split = sparse.split(runs, tables["full"], pos, valid,
                                          rows_at)
+                if latent is not None:
+                    # the rows the expanded walk takes leave the
+                    # absorbed kernel's runs
+                    lat_slots, xtab, xrows = slot_ids, None, None
+                    if expand is not None:
+                        xtab, xrows = expand.table(slot_ids, pos)
+                        lat_slots = jnp.where(xrows, -1, slot_ids)
+                    lat_runs = paged_runs(lat_slots, pos, max_run)
             with jax.named_scope("sample"):
                 key, rng = jax.random.split(key)
             selections = []
@@ -1262,17 +1285,23 @@ class ServingEngine:
                 kind = kinds[li]
                 if kind == "latent":
                     # q: the absorbed query rows, k: the tokens' latent
-                    # rows; one pool, read for the logits and the values
+                    # rows; one pool, read for the logits and the values;
+                    # idx: (q_nope, q_pe, kv_b) for the expanded walk
                     with jax.named_scope("kv_write"):
                         lp = pools[at[li]].at[wb["full"], wo].set(
                             k.astype(pools[at[li]].dtype))
                     pools[at[li]] = lp
                     with jax.named_scope("attn_latent"):
-                        return mla_attend(
-                            q, lp, tables["full"], slot_ids, pos,
+                        lat = mla_attend(
+                            q, lp, tables["full"], lat_slots, pos,
                             scale=latent.scale,
-                            value_dim=latent.value_dim, runs=runs,
+                            value_dim=latent.value_dim, runs=lat_runs,
                             max_run=max_run)
+                        if xtab is None:
+                            return lat, None, None
+                        return lat, mla_expand_attend(
+                            idx[0], idx[1], lp, idx[2], tables["full"],
+                            xtab, scale=latent.scale), xrows
                 kp, vp = pools[at[li]], pools[at[li] + 1]
                 if kind == "sparse":
                     o, kept = sparse.attend(
@@ -3210,15 +3239,17 @@ class ServingEngine:
 
     def _latent_work(self, plan):
         """The latent layers' flight fields (FLIGHT_FIELDS_MLA.md): one
-        layer's counts of the runs the step makes (`mla_attention.
-        mla_work`, cut at the block step's `max_run`) times the latent
-        layers; `mla_logits_useful` the causal pairs x the query
+        layer's counts of the walks the step makes (`mla_attention.
+        mla_work`: the rows the engine's `ExpandRule` expands one walk
+        a slot, the rest cut at the block step's `max_run`) times the
+        latent layers; `mla_logits_useful` the causal pairs x the query
         heads."""
         from ..ops.pallas.mla_attention import mla_work
         n, H = len(self.kv.latent_layers), self._block.arch.num_heads
         work = mla_work(_plan_groups(plan), H, self.block_size,
                         self.kv.max_blocks_per_slot,
-                        min(self.token_budget, _BLOCK_MAX_RUN))
+                        min(self.token_budget, _BLOCK_MAX_RUN),
+                        expand=self._expand, budget=self.token_budget)
         work = {k: n * v for k, v in work.items()}
         work["mla_logits_useful"] = H * (work["mla_pairs_decode"]
                                          + work["mla_pairs_chunk"])

@@ -124,7 +124,7 @@ class Scheduler:
                  clock=time.monotonic, draft_k=0, draft_fn=None,
                  device_draft=False, prefix_cache=None,
                  adapter_cache=None, reserve_region=False,
-                 prefill_align=1, block_decoding=None):
+                 prefill_align=1, prefill_chunk=None, block_decoding=None):
         self.kv = kv_cache
         # a model that decodes by blocks (`models.serving_block.
         # BlockDecoding`; None: a token at a time)
@@ -143,6 +143,9 @@ class Scheduler:
         # request served alone, in company or again after a preemption
         # computes the same numbers bit for bit
         self.prefill_align = int(prefill_align)
+        # a prefill chunk's size before that cut, from (remaining,
+        # budget left): the largest power of two by default
+        self.prefill_chunk = prefill_chunk or batcher.prefill_chunk
         self.max_slots = max_slots
         self.token_budget = token_budget
         self.clock = clock
@@ -592,7 +595,7 @@ class Scheduler:
                 return n if n >= remaining else \
                     n // self.prefill_align * self.prefill_align
 
-            chunk = cut(batcher.prefill_chunk(remaining, budget_left))
+            chunk = cut(self.prefill_chunk(remaining, budget_left))
             # prefill only uses FREE blocks — shrink to what fits
             while chunk > 0 and not self.kv.ensure_capacity(
                     req.slot, req.fed + chunk):

@@ -155,11 +155,11 @@ def test_absorbed_equals_expanded_layer_by_layer():
                        0, VOCAB, T), jnp.int32))
     lat = arch.latent
 
-    def attend(qa, row, _, li):
+    def attend(qa, row, _, li, expanded):
         s = jnp.einsum("thw,sw->hts", qa, row) * lat.scale
         s = jnp.where((pos[None, :] <= pos[:, None])[None], s, -jnp.inf)
         return jnp.einsum("hts,sc->thc", jax.nn.softmax(s, -1),
-                          row[:, :lat.value_dim])
+                          row[:, :lat.value_dim]), None, None
 
     for li, lw in enumerate(m.weights["layers"]):
         a, _ = axk1.layer_forward(arch, li, lw, h, pos, valid, attend)
@@ -256,7 +256,8 @@ def test_flight_counts_of_the_kernel():
                        mla_rows_chunk=rows["chunk"],
                        mla_pairs_decode=pairs["decode"],
                        mla_pairs_chunk=pairs["chunk"],
-                       mla_tokens_read=read, mla_logits_issued=issued)
+                       mla_tokens_read=read, mla_logits_issued=issued,
+                       mla_rows_expanded=0)
 
 
 def test_the_engine_records_the_latent_fields():
@@ -273,6 +274,343 @@ def test_the_engine_records_the_latent_fields():
     assert work["mla_logits_useful"] == 8 * (
         work["mla_pairs_decode"] + work["mla_pairs_chunk"])
     assert work["attn_logits_useful"] == 0
+
+
+# ------------------------------------------------------ the expanded walk
+
+#: the cell's widths (kv_lora, nope, v), token budget and slots
+CELL = dict(latent_dim=512, nope_dim=128, value_dim=128, token_budget=512,
+            max_slots=16)
+
+
+def _flops(pos, n, Dc=512, Dn=128, Dr=64, Dv=128, H=64):
+    """(absorbed, expanded) FLOPs of a chunk of n rows from `pos`, as the
+    benchmark's harness counts the two forms."""
+    pairs = n * pos + n * (n + 1) // 2
+    absorbed = pairs * 2 * H * (2 * Dc + Dr) + n * 2 * H * Dc * (Dn + Dv)
+    expanded = (pairs * 2 * H * (Dn + Dr + Dv)
+                + (pos + n) * 2 * Dc * H * (Dn + Dv))
+    return absorbed, expanded
+
+
+def test_the_rule_at_the_cell_s_widths():
+    """171 rows a walk is where the expanded form is the cheaper at every
+    context, by the harness's FLOP counts (170 still loses at 33,792;
+    over a context ending at 16,384 the two cross between 169 and 170,
+    which a rule that may not look at the context leaves absorbed); the
+    blocks are as large as two fit beside 16 decode rows in a step of
+    512, in whole tiles of 16 rows: 240; a slot's rows of a whole block
+    expanded, of a part block absorbed."""
+    rule = ma.expand_rule(**CELL)
+    assert (rule.rows, rule.entries) == (240, 2)
+    for pos in (0, 15, 4000, 16000, 33000):
+        absorbed, expanded = _flops(pos, 171)
+        assert expanded < absorbed, pos
+    absorbed, expanded = _flops(33792 - 170, 170)
+    assert expanded > absorbed
+    for n, gains in ((169, False), (170, True)):
+        absorbed, expanded = _flops(16384 - n, n)
+        assert (expanded < absorbed) is gains
+    assert rule.walks(15840, 480) == [(15840, 480, True)]
+    assert rule.walks(16080, 300) == [(16080, 240, True),
+                                      (16320, 60, False)]
+    assert rule.walks(16000, 300) == [(16000, 300, False)]
+    assert rule.walks(0, 240) == [(0, 240, True)]
+    assert rule.walks(0, 239) == [(0, 239, False)]
+    assert rule.walks(20000, 1) == [(20000, 1, False)]
+
+
+@pytest.mark.parametrize("budget,slots,least", [
+    (512, 16, 240), (512, 8, 240), (1024, 16, 192), (256, 16, 240),
+    (187, 16, 171), (186, 16, None), (512, 128, 192), (64, 4, None)])
+def test_the_blocks_fill_the_room_beside_the_decode_rows(budget, slots,
+                                                         least):
+    """The block is at least the 171 rows from which the expanded form
+    gains, and as large as the room beside a full decode load shares
+    out among as many blocks as it holds (rounded down to 16 rows while
+    that stays above 171); no rule where no block fits or the form
+    never gains."""
+    rule = ma.expand_rule(512, 128, 128, budget, slots)
+    if least is None:
+        assert rule is None
+        return
+    assert rule.rows == least
+    n = (budget - slots) // 171
+    assert n * rule.rows <= budget - slots < (n + 1) * 171
+    assert rule.entries == budget // rule.rows
+    assert ma.expand_rule(512, 256, 768, budget, slots) is None
+
+
+@pytest.mark.parametrize("groups", [
+    ((20000, 1), (15840, 240), (0, 250), (3, 1), (9000, 14)),
+    ((5, 1), (7, 1), (12000, 250), (19920, 240)),
+    ((0, 40), (40, 1), (960, 300)),
+    ((100, 1), (2000, 1)),
+], ids=("two", "tail", "one", "decode"))
+def test_the_step_s_table_is_the_host_s_walks(groups):
+    """`ExpandRule.table` on the device marks the rows `walks` gives the
+    expanded form on the host and lists them as one walk a slot, in
+    ascending start; padding after."""
+    rule = ma.expand_rule(**CELL)
+    slots = sum(([s] * n for s, (_, n) in enumerate(groups)), [])
+    pos = sum((list(range(p, p + n)) for p, n in groups), [])
+    T = 512
+    sl = jnp.asarray(slots + [-1] * (T - len(slots)), jnp.int32)
+    ps = jnp.asarray(pos + [0] * (T - len(pos)), jnp.int32)
+    (start, rows, slot, first), taken = jax.jit(rule.table)(sl, ps)
+    at = np.cumsum([0] + [n for _, n in groups])
+    mask, want = np.zeros(T, bool), []
+    for i, (p, n) in enumerate(groups):
+        for q, m, x in rule.walks(p, n):
+            if x:
+                mask[at[i] + q - p:at[i] + q - p + m] = True
+                want.append((int(at[i] + q - p), m, i, q))
+    got = [(int(a), int(b), int(c), int(d))
+           for a, b, c, d in zip(start, rows, slot, first) if b]
+    assert got == want
+    assert not np.asarray(rows)[len(want):].any()
+    np.testing.assert_array_equal(np.asarray(taken), mask)
+
+
+def _expand_case(entries, *, T=512, H=16, Dn=32, Dr=16, Dv=32, Dc=64,
+                 W=128, BS=16, seed=0):
+    """A step of T tokens whose table holds `entries`, (flat start,
+    first position, rows), each its own slot, with an unused entry
+    after them; every other token is another slot's."""
+    rng = np.random.default_rng(seed)
+    S = len(entries) + 1
+    MB = -(-max(p + n for _, p, n in entries) // BS) + 1
+    NB = S * MB + 1
+    pool = rng.standard_normal((NB, BS, W)).astype(np.float32)
+    pool[..., Dc + Dr:] = 0
+    tables = (1 + rng.permutation(S * MB)).reshape(S, MB)
+    w = rng.standard_normal((Dc, H * (Dn + Dv))).astype(np.float32) / 8
+    qn = rng.standard_normal((T, H, Dn)).astype(np.float32)
+    qp = rng.standard_normal((T, H, Dr)).astype(np.float32)
+    table = [np.zeros(len(entries) + 1, np.int32) for _ in range(4)]
+    for i, (start, p, n) in enumerate(entries):
+        for a, v in zip(table, (start, n, i, p)):
+            a[i] = v
+    return (jnp.asarray(qn), jnp.asarray(qp), jnp.asarray(pool),
+            jnp.asarray(w), jnp.asarray(tables, jnp.int32),
+            tuple(jnp.asarray(a) for a in table))
+
+
+@pytest.mark.parametrize("entries", [
+    ((37, 1000, 203),),
+    ((5, 300, 171), (176, 2000, 336)),
+    ((0, 0, 512),),
+], ids=("odd_rows_mid_block", "two_adjacent", "whole_budget"))
+def test_expand_kernel_interpreted_is_the_float32_oracle(entries):
+    """`mla_expand_attend` interpreted, and its XLA form, against a
+    float64 oracle of the expanded form: rows not a multiple of 8 from
+    an unaligned flat row, contexts that end mid-block, two entries
+    back to back, a chunk of the whole budget from position 0, and an
+    unused entry after them; two head groups of 8."""
+    qn, qp, pool, w, tables, table = _expand_case(entries)
+    scale = 0.21
+    with interpret_mode():
+        got = np.asarray(jax.jit(functools.partial(
+            ma.mla_expand_attend, scale=scale))(qn, qp, pool, w, tables,
+                                                table))
+    xla = np.asarray(ma.mla_expand_attend_xla(qn, qp, pool, w, tables,
+                                              table, scale=scale))
+    H, Dn = qn.shape[1:]
+    Dc, W = w.shape[0], pool.shape[-1]
+    kv_w = np.asarray(w, np.float64).reshape(Dc, H, -1)
+    for slot, (start, p, n) in enumerate(entries):
+        ctx = np.asarray(pool, np.float64)[np.asarray(tables)[slot]]
+        ctx = ctx.reshape(-1, W)[:p + n]
+        kv = np.einsum("sc,chd->shd", ctx[:, :Dc], kv_w)
+        q = np.asarray(qn, np.float64)[start:start + n]
+        s = (np.einsum("thn,shn->hts", q, kv[..., :Dn])
+             + np.einsum("thr,sr->hts",
+                         np.asarray(qp, np.float64)[start:start + n],
+                         ctx[:, Dc:Dc + qp.shape[2]])) * scale
+        keep = np.arange(p + n)[None, :] <= (p + np.arange(n))[:, None]
+        s = np.where(keep[None], s, -np.inf)
+        pr = np.exp(s - s.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        want = np.einsum("hts,shv->thv", pr, kv[..., Dn:])
+        for name, out in (("kernel", got), ("xla", xla)):
+            np.testing.assert_allclose(out[start:start + n], want,
+                                       atol=2e-5, rtol=2e-5,
+                                       err_msg=name)
+    mine = np.zeros(len(xla), bool)
+    for start, _, n in entries:
+        mine[start:start + n] = True
+    assert not xla[~mine].any()
+
+
+def test_flight_counts_of_an_expanded_walk():
+    """At the cell's widths a chunk of 504 rows deep in its prompt (two
+    whole blocks and 24 rows of a third) takes the expanded walk for
+    its blocks: their context counted ONCE (not once a run of 128),
+    rows and pairs as before, logits by the walk's own tiles; the part
+    block, a decode token and a short chunk stay the absorbed
+    kernel's, cut at 128."""
+    H, BS, MB, T = 64, 16, 2112, 512
+    rule = ma.expand_rule(**CELL)
+    runs = [(20000, 1), (15840, 504), (5000, 7)]
+    got = ma.mla_work(runs, H, BS, MB, 128, expand=rule, budget=T)
+    plain = ma.mla_work(runs, H, BS, MB, 128)
+    assert got["mla_rows_expanded"] == 480
+    assert plain["mla_rows_expanded"] == 0
+    for f in ("mla_rows_decode", "mla_rows_chunk", "mla_pairs_decode",
+              "mla_pairs_chunk"):
+        assert got[f] == plain[f], f
+    assert got["mla_tokens_read"] == 20001 + 16320 + 16344 + 5007
+    assert plain["mla_tokens_read"] == (20001 + 15968 + 16096 + 16224
+                                        + 16344 + 5007)
+    G, TQ, _ = ma.mla_expand_tiles(BS, MB, T)
+    GK = G * BS
+    expanded = 0
+    for off in range(0, 480, TQ):
+        top = 15840 + off + TQ - 1
+        expanded += sum(1 for g in range((16319 // BS) // G + 1)
+                        if g * GK <= top) * TQ * H * GK
+    rest = ma.mla_work([(20000, 1), (16320, 24), (5000, 7)], H, BS, MB,
+                       128)
+    assert got["mla_logits_issued"] == expanded + rest["mla_logits_issued"]
+
+
+def test_expanded_and_absorbed_steps_give_the_same_tokens(monkeypatch):
+    """The same requests, cut into the same chunks (whole blocks of 48
+    at these widths), through the expanded walk and through the
+    absorbed kernel alone (a step whose table takes no row): the same
+    tokens, the same rows of logits to the kernel's float32 tolerance,
+    both the reference's."""
+    m = model()
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, VOCAB, n).tolist() for n in (110, 70, 9)]
+
+    def no_rows(rule, slot_ids, positions):
+        zero = jnp.zeros((rule.entries,), jnp.int32)
+        return (zero,) * 4, jnp.zeros(slot_ids.shape, bool)
+
+    with interpret_mode():
+        eng = engine(m, budget=64)
+        assert (eng._expand.rows, eng._expand.entries) == (48, 1)
+        eng.submit(prompts[0], max_new_tokens=1)
+        assert eng._plan_work(eng.scheduler.plan())["mla_rows_expanded"] \
+            == 2 * 48
+        got, rows = serve(engine(m, budget=64), prompts, (4, 3, 5))
+        monkeypatch.setattr(ma.ExpandRule, "table", no_rows)
+        want, rows_plain = serve(engine(m, budget=64), prompts, (4, 3, 5))
+    assert got == want
+    for a, b in zip(rows, rows_plain):
+        for x, y in zip(a, b):
+            assert np.abs(x - y).max() / np.abs(y).max() < 1e-4
+    for p, toks, r in zip(prompts, got, rows):
+        assert worst_error(m, p, toks, r) < 1e-4
+
+
+def test_a_request_alone_and_in_company_gives_the_same_bits():
+    """A long prompt served alone and among other requests, the
+    expanded walk taking its blocks in both: the same tokens and the
+    same rows of logits, bit for bit (its rows' form and their
+    arithmetic do not depend on what else a step holds)."""
+    m = model()
+    rng = np.random.default_rng(12)
+    prompt = rng.integers(0, VOCAB, 110).tolist()
+    others = [rng.integers(0, VOCAB, n).tolist() for n in (90, 50, 7)]
+    with interpret_mode():
+        (alone,), (ra,) = serve(engine(m, budget=64), [prompt], [6])
+        got, rows = serve(engine(m, budget=64), others + [prompt],
+                          (5, 4, 6, 6))
+    assert got[-1] == alone
+    for a, b in zip(rows[-1], ra):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_kernel_step_writes_no_per_head_keys_of_a_context():
+    """The step's expanded walk up-projects a slot's context inside the
+    kernel: the lowered step (the kernel interpreted) holds no [context,
+    heads, Dn + Dv] array, where the XLA form makes one an entry."""
+    m = model()
+    arch = m.arch
+
+    def text():
+        eng = engine(m, budget=64)
+        C = eng.kv.max_blocks_per_slot * eng.block_size
+        return f"tensor<{C}x{arch.num_heads}x{arch.nope_dim + arch.v_dim}x", \
+            eng._step_fn._jitted.lower(*eng.example_step_args()).as_text()
+    shape, xla = text()
+    assert shape in xla
+    with interpret_mode():
+        shape, kernel = text()
+    assert shape not in kernel
+
+
+#: sha256 of the lowered mixed step of each serve model with no latent
+#: layer at `_step_digests`' sizes, as the tree before the expanded walk
+#: lowered them: the walk is the latent layers' alone
+STEP_DIGESTS = dict(
+    gpt="4ddd73ce10a3409e8976f40fd1df776fbd93c85988ecc513becf31dd0dc9a400",
+    trinity="ba8beb7fb04c06ecf51bbe86e303eb4ba8555c31fe98c262e5977a68fa5e9d8e",
+    olmo="f7a3a52989e85516a321835b75327156ee25918aca4551ab0a0899526b281517",
+    sdar="a8d2b4437b24b8d82d049377478aa7e55a66234e1f1c13c3984cac6b68fc6429",
+    keye="4c68c51d43a94a0afa7ffb1c29edea6c981510a115ab877ef94b12b431776afb")
+
+
+def _step_digests():
+    import hashlib
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import keye_vl2, sdar_moe
+    from paddle_tpu.models import olmo_hybrid as oh
+    from paddle_tpu.models.gpt import GPTForGeneration
+    from paddle_tpu.models.serving_block import (BlockDecoding,
+                                                 LearnedSelection)
+    moe = dict(hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+               num_layers=2, num_experts=8, top_k=2, expert_width=32,
+               vocab_rows=97, rope_theta=1e4, max_positions=256,
+               compute_dtype="float32")
+    paddle.seed(0)
+    gpt = GPTForGeneration(vocab_size=97, hidden_size=32, num_layers=2,
+                           num_attention_heads=4,
+                           max_position_embeddings=128,
+                           compute_dtype="float32")
+    gpt.eval()
+    models = dict(
+        gpt=gpt,
+        trinity=afmoe.AfmoeForGeneration(afmoe.make_arch(
+            layer_types=["sliding_attention"] * 2 + ["full_attention"],
+            num_dense_layers=1, hidden_size=64, num_heads=4,
+            num_kv_heads=2, head_dim=16, window=16, dense_width=128,
+            vocab_rows=97, max_positions=256, compute_dtype="float32",
+            moe=dict(num_experts=16, top_k=2, expert_width=32,
+                     experts_held=4, expert_rank=0, route_scale=2.448)),
+            seed=3),
+        olmo=oh.OlmoHybridForGeneration(oh.OlmoHybridArch(
+            hidden_size=64, num_heads=4, head_dim=16, linear_heads=4,
+            linear_key_dim=8, linear_value_dim=16, mlp_width=128,
+            vocab_rows=97, layer_kinds=(oh.LINEAR, oh.FULL),
+            max_positions=256, compute_dtype="float32", delta_chunk=8),
+            seed=3),
+        sdar=sdar_moe.SdarMoeForGeneration(sdar_moe.SdarMoeArch(
+            **moe, block_decoding=BlockDecoding(
+                block_length=4, mask_token_id=96, denoising_steps=4,
+                rule="low_confidence_static", threshold=0.9)), seed=3),
+        keye=keye_vl2.KeyeModel(keye_vl2.KeyeArch(
+            **moe, selection=LearnedSelection(
+                num_heads=4, head_dim=16, topk=16, rope_dims=8,
+                scale=0.125)), seed=3))
+    out = {}
+    for name, m in models.items():
+        eng = ServingEngine(m, max_slots=4, block_size=8, num_blocks=40,
+                            max_seq_len=64, token_budget=32,
+                            cache_dtype="float32")
+        text = eng._step_fn._jitted.lower(
+            *eng.example_step_args()).as_text()
+        out[name] = hashlib.sha256(text.encode()).hexdigest()
+    return out
+
+
+def test_the_other_models_step_programs_are_unchanged():
+    """The GPT, Trinity, Olmo-Hybrid, SDAR and Keye steps lower to the
+    text they lowered to before the expanded walk, byte for byte."""
+    assert _step_digests() == STEP_DIGESTS
 
 
 # ------------------------------------------------------------ the router

@@ -10,7 +10,8 @@ place; the block-causal rule of a model that decodes by blocks), fused add+Layer
 backward), the hand flash-forward kernel, the grouped-expert matmul
 (fp32 / int8 / int4 weights), the ragged chunked delta rule (a key
 dim of 96), the exact top-k selection (bit for bit) and the absorbed
-latent attention (`mla_attend`, run by run) against their
+latent attention (`mla_attend`, run by run; `mla_expand_attend`, entry
+by entry) against their
 pure-XLA oracles with
 interpret mode OFF. A
 kernel that compiles and is wrong fails here.
@@ -883,6 +884,83 @@ def validate_mla_attend(*, H=64, W=640, Dv=512, BS=16, slots=16, T=512,
     return cells
 
 
+def validate_mla_expand_attend(*, H=64, Dn=128, Dr=64, Dv=128, Dc=512,
+                               W=640, BS=16, T=512, steps=None, seed=6,
+                               dtype="bfloat16", tol=3e-2):
+    """The expanded latent attention kernel (`mla_expand_attend`)
+    against a float32 oracle entry by entry, at the A.X-K1 cell's
+    shapes: 64 heads, rows of 640 lanes `[c (512) | k_pe (64) |
+    zeros]`, kv_b [512, 64 x 256]; each step a table of two entries
+    (the second unused where a step has one chunk) among decode tokens
+    of other slots, chunks of 171-512 rows from unaligned flat rows
+    (the engine's whole blocks of 240 and two-block walks of 480 among
+    them), contexts of 4k-33k, some ending mid-block; a slot's blocks
+    scattered over the pool. `steps`: per step, the (first position, rows) of its
+    chunks."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import mla_attention as ma
+
+    steps = steps or (((4080, 240), (32880, 240)), ((19680, 480),),
+                      ((4000, 171), (33000, 336)), ((19700, 512),),
+                      ((11800, 203), (7900, 250)))
+    rng = np.random.RandomState(seed)
+    cells = []
+    for chunks in steps:
+        lead = T - sum(n for _, n in chunks)       # decode rows around
+        starts, at = [], rng.randint(0, lead + 1) if lead else 0
+        for _, n in chunks:
+            starts.append(at)
+            at += n
+        MB = -(-max(p + n for p, n in chunks) // BS)
+        S = len(chunks)
+        NB = S * MB + 1
+        tables = (1 + rng.permutation(NB - 1)).reshape(S, MB)
+        pool = rng.randn(NB, BS, W).astype(np.float32)
+        pool[..., Dc + Dr:] = 0
+        pool = jnp.asarray(pool, dtype)
+        w = jnp.asarray(rng.randn(Dc, H * (Dn + Dv)) / np.sqrt(Dc), dtype)
+        qn = jnp.asarray(rng.randn(T, H, Dn), dtype)
+        qp = jnp.asarray(rng.randn(T, H, Dr), dtype)
+        table = tuple(jnp.asarray(np.array(a + [0] * (2 - S), np.int32))
+                      for a in (starts, [n for _, n in chunks],
+                                list(range(S)), [p for p, _ in chunks]))
+        scale = 0.13
+        got = np.asarray(jax.jit(lambda qn, qp, pool, w, t, tab: (
+            ma.mla_expand_attend(qn, qp, pool, w, t, tab, scale=scale)))(
+                qn, qp, pool, w, jnp.asarray(tables, jnp.int32), table),
+            np.float32)
+
+        @_exact
+        def oracle(q, r, ctx, w, p):
+            kv = jnp.einsum("sc,chd->shd", ctx[:, :Dc].astype(jnp.float32),
+                            w.astype(jnp.float32))
+            s = (jnp.einsum("thn,shn->hts", q.astype(jnp.float32),
+                            kv[..., :Dn])
+                 + jnp.einsum("thr,sr->hts", r.astype(jnp.float32),
+                              ctx[:, Dc:Dc + Dr].astype(jnp.float32))
+                 ) * scale
+            keep = jnp.arange(ctx.shape[0])[None, :] <= p[:, None]
+            s = jnp.where(keep[None], s, -jnp.inf)
+            return jnp.einsum("hts,shv->thv", jax.nn.softmax(s, -1),
+                              kv[..., Dn:])
+
+        wh = w.reshape(Dc, H, Dn + Dv)
+        for i, ((p, n), st) in enumerate(zip(chunks, starts)):
+            ctx = pool[jnp.asarray(tables[i])].reshape(-1, W)[:p + n]
+            pr = jnp.arange(p, p + n)
+            want = np.concatenate([np.asarray(oracle(
+                qn[st:st + n, h:h + 16], qp[st:st + n, h:h + 16], ctx,
+                wh[:, h:h + 16], pr)) for h in range(0, H, 16)], 1)
+            cells.append(_cell(
+                f"mla_expand_attend {dtype} H={H} Dc={Dc} W={W} chunk of "
+                f"{n} rows from position {p} at flat row {st} (T={T}, "
+                f"{S} of 2 entries)", got[st:st + n], want, tol, tol))
+    return cells
+
+
 def validate_add_ln(*, rows=512, d=256, dtype="bfloat16"):
     """Fused residual-add + LayerNorm against its jnp form: the forward
     pair (normalized, new residual) and the input/scale/shift grads of
@@ -1049,6 +1127,10 @@ def run_matrix(rehearse=False):
             + validate_mla_attend(**(dict(
                 H=8, W=128, Dv=32, BS=8, slots=5, T=48, max_ctx=300,
                 max_run=16, decode=2) if rehearse else {}))
+            + validate_mla_expand_attend(**(dict(
+                H=16, Dn=32, Dr=16, Dv=32, Dc=64, W=128, BS=8, T=64,
+                steps=(((40, 21), (300, 30)), ((130, 64),)))
+                if rehearse else {}))
             + validate_gated_delta(**(dict(
                 H=2, lens=(1, 3, 65), step=dict(H=2, T=264, slots=16))
                 if rehearse else {}))
