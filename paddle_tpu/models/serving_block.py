@@ -90,6 +90,37 @@ class BlockDecoding:
         return sorted(sorted(
             masked, key=lambda i: (-float(confidence[i]), i))[:n])
 
+    def decide_rows(self, masked, confidence, n_pass):
+        """`decide` for many blocks at once, in `jax.numpy` (the step
+        decides on the device): `masked` bool [S, L], `confidence`
+        float32 [S, L], `n_pass` int32 [S] -> bool [S, L], the
+        positions each block's pass decides. The same choice as
+        `decide` on the same float32 values: a tie goes to the earlier
+        position, and the dynamic rule compares against the largest
+        float32 at or below `threshold`, which a float32 exceeds exactly
+        when it exceeds the threshold in double precision."""
+        import jax.numpy as jnp
+        import numpy as np
+        L = self.block_length
+        steps = self.denoising_steps or L
+        base, rem = divmod(L, steps)
+        want = jnp.where(n_pass < steps, jnp.maximum(
+            1, base + (n_pass < rem).astype(jnp.int32)), 1)
+        n = jnp.minimum(want, masked.sum(-1))
+        c, i = confidence, jnp.arange(L)
+        # ahead[s, a, b]: masked position b ranks before position a
+        ahead = masked[:, None, :] & (
+            (c[:, None, :] > c[:, :, None])
+            | ((c[:, None, :] == c[:, :, None]) & (i < i[:, None])))
+        take = masked & (ahead.sum(-1) < n[:, None])
+        if self.rule == "low_confidence_dynamic":
+            t = np.float32(self.threshold)
+            if float(t) > self.threshold:
+                t = np.nextafter(t, np.float32(-np.inf))
+            high = masked & (c > t)
+            take = jnp.where((high.sum(-1) >= n)[:, None], high, take)
+        return take
+
 
 @dataclasses.dataclass(frozen=True)
 class LearnedSelection:

@@ -174,7 +174,9 @@ def select_token(logits, key, sc: SamplingConfig, history=None,
 
 
 #: the id of a decode token that is still on the device: the step takes
-#: it from the sampled tokens of the step before (`prev_tokens[slot]`)
+#: it from the sampled tokens of the step before (`prev_tokens[slot]`),
+#: or, decoding by blocks, from the blocks' state the step before handed
+#: over (the block's ids, decided positions and passes)
 PREV_TOKEN = -1
 
 
@@ -366,7 +368,9 @@ class PlanLayout:
 
         token_ids [T], slot_ids [T], positions [T], sample_index [S]
         (or [S, L]: `sample_rows`), each block table [S, MB] (one, or full then window),
-        adapter_ids [T] (only with adapters registered)
+        adapter_ids [T] (only with adapters registered), block_decided
+        [S, L] and block_passes [S] (only with `sample_rows`: decoding by
+        blocks, the state of a block the host plans explicitly)
 
     Built once an engine, from what it can see of itself. The packer
     (`PlanBuffers`) and the compiled step (`unpack` on the traced
@@ -384,6 +388,8 @@ class PlanLayout:
                    for name, shape in tables]
         if adapters:
             shapes.append(("adapter_ids", (T,)))
+        if sample_rows != 1:
+            shapes += [("block_decided", sample), ("block_passes", (S,))]
         #: the block tables' field names, in `kv.tables()` order
         self.tables = tuple(name for name, _ in tables)
         self.fields = {}
@@ -432,6 +438,9 @@ class PlanBuffers:
         self.slot_ids[:] = -1
         self.positions[:] = 0
         self.sample_index[:] = -1
+        if "block_passes" in vars(self):
+            self.block_decided[:] = 0
+            self.block_passes[:] = 0
 
 
 def pack_step(token_budget, max_slots, decode, prefills,

@@ -60,15 +60,16 @@ window, full and linear layers, grouped queries, dropless experts,
 greedy or plain sampling, and DECODING BY BLOCKS (an architecture whose
 `block_decoding` is set generates by diffusion over blocks; docs/
 SERVING.md "Decoding by blocks"): a decode entry is the slot's current
-block of L positions, the step samples all L rows and returns the
-candidates and their confidences, `_run_block_tick` decides some of
-them by the model's rule, delivers tokens in position order, and the
-pass that feeds a block with nothing masked commits its K/V; attention
-is block-causal, in the prefill too. What stays built into the GPT scan
-only is refused with the reason: drafts, quantized pools, adapters,
-sparse decode, the device loop, disaggregated roles, penalized
-sampling; a prefix cache with window or linear layers or block
-decoding; block decoding with window or linear layers or with a
+block of L positions, the step samples all L rows, decides some of them
+by the model's rule and carries the blocks' state to the next step on
+the device, the host replays the same decision when it reads the
+candidates and their confidences back and delivers tokens in position
+order, and the pass that feeds a block with nothing masked commits its
+K/V; attention is block-causal, in the prefill too. What stays built
+into the GPT scan only is refused with the reason: drafts, quantized
+pools, adapters, sparse decode, the device loop, disaggregated roles,
+penalized sampling; a prefix cache with window or linear layers or
+block decoding; block decoding with window or linear layers or with a
 temperature.
 """
 from __future__ import annotations
@@ -891,20 +892,29 @@ class ServingEngine:
         # "Dispatching ahead") where nothing the NEXT plan needs exists
         # only on the device: not with host drafting (the drafter reads
         # tokens), not with penalized sampling (the counts are host
-        # arrays), not in the device loop or the decide loop of block
-        # diffusion (they have loops of their own), not on a prefill
-        # replica (its requests park at their first token). Decided
-        # here, once, from what the engine sees of itself. `_ahead`
-        # shapes the compiled step (one more argument: the sampled
-        # tokens of the step before, which never leave the device);
+        # arrays), not in the device loop (it has a loop of its own),
+        # not on a prefill replica (its requests park at their first
+        # token). Decided here, once, from what the engine sees of
+        # itself. `_ahead` shapes the compiled step (one more argument:
+        # the sampled tokens of the step before, or, decoding by blocks,
+        # the blocks' state after it, which never leave the device);
         # `_depth` is how far the loop runs ahead NOW: 1, or 0, which is
         # the synchronous order (dispatch, read back, emit)
         self._ahead = (self.draft_k == 0 and not self._multitick
-                       and self._diff is None and role != "prefill"
+                       and role != "prefill"
                        and not batcher.needs_history(self.sampling))
         self._depth = int(self._ahead)
-        self._prev_tokens = commit(jnp.zeros((max_slots,), jnp.int32)) \
-            if self._ahead else None
+        self._prev_tokens = None
+        if self._diff is not None:
+            # each slot's block: ids, decided positions, denoise passes
+            L = self._diff.block_length
+            self._prev_tokens = tuple(map(commit, (
+                jnp.full((max_slots, L), self._diff.mask_token_id,
+                         jnp.int32),
+                jnp.zeros((max_slots, L), bool),
+                jnp.zeros((max_slots,), jnp.int32))))
+        elif self._ahead:
+            self._prev_tokens = commit(jnp.zeros((max_slots,), jnp.int32))
         self._inflight = None       # the `_Flight` not read back yet
         self.steps_ahead = 0        # steps dispatched over an unread one
         self.ahead_wasted_rows = 0  # rows fed to a request that had ended
@@ -1194,9 +1204,18 @@ class ServingEngine:
         and in place of tokens the step returns int32 `[max_slots, 2,
         L]`: each row's greedy candidate, and the bits of its float32
         confidence (the softmax probability of the candidate): one
-        array, one readback. The logits are `[max_slots, L, V]`. Which
-        positions take their candidate is the host's (`_run_block_tick`):
-        the step does not know which rows are masked."""
+        array, one readback. The logits are `[max_slots, L, V]`. In
+        place of the tokens of the step before it takes, and before the
+        key it returns, each slot's block state (ids `[max_slots, L]`,
+        decided bool `[max_slots, L]`, denoise passes `[max_slots]`): a
+        block the host planned as `batcher.PREV_TOKEN` rows is fed from
+        it, one the host planned explicitly from the plan's ids,
+        `block_decided` and `block_passes`; the step decides the fed
+        blocks' masked positions by the model's rule
+        (`BlockDecoding.decide_rows`), and a block fed with nothing
+        masked was the commit: its slot's next block opens, all masked.
+        The host replays the same decision when it reads the candidates
+        back (`_land_blocks`)."""
         import jax
         import jax.numpy as jnp
 
@@ -1251,7 +1270,14 @@ class ServingEngine:
                 valid = slot_ids >= 0
                 pos = jnp.where(valid, positions, 0)
                 safe_slot = jnp.where(valid, slot_ids, 0)
-                if ahead:
+                if diff:
+                    # a block the host planned one pass stale: its ids
+                    # are the state the step before handed over
+                    from_prev = token_ids == batcher.PREV_TOKEN
+                    token_ids = jnp.where(
+                        from_prev, prev[0][0][safe_slot, pos % causal_block],
+                        token_ids)
+                elif ahead:
                     # a decode token the host never saw: the step before
                     # sampled it, and hands it over on the device
                     token_ids = batcher.take_prev_tokens(
@@ -1350,15 +1376,40 @@ class ServingEngine:
                     top = jnp.take_along_axis(logits, tok[:, None], 1)
                     conf = jnp.exp(top[:, 0] - jax.nn.logsumexp(
                         logits, axis=-1))
+                    state = next_blocks(f, token_ids, from_prev, prev[0],
+                                        tok.reshape(sample_index.shape),
+                                        conf.reshape(sample_index.shape))
                     tok = jnp.stack(
                         [tok, jax.lax.bitcast_convert_type(
                             conf, jnp.int32)]).reshape(
                         2, *sample_index.shape).swapaxes(0, 1)
                     logits = logits.reshape(*sample_index.shape, -1)
+                return (tok, *pools, stats, logits, state, key)
             if sel is not None:
                 return (tok, *pools, stats, logits,
                         jnp.stack(selections), key)
             return (tok, *pools, stats, logits, key)
+
+        def next_blocks(f, token_ids, from_prev, prev, cand, conf):
+            """Each fed block's state after its pass: (ids, decided,
+            passes), `prev` for a slot with no block this step."""
+            ids0, was0, n0 = prev
+            fed = f["sample_index"][:, 0] >= 0
+            rows = jnp.clip(f["sample_index"], 0, T - 1)
+            mine = from_prev[rows]
+            ids = token_ids[rows]
+            was = jnp.where(mine, was0, f["block_decided"] != 0)
+            n_pass = jnp.where(mine[:, 0], n0, f["block_passes"])
+            take = diff.decide_rows(~was, conf, n_pass)
+            # a pass that fed nothing masked was the commit
+            opens = (fed & was.all(-1))[:, None]
+            keep = ~fed[:, None]
+            return (
+                jnp.where(keep, ids0, jnp.where(
+                    opens, diff.mask_token_id,
+                    jnp.where(take, cand, ids))),
+                jnp.where(keep, was0, ~opens & (was | take)),
+                jnp.where(fed, jnp.where(opens[:, 0], 0, n_pass + 1), n0))
 
         return step
 
@@ -2549,6 +2600,14 @@ class ServingEngine:
                        reserve_region=self._sparse, buffers=buf)
         for name, table in zip(self.plan_layout.tables, self.kv.tables()):
             np.copyto(getattr(buf, name), table)
+        if self._diff:
+            # a block planned explicitly: the host's state of it (one
+            # planned as PREV_TOKEN rows takes the device's)
+            for slot, toks, _ in decode:
+                if toks[0] != batcher.PREV_TOKEN:
+                    req = self.scheduler.slots[slot]
+                    buf.block_decided[slot] = req.block_decided
+                    buf.block_passes[slot] = req.block_passes
         if self.adapters is not None:
             np.copyto(buf.adapter_ids, self._adapter_token_ids(sp))
         return sp
@@ -2558,7 +2617,8 @@ class ServingEngine:
         nowhere else: weights, pools, adapter arrays, the packed plan
         (ONE buffer: flat tokens, sample index, block tables, adapter
         ids), the tokens the step before sampled (an engine that can
-        dispatch ahead: `_prev_tokens`, which never left the device),
+        dispatch ahead: `_prev_tokens`, which never left the device; a
+        model that decodes by blocks: the blocks' state after it),
         penalty counts, the key, the device loop's `tail`. What
         the host made goes up in one `device_put`: the plan alone
         unless logit processors or the device loop are on. Live steps
@@ -2635,9 +2695,9 @@ class ServingEngine:
         """One engine iteration. Returns True when any work (tokens or
         expiries) happened, False when the engine is idle/starved.
         Pack, dispatch and readback are the device program's own
-        (`_launch_tick` and `_land_tick`, `_run_block_tick`,
-        `_run_multitick`); what they hand back is one host form, and
-        from `engine.emit` on there is one loop.
+        (`_launch_tick` and `_land_tick`, `_run_multitick`); what they
+        hand back is one host form, and from `engine.emit` on there is
+        one loop.
 
         At depth 1 (`_depth`; docs/SERVING.md "Dispatching ahead") the
         call is: plan k+1 -> pack -> dispatch k+1 -> read back step k ->
@@ -2657,9 +2717,9 @@ class ServingEngine:
             # (the flight field `trace_self`)
             self._trace_self = 0.0
         had = self._inflight is not None
-        # (with nothing in flight `plan` is called as it always was:
-        # callers wrap it, the benchmark's planted faults among them)
-        plan = sch.plan(self.drain) if had else sch.plan()
+        # (while a step is in flight the scheduler holds `drain`: callers
+        # wrap `plan`, the benchmark's planted faults among them)
+        plan = sch.plan()
         if _pmetrics._enabled and plan.expired:
             smetrics.SERVING_REQUESTS.labels("expired").inc(
                 len(plan.expired))
@@ -2674,10 +2734,9 @@ class ServingEngine:
             return had or bool(plan.expired)
         if trace_on:
             ph.mark("engine.pack")
-        if self._multitick or self._diff:
-            # loops of their own: dispatched AND read back in there
-            cur = (self._run_multitick if self._multitick
-                   else self._run_block_tick)(plan, trace_on)
+        if self._multitick:
+            # a loop of its own: dispatched AND read back in there
+            cur = self._run_multitick(plan, trace_on)
             hold, landed = False, [cur]
         else:
             cur = self._launch_tick(plan, trace_on)
@@ -2750,7 +2809,7 @@ class ServingEngine:
         """`flight` (or None) is the step in flight from here on; the
         scheduler counts it as work (`has_work`)."""
         self._inflight = flight
-        self.scheduler.step_in_flight = flight is not None
+        self.scheduler.drain = None if flight is None else self.drain
 
     def drain(self):
         """Read the step in flight back and emit its tokens, if there is
@@ -2790,9 +2849,10 @@ class ServingEngine:
         request that has ended since (on EOS, one step before the host
         learned it) was fed a row too many: its token is dropped here.
         -> the rows of LATER dispatches that this emit found wasted, the
-        same way: a request that ends here with a token still in
-        flight."""
+        same way: a request that ends here with a token (decoding by
+        blocks: a pass of L rows) still in flight."""
         sp, got, reqs = fl.sp, fl.got, fl.reqs
+        rows = self._causal_block or 1
         if got["logits"] is not None:
             # after `step()` returns, `sample_logits` is what the tokens
             # just emitted were taken from
@@ -2808,7 +2868,7 @@ class ServingEngine:
                 # request feeds its first block from the next step on
                 req.state = "decode"
             elif self.emit(req, [int(got["first"][slot])], now, trace_on):
-                wasted += req.in_flight
+                wasted += req.in_flight * rows
             elif self.role == "prefill":
                 # prefill-role handoff point: the first token is
                 # sampled, every prompt token's K/V is written — the
@@ -2824,7 +2884,7 @@ class ServingEngine:
             if req is None or req.done:
                 continue
             if self.emit(req, tokens, now, trace_on, got["verify"]):
-                wasted += req.in_flight
+                wasted += req.in_flight * rows
             elif accepted is not None:
                 self._note_accept(slot, accepted)
         return wasted
@@ -2852,7 +2912,7 @@ class ServingEngine:
         # spec: drafts proposed, drafts accepted, groups by accept length
         got = dict(verify=False, moe_stats=None, block_stats=None,
                    logits=None, selection=None, spec=(0, 0, ()),
-                   work=None, dispatch={})
+                   work=None, dispatch={}, block_state=None)
         args = self._step_args(sp, tail,
                                got["dispatch"] if trace_on else None)
         if trace_on:
@@ -2865,6 +2925,10 @@ class ServingEngine:
         elif self._block is not None:
             if self._select is not None:
                 res, got["selection"] = res[:-1], res[-1]
+            if self._diff:
+                # the blocks' state after the step: the next step's, and
+                # what a test holds the host's replay against
+                res, got["block_state"] = res[:-1], res[-1]
             # the sample rows' logits stay on the device, a row a slot:
             # nothing reads them back but a caller who asks
             # (`sample_logits`, rebound when the step's tokens are
@@ -2872,7 +2936,8 @@ class ServingEngine:
             res, got["block_stats"], got["logits"] = \
                 res[:-2], res[-2], res[-1]
         if self._ahead:
-            self._prev_tokens = res[0]
+            self._prev_tokens = got["block_state"] if self._diff \
+                else res[0]
         self.kv._set_pools(res[1:])
         self.scheduler.note_fed(plan)
         self.steps_run += 1
@@ -2881,19 +2946,22 @@ class ServingEngine:
     def _launch_tick(self, plan, trace_on):
         """Pack and dispatch one tick of the mixed step; nothing is read
         back. -> the `_Flight`. The requests it will hand a token (a
-        decode slot's, a completed prefill's) count it as in flight
-        from here, and a completed prefill decodes from here: the next
-        plan may be made before this step is read."""
+        decode slot's, a completed prefill's) or a block's pass count it
+        as in flight from here, and a completed prefill decodes from
+        here: the next plan may be made before this step is read."""
         reqs = self._fed_requests(plan)
         sp = self._pack(plan.decode, plan.prefills)
         out, got = self._dispatch(plan, sp, (), trace_on)
         got.update(verify=bool(self.draft_k),
                    decode_tokens=sp.decode_tokens)
-        owed = [reqs[slot] for slot in sp.decode_slots + sp.prefill_done]
+        owed = [reqs[slot] for slot in sp.decode_slots]
+        if not self._diff:      # (a block model's prefill samples nothing)
+            owed += [reqs[slot] for slot in sp.prefill_done]
         for req in owed:
             req.in_flight += 1
-            if req.state == "prefill":
-                req.state = "decode"
+        for slot in sp.prefill_done:
+            if reqs[slot].state == "prefill":
+                reqs[slot].state = "decode"
         if self._depth:
             # the copy to the host starts when the step ends, not when
             # the next call asks for it
@@ -2912,9 +2980,9 @@ class ServingEngine:
     def _land_tick(self, fl):
         """Read a dispatched tick back, into its host form (`fl.got`):
         `first` the sampled token a slot (what a completed prefill
-        emits), `groups` a decode slot's (slot, tokens to emit, length
-        to roll the slot back to or None), and the counters only this
-        program has."""
+        emits; None decoding by blocks), `groups` a decode slot's (slot,
+        tokens to emit, length to roll the slot back to or None), and
+        the counters only this program has."""
         import jax
         sp, got, out = fl.sp, fl.got, fl.out
         for req in fl.owed:
@@ -2931,6 +2999,9 @@ class ServingEngine:
                     (out, got["block_stats"]))
             else:
                 tok_np = np.asarray(out)
+            if self._diff:
+                got.update(first=None, groups=self._land_blocks(fl, tok_np))
+                return
             got.update(first=tok_np, groups=[
                 (slot, [int(tok_np[slot])], None)
                 for slot in sp.decode_slots])
@@ -2962,52 +3033,42 @@ class ServingEngine:
             groups.append((slot, emitted, pos + m + 1))
         got.update(first=tok_np, groups=groups, spec=(prop, acc, hist))
 
-    def _run_block_tick(self, plan, trace_on):
-        """`_launch_tick` and `_land_tick` in one, for a model that
-        decodes by blocks (-> the `_Flight`, read back): a decode
-        entry fed its slot's current block of L rows, and the step hands
-        back every row's candidate and confidence. Here the block's
-        state moves, by POSITION (an id equal to the mask id means
-        nothing): a slot pass that fed masked rows is a DENOISE pass,
-        the model's rule (`BlockDecoding.decide`) picks the masked
-        positions that take their candidate for good, and the block's
-        decided tokens that follow what the client already has go out,
-        in position order (`groups`; none where an earlier position is
-        still masked); a pass that fed no masked row was the COMMIT
-        (`Scheduler.note_fed` grew the slot by the block and opened the
-        next one): its outputs are unused. A pass yields 0..L tokens. A
-        completed prefill samples nothing (`first` is None)."""
-        bd, sch = self._diff, self.scheduler
-        L = bd.block_length
-        # the states the plan was made from: the dispatch notes the plan
-        # fed, and a committed block's state is the next block's by then
-        fed = [(slot, sch.slots[slot], list(sch.slots[slot].block_decided),
-                toks, pos) for slot, toks, pos in plan.decode]
-        reqs = self._fed_requests(plan)
-        sp = self._pack(plan.decode, plan.prefills)
-        out, got = self._dispatch(plan, sp, (), trace_on)
-        self._flush_deferred()      # behind the device
-        got.update(decode_tokens=sp.decode_tokens, first=None)
-        if trace_on:
-            import jax
-            got["work"] = self._plan_work(plan)
-            # the block's counters with the candidates: one readback
-            both, got["block_stats"] = jax.device_get(
-                (out, got["block_stats"]))
-        else:
-            both = np.asarray(out)              # [S, 2, L] int32
+    def _land_blocks(self, fl, both):
+        """The block passes of a step read back (`both`: int32 `[S, 2,
+        L]`, each row's candidate and the bits of its confidence): the
+        step has decided on the device, and the host replays the same
+        decision on its own state of each block (`Request.block_*`, the
+        state after the pass before: passes land in order), by POSITION
+        (an id equal to the mask id means nothing). A pass that fed
+        masked rows was a DENOISE pass: the model's rule
+        (`BlockDecoding.decide`) picks the masked positions that take
+        their candidate for good, and the block's decided tokens that
+        follow what the client already has go out, in position order
+        (-> `groups`; none where an earlier position is still masked). A
+        pass that fed no masked row was the COMMIT: the slot grows by
+        the block and the next block opens (`Scheduler.note_commit`),
+        its outputs are unused. A pass yields 0..L tokens. A request that
+        ended when the pass before landed was fed a pass too many
+        (`emit` counted its rows as wasted): nothing of it is replayed."""
+        bd, L = self._diff, self._diff.block_length
         cand, conf = both[:, 0], both[:, 1].view(np.float32)
-        groups, masked_rows, decided, commits = [], 0, 0, 0
-        for slot, req, was, toks, pos in fed:
+        groups, masked_rows, decided, commits, passes = [], 0, 0, 0, 0
+        for slot, _, pos in fl.sp.decode_entries:
+            req = fl.reqs[slot]
+            if req.done:
+                continue
+            passes += 1
+            was, fed = list(req.block_decided), list(req.block_tokens)
             masked = [i for i in range(L) if not was[i]]
             take = bd.decide(masked, conf[slot], req.block_passes) \
                 if masked else []
             if self.on_block_pass is not None:
-                self.on_block_pass(req, pos, toks, was, take,
+                self.on_block_pass(req, pos, fed, was, take,
                                    [int(cand[slot, i]) for i in take])
             if not masked:
                 commits += 1
-                if trace_on:
+                self.scheduler.note_commit(req)
+                if fl.traced:
                     _tracing.TRACER.queue(
                         req.trace_id, "block_committed", replica=self.name,
                         ts=self.clock(), start=int(pos))
@@ -3029,13 +3090,13 @@ class ServingEngine:
                     break
             if end > at:
                 groups.append((slot, req.block_tokens[at:end], None))
-        got["groups"] = groups
-        if trace_on:
-            got["work"].update(
-                diff_block_len=L, diff_slot_passes=len(fed),
+        if fl.traced:
+            fl.got["work"].update(
+                diff_block_len=L, diff_slot_passes=passes,
+                diff_passes_wasted=len(fl.sp.decode_entries) - passes,
                 diff_rows_masked=masked_rows, diff_tokens_decided=decided,
                 diff_commits=commits, diff_blocks_committed=commits)
-        return _Flight(sp, got, None, reqs, trace_on)
+        return groups
 
     def _run_multitick(self, plan, trace_on):
         """`_launch_tick` and `_land_tick` in one, for the device loop
@@ -3454,7 +3515,15 @@ class ServingEngine:
         """Flush the deferred observability of the LAST step (a
         multi-tick dispatch's metrics and a traced step's flight record
         normally publish after the NEXT dispatch launches, overlapping
-        device execution). The frontend calls this when going idle."""
+        device execution). A step still in flight is read back first:
+        its record takes counters from the readback (a block model's
+        replayed decisions), and the host time of that readback, in no
+        step, is no step's phase. The frontend calls this when going
+        idle, with nothing in flight."""
+        if self._inflight is not None:
+            self.drain()
+            self.phases.take()
+            self.phases.take_own(_tracing.SUMMED_PHASES)
         self._flush_deferred()
 
     def _auto_ticks(self, n_max):

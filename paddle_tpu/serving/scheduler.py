@@ -83,16 +83,19 @@ class Request:                     # in sets/queues across state moves
     # block's first position (= the slot's committed length), its L
     # token ids (a masked position holds the mask id), which positions
     # are decided (by POSITION: a prompt's or a candidate's id may equal
-    # the mask id), and the denoise passes it has had. Kept across a
-    # preemption: what was decided is never decided again
+    # the mask id), and the denoise passes it has had: the state after
+    # the last pass READ BACK (a pass in flight moves the device's copy
+    # first). Kept across a preemption: what was decided is never
+    # decided again
     block_start: int = -1
     block_tokens: Optional[list] = None
     block_decided: Optional[list] = None
     block_passes: int = 0
     # tokens of this request that a dispatched step has sampled and the
-    # host has not read back yet (0 or 1: the engine's pipeline is one
-    # step deep; docs/SERVING.md "Dispatching ahead"). The engine counts
-    # it up at dispatch and down at readback
+    # host has not read back yet, or (decoding by blocks) passes over its
+    # block (0 or 1: the engine's pipeline is one step deep; docs/
+    # SERVING.md "Dispatching ahead"). The engine counts it up at
+    # dispatch and down at readback
     in_flight: int = 0
 
     @property
@@ -183,9 +186,11 @@ class Scheduler:
         # replica label the tracing hooks stamp on span events; the
         # owning engine overwrites it with its own name
         self.replica = None
-        # a dispatched step whose tokens the engine has not read back
-        # (the engine sets it): work, whatever the slots hold
-        self.step_in_flight = False
+        # while a dispatched step is unread, the engine's `drain` (read
+        # it back, emit its tokens; the engine sets it): work, whatever
+        # the slots hold, and what `plan` calls where it needs the step's
+        # outcome first
+        self.drain = None
 
     # ---------------------------------------------------------- intake
     def submit(self, prompt, max_new_tokens, eos_token_id=None,
@@ -254,7 +259,7 @@ class Scheduler:
     @property
     def has_work(self):
         return bool(self.queue) or self.num_active > 0 \
-            or self.step_in_flight
+            or self.drain is not None
 
     # ------------------------------------------------------- internals
     def _free_slot(self, req):
@@ -479,19 +484,25 @@ class Scheduler:
         return pos + 1 + max(k, 0)
 
     # ------------------------------------------------------------ plan
-    def plan(self, drain=None) -> Plan:
+    def plan(self) -> Plan:
         """One engine iteration's work. Mutates scheduler/cache state
         (admissions, block allocation, preemptions, expiries).
 
-        `drain`: given while a dispatched step is unread (the engine's
-        `drain`: read it back, emit its tokens). A decode slot whose
-        newest token is in that step (`Request.in_flight`) is fed the
-        sentinel `batcher.PREV_TOKEN` at its next position, and the
-        compiled step takes the token from the step before it, on the
-        device; a request whose horizon the tokens in flight reach is
-        not fed again. What needs the step's OUTCOME first calls
-        `drain()` and then runs as it always did: the expiry of a
-        request with a token in flight, and a preemption."""
+        While a dispatched step is unread (`self.drain` is set), a
+        decode slot whose newest token is in that step
+        (`Request.in_flight`) is fed the sentinel `batcher.PREV_TOKEN`
+        at its next position, and the compiled step takes the token from
+        the step before it, on the device; a request whose horizon the
+        tokens in flight reach is not fed again. Decoding by blocks, a
+        block with a pass in flight is fed L sentinels, and the step
+        takes the block's ids, decided positions and passes from the
+        state the pass in flight hands over; where the host's state
+        (one pass stale) already has the block whole, the pass in flight
+        is the commit and this one opens the next block. What needs the
+        step's OUTCOME first calls `drain()` and then runs as it always
+        did: the expiry of a request with a token in flight, and a
+        preemption."""
+        drain = self.drain
         now = self.clock()
         if drain is not None and any(
                 r is not None and r.in_flight and r.deadline is not None
@@ -520,19 +531,25 @@ class Scheduler:
             (r for r in self.slots
              if r is not None and r.state == "decode"),
             key=lambda r: r.arrival)
+        blocks = self.block_decoding is not None
         for req in decoders:
             if req.slot < 0:    # preempted by an earlier iteration
                 continue
-            if req.in_flight and len(req.output) + req.in_flight \
-                    >= req.max_new_tokens:
+            if req.in_flight and not blocks and len(req.output) \
+                    + req.in_flight >= req.max_new_tokens:
                 # the token in flight ends it by LENGTH: the host can
                 # count, and the slot is not fed again
                 continue
             # position of the token being fed = tokens already cached
-            pos = int(self.kv.slot_lens[req.slot])
-            # a block's rows are all written, every pass
-            width = 1 if self.block_decoding is None \
-                else self.block_decoding.block_length
+            pos, width = int(self.kv.slot_lens[req.slot]), 1
+            if blocks:
+                # a block's rows are all written, every pass, from the
+                # block's first position: the next block's, where the
+                # pass in flight is the commit (the same position after
+                # a drain below: the commit has landed by then)
+                width = self.block_decoding.block_length
+                pos = req.block_start + width * (
+                    req.in_flight and all(req.block_decided))
             while not self.kv.ensure_capacity(req.slot, pos + width):
                 if drain is not None:
                     # the pool is dry with a step unread: a request it
@@ -550,12 +567,10 @@ class Scheduler:
             if req.slot < 0:
                 continue
             protected.add(req)
-            if self.block_decoding is not None:
-                if pos != req.block_start:
-                    raise AssertionError(
-                        f"slot {req.slot} holds {pos} committed tokens, "
-                        f"its block starts at {req.block_start}")
-                decode.append((req.slot, list(req.block_tokens), pos))
+            if blocks:
+                ids = [batcher.PREV_TOKEN] * width if req.in_flight \
+                    else list(req.block_tokens)
+                decode.append((req.slot, ids, pos))
             elif self.draft_k > 0 and not self.device_draft:
                 decode.append((req.slot,
                                self._draft_tokens(req, pos), pos))
@@ -618,17 +633,10 @@ class Scheduler:
 
         Speculative decodes are NOT advanced here: how far a verify
         group really got is only known after the engine reads the
-        accept length back, so `note_accept` owns that bookkeeping."""
-        if self.block_decoding is not None:
-            # a block fed with nothing masked is COMMITTED: its K/V is
-            # final, the slot grows by it and the next block opens; a
-            # denoise pass leaves the slot's length where it was
-            for slot, toks, pos in plan.decode:
-                req = self.slots[slot]
-                if req is not None and all(req.block_decided):
-                    self.kv.slot_lens[slot] = pos + len(toks)
-                    self._open_block(req, pos + len(toks))
-        elif self.draft_k == 0:
+        accept length back, so `note_accept` owns that bookkeeping; nor
+        are blocks: a block grows its slot when the host learns that a
+        pass was its commit (`note_commit`)."""
+        if self.block_decoding is None and self.draft_k == 0:
             for slot, _toks, pos in plan.decode:
                 self.kv.slot_lens[slot] = pos + 1
         for slot, chunk, start, completes in plan.prefills:
@@ -641,6 +649,14 @@ class Scheduler:
                 req = self.slots[slot]
                 if req is not None and req.adapter_id is None:
                     self.prefix_cache.insert(slot, req.runtime_prompt)
+
+    def note_commit(self, req):
+        """The pass that fed `req`'s block with nothing masked has been
+        read back: it was the COMMIT, the block's K/V is final, the slot
+        grows by it and the next block opens."""
+        end = req.block_start + self.block_decoding.block_length
+        self.kv.slot_lens[req.slot] = end
+        self._open_block(req, end)
 
     def note_accept(self, slot, new_len):
         """Record a verify group's outcome: `new_len` tokens of the

@@ -64,11 +64,34 @@ def shared_engine(**kw):
     return engine(model(**kw))
 
 
-def serve(eng, prompts, new_tokens, *, interpret=False, between=None):
+def held_to_the_device(eng):
+    """Wrap `eng`'s readback: after every step read back, each live
+    request's block (the host's replay of the decision) must be the
+    state the step handed the next one on the device. -> the undo."""
+    land = eng._land_tick
+
+    def checked(fl):
+        land(fl)
+        ids, was, n = (np.asarray(a) for a in fl.got["block_state"])
+        for slot, _, _ in fl.sp.decode_entries:
+            req = fl.reqs[slot]
+            if not req.done:
+                assert (ids[slot].tolist(), was[slot].tolist(),
+                        int(n[slot])) == (req.block_tokens,
+                                          req.block_decided,
+                                          req.block_passes), slot
+
+    eng._land_tick = checked
+    return lambda: vars(eng).pop("_land_tick")
+
+
+def serve(eng, prompts, new_tokens, *, interpret=False, between=None,
+          depth=1, check=True):
     """-> (each request's tokens, each request's passes as the cell's
     driver keeps them: (block start, ids fed, decided before, positions
-    decided, their tokens, the engine's rows [L, V])). `between(eng,
-    reqs)` runs after every step."""
+    decided, their tokens, the engine's rows [L, V])), served at `depth`
+    (`check`: every decision the host replays held against the
+    device's). `between(eng, reqs)` runs after every step."""
     passes = {}
     eng.on_block_pass = lambda req, start, fed, was, take, tokens: \
         passes.setdefault(req.req_id, []).append(
@@ -76,25 +99,34 @@ def serve(eng, prompts, new_tokens, *, interpret=False, between=None):
              tuple(tokens)))
     new = new_tokens if isinstance(new_tokens, (list, tuple)) \
         else [new_tokens] * len(prompts)
-    with (interpret_mode() if interpret else contextlib.nullcontext()):
-        reqs = [eng.submit(p, max_new_tokens=n)
-                for p, n in zip(prompts, new)]
-        out = [[] for _ in reqs]
-        slots = [-1] * len(reqs)
-        while eng.scheduler.has_work:
-            had = [len(passes.get(r.req_id, ())) for r in reqs]
-            for i, r in enumerate(reqs):
-                slots[i] = r.slot if r.slot >= 0 else slots[i]
-            eng.step()
-            for i, r in enumerate(reqs):
-                slots[i] = r.slot if r.slot >= 0 else slots[i]
-                for p in passes.get(r.req_id, ())[had[i]:]:
-                    out[i].append(p + (np.asarray(
-                        eng.sample_logits[slots[i]]),))
-            if between is not None:
-                between(eng, reqs)
-    eng.on_block_pass = None
-    assert eng.kv.blocks_in_use == 0
+    eng._depth = depth
+    undo = held_to_the_device(eng) if check else lambda: None
+    try:
+        with (interpret_mode() if interpret else contextlib.nullcontext()):
+            reqs = [eng.submit(p, max_new_tokens=n)
+                    for p, n in zip(prompts, new)]
+            out = [[] for _ in reqs]
+            slots = [-1] * len(reqs)
+
+            def landed():
+                # the passes read back since, with the rows they read
+                for i, r in enumerate(reqs):
+                    slots[i] = r.slot if r.slot >= 0 else slots[i]
+                    for p in passes.get(r.req_id, ())[len(out[i]):]:
+                        out[i].append(p + (np.asarray(
+                            eng.sample_logits[slots[i]]),))
+
+            while eng.scheduler.has_work:
+                landed()
+                eng.step()
+                landed()
+                if between is not None:
+                    between(eng, reqs)
+    finally:
+        undo()
+        eng._depth = 1
+        eng.on_block_pass = None
+    assert eng.kv.blocks_in_use == 0 and eng._inflight is None
     return [list(r.output) for r in reqs], out
 
 
@@ -169,7 +201,9 @@ def test_the_comparison_catches_a_fault_of_the_program(kind):
     prompt = prompts_of((22,), seed=4)
     undo = CONTROLS.plant(kind, m.arch.num_layers)
     try:
-        _, (ps,) = serve(engine(m), prompt, 10)
+        # (a fault of the host's plan leaves the host's blocks and the
+        # device's apart: nothing to hold the replay against)
+        _, (ps,) = serve(engine(m), prompt, 10, check=False)
     finally:
         undo()
     got = compare(m, prompt[0], ps)
@@ -215,6 +249,8 @@ def test_alone_in_company_and_after_a_preemption_mid_block():
     def preempt(eng, reqs):
         r = reqs[0]
         if not hit and r.block_start == 28 and r.block_passes == 2:
+            # as a plan that preempts: the pass in flight is read first
+            eng.drain()
             before = (r.block_start, list(r.block_tokens),
                       list(r.block_decided))
             assert eng.scheduler._preempt_victim(set()) is r
@@ -231,13 +267,166 @@ def test_alone_in_company_and_after_a_preemption_mid_block():
 
 
 def test_block_pressure_preempts_and_resumes():
+    """A pool too small for three requests: the plan that has to preempt
+    reads the pass in flight back first, then runs as the synchronous
+    order does: the same victims, the same passes, the same tokens."""
     m = model()
     prompts = prompts_of((40, 23, 31), seed=8)
     want, _ = serve(shared_engine(), prompts, 12)
     eng = engine(m, blocks=13)
-    got, _ = serve(eng, prompts, 12)
+    got, passes = serve(eng, prompts, 12)
     assert eng.scheduler.preemption_count > 0
     assert got == want
+    n = eng.scheduler.preemption_count
+    sync, passes0 = serve(eng, prompts, 12, depth=0)
+    assert sync == want
+    assert eng.scheduler.preemption_count == 2 * n
+    assert [[p[:5] for p in ps] for ps in passes] \
+        == [[p[:5] for p in ps] for ps in passes0]
+
+
+# ------------------------------------------------- depth 1 against depth 0
+
+
+@pytest.mark.parametrize("rule,threshold,steps", (
+    ("low_confidence_static", 0.9, 4), ("low_confidence_static", 0.9, 2),
+    ("low_confidence_dynamic", 0.0145, 4),
+    ("low_confidence_dynamic", 0.9, 4), ("low_confidence_dynamic", 0.1, 4)))
+def test_the_device_decides_as_the_host_rule(rule, threshold, steps):
+    """`BlockDecoding.decide_rows` (the step's) against `decide` (the
+    host's replay) on random confidences and masks at every pass count,
+    with exact ties and the float32 neighbours of the threshold (whose
+    float32 lies below it at 0.9 and above it at 0.1)."""
+    import jax.numpy as jnp
+    bd = model(rule=rule, threshold=threshold, steps=steps) \
+        .arch.block_decoding
+    rng = np.random.default_rng(len(rule) + steps)
+    S = 512
+    near = np.float32(threshold)
+    pool = np.asarray([np.nextafter(near, np.float32(0)), near,
+                       np.nextafter(near, np.float32(1)),
+                       np.float32(0.89999998), np.float32(0.90000004),
+                       0.25, 0.5, 0.5, 0.015], np.float32)
+    conf = np.where(rng.random((S, L)) < 0.5,
+                    rng.choice(pool, (S, L)),
+                    rng.random((S, L)).astype(np.float32))
+    conf[:8] = conf[:8, :1]                     # every position tied
+    masked = rng.random((S, L)) < 0.7
+    masked[:, 0] |= ~masked.any(-1)             # a pass masks one at least
+    n_pass = rng.integers(0, L + 2, S).astype(np.int32)
+    got = np.asarray(bd.decide_rows(jnp.asarray(masked), jnp.asarray(conf),
+                                    jnp.asarray(n_pass)))
+    for s in range(S):
+        want = bd.decide([i for i in range(L) if masked[s, i]], conf[s],
+                         int(n_pass[s]))
+        assert np.flatnonzero(got[s]).tolist() == want, (s, conf[s])
+    if rule == "low_confidence_dynamic" and threshold == 0.9:
+        # above the threshold in double precision: 0.90000004 is,
+        # float32(0.9) = 0.89999998 is not
+        row = jnp.asarray([[0.89999998, 0.90000004, 0.1, 0.2]], jnp.float32)
+        one = jnp.ones((1, L), bool)
+        assert np.asarray(bd.decide_rows(
+            one, row, jnp.asarray([0]))).tolist() == [[False, True,
+                                                       False, False]]
+
+
+@pytest.mark.parametrize("rule,threshold,steps", (
+    ("low_confidence_static", 0.9, 4), ("low_confidence_static", 0.9, 2),
+    ("low_confidence_dynamic", 0.0145, 4)))
+def test_depth_1_is_depth_0_pass_for_pass(rule, threshold, steps):
+    """The same compiled step in both orders, prompts that end inside a
+    block (and one shorter than a block): the same tokens, the same
+    passes (block start, ids fed, decided before, decided, tokens) and
+    the same rows bit for bit, steps that feed the same rows; every
+    decision the host replays is the device's (`serve` holds them)."""
+    from paddle_tpu.serving import tracing
+    m = model(rule=rule, threshold=threshold, steps=steps)
+    eng = shared_engine(rule=rule, threshold=threshold, steps=steps)
+    prompts = prompts_of((9, 18, 23, 3), seed=10)
+    new = (9, 6, 11, 7)
+    got, recs = {}, {}
+    for depth in (1, 0):
+        had = len(eng.flight.records)
+        tracing.enable()
+        try:
+            got[depth] = serve(eng, prompts, new, depth=depth)
+        finally:
+            tracing.disable()
+        eng.flush_observability()
+        recs[depth] = list(eng.flight.records)[had:]
+    (a, pa), (b, pb) = got[1], got[0]
+    assert a == b == [m.generate(p, n) for p, n in zip(prompts, new)]
+    assert [[p[:5] for p in ps] for ps in pa] \
+        == [[p[:5] for p in ps] for ps in pb]
+    assert all((x[5] == y[5]).all() for ps, qs in zip(pa, pb)
+               for x, y in zip(ps, qs))
+    # every step but the first dispatched over an unread one; a request
+    # that ended had one pass of L rows in flight, and nothing else
+    # differs between the steps of the two orders
+    assert [r["ahead"] for r in recs[1]] == [0] + [1] * (len(recs[1]) - 1)
+    assert not any(r["ahead"] for r in recs[0])
+    assert sum(r["diff_passes_wasted"] for r in recs[1]) == len(prompts)
+    assert not any(r["diff_passes_wasted"] for r in recs[0])
+
+    def fed(rs):
+        return [(r["prefill_tokens"],
+                 r["decode_tokens"] - L * r["diff_passes_wasted"],
+                 r["diff_slot_passes"], r["diff_tokens_decided"],
+                 r["diff_commits"]) for r in rs
+                if r["prefill_tokens"] or r["diff_slot_passes"]]
+    assert fed(recs[1]) == fed(recs[0])
+
+
+@functools.lru_cache(maxsize=None)
+def clocked_engine():
+    clock = types.SimpleNamespace(now=0.0)
+    return engine(model(), clock=lambda: clock.now), clock
+
+
+@pytest.mark.parametrize("end", ("cancel", "expiry"))
+def test_an_end_with_a_pass_in_flight_drains_first(end):
+    """A cancel, and a plan that expires a request, read the pass in
+    flight back first: at depth 1 the request ends with every pass that
+    was dispatched for it, as at depth 0 one pass later, and the other
+    request is served the same way in both orders."""
+    eng, clock = clocked_engine()
+    prompts = prompts_of((10, 17), seed=12)
+    out = {}
+    for depth in (1, 0):
+        clock.now = 0.0
+        eng._depth = depth
+        passes = {}
+        eng.on_block_pass = lambda req, start, fed, was, take, tokens: \
+            passes.setdefault(req.req_id, []).append(
+                (start, tuple(fed), tuple(was), tuple(take), tuple(tokens)))
+        undo = held_to_the_device(eng)
+        try:
+            req = eng.submit(prompts[0], max_new_tokens=20, deadline=5.0)
+            keep = eng.submit(prompts[1], max_new_tokens=12)
+            # depth 1: the sixth pass is in flight when the fifth is read
+            while len(passes.get(req.req_id, ())) < 5 + (1 - depth):
+                eng.step()
+            if depth:
+                assert req.in_flight == 1 and eng._inflight is not None
+            if end == "cancel":
+                assert eng.cancel(req)
+            else:
+                clock.now = 6.0
+                assert eng.step()
+            assert req.state == ("cancelled" if end == "cancel"
+                                 else "expired")
+            assert req.in_flight == 0 and req.slot == -1
+            assert len(passes[req.req_id]) == 6
+            eng.run()
+        finally:
+            undo()
+            eng._depth = 1
+            eng.on_block_pass = None
+        assert keep.state == "finished" and eng.kv.blocks_in_use == 0
+        out[depth] = (list(req.output), passes[req.req_id],
+                      list(keep.output), passes[keep.req_id])
+    assert out[1] == out[0]
+    assert out[1][2] == model().generate(prompts[1], 12)
 
 
 # ------------------------------------------------------------- the rules
@@ -272,47 +461,81 @@ def test_a_mask_id_in_the_prompt_or_among_the_candidates_is_a_token():
     (out,), (ps,) = serve(shared_engine(), [prompt], 6)
     assert out == m.generate(prompt, 6)
     assert ps[0][1][:2] == (prompt[8], MASK) and ps[0][2][:2] == (True, True)
-    # a decided token equal to the mask id is not masked again: every
-    # decided id of the block is overwritten with it after each step
-    eng, seen = shared_engine(), []
-    eng.on_block_pass = lambda req, start, fed, was, take, tokens: \
-        seen.append((fed, was))
-    req = eng.submit(prompts_of((8,))[0], max_new_tokens=4)
-    while eng.scheduler.has_work:
-        eng.step()
-        for i, d in enumerate(req.block_decided or ()):
-            if d:
-                req.block_tokens[i] = MASK
-    eng.on_block_pass = None
-    assert len(seen) == 4 and len(req.output) == 4
-    assert [sum(was) for _, was in seen] == [0, 1, 2, 3]
-    assert all(list(fed) == [MASK] * L for fed, _ in seen)
+    # a decided token equal to the mask id is not masked again: after
+    # each step every decided id of a block is overwritten with it, in
+    # the host's state and in the state the step in flight hands the
+    # next one on the device; the device's decisions stay the host's
+    import jax.numpy as jnp
+    eng = shared_engine()
+    for depth in (1, 0):
+        seen = []
+        eng._depth = depth
+        undo = held_to_the_device(eng)
+        eng.on_block_pass = lambda req, start, fed, was, take, tokens: \
+            seen.append((fed, was))
+        try:
+            req = eng.submit(prompts_of((8,))[0], max_new_tokens=4)
+            while eng.scheduler.has_work:
+                eng.step()
+                for i, d in enumerate(req.block_decided or ()):
+                    if d:
+                        req.block_tokens[i] = MASK
+                ids, was, n = eng._prev_tokens
+                eng._prev_tokens = (jnp.where(was, MASK, ids), was, n)
+        finally:
+            undo()
+            eng._depth = 1
+            eng.on_block_pass = None
+        assert len(seen) == 4 and len(req.output) == 4
+        assert [sum(was) for _, was in seen] == [0, 1, 2, 3]
+        assert all(list(fed) == [MASK] * L for fed, _ in seen)
 
 
 # ------------------------------------------- scheduler, batcher, refusals
 
 
 def test_a_slot_advances_at_a_commit_and_only_then():
+    """The slot holds what is COMMITTED: it grows by L when the host
+    learns that a pass was its block's commit (the pass is read back),
+    never at a denoise pass. At depth 1 the next block's first pass is
+    dispatched before that, over the commit in flight, at the block's
+    end; at depth 0 never."""
     m = model()
     eng = shared_engine()
-    req = eng.submit(prompts_of((13,), seed=3)[0], max_new_tokens=11)
-    lens = []
-    while eng.scheduler.has_work:
-        slot = req.slot
-        fed_all = req.block_decided is not None and all(req.block_decided) \
-            and req.state == "decode"
-        before = int(eng.kv.slot_lens[slot]) if slot >= 0 else None
-        plan_before = req.state
-        eng.step()
-        if req.slot < 0:
-            break
-        after = int(eng.kv.slot_lens[req.slot])
-        if plan_before == "decode":
-            assert after == before + (L if fed_all else 0)
-            assert after % L == 0 and after == req.block_start
-        lens.append(after)
-    assert lens[0] == 12 and max(lens) == 20
-    assert req.output == m.generate(req.prompt, 11)
+    prompt = prompts_of((13,), seed=3)[0]
+    for depth in (1, 0):
+        eng._depth = depth
+        starts, over = [], []
+
+        def note(req, start, fed, was, take, tokens):
+            assert int(eng.kv.slot_lens[req.slot]) == start \
+                == req.block_start
+            starts.append(start)
+
+        launch = eng._launch_tick
+
+        def launched(plan, trace_on):
+            for slot, _, pos in plan.decode:
+                held = int(eng.kv.slot_lens[slot])
+                assert pos in (held, held + L)
+                over.append(pos == held + L)
+            return launch(plan, trace_on)
+
+        eng.on_block_pass, eng._launch_tick = note, launched
+        try:
+            req = eng.submit(prompt, max_new_tokens=11)
+            eng.run()
+        finally:
+            eng.on_block_pass = None
+            vars(eng).pop("_launch_tick")
+            eng._depth = 1
+        # 13 -> 24: blocks at 12 (its first position the prompt's),
+        # 16 and 20; the last one's commit is the pass too many
+        assert sorted(set(starts)) == [12, 16, 20] and starts == \
+            sorted(starts)
+        assert sum(over) == (2 if depth else 0)
+        assert req.output == m.generate(prompt, 11)
+        assert eng.ahead_wasted_rows % L == 0
 
 
 def test_plan_entries_are_lists_from_a_first_position():
@@ -325,12 +548,10 @@ def test_plan_entries_are_lists_from_a_first_position():
     while eng.scheduler.has_work:
         plan = eng.scheduler.plan()
         seen += plan.decode
-        # (drive the planned step by hand: what step() does)
-        flight = eng._run_block_tick(plan, False)
-        sp, got = flight.sp, flight.got
-        for slot in sp.prefill_done:
-            eng.scheduler.slots[slot].state = "decode"
-        for slot, tokens, _ in got["groups"]:
+        # (drive the planned step by hand: what step() does at depth 0)
+        flight = eng._launch_tick(plan, False)
+        eng._land_tick(flight)
+        for slot, tokens, _ in flight.got["groups"]:
             eng.emit(eng.scheduler.slots[slot], tokens, 0.0, False)
     assert seen and all(isinstance(t, list) and len(t) == L
                         and pos % L == 0 for _, t, pos in seen)
@@ -418,7 +639,8 @@ def test_flight_record_spans_and_scopes():
     finally:
         tracing.disable()
     recs = list(eng.flight.records)
-    fields = ("diff_block_len", "diff_slot_passes", "diff_rows_masked",
+    fields = ("diff_block_len", "diff_slot_passes", "diff_passes_wasted",
+              "diff_rows_masked",
               "diff_tokens_decided", "diff_commits",
               "diff_blocks_committed", "kv_tokens_read_full",
               "attn_pairs_full", "moe_pairs_total", "moe_pairs_local",
@@ -428,8 +650,10 @@ def test_flight_record_spans_and_scopes():
     for r in recs:
         assert set(fields) <= set(r)
         assert r["diff_block_len"] == L
-        # decode_tokens counts the ROWS fed
-        assert r["decode_tokens"] == L * r["diff_slot_passes"]
+        # decode_tokens counts the ROWS fed, a request's pass dispatched
+        # after its end among them
+        assert r["decode_tokens"] == \
+            L * (r["diff_slot_passes"] + r["diff_passes_wasted"])
         assert r["diff_commits"] == r["diff_blocks_committed"] \
             <= r["diff_slot_passes"]
         assert r["diff_tokens_decided"] == \
@@ -443,6 +667,11 @@ def test_flight_record_spans_and_scopes():
             layers * heads * r["attn_pairs_full"]
         assert r["attn_logits_issued"] >= r["attn_logits_useful"] > 0
     assert sum(r["diff_tokens_decided"] for r in recs) >= 30
+    # at depth 1 each request had one pass in flight when it ended
+    assert eng._depth == 1
+    assert sum(r["diff_passes_wasted"] for r in recs) == len(reqs)
+    assert eng.ahead_wasted_rows == L * len(reqs) \
+        == sum(r["ahead_wasted_rows"] for r in recs)
     assert sum(r["diff_commits"] for r in recs) == 2 + 2 + 2
     for req in reqs:
         t = traces[req.trace_id]

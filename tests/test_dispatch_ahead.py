@@ -1,9 +1,10 @@
 """The engine dispatches step k+1 before it reads step k back (ISSUE 36;
 docs/SERVING.md "Dispatching ahead"): the pipelined order (`_depth` 1)
 against the synchronous one (`_depth` 0, the same compiled step) on the
-GPT engine, a tiny AFMoE (window tables) and a tiny Olmo-Hybrid
-(recurrent state); a request that ends on EOS one step before the host
-learns it; the rare paths that drain first (a preemption, a cancel, an
+GPT engine, a tiny AFMoE (window tables), a tiny Olmo-Hybrid (recurrent
+state) and a tiny SDAR (decoding by blocks, the decision on the device;
+more in `tests/test_block_diffusion_serving.py`); a request that ends
+on EOS one step before the host learns it; the rare paths that drain first (a preemption, a cancel, an
 expiry); `sample_logits` as the drivers' sentinel loop reads it; the
 drain at the end of `run()`; the one compile; the engines that stay at
 depth 0. (The reader of `mixed_step.dispatch_ahead_pct` is tested beside
@@ -302,31 +303,57 @@ def test_has_work_holds_while_a_step_is_in_flight_and_run_drains():
     assert len(req.output) == 8 and eng.step_compile_count() == 1
 
 
-# ---------------------------------------- what stays at depth 0, and why
+# --------------------------------------------------- decoding by blocks
 def _diffusion():
     import test_block_diffusion_serving as bd
-    return bd.engine(bd.model()), 32
+    return bd.engine(bd.model()), bd
 
 
+def test_depth_1_is_depth_0_decoding_by_blocks():
+    """A model that decodes by blocks runs at depth 1: a block's pass is
+    planned one pass stale (its ids, decided positions and passes come
+    from the device) and the device decides, so every step but the
+    first is dispatched over an unread one. Mixed prefill and decode, as
+    many requests as slots: the same tokens as the synchronous order,
+    the same steps, and the rows a request's last pass fed after its end
+    counted as wasted."""
+    eng, bd = _diffusion()
+    assert eng._ahead and eng._depth == 1
+    prompts = prompts_of((41, 5, 19, 9), bd.VOCAB - 1)
+    new = (9, 14, 6, 11)
+    ahead, recs1 = serve(eng, prompts, new, 1)
+    wasted = eng.ahead_wasted_rows
+    sync, recs0 = serve(eng, prompts, new, 0)
+    assert ahead == sync == [bd.model().generate(p, n)
+                             for p, n in zip(prompts, new)]
+    assert [r["ahead"] for r in recs1] == [0] + [1] * (len(recs1) - 1)
+    recs0 = recs0[len(recs1):]
+    assert not any(r["ahead"] for r in recs0)
+    assert wasted == bd.L * len(prompts) == eng.ahead_wasted_rows
+    fed = lambda recs: [  # noqa: E731
+        (r["prefill_tokens"],
+         r["decode_tokens"] - bd.L * r["diff_passes_wasted"])
+        for r in recs if r["prefill_tokens"] or r["diff_slot_passes"]]
+    assert fed(recs1) == fed(recs0)
+    assert any(p and d for p, d in fed(recs1))      # mixed steps
+    assert eng.step_compile_count() == 1
+
+
+# ---------------------------------------- what stays at depth 0, and why
 @pytest.mark.parametrize("case", [
-    "draft_k", "repetition_penalty", "ticks_per_dispatch", "prefill_role",
-    "block_diffusion"])
+    "draft_k", "repetition_penalty", "ticks_per_dispatch", "prefill_role"])
 def test_engines_that_need_the_tokens_on_the_host_stay_at_depth_0(case):
     from paddle_tpu.serving import tracing
     from paddle_tpu.serving.batcher import SamplingConfig
-    vocab = VOCAB
-    if case == "block_diffusion":
-        eng, vocab = _diffusion()
-    else:
-        eng = gpt(**{
-            "draft_k": dict(draft_k=2),
-            "repetition_penalty": dict(
-                sampling=SamplingConfig(repetition_penalty=1.3)),
-            "ticks_per_dispatch": dict(ticks_per_dispatch=4),
-            "prefill_role": dict(role="prefill")}[case])
+    eng = gpt(**{
+        "draft_k": dict(draft_k=2),
+        "repetition_penalty": dict(
+            sampling=SamplingConfig(repetition_penalty=1.3)),
+        "ticks_per_dispatch": dict(ticks_per_dispatch=4),
+        "prefill_role": dict(role="prefill")}[case])
     assert eng._depth == 0 and not eng._ahead
     reqs = [eng.submit(p, max_new_tokens=6)
-            for p in prompts_of((9, 20), vocab)]
+            for p in prompts_of((9, 20), VOCAB)]
     tracing.enable()
     try:
         for _ in range(60):
@@ -355,3 +382,4 @@ def test_the_depth_is_the_engine_s_own_business():
     assert gpt()._depth == 1 and gpt(role="decode")._depth == 1
     assert gpt(kv_dtype="int8")._depth == 1
     assert gpt(sparse_blocks=4)._depth == 1
+    assert _diffusion()[0]._depth == 1
